@@ -164,7 +164,7 @@ class TpuVmBackend(backend_lib.Backend):
                     task, candidate)
             except exceptions.InvalidTaskError as e:
                 # Volume-incompatible *candidate*, not a broken task:
-                # surface inside the failover taxonomy so the engine
+                # surface inside the failover classes so the engine
                 # moves to the next placement (one of which may host
                 # the volume) instead of aborting the launch.
                 raise exceptions.ProvisionError(str(e)) from e

@@ -1,6 +1,6 @@
 """Typed exception hierarchy for skypilot-tpu.
 
-Capability parity with the reference's error taxonomy (sky/exceptions.py), but
+Capability parity with the reference's error classes (sky/exceptions.py), but
 organized around TPU-native failure modes: slice stockouts, queued-resource
 timeouts, and preemption of whole pod slices rather than single VMs.
 """

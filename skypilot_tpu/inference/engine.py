@@ -123,6 +123,7 @@ from skypilot_tpu.perf import compile_telemetry
 from skypilot_tpu.perf import cost_model as cost_model_lib
 from skypilot_tpu.server import metrics as metrics_lib
 from skypilot_tpu.server import tracing
+from skypilot_tpu.utils import compile_cache
 
 logger = sky_logging.init_logger(__name__)
 
@@ -454,16 +455,10 @@ class DecodeEngine:
                 not self._paged):
             # The AOT layout pass is specialized to the contiguous
             # cache; the paged pool rides default layouts (its decode
-            # gathers re-tile anyway).
-            try:
-                self._optimize_layouts()
-            except Exception:  # pylint: disable=broad-except
-                # Degraded but functional: decode relays out weights as
-                # HLO temps (extra HBM). Big models may OOM — but never
-                # refuse to serve because a layout API changed.
-                logger.exception('param layout optimization failed; '
-                                 'serving with default layouts')
-                self._fmt_params = None
+            # gathers re-tile anyway).  A failure here is an error: the
+            # default layouts cost a 7B ~3 GB of HLO temps, which is an
+            # OOM a minute later with a worse message.
+            self._optimize_layouts()
         # Cost model + compile telemetry.  from_engine_state reads only
         # leaf METADATA (shape/dtype — the page pool's dtype is how a
         # future int8 KV cache lands as a measured bytes/token halving),
@@ -1145,31 +1140,42 @@ class DecodeEngine:
         _abs = self._abs_tree
         auto = jax.tree.map(lambda _: Format(Layout.AUTO), self.params)
         rng_abs = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
-        compiled = jax.jit(
-            self._decode_raw, donate_argnums=(1, 2, 3),
-            in_shardings=(auto, Format(Layout.AUTO), Format(Layout.AUTO),
-                          Format(Layout.AUTO), Format(Layout.AUTO)),
-            # Donated inputs require matching AUTO outputs (out row 0 is
-            # host-fetched; its layout is immaterial).
-            out_shardings=(Format(Layout.AUTO), Format(Layout.AUTO),
-                           Format(Layout.AUTO), Format(Layout.AUTO)),
-        ).lower(_abs(self.params), _abs(self._cache), _abs(self._last_d),
-                _abs(self._lens_d), rng_abs).compile()
+        compiled = self._compile_pinned(
+            jax.jit(
+                self._decode_raw, donate_argnums=(1, 2, 3),
+                in_shardings=(auto, Format(Layout.AUTO), Format(Layout.AUTO),
+                              Format(Layout.AUTO), Format(Layout.AUTO)),
+                # Donated inputs require matching AUTO outputs (out row 0
+                # is host-fetched; its layout is immaterial).
+                out_shardings=(Format(Layout.AUTO), Format(Layout.AUTO),
+                               Format(Layout.AUTO), Format(Layout.AUTO))),
+            _abs(self.params), _abs(self._cache), _abs(self._last_d),
+            _abs(self._lens_d), rng_abs)
         fmts, _ = compiled.input_formats
         self._fmt_params, self._fmt_cache = fmts[0], fmts[1]
         self._fmt_last, self._fmt_lens = fmts[2], fmts[3]
         # donate=True: relayout leaf-by-leaf in place — without it the
         # whole param tree exists twice mid-put (2x 13.3 GB for a 7B).
-        self.params = jax.device_put(self.params, self._fmt_params,
-                                     donate=True)
-        self._cache = jax.device_put(self._cache, self._fmt_cache,
-                                     donate=True)
-        self._last_d = jax.device_put(self._last_d, self._fmt_last,
-                                      donate=True)
-        self._lens_d = jax.device_put(self._lens_d, self._fmt_lens,
-                                      donate=True)
+        # A relayout is itself a compiled program with a pinned output.
+        with compile_cache.bypassed():
+            self.params = jax.device_put(self.params, self._fmt_params,
+                                         donate=True)
+            self._cache = jax.device_put(self._cache, self._fmt_cache,
+                                         donate=True)
+            self._last_d = jax.device_put(self._last_d, self._fmt_last,
+                                          donate=True)
+            self._lens_d = jax.device_put(self._lens_d, self._fmt_lens,
+                                          donate=True)
         self._decode = compiled
         self._params_owned = True    # relaid-out tree is engine-private
+
+    @staticmethod
+    def _compile_pinned(jitted, *abstract_args):
+        """AOT-compile a program pinned to the decode-chosen layouts,
+        outside the persistent compile cache (compile_cache.bypassed
+        says why)."""
+        with compile_cache.bypassed():
+            return jitted.lower(*abstract_args).compile()
 
     def _prefill_for(self, bucket: int, padded_n: int):
         """Prefill executable for one (bucket, batch) shape, pinned to
@@ -1184,18 +1190,18 @@ class DecodeEngine:
             toks = jax.ShapeDtypeStruct((padded_n, bucket), jnp.int32)
             vec = jax.ShapeDtypeStruct((padded_n,), jnp.int32)
             rng_abs = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
-            fn = jax.jit(
-                self._prefill_raw, donate_argnums=(1, 2, 3),
-                in_shardings=(self._fmt_params, self._fmt_cache,
-                              self._fmt_last, self._fmt_lens,
-                              None, None, None, None, None),
-                # Outputs feed the next decode call via donation — they
-                # must come back in the decode-chosen layouts.
-                out_shardings=(self._fmt_cache, self._fmt_last,
-                               self._fmt_lens),
-            ).lower(_abs(self.params), _abs(self._cache),
-                    _abs(self._last_d), _abs(self._lens_d), toks, vec, vec,
-                    vec, rng_abs).compile()
+            fn = self._compile_pinned(
+                jax.jit(
+                    self._prefill_raw, donate_argnums=(1, 2, 3),
+                    in_shardings=(self._fmt_params, self._fmt_cache,
+                                  self._fmt_last, self._fmt_lens,
+                                  None, None, None, None, None),
+                    # Outputs feed the next decode call via donation —
+                    # they must come back in the decode-chosen layouts.
+                    out_shardings=(self._fmt_cache, self._fmt_last,
+                                   self._fmt_lens)),
+                _abs(self.params), _abs(self._cache), _abs(self._last_d),
+                _abs(self._lens_d), toks, vec, vec, vec, rng_abs)
             self._prefill_compiled[key] = fn
         return fn
 
@@ -1231,11 +1237,10 @@ class DecodeEngine:
             scalar = jax.ShapeDtypeStruct((), jnp.int32)
             scratch_abs = jax.eval_shape(lambda p: self._make_cache(p, 1),
                                          self._abs_tree(self.params))
-            fn = jax.jit(
-                self._chunk_raw, donate_argnums=(1,),
-                in_shardings=(self._fmt_params, None, None, None),
-            ).lower(self._abs_tree(self.params), scratch_abs, toks,
-                    scalar).compile()
+            fn = self._compile_pinned(
+                jax.jit(self._chunk_raw, donate_argnums=(1,),
+                        in_shardings=(self._fmt_params, None, None, None)),
+                self._abs_tree(self.params), scratch_abs, toks, scalar)
             self._chunk_compiled[key] = fn
         return fn
 
@@ -1253,17 +1258,17 @@ class DecodeEngine:
             rng_abs = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
             scratch_abs = jax.eval_shape(lambda p: self._make_cache(p, 1),
                                          self._abs_tree(self.params))
-            fn = jax.jit(
-                self._chunk_insert_raw, donate_argnums=(1, 2, 3),
-                in_shardings=(self._fmt_params, self._fmt_cache,
-                              self._fmt_last, self._fmt_lens,
-                              None, None, None, None, None, None, None),
-                out_shardings=(self._fmt_cache, self._fmt_last,
-                               self._fmt_lens),
-            ).lower(self._abs_tree(self.params), self._abs_tree(self._cache),
-                    self._abs_tree(self._last_d),
-                    self._abs_tree(self._lens_d), scratch_abs, toks,
-                    scalar, scalar, scalar, scalar, rng_abs).compile()
+            fn = self._compile_pinned(
+                jax.jit(
+                    self._chunk_insert_raw, donate_argnums=(1, 2, 3),
+                    in_shardings=(self._fmt_params, self._fmt_cache,
+                                  self._fmt_last, self._fmt_lens,
+                                  None, None, None, None, None, None, None),
+                    out_shardings=(self._fmt_cache, self._fmt_last,
+                                   self._fmt_lens)),
+                self._abs_tree(self.params), self._abs_tree(self._cache),
+                self._abs_tree(self._last_d), self._abs_tree(self._lens_d),
+                scratch_abs, toks, scalar, scalar, scalar, scalar, rng_abs)
             self._chunk_compiled[key] = fn
         return fn
 
@@ -1489,7 +1494,8 @@ class DecodeEngine:
         if self._fmt_params is not None:
             # TPU layout path: lay the new tree out into the formats
             # the decode executable was pinned to.
-            return jax.device_put(params, self._fmt_params), True
+            with compile_cache.bypassed():
+                return jax.device_put(params, self._fmt_params), True
         if self._param_shardings is not None:
             # Mesh path: land the new tree (host numpy from an RL
             # learner, or another placement) in the SAME committed

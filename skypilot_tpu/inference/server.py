@@ -423,7 +423,10 @@ def main() -> None:
     import dataclasses
     import jax
     from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama, init_params
+    from skypilot_tpu.utils import compile_cache
 
+    # Before the first compile: a restarted replica reloads its programs.
+    compile_cache.enable()
     cfg = dataclasses.replace(LLAMA_CONFIGS[args.model],
                               max_seq_len=args.max_seq_len)
     if args.param_dtype:

@@ -232,7 +232,7 @@ class Attention(nn.Module):
             assert self.mesh is not None, 'ring attention needs a mesh'
             return ring.ring_attention(q, k, v, mesh=self.mesh, causal=True)
         if cfg.attention_impl == 'flash':
-            return attn_lib.flash_attention(q, k, v, True)
+            return attn_lib.flash_attention_on_mesh(q, k, v, self.mesh)
         return attn_lib.mha_reference(q, k, v, causal=True)
 
     def _decode_attend(self, q, k, v, positions):
@@ -493,4 +493,9 @@ def init_params(model: Llama, rng: jax.Array, batch: int = 1,
     cfg = model.cfg
     seq = seq or min(cfg.max_seq_len, 128)
     tokens = jnp.zeros((batch, seq), jnp.int32)
-    return model.init(rng, tokens)
+    # Without per-block remat: the parameters are the same, and an eager
+    # jax.checkpoint leaves every array it traced over alive in JAX's
+    # trace cache — under the tensor-parallel engine a whole unsharded
+    # copy stayed on device 0 (four-chip run, PR 22).
+    plain = model.clone(cfg=dataclasses.replace(cfg, remat=False))
+    return plain.init(rng, tokens)

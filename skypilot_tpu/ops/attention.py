@@ -14,10 +14,12 @@ expressed by Hq = G * Hkv (query heads grouped over kv heads).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _expand_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
@@ -80,18 +82,8 @@ def flash_attention(q: jax.Array,
     return _flash_fwd_impl(q, k, v, causal, block_size)
 
 
-def _backend() -> str:
-    """jax.default_backend(), or 'cpu' when no backend can initialize
-    (abstract-only analysis, e.g. placement validation's eval_shape
-    tracing on a machine with no usable runtime)."""
-    try:
-        return jax.default_backend()
-    except RuntimeError:
-        return 'cpu'
-
-
 def _flash_fwd_impl(q, k, v, causal, block_size):
-    if _backend() == 'tpu':
+    if jax.default_backend() == 'tpu':
         from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
         return pallas_fa.flash_attention_fwd(q, k, v, causal=causal,
                                              block_size=block_size)
@@ -99,7 +91,7 @@ def _flash_fwd_impl(q, k, v, causal, block_size):
 
 
 def _flash_fwd(q, k, v, causal, block_size):
-    if _backend() == 'tpu':
+    if jax.default_backend() == 'tpu':
         from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
         out, lse = pallas_fa.flash_attention_fwd(
             q, k, v, causal=causal, block_size=block_size,
@@ -125,3 +117,39 @@ def _flash_bwd(causal, block_size, residuals, g):
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)
+
+
+def flash_attention_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
+                            mesh: Optional[Mesh],
+                            causal: bool = True) -> jax.Array:
+    """`flash_attention` inside a program partitioned over `mesh`.
+
+    XLA cannot partition a Mosaic kernel ("wrap the call in a
+    shard_map"), so each device runs it on the rows it already holds:
+    batch and heads over the axes the sharding rules give them — the
+    layout the model's activations have.  A dimension its axes do not
+    divide stays whole: every device then repeats that work, the answer
+    is the same.
+    """
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal)
+    return _flash_attention_sharded(q, k, v, mesh=mesh, causal=causal)
+
+
+# Jitted like ring_attention, so that an eager caller (model.init under a
+# mesh) compiles the shard_map once and not once a layer.
+@functools.partial(jax.jit, static_argnames=('mesh', 'causal'))
+def _flash_attention_sharded(q, k, v, mesh, causal):
+    from skypilot_tpu.parallel import sharding as sharding_lib
+    rules = dict(sharding_lib.DEFAULT_RULES)
+    batch_axes = tuple(a for a in rules['batch'] if a in mesh.shape)
+    head_axis = rules['heads']
+    n_batch = math.prod(mesh.shape[a] for a in batch_axes)
+    n_heads = mesh.shape.get(head_axis, 1)
+    spec = P(batch_axes if q.shape[0] % n_batch == 0 else None,
+             head_axis if (q.shape[1] % n_heads == 0 and
+                           k.shape[1] % n_heads == 0) else None)
+    return jax.shard_map(
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)

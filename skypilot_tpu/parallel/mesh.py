@@ -42,19 +42,6 @@ def mesh_axes() -> Tuple[str, ...]:
     return MESH_AXES
 
 
-def shard_map_compat(f, **kwargs):
-    """`jax.shard_map` across jax versions: the top-level API (with its
-    `check_vma=` kwarg) where it exists, else the experimental module
-    (whose equivalent kwarg is `check_rep=`).  Callers pass the
-    NEW-style kwargs."""
-    sm = getattr(jax, 'shard_map', None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm
-        if 'check_vma' in kwargs:
-            kwargs['check_rep'] = kwargs.pop('check_vma')
-    return sm(f, **kwargs)
-
-
 @dataclasses.dataclass(frozen=True)
 class MeshPlan:
     """Chosen parallelism degrees; product must equal device count.

@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from skypilot_tpu.parallel.mesh import shard_map_compat
-
 
 def stack_stage_params(per_stage_params: list) -> Any:
     """[stage0_tree, stage1_tree, ...] -> one tree with leading stage
@@ -71,7 +69,7 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
     perm = [(i, (i + 1) % n_stages) for i in range(n_stages)]
 
     @functools.partial(
-        shard_map_compat, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=(P('pipeline'), P()),
         out_specs=P(),
         check_vma=False)

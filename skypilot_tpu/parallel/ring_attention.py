@@ -23,7 +23,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from skypilot_tpu.ops import attention as attn_lib
-from skypilot_tpu.parallel.mesh import shard_map_compat
 
 _NEG_INF = -1e30
 
@@ -112,7 +111,7 @@ def ring_attention(q: jax.Array,
     on 'data', heads on 'tensor').
     """
     spec_q = P(None, 'tensor', axis_name, None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_sharded, axis_name=axis_name,
                           causal=causal),
         mesh=mesh,

@@ -24,8 +24,9 @@ from typing import Optional, Sequence
 from skypilot_tpu.train import flops as flops_lib
 
 # Per-chip HBM bandwidth, GB/s (same table bench.py's per-bandwidth
-# baseline comparison uses; 'cpu' is nominal so accounting runs
-# anywhere, same convention as PEAK_BF16_TFLOPS['cpu']).
+# baseline comparison uses).  'cpu' is nominal, so the accounting runs
+# in the CPU tests, same convention as PEAK_BF16_TFLOPS['cpu']; never a
+# device number, and it goes with ROADMAP A0(b).
 HBM_GBPS = {
     'v5litepod': 819.0,
     'v5e': 819.0,

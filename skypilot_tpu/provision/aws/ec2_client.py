@@ -11,7 +11,7 @@ The interface is deliberately tiny: instances are identified by their
 ``Name`` tag (``<cluster>-<i>``) and grouped by a ``skytpu-cluster`` tag,
 mirroring the label scheme of the GCP provisioners.
 
-Error taxonomy (feeds the failover blocklists, provision/failover.py):
+Error classes (feeds the failover blocklists, provision/failover.py):
   InsufficientInstanceCapacity / SpotMaxPriceTooLow -> stockout (zone)
   VcpuLimitExceeded / *LimitExceeded               -> quota (region)
 """

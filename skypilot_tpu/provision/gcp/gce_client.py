@@ -8,7 +8,7 @@ control-plane workloads TPU slices can't: serve load balancers and
 controllers, CPU-only tasks.
 
 Shares the TPU client's auth + error-classification (same project, same
-google.auth flow, same stockout/quota taxonomy feeding the failover
+google.auth flow, same stockout/quota classes feeding the failover
 blocklists).
 """
 from __future__ import annotations

@@ -149,7 +149,7 @@ class _Client:
                 verify=self.verify, timeout=30, **kwargs)
         except requests_lib.RequestException as e:
             # Keep transport failures inside the provision-error
-            # taxonomy (SSL/conn errors otherwise escape the failover
+            # classes (SSL/conn errors otherwise escape the failover
             # engine's classification).
             raise exceptions.ProvisionError(
                 f'k8s API unreachable ({type(e).__name__}): {e}') from e

@@ -17,20 +17,27 @@ PEAK_BF16_TFLOPS = {
     'v6e': 918.0,
     'v5p': 459.0,
     'v4': 275.0,
-    'cpu': 1.0,  # nominal, so accounting runs anywhere
+    # Nominal, so the accounting runs in the CPU tests; never a device
+    # number.  Goes with ROADMAP A0(b).
+    'cpu': 1.0,
 }
 
 
 def chip_kind() -> str:
-    """Normalized device-kind name of the first local device."""
+    """Normalized device-kind name of the first local device.  A TPU
+    this table does not know is an error, not a 1 TFLOP/s 'cpu'."""
     import jax
     dev = jax.devices()[0]
-    kind = getattr(dev, 'device_kind', 'cpu').lower().replace(' ', '')
+    kind = dev.device_kind.lower().replace(' ', '')
     for name in PEAK_BF16_TFLOPS:
         if name in kind:
             return name
     if 'lite' in kind:      # 'TPU v5 lite'
         return 'v5litepod'
+    if dev.platform == 'tpu':
+        raise ValueError(
+            f'unknown TPU device_kind {dev.device_kind!r}: add its peak '
+            f'to PEAK_BF16_TFLOPS')
     return 'cpu'
 
 

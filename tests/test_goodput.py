@@ -462,6 +462,30 @@ def test_trainer_hot_loop_zero_syncs_zero_recompiles(_tiny_train,
     assert not tracing.events_for(compile_telemetry.SENTINEL_REQUEST_ID)
 
 
+def test_trainer_window_ends_after_the_log_fetch(_tiny_train, monkeypatch):
+    """Found on the chip (PR 22): with a log_fn the throughput window
+    was read before the boundary's fetch, so it held dispatch time only
+    and the MFU gauge said 20,000%.  A fetch that takes 0.2 s (a device
+    that takes 0.2 s a step) bounds the gauge from above."""
+    import jax
+    from skypilot_tpu.train.trainer import TrainConfig, Trainer
+    model, mesh, rng, tokens = _tiny_train
+    trainer = Trainer(model, mesh, rng, tokens,
+                      TrainConfig(warmup_steps=1, total_steps=20),
+                      host='h0')
+    real_get = jax.device_get
+
+    def slow_get(x):
+        time.sleep(0.2)
+        return real_get(x)
+    monkeypatch.setattr(jax, 'device_get', slow_get)
+    trainer.run(_batches(tokens), 3, log_every=1, log_fn=lambda m: None)
+    monkeypatch.setattr(jax, 'device_get', real_get)
+    gauge = [l for l in metrics_lib.render().splitlines()
+             if l.startswith('skytpu_train_tokens_per_second ')]
+    assert 0 < float(gauge[0].split()[1]) <= tokens.size / 0.2
+
+
 # ---------------------------------------------------------------------------
 # jobs top
 # ---------------------------------------------------------------------------
