@@ -20,7 +20,9 @@ this rule asserts them for EVERY call site statically:
 The flight recorder's span names (server/tracing.py) are the same kind
 of cross-process contract — the LB federates /debug views by span name
 and `skytpu trace`'s decomposition keys on them — so every
-``record_span``/``record_instant`` call site is held to the same bar:
+``record_span``/``record_instant`` call site, and every loop ``phase``
+(whose name is what a profiler session's readers look for), is held to
+the same bar:
 
 - the span name is legal (dotted lowercase, ``component.event``);
 - it has a ``SPAN_HELP`` entry in server/tracing.py.
@@ -64,8 +66,9 @@ _KINDS = {
     'observe': 'summary',
     'observe_hist': 'histogram',
 }
-# Flight-recorder registration fns (span name = 2nd positional arg).
-_SPAN_FNS = ('record_span', 'record_instant')
+# Flight-recorder registration fns -> position of the span name (the
+# recorders take the request id first; a phase has no request).
+_SPAN_FNS = {'record_span': 1, 'record_instant': 1, 'phase': 0}
 # Device-cost attribution suffixes (perf/cost_model.py): instantaneous
 # modeled ratios, legal only as gauges — see module docstring.
 _GAUGE_ONLY_SUFFIXES = ('_mfu', '_per_token', '_intensity')
@@ -154,9 +157,11 @@ class MetricNamingRule(Rule):
                     findings.extend(self._check_name(
                         project, module, node, kind, name, help_keys))
                     continue
-                if self._is_span_registration(node, module):
+                span_arg = self._span_name_arg(node, module)
+                if span_arg is not None:
                     name = self._static_name(node, module, consts,
-                                             metrics_consts, arg_idx=1)
+                                             metrics_consts,
+                                             arg_idx=span_arg)
                     if name is None:
                         continue
                     findings.extend(self._check_span_name(
@@ -184,15 +189,18 @@ class MetricNamingRule(Rule):
             return _KINDS[last]
         return None
 
-    def _is_span_registration(self, call: ast.Call,
-                              module: Module) -> bool:
+    def _span_name_arg(self, call: ast.Call,
+                       module: Module) -> Optional[int]:
+        """Position of the span name in a flight-recorder or phase
+        call; None for any other call."""
         dotted = cg._dotted(call.func)
         if dotted is None:
-            return False
+            return None
         resolved = cg.resolve_alias(dotted, module)
         last = resolved.split('.')[-1]
-        return last in _SPAN_FNS and \
-            resolved == f'{_TRACING_MODULE}.{last}'
+        if resolved == f'{_TRACING_MODULE}.{last}':
+            return _SPAN_FNS.get(last)
+        return None
 
     def _is_alert_rule(self, call: ast.Call, module: Module) -> bool:
         dotted = cg._dotted(call.func)

@@ -317,7 +317,9 @@ def trace_cmd(request_id, endpoint, chrome_out):
     process's always-on flight recorder (bounded ring, knob
     SKYTPU_TRACE_RING_SIZE); this fetches /debug/requests/<id> and
     renders the timeline plus the decomposition
-    queue wait + N x prefill chunk + dispatch = measured TTFT.
+    queue wait + N x prefill chunk + dispatch = measured TTFT, with
+    dispatch split into the prefill's wait behind the decode call in
+    flight and the first token's ride on the next one.
     """
     import json as json_lib
     import urllib.error
@@ -362,10 +364,16 @@ def trace_cmd(request_id, endpoint, chrome_out):
         chunks = s.get('prefill_chunks', 0)
         prefill_part = (f'{chunks} x chunk {s["prefill_ms"]:.1f}'
                         if chunks else f'prefill {s["prefill_ms"]:.1f}')
+        # The two parts of dispatch, where the replica records them:
+        # shown inside it, never as further terms of the sum.
+        parts = ''
+        if s.get('first_token_ride_ms'):
+            parts = (f'[prefill wait {s["prefill_wait_ms"]:.1f} + '
+                     f'first-token ride {s["first_token_ride_ms"]:.1f}] ')
         click.echo(
             f'TTFT {s["ttft_ms"]:.1f} ms = '
             f'queue {s["queue_wait_ms"]:.1f} + {prefill_part} + '
-            f'dispatch {s["dispatch_ms"]:.1f} '
+            f'dispatch {s["dispatch_ms"]:.1f} {parts}'
             f'(decomposed {s["decomposed_ttft_ms"]:.1f}, '
             f'unattributed {s["unattributed_ms"]:.1f})')
     else:

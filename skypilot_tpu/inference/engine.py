@@ -106,6 +106,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import queue
 import threading
@@ -126,6 +127,21 @@ from skypilot_tpu.server import tracing
 from skypilot_tpu.utils import compile_cache
 
 logger = sky_logging.init_logger(__name__)
+
+# Flight-recorder request id of the engine.setup.* spans: fixed, so
+# /debug/requests/engine-setup shows what a start's set-up was made of.
+SETUP_REQUEST_ID = 'engine-setup'
+
+
+def _named(fn, name: str):
+    """`fn` under another __name__.  jit names a program after its
+    function, so a pinned program carries its shape into the device
+    trace and the profiler's module list (jit_prefill_insert_b512_n16)."""
+    @functools.wraps(fn)
+    def named(*args):
+        return fn(*args)
+    named.__name__ = named.__qualname__ = name
+    return named
 
 
 @dataclasses.dataclass(frozen=True)
@@ -236,8 +252,8 @@ class Request:
 
 
 class _Slot:
-    __slots__ = ('request', 'length', 'first_pending', 'done', 'pages',
-                 'n_shared', 'toks')
+    __slots__ = ('request', 'length', 'first_pending', 'wait_end', 'done',
+                 'pages', 'n_shared', 'toks')
 
     def __init__(self, request: Request, length: int,
                  pages: Optional[List[int]] = None,
@@ -247,6 +263,12 @@ class _Slot:
         # True until the prefill-sampled first token has been emitted
         # (it arrives as row 0 of the next decode call's output).
         self.first_pending = True
+        # perf_counter stamp of the host's return from the last fetch of
+        # a decode call that did NOT carry this slot (the call in flight
+        # at admission, which the prefill sat behind on the device):
+        # where engine.prefill_wait ends and engine.first_token_ride
+        # begins.  None when no such call was fetched.
+        self.wait_end: Optional[float] = None
         # Finished (retired); set on the SLOT object so a pipelined
         # in-flight call's snapshot can tell "emit this slot's remaining
         # rows" (handoff: a successor was admitted into the slot index)
@@ -428,6 +450,14 @@ class DecodeEngine:
         self._perf_occ_sum = 0
         self._perf_window: Optional[tuple] = None
         self._perf_last: Optional[dict] = None
+        # Loop-phase seconds (tracing.phase): plain floats summed on
+        # the loop thread — no lock per phase — and flushed to the
+        # registry when the perf window rolls: busy = dispatch + emit +
+        # admit, device = the fetch, idle = the 1 ms sleeps.
+        self._loop_busy_s = 0.0
+        self._loop_device_s = 0.0
+        self._loop_idle_s = 0.0
+        self._setup_programs = 0    # engine.setup.compile spans so far
         # Minimum attribution window; benchmarks/tests shrink or grow
         # it to bracket exactly their measured region.
         self.perf_window_s = float(
@@ -458,7 +488,10 @@ class DecodeEngine:
             # gathers re-tile anyway).  A failure here is an error: the
             # default layouts cost a 7B ~3 GB of HLO temps, which is an
             # OOM a minute later with a worse message.
+            t0 = time.perf_counter()
             self._optimize_layouts()
+            tracing.record_span(SETUP_REQUEST_ID, 'engine.setup.layouts',
+                                t0, time.perf_counter())
         # Cost model + compile telemetry.  from_engine_state reads only
         # leaf METADATA (shape/dtype — the page pool's dtype is how a
         # future int8 KV cache lands as a measured bytes/token halving),
@@ -1150,7 +1183,7 @@ class DecodeEngine:
                 out_shardings=(Format(Layout.AUTO), Format(Layout.AUTO),
                                Format(Layout.AUTO), Format(Layout.AUTO))),
             _abs(self.params), _abs(self._cache), _abs(self._last_d),
-            _abs(self._lens_d), rng_abs)
+            _abs(self._lens_d), rng_abs, kind='decode')
         fmts, _ = compiled.input_formats
         self._fmt_params, self._fmt_cache = fmts[0], fmts[1]
         self._fmt_last, self._fmt_lens = fmts[2], fmts[3]
@@ -1169,13 +1202,23 @@ class DecodeEngine:
         self._decode = compiled
         self._params_owned = True    # relaid-out tree is engine-private
 
-    @staticmethod
-    def _compile_pinned(jitted, *abstract_args):
+    def _record_compile(self, t0: float, kind: str, **shape: int) -> None:
+        """One engine.setup.compile span, from `t0` to now (`shape`:
+        the program's bucket and rows, where it has them)."""
+        self._setup_programs += 1
+        tracing.record_span(SETUP_REQUEST_ID, 'engine.setup.compile', t0,
+                            time.perf_counter(), kind=kind, **shape)
+
+    def _compile_pinned(self, jitted, *abstract_args, kind: str,
+                        **shape: int):
         """AOT-compile a program pinned to the decode-chosen layouts,
         outside the persistent compile cache (compile_cache.bypassed
         says why)."""
+        t0 = time.perf_counter()
         with compile_cache.bypassed():
-            return jitted.lower(*abstract_args).compile()
+            compiled = jitted.lower(*abstract_args).compile()
+        self._record_compile(t0, kind, **shape)
+        return compiled
 
     def _prefill_for(self, bucket: int, padded_n: int):
         """Prefill executable for one (bucket, batch) shape, pinned to
@@ -1192,7 +1235,9 @@ class DecodeEngine:
             rng_abs = jax.ShapeDtypeStruct(self._rng.shape, self._rng.dtype)
             fn = self._compile_pinned(
                 jax.jit(
-                    self._prefill_raw, donate_argnums=(1, 2, 3),
+                    _named(self._prefill_raw,
+                           f'prefill_insert_b{bucket}_n{padded_n}'),
+                    donate_argnums=(1, 2, 3),
                     in_shardings=(self._fmt_params, self._fmt_cache,
                                   self._fmt_last, self._fmt_lens,
                                   None, None, None, None, None),
@@ -1201,7 +1246,8 @@ class DecodeEngine:
                     out_shardings=(self._fmt_cache, self._fmt_last,
                                    self._fmt_lens)),
                 _abs(self.params), _abs(self._cache), _abs(self._last_d),
-                _abs(self._lens_d), toks, vec, vec, vec, rng_abs)
+                _abs(self._lens_d), toks, vec, vec, vec, rng_abs,
+                kind='prefill', bucket=bucket, rows=padded_n)
             self._prefill_compiled[key] = fn
         return fn
 
@@ -1238,9 +1284,11 @@ class DecodeEngine:
             scratch_abs = jax.eval_shape(lambda p: self._make_cache(p, 1),
                                          self._abs_tree(self.params))
             fn = self._compile_pinned(
-                jax.jit(self._chunk_raw, donate_argnums=(1,),
+                jax.jit(_named(self._chunk_raw, f'prefill_chunk_w{width}'),
+                        donate_argnums=(1,),
                         in_shardings=(self._fmt_params, None, None, None)),
-                self._abs_tree(self.params), scratch_abs, toks, scalar)
+                self._abs_tree(self.params), scratch_abs, toks, scalar,
+                kind='chunk', bucket=width)
             self._chunk_compiled[key] = fn
         return fn
 
@@ -1260,7 +1308,9 @@ class DecodeEngine:
                                          self._abs_tree(self.params))
             fn = self._compile_pinned(
                 jax.jit(
-                    self._chunk_insert_raw, donate_argnums=(1, 2, 3),
+                    _named(self._chunk_insert_raw,
+                           f'prefill_chunk_insert_b{bucket}'),
+                    donate_argnums=(1, 2, 3),
                     in_shardings=(self._fmt_params, self._fmt_cache,
                                   self._fmt_last, self._fmt_lens,
                                   None, None, None, None, None, None, None),
@@ -1268,7 +1318,8 @@ class DecodeEngine:
                                    self._fmt_lens)),
                 self._abs_tree(self.params), self._abs_tree(self._cache),
                 self._abs_tree(self._last_d), self._abs_tree(self._lens_d),
-                scratch_abs, toks, scalar, scalar, scalar, scalar, rng_abs)
+                scratch_abs, toks, scalar, scalar, scalar, scalar, rng_abs,
+                kind='chunk_insert', bucket=bucket)
             self._chunk_compiled[key] = fn
         return fn
 
@@ -1574,18 +1625,33 @@ class DecodeEngine:
         real admission overwrites it).  This matters most exactly here:
         a 70B-class sharded program is the longest compile in the
         system, and must not be paid under live traffic.
+
+        Either way each program leaves an engine.setup.compile span and
+        the whole an engine.setup.prewarm span (rid "engine-setup").
         """
-        if self._mesh is not None:
-            self._prewarm_mesh()
-            compile_telemetry.arm()
-            return
-        if self._fmt_params is None:
+        if self._mesh is None and self._fmt_params is None:
             # Lazy-compile path (no TPU layout pass): nothing was
             # compiled here, so arming the recompile sentinel would
             # flag the first LEGITIMATE compiles.  Callers that warm
             # their shapes by running them opt in via
             # arm_recompile_sentinel().
             return
+        t0 = time.perf_counter()
+        before = self._setup_programs
+        if self._mesh is not None:
+            self._prewarm_mesh()
+        else:
+            self._prewarm_pinned()
+        # The full admissible shape set is compiled: any compile after
+        # this point is a mid-traffic stall — arm the runtime sentinel
+        # (the twin of the static recompile-hazard rule).
+        compile_telemetry.arm()
+        tracing.record_span(SETUP_REQUEST_ID, 'engine.setup.prewarm', t0,
+                            time.perf_counter(),
+                            programs=self._setup_programs - before)
+
+    def _prewarm_pinned(self) -> None:
+        """AOT-compile every pinned prefill and chunk program."""
         # Include the first power of two >= n_slots: _admit_group pads to
         # the NEXT power of two, which exceeds n_slots when n_slots is not
         # itself one (n_slots=6, burst of 5 -> pad 8) — without it the
@@ -1595,14 +1661,12 @@ class DecodeEngine:
             for size in self._prewarm_sizes():
                 self._prefill_for(bucket, size)
         if self._chunking_possible():
-            self._new_scratch()     # compiles the scratch-init program
+            # The scratch-init program is not pinned: it compiles by
+            # running.
+            self._warm('scratch', self._new_scratch)
             self._chunk_for(self.cfg.prefill_buckets[-1])
             for bucket in self.cfg.prefill_buckets:
                 self._chunk_insert_for(bucket)
-        # The full admissible shape set is compiled: any compile after
-        # this point is a mid-traffic stall — arm the runtime sentinel
-        # (the twin of the static recompile-hazard rule).
-        compile_telemetry.arm()
 
     def _chunking_possible(self) -> bool:
         """True when an admissible prompt can exceed the largest bucket
@@ -1620,6 +1684,15 @@ class DecodeEngine:
             n *= 2
         return sizes
 
+    def _warm(self, kind: str, fn, *args, **shape: int):
+        """One dummy dispatch of prewarm.  The call returns when the
+        shape is traced and compiled (the dispatch itself is async), so
+        its host time is the program's compile time."""
+        t0 = time.perf_counter()
+        out = fn(*args)
+        self._record_compile(t0, kind, **shape)
+        return out
+
     def _prewarm_mesh(self):
         """Compile every sharded shape by executing dummy dispatches.
 
@@ -1636,20 +1709,14 @@ class DecodeEngine:
                 tokens = jnp.zeros((size, bucket), jnp.int32)
                 ones = jnp.ones((size,), jnp.int32)
                 zeros = jnp.zeros((size,), jnp.int32)
-                if self._paged:
-                    rows = jnp.broadcast_to(trash_row[None, :],
-                                            (size, self._pages_per_slot))
-                    (self._cache, self._last_d,
-                     self._lens_d) = self._prefill_insert(
-                         self.params, self._cache, self._last_d,
-                         self._lens_d, tokens, ones, zeros, rows, zeros,
-                         self._next_rng())
-                else:
-                    (self._cache, self._last_d,
-                     self._lens_d) = self._prefill_insert(
-                         self.params, self._cache, self._last_d,
-                         self._lens_d, tokens, ones, zeros, zeros,
-                         self._next_rng())
+                pt_rows = ((jnp.broadcast_to(
+                    trash_row[None, :], (size, self._pages_per_slot)),)
+                           if self._paged else ())
+                (self._cache, self._last_d, self._lens_d) = self._warm(
+                    'prefill', self._prefill_insert, self.params,
+                    self._cache, self._last_d, self._lens_d, tokens, ones,
+                    zeros, *pt_rows, zeros, self._next_rng(),
+                    bucket=bucket, rows=size)
         if self._chunking_possible() or (self._paged and
                                          self._radix is not None):
             # Chunked-prefill shapes: one intermediate-chunk program
@@ -1660,61 +1727,57 @@ class DecodeEngine:
             chunk = self.cfg.prefill_buckets[-1]
             one = jnp.ones((), jnp.int32)
             zero = jnp.zeros((), jnp.int32)
+            # One scratch serves every insert: the insert programs do
+            # not donate it.
+            scratch = self._warm(
+                'chunk', self._prefill_chunk, self.params,
+                self._warm('scratch', self._new_scratch),
+                jnp.zeros((1, chunk), jnp.int32), zero, bucket=chunk)
+            pt_row = (trash_row,) if self._paged else ()
             for bucket in self.cfg.prefill_buckets:
-                scratch = self._prefill_chunk(
-                    self.params, self._new_scratch(),
-                    jnp.zeros((1, chunk), jnp.int32), zero)
-                if self._paged:
-                    (self._cache, self._last_d,
-                     self._lens_d) = self._chunk_insert(
-                         self.params, self._cache, self._last_d,
-                         self._lens_d, scratch,
-                         jnp.zeros((1, bucket), jnp.int32), one, zero,
-                         one, zero, trash_row, self._next_rng())
-                else:
-                    (self._cache, self._last_d,
-                     self._lens_d) = self._chunk_insert(
-                         self.params, self._cache, self._last_d,
-                         self._lens_d, scratch,
-                         jnp.zeros((1, bucket), jnp.int32), one, zero,
-                         one, zero, self._next_rng())
+                (self._cache, self._last_d, self._lens_d) = self._warm(
+                    'chunk_insert', self._chunk_insert, self.params,
+                    self._cache, self._last_d, self._lens_d, scratch,
+                    jnp.zeros((1, bucket), jnp.int32), one, zero, one,
+                    zero, *pt_row, self._next_rng(), bucket=bucket)
         if self._paged and self._radix is not None:
-            self._gather_prefix(self._cache, trash_row)
+            self._warm('gather_prefix', self._gather_prefix, self._cache,
+                       trash_row)
         if self._paged:
             # Handoff programs (disaggregated serving): one dummy
             # export gather plus one adopt scatter whose rows all land
             # in the trash page (slot 0's last/lens scribble is
             # overwritten by the first real insert, like everything
             # else prewarm touches).
-            self._export_pages(self._cache, trash_row)
+            self._warm('export_pages', self._export_pages, self._cache,
+                       trash_row)
             zero_stacks = jax.tree.map(
                 lambda leaf: jnp.zeros(
                     (self._pages_per_slot,) + tuple(leaf.shape[1:]),
                     leaf.dtype), self._cache)
             zero = jnp.zeros((), jnp.int32)
-            (self._cache, self._last_d,
-             self._lens_d) = self._adopt_insert(
-                 self._cache, self._last_d, self._lens_d, zero_stacks,
-                 trash_row, zero, zero, jnp.ones((), jnp.int32))
+            (self._cache, self._last_d, self._lens_d) = self._warm(
+                'adopt_insert', self._adopt_insert, self._cache,
+                self._last_d, self._lens_d, zero_stacks, trash_row, zero,
+                zero, jnp.ones((), jnp.int32))
         if self._paged:
-            _, self._cache, self._last_d, self._lens_d = self._decode(
-                self.params, self._cache, self._pt(), self._last_d,
-                self._lens_d, self._next_rng())
+            _, self._cache, self._last_d, self._lens_d = self._warm(
+                'decode', self._decode, self.params, self._cache,
+                self._pt(), self._last_d, self._lens_d, self._next_rng())
             if self._spec_k:
                 # The verify program is the only other steady-state
                 # shape: zero drafts against all-trash tables (every
                 # write lands in the trash page; slot state is donated
                 # back scribbled like the decode warm above).
-                _, self._cache, self._last_d, self._lens_d = \
-                    self._verify(
-                        self.params, self._cache, self._pt(),
-                        self._last_d, self._lens_d,
-                        jnp.zeros((self.cfg.n_slots, self._spec_k),
-                                  jnp.int32))
+                _, self._cache, self._last_d, self._lens_d = self._warm(
+                    'verify', self._verify, self.params, self._cache,
+                    self._pt(), self._last_d, self._lens_d,
+                    jnp.zeros((self.cfg.n_slots, self._spec_k),
+                              jnp.int32))
         else:
-            _, self._cache, self._last_d, self._lens_d = self._decode(
-                self.params, self._cache, self._last_d, self._lens_d,
-                self._next_rng())
+            _, self._cache, self._last_d, self._lens_d = self._warm(
+                'decode', self._decode, self.params, self._cache,
+                self._last_d, self._lens_d, self._next_rng())
 
     def start(self):
         self._thread = threading.Thread(target=self._loop,
@@ -2383,6 +2446,7 @@ class DecodeEngine:
         t0, tok0, ctx0, occ0 = self._perf_window
         if now - t0 < self.perf_window_s:
             return
+        self._flush_loop_seconds()
         d_tok = self._perf_tokens - tok0
         self._perf_window = (now, self._perf_tokens, self._perf_ctx_sum,
                              self._perf_occ_sum)
@@ -2412,6 +2476,23 @@ class DecodeEngine:
         metrics_lib.set_gauge('skytpu_engine_hbm_bytes_per_token',
                               hbm_bytes)
         metrics_lib.set_gauge('skytpu_engine_arith_intensity', intensity)
+
+    def _flush_loop_seconds(self) -> None:
+        """The loop-phase sums since the last flush, to the registry
+        (loop thread, at the perf window's cadence and at loop exit)."""
+        busy, device, idle = (self._loop_busy_s, self._loop_device_s,
+                              self._loop_idle_s)
+        self._loop_busy_s = self._loop_device_s = self._loop_idle_s = 0.0
+        if busy:
+            metrics_lib.inc_counter(
+                'skytpu_engine_loop_busy_seconds_total', busy)
+        if device:
+            metrics_lib.inc_counter(
+                'skytpu_engine_loop_wait_seconds_total', device,
+                on='device')
+        if idle:
+            metrics_lib.inc_counter(
+                'skytpu_engine_loop_wait_seconds_total', idle, on='idle')
 
     def _sample_gauges(self, n_active: int) -> None:
         """Loop-thread occupancy/queue gauges; skipped when unchanged so
@@ -2454,32 +2535,43 @@ class DecodeEngine:
         Returns #active slots.  Exposed for tests and debugging; the
         serving loop and benchmarks use step_pipelined, which overlaps
         the host work with the next device call."""
-        self._install_staged()
-        self._step_chunked()
-        self._step_adopt()
-        self._admit_free()
-        active = [i for i in range(self.cfg.n_slots)
-                  if self._slots[i] is not None]
-        self._sample_gauges(len(active))
+        with tracing.phase('engine.loop.dispatch') as ph:
+            self._install_staged()
+            self._step_chunked()
+        self._loop_busy_s += ph.seconds
+        with tracing.phase('engine.loop.admit') as ph:
+            self._step_adopt()
+            self._admit_free()
+            active = [i for i in range(self.cfg.n_slots)
+                      if self._slots[i] is not None]
+            self._sample_gauges(len(active))
+        self._loop_busy_s += ph.seconds
         if not active:
             self._release_retiring()
             return 0
         t0 = time.perf_counter()
-        out, self._cache, self._last_d, self._lens_d = \
-            self._dispatch_decode()
-        # skytpu: allow-sync(the ONE device->host fetch per step — the engine's contract)
-        out = np.asarray(out)            # [T+1, B] — the ONE sync per step
+        with tracing.phase('engine.loop.dispatch') as ph:
+            out_d, self._cache, self._last_d, self._lens_d = \
+                self._dispatch_decode()
+        self._loop_busy_s += ph.seconds
+        with tracing.phase('engine.loop.fetch') as ph:
+            # skytpu: allow-sync(the ONE device->host fetch per step — the engine's contract)
+            out = np.asarray(out_d)      # [T+1, B] — the ONE sync per step
+        self._loop_device_s += ph.seconds
         t1 = time.perf_counter()
-        snapshot = {i: self._slots[i] for i in active}
-        if self._spec_k:
-            # Speculative verify: the last output row is the per-slot
-            # acceptance count m (1..k+1) — rows 1..m are committed
-            # tokens, rows past m are rejected drafts' garbage.
-            self._process_rows(out[:-1], snapshot, counts=out[-1],
-                               verify_span=(t0, t1))
-        else:
-            self._process_rows(out, snapshot)
-        self._release_retiring()
+        with tracing.phase('engine.loop.emit') as ph:
+            snapshot = {i: self._slots[i] for i in active}
+            if self._spec_k:
+                # Speculative verify: the last output row is the
+                # per-slot acceptance count m (1..k+1) — rows 1..m are
+                # committed tokens, rows past m are rejected drafts'
+                # garbage.
+                self._process_rows(out[:-1], snapshot, counts=out[-1],
+                                   verify_span=(t0, t1))
+            else:
+                self._process_rows(out, snapshot)
+            self._release_retiring()
+        self._loop_busy_s += ph.seconds
         return len(active)
 
     def step_pipelined(self) -> int:  # skytpu: hot-entry
@@ -2517,41 +2609,55 @@ class DecodeEngine:
             # instead; step() keeps the same admission/chunked/adopt
             # machinery and the one-sync contract.
             return self.step()
-        self._install_staged()
-        active = [i for i in range(self.cfg.n_slots)
-                  if self._slots[i] is not None]
-        self._sample_gauges(len(active))
-        dispatched = None
-        if active:
-            out_d, self._cache, self._last_d, self._lens_d = \
-                self._dispatch_decode()
-            dispatched = (out_d, {i: self._slots[i] for i in active})
-        chunked = self._step_chunked()   # queues behind the decode call
+        # The phases below tile the iteration: what the loop thread
+        # does outside them is the `while` of _loop.
+        with tracing.phase('engine.loop.dispatch') as ph:
+            self._install_staged()
+            active = [i for i in range(self.cfg.n_slots)
+                      if self._slots[i] is not None]
+            self._sample_gauges(len(active))
+            dispatched = None
+            if active:
+                out_d, self._cache, self._last_d, self._lens_d = \
+                    self._dispatch_decode()
+                dispatched = (out_d, {i: self._slots[i] for i in active})
+            chunked = self._step_chunked()   # queues behind the decode call
+        self._loop_busy_s += ph.seconds
+        out = snapshot = None
         if self._inflight is not None:
             out_prev, snapshot = self._inflight
             self._inflight = None
-            # skytpu: allow-sync(the ONE fetch per step, one call late: syncs call k-1 while call k runs)
-            self._process_rows(np.asarray(out_prev), snapshot)
-        self._release_retiring()
-        self._inflight = dispatched
-        # Admissions AFTER processing: retired slots are free now, and
-        # slots whose occupant will PROVABLY finish inside the call just
-        # dispatched (its remaining max_new fits the rows that call
-        # delivers) hand off to a successor with zero garbage calls —
-        # the successor's prefill queues behind the in-flight call.
-        handoff = []
-        if dispatched is not None:
-            steps = self.cfg.steps_per_call
-            for i, slot in dispatched[1].items():
-                if self._slots[i] is not slot or slot.done:
-                    continue
-                rows_to_come = steps + (1 if slot.first_pending else 0)
-                remaining = (slot.request.max_new_tokens -
-                             slot.request.emitted)
-                if remaining <= rows_to_come:
-                    handoff.append(i)
-        self._step_adopt()
-        self._admit_free(handoff)
+            with tracing.phase('engine.loop.fetch') as ph:
+                # skytpu: allow-sync(the ONE fetch per step, one call late: syncs call k-1 while call k runs)
+                out = np.asarray(out_prev)
+            self._loop_device_s += ph.seconds
+        with tracing.phase('engine.loop.emit') as ph:
+            if snapshot is not None:
+                self._process_rows(out, snapshot)
+            self._release_retiring()
+            self._inflight = dispatched
+        self._loop_busy_s += ph.seconds
+        with tracing.phase('engine.loop.admit') as ph:
+            # Admissions AFTER processing: retired slots are free now,
+            # and slots whose occupant will PROVABLY finish inside the
+            # call just dispatched (its remaining max_new fits the rows
+            # that call delivers) hand off to a successor with zero
+            # garbage calls — the successor's prefill queues behind the
+            # in-flight call.
+            handoff = []
+            if dispatched is not None:
+                steps = self.cfg.steps_per_call
+                for i, slot in dispatched[1].items():
+                    if self._slots[i] is not slot or slot.done:
+                        continue
+                    rows_to_come = steps + (1 if slot.first_pending else 0)
+                    remaining = (slot.request.max_new_tokens -
+                                 slot.request.emitted)
+                    if remaining <= rows_to_come:
+                        handoff.append(i)
+            self._step_adopt()
+            self._admit_free(handoff)
+        self._loop_busy_s += ph.seconds
         return len(active) + (1 if chunked else 0)
 
     def _process_rows(self, out: np.ndarray, snapshot: Dict[int, _Slot],
@@ -2569,6 +2675,15 @@ class DecodeEngine:
         (dispatch, fetch) perf_counter bracket for the engine.verify
         flight-recorder span of traced requests."""
         now = time.perf_counter()
+        # A slot whose first token is pending and that this call did not
+        # carry was admitted behind it: its prefill sat on the device
+        # until the call just fetched had run.  The LAST such fetch is
+        # where its engine.prefill_wait ends (a chunked insert goes out
+        # with two calls still ahead of it).
+        for i, slot in enumerate(self._slots):
+            if (slot is not None and slot.first_pending and
+                    snapshot.get(i) is not slot):
+                slot.wait_end = now
         emitted = 0
         spec_proposed = spec_accepted = 0
         for i, slot in snapshot.items():
@@ -2597,13 +2712,22 @@ class DecodeEngine:
                 if rid is not None:
                     # The decode call the first token rode: from the
                     # prefill dispatch's end to the host observing the
-                    # token — closes the TTFT tiling.
-                    tracing.record_span(
-                        rid, 'engine.dispatch',
-                        slot.request.prefill_end_at
-                        if slot.request.prefill_end_at is not None
-                        else slot.request.submitted_at,
-                        now, slot=i)
+                    # token — closes the TTFT tiling.  Its two parts
+                    # tile it: the prefill waiting behind the call in
+                    # flight at admission (zero length when there was
+                    # none), then the sampled token riding the next
+                    # whole call.
+                    start_at = (slot.request.prefill_end_at
+                                if slot.request.prefill_end_at is not None
+                                else slot.request.submitted_at)
+                    wait_end = (slot.wait_end if slot.wait_end is not None
+                                else start_at)
+                    tracing.record_span(rid, 'engine.dispatch', start_at,
+                                        now, slot=i)
+                    tracing.record_span(rid, 'engine.prefill_wait',
+                                        start_at, wait_end, slot=i)
+                    tracing.record_span(rid, 'engine.first_token_ride',
+                                        wait_end, now, slot=i)
                     # Decode-batch membership + the measured TTFT the
                     # decomposition is checked against.
                     tracing.record_instant(
@@ -2648,6 +2772,12 @@ class DecodeEngine:
 
 
     def _loop(self):  # skytpu: hot-entry
+        try:
+            self._run_loop()
+        finally:
+            self._flush_loop_seconds()
+
+    def _run_loop(self):  # skytpu: hot-entry
         while not self._stop.is_set():
             try:
                 n = self.step_pipelined()
@@ -2701,4 +2831,6 @@ class DecodeEngine:
                     self._queued_tokens = 0
                 return
             if n == 0:
-                time.sleep(0.001)
+                with tracing.phase('engine.loop.idle') as ph:
+                    time.sleep(0.001)
+                self._loop_idle_s += ph.seconds

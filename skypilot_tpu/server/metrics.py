@@ -140,6 +140,16 @@ _HELP = {
     'skytpu_engine_arith_intensity':
         'Modeled decode arithmetic intensity (FLOPs/HBM byte) at the '
         'current occupancy — distance from the chip\'s roofline ridge',
+    'skytpu_engine_loop_busy_seconds_total':
+        'Seconds the engine\'s loop thread spent WORKING on the host '
+        '(phases engine.loop.dispatch + emit + admit), flushed once '
+        'per perf window: rate(busy) / (rate(busy) + rate(wait)) near '
+        '1 is a host-bound replica',
+    'skytpu_engine_loop_wait_seconds_total':
+        'Seconds the engine\'s loop thread spent WAITING, by what for: '
+        'on="device" is the one fetch per step (the device is the '
+        'bottleneck, as it should be), on="idle" the 1 ms sleeps of '
+        'an engine with nothing to do',
     'skytpu_engine_xla_compile_total':
         'XLA backend compiles observed in this process '
         '(jax.monitoring): increments after engine warmup are '
@@ -186,12 +196,6 @@ _HELP = {
         'denominator)',
     'skytpu_train_mfu_percent':
         'Estimated model FLOPs utilization (bench.py accounting)',
-    'skytpu_train_hbm_bytes_per_token':
-        'Modeled training HBM traffic per token (weight fwd+bwd '
-        'streams, gradient write, optimizer-state read/write, '
-        'amortized over the step\'s tokens — train/flops.py)',
-    'skytpu_train_arith_intensity':
-        'Modeled training arithmetic intensity (FLOPs/HBM byte)',
     # ----- training goodput plane (obs/goodput.py) -------------------------
     'skytpu_train_goodput_percent':
         'Share of this run\'s classified wall-clock spent in '

@@ -35,12 +35,22 @@ syncs and nothing blocking in async handlers — both enforced by
 ``skytpu check``, whose metric-naming rule also validates every span
 name at the call site against the central ``SPAN_HELP`` table below.
 
+Loop PHASES (``phase``) are the third kind of event: what a loop thread
+is doing between requests (the engine loop's dispatch / fetch / emit /
+admit / idle, the trainer's feed / dispatch / fetch / export).  A phase
+is a ``jax.profiler.TraceAnnotation``, so during any profiler session
+(``/debug/profile``, the benchmark's ``--trace 1``) it is a host event
+on the device trace's own clock, and it hands its elapsed seconds back
+to the caller, which keeps its own sums.  It never enters the ring: a
+phase per loop iteration would evict the request spans the ring is for.
+
 Knob: ``SKYTPU_TRACE_RING_SIZE`` — events retained per process
 (default 8192; 0 disables recording entirely).
 """
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 import uuid
@@ -99,6 +109,17 @@ SPAN_HELP = {
     'engine.dispatch':
         'End of the last prefill dispatch to the host observing the '
         'first token (the decode call the token rode)',
+    'engine.prefill_wait':
+        'First part of engine.dispatch: end of the last prefill '
+        'dispatch to the host\'s return from the fetch of the decode '
+        'call that was in flight when the request was admitted — the '
+        'prefill sat on the device behind that call (zero length when '
+        'nothing was in flight)',
+    'engine.first_token_ride':
+        'Second part of engine.dispatch: from there to the host '
+        'observing the first token — the token, already sampled by '
+        'the prefill, rode the next whole decode call.  prefill_wait '
+        '+ first_token_ride tile engine.dispatch exactly',
     'engine.first_token':
         'First token emitted: decode-batch membership (slot, batch '
         'size) and the measured TTFT',
@@ -118,6 +139,33 @@ SPAN_HELP = {
         'call (attrs: proposed, accepted).  A decode-phase span — '
         'NOT part of the TTFT tiling, which first_token closes before '
         'any verify runs',
+    # ----- engine loop phases (profiler sessions only; never in the ring) ---
+    'engine.loop.dispatch':
+        'Loop phase: weight-swap install, the decode dispatch and at '
+        'most one prefill chunk behind it (host work; async on device)',
+    'engine.loop.fetch':
+        'Loop phase: the one device->host fetch per step — the host '
+        'WAITING for the device (wait_seconds{on="device"})',
+    'engine.loop.emit':
+        'Loop phase: a fetched call\'s tokens streamed to their '
+        'requests, retires, swapped-out weights released',
+    'engine.loop.admit':
+        'Loop phase: KV adoptions and admissions into free slots, '
+        'including the prefill dispatch',
+    'engine.loop.idle':
+        'Loop phase: the 1 ms sleep of an iteration that found '
+        'nothing to do (wait_seconds{on="idle"})',
+    # ----- engine set-up (rid "engine-setup") ------------------------------
+    'engine.setup.layouts':
+        'Engine construction: the AOT decode compile with AUTO '
+        'layouts and the relayout of weights, cache and slot state '
+        'into what it chose (TPU, unpaged, no mesh)',
+    'engine.setup.compile':
+        'One prewarmed program compiled (attrs: kind = prefill | '
+        'chunk | chunk_insert | scratch | decode | ..., bucket, rows) '
+        '— what setup time is made of, program by program',
+    'engine.setup.prewarm':
+        'The whole of prewarm() (attr: programs compiled)',
     # ----- device-level perf observability (perf/) -------------------------
     'perf.recompile':
         'Post-warmup XLA compile caught by the runtime recompile '
@@ -150,6 +198,21 @@ SPAN_HELP = {
         'bracketed by the jobs.preemption/jobs.recovery instants — '
         'the durable twin is a goodput_intervals row',
     # ----- training goodput plane (obs/goodput.py) -------------------------
+    # ----- trainer loop phases (profiler sessions only; never in the ring) --
+    'train.feed':
+        'Trainer phase: next(batch) — the input pipeline (its time is '
+        'the stall the goodput ledger carves out as input_stall)',
+    'train.dispatch':
+        'Trainer phase: the train_step call (async dispatch; donated '
+        'buffers backpressure it to the device step rate)',
+    'train.fetch':
+        'Trainer phase: jax.device_get(metrics) at a log boundary — '
+        'the host waiting for the device',
+    'train.export':
+        'Trainer phase: throughput and goodput gauges and the log_fn '
+        'at a log boundary',
+    'train.checkpoint':
+        'Trainer phase: save_checkpoint() at a checkpoint boundary',
     'train.phase':
         'One trainer-side goodput-ledger interval (category = '
         'productive | init_compile | checkpoint_save | '
@@ -230,6 +293,47 @@ def record_instant(request_id: str, name: str,
         _ring.append(evt)
 
 
+_annotation_cls = None
+
+
+def _trace_annotation():
+    """jax.profiler.TraceAnnotation, once some other module of this
+    process has imported jax; None before (the load balancer and the
+    API server never import it, and a phase must not be what does)."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        profiler = getattr(sys.modules.get('jax'), 'profiler', None)
+        _annotation_cls = getattr(profiler, 'TraceAnnotation', None)
+    return _annotation_cls
+
+
+class phase:  # pylint: disable=invalid-name
+    """``with tracing.phase('engine.loop.fetch') as ph: ...`` then
+    ``ph.seconds``: one loop phase, named in SPAN_HELP.
+
+    During a profiler session the phase is a host event on the clock of
+    the device trace; with none open it costs an inactive TraceMe and
+    two perf_counter reads.  The elapsed seconds go back to the caller,
+    never to the ring (see the module docstring)."""
+    __slots__ = ('seconds', '_annotation', '_start')
+
+    def __init__(self, name: str) -> None:
+        cls = _trace_annotation()
+        self._annotation = cls(name) if cls is not None else None
+        self.seconds = 0.0
+
+    def __enter__(self) -> 'phase':
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self._start
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+
+
 # ----- queries ----------------------------------------------------------------
 def _render(evt: dict) -> dict:
     """Internal event -> the wire/JSON form (wall-clock ts seconds,
@@ -298,6 +402,8 @@ def decompose(events: List[dict]) -> dict:
     queue_wait + prefill (fused or N chunks) + dispatch should SUM to
     the measured TTFT (`engine.first_token`'s ttft_s attr);
     ``unattributed_ms`` is the residual and should be ~0.
+    ``prefill_wait_ms`` + ``first_token_ride_ms`` are the two parts of
+    ``dispatch_ms``, shown beside it and not added again.
     """
     def durs(name):
         return [e['dur_ms'] for e in events
@@ -313,6 +419,9 @@ def decompose(events: List[dict]) -> dict:
     prefill = (sum(durs('engine.prefill')) + sum(chunks) + sum(hits) +
                sum(adopts))
     dispatch = sum(durs('engine.dispatch'))
+    # The two parts of dispatch (they tile it; never terms of the sum).
+    prefill_wait = sum(durs('engine.prefill_wait'))
+    ride = sum(durs('engine.first_token_ride'))
     cached_tokens = sum(
         e['attrs'].get('cached_tokens') or 0 for e in events
         if e['name'] == 'engine.prefix_hit')
@@ -353,6 +462,8 @@ def decompose(events: List[dict]) -> dict:
         'prefill_chunks': len(chunks),
         'prefix_cached_tokens': cached_tokens,
         'dispatch_ms': round(dispatch, 4),
+        'prefill_wait_ms': round(prefill_wait, 4),
+        'first_token_ride_ms': round(ride, 4),
         'decomposed_ttft_ms': decomposed,
         'unattributed_ms': (round(ttft_ms - decomposed, 4)
                             if ttft_ms is not None else None),

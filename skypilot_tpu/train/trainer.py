@@ -157,6 +157,13 @@ class Trainer:
         self.state, self.shardings = make_train_state(
             model, mesh, rng, sample_tokens, train_cfg, rules)
         self.train_step = make_sharded_train_step(mesh, self.shardings)
+        # A constant of the configuration, for the MFU gauge: counted
+        # once here and not at every log boundary.  None where the
+        # model's cfg is not LlamaConfig-shaped (no MFU gauge then).
+        try:
+            self._n_params = model.cfg.num_params()
+        except (AttributeError, TypeError):
+            self._n_params = None
         self.checkpoint_dir = checkpoint_dir
         self._ckpt_mgr = None
         if checkpoint_dir is not None:
@@ -182,6 +189,7 @@ class Trainer:
             log_every: int = 10,
             log_fn: Callable[[dict], None] = None) -> dict:
         from skypilot_tpu.server import metrics as metrics_lib
+        from skypilot_tpu.server import tracing
         gp = self._gp
         phases = self.phases
         metrics = {}
@@ -204,12 +212,16 @@ class Trainer:
         window_nonprod = 0.0
         window_stall = 0.0
         for i in range(num_steps):
-            fetch_t = time.perf_counter()
-            batch = next(data)
-            stall = time.perf_counter() - fetch_t
+            # The tracing.phase blocks put the loop's host work on the
+            # clock of a profiler session's device trace; the goodput
+            # ledger and the step histogram keep the sums.
+            with tracing.phase('train.feed') as feed:
+                batch = next(data)
+            stall = feed.seconds
             tokens_seen += batch.size
             window_tokens += batch.size
-            self.state, metrics = self.train_step(self.state, batch)
+            with tracing.phase('train.dispatch'):
+                self.state, metrics = self.train_step(self.state, batch)
             # Host wall time per iteration: async dispatch, but donated
             # buffers backpressure the host to the device step rate at
             # steady state — and no sync is added here.
@@ -229,7 +241,8 @@ class Trainer:
             if checkpoint_every and (i + 1) % checkpoint_every == 0:
                 ck0 = time.perf_counter()
                 phases.begin(gp.CHECKPOINT_SAVE, ck0)
-                self.save_checkpoint()
+                with tracing.phase('train.checkpoint'):
+                    self.save_checkpoint()
                 ck1 = time.perf_counter()
                 phases.begin(gp.PRODUCTIVE, ck1)
                 nonprod_s += ck1 - ck0
@@ -246,17 +259,20 @@ class Trainer:
                 nonprod_s += window_stall
                 window_nonprod += window_stall
                 if log_fn:
-                    # skytpu: allow-sync(log-boundary read only; the window below ends after it, so its steps have run and not merely been dispatched)
-                    m = jax.device_get(metrics)
-                elapsed = time.perf_counter() - window_start
-                self._export_throughput(
-                    window_tokens / max(elapsed - window_nonprod, 1e-9),
-                    batch)
-                self._export_goodput()
-                if log_fn:
-                    m['tokens_per_s'] = tokens_seen / max(
-                        time.perf_counter() - t0 - nonprod_s, 1e-9)
-                    log_fn(m)
+                    with tracing.phase('train.fetch'):
+                        # skytpu: allow-sync(log-boundary read only; the window below ends after it, so its steps have run and not merely been dispatched)
+                        m = jax.device_get(metrics)
+                with tracing.phase('train.export'):
+                    elapsed = time.perf_counter() - window_start
+                    self._export_throughput(
+                        window_tokens / max(elapsed - window_nonprod,
+                                            1e-9),
+                        batch)
+                    self._export_goodput()
+                    if log_fn:
+                        m['tokens_per_s'] = tokens_seen / max(
+                            time.perf_counter() - t0 - nonprod_s, 1e-9)
+                        log_fn(m)
                 window_tokens = 0
                 window_stall = 0.0
                 window_nonprod = 0.0
@@ -315,33 +331,16 @@ class Trainer:
         metrics_lib.set_gauge('skytpu_train_tokens_per_second',
                               tokens_per_s)
         cfg = getattr(self.model, 'cfg', None)
-        if batch is None or cfg is None:
+        if batch is None or cfg is None or self._n_params is None:
             return
         try:
-            n_params = cfg.num_params()
             mfu = flops_lib.estimate_mfu(
-                tokens_per_s, n_params, cfg.n_layers, cfg.dim,
+                tokens_per_s, self._n_params, cfg.n_layers, cfg.dim,
                 seq_len=batch.shape[-1], n_chips=self.mesh.size)
         except (AttributeError, TypeError):
             return      # cfg not LlamaConfig-shaped: no MFU gauge
         if mfu > 0:
             metrics_lib.set_gauge('skytpu_train_mfu_percent', mfu)
-        # Device-cost twins of the decode engine's perf gauges
-        # (perf/cost_model.py): modeled HBM bytes per trained token and
-        # the resulting arithmetic intensity, from the same shared FLOP
-        # accounting.
-        tokens_per_step = int(batch.size)
-        hbm_bytes = flops_lib.train_hbm_bytes_per_token(
-            n_params, tokens_per_step)
-        if hbm_bytes > 0:
-            metrics_lib.set_gauge('skytpu_train_hbm_bytes_per_token',
-                                  hbm_bytes)
-            metrics_lib.set_gauge(
-                'skytpu_train_arith_intensity',
-                flops_lib.train_arith_intensity(
-                    n_params, cfg.n_layers, cfg.dim,
-                    seq_len=batch.shape[-1],
-                    tokens_per_step=tokens_per_step))
 
     def save_checkpoint(self) -> None:
         if self._ckpt_mgr is not None:

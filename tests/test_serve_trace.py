@@ -144,7 +144,8 @@ def test_tracing_adds_zero_device_syncs(tiny_engine_model, monkeypatch):
     assert counting.asarray_calls == active_steps
     names = [e['name'] for e in tracing.events_for('sync-check')]
     assert names == ['engine.queue_wait', 'engine.prefill',
-                     'engine.dispatch', 'engine.first_token',
+                     'engine.dispatch', 'engine.prefill_wait',
+                     'engine.first_token_ride', 'engine.first_token',
                      'engine.stream_end']
 
 
@@ -268,6 +269,12 @@ def test_trace_e2e_decomposition_sums_to_ttft(tiny_engine_model):
         assert re.search(r'TTFT [0-9.]+ ms = queue [0-9.]+ \+ '
                          r'3 x chunk [0-9.]+ \+ dispatch', res.output), \
             res.output
+        # ...with dispatch's two parts shown inside it, not as terms.
+        assert re.search(r'dispatch [0-9.]+ \[prefill wait [0-9.]+ \+ '
+                         r'first-token ride [0-9.]+\] \(decomposed',
+                         res.output), res.output
+        assert s['prefill_wait_ms'] + s['first_token_ride_ms'] == \
+            pytest.approx(s['dispatch_ms'], abs=1e-3)
 
         # Chrome/Perfetto export through the same endpoint.
         _, _, chrome_text = _get(

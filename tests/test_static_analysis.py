@@ -140,6 +140,13 @@ def test_fixture_findings_land_where_expected():
     span_hits = [f for f in by_rule['metric-naming']
                  if f.path == 'bad_spans.py']
     assert len(span_hits) == 3
+    # Loop phases (tracing.phase, name first) are held to the same
+    # registry: the rogue and the illegal name, not the registered one.
+    phase_hits = [f for f in by_rule['metric-naming']
+                  if f.path == 'bad_phases.py']
+    assert len(phase_hits) == 2
+    phase_msgs = ' '.join(f.message for f in phase_hits)
+    assert 'engine.loop.rogue' in phase_msgs and "'Loop'" in phase_msgs
     # Paged-KV fixture: an unregistered page-cache gauge + counter and
     # an unregistered prefix span — each caught (registry discipline
     # covers the new families too).
@@ -203,6 +210,35 @@ def test_fixture_findings_land_where_expected():
     spec_msgs = ' '.join(f.message for f in spec)
     assert 'defeats the compile cache' in spec_msgs
     assert 'without pinned' in spec_msgs
+
+
+def test_phase_calls_are_held_to_the_span_registry():
+    """`tracing.phase(<name>)` is a span registration whose name comes
+    FIRST (the recorders take the request id first): the rule reads the
+    right argument, flags an unregistered or illegal phase, and passes
+    the engine's and the trainer's own phases and the loop counters."""
+    report = analysis.run_check(
+        [os.path.join(FIXTURES, 'bad_phases.py')], rules=['metric-naming'])
+    assert sorted(f.line for f in report.unsuppressed) == [7, 9]
+    report = analysis.run_check(
+        [os.path.join(PKG, 'inference', 'engine.py'),
+         os.path.join(PKG, 'train', 'trainer.py')],
+        rules=['metric-naming'])
+    assert not report.unsuppressed, [f.format() for f in
+                                     report.unsuppressed]
+    # ...and it did look: drop a phase from SPAN_HELP's view by
+    # renaming it in a copy of the trainer, and the rule objects.
+    src = open(os.path.join(PKG, 'train', 'trainer.py')).read()
+    assert "tracing.phase('train.feed')" in src
+    mutated = src.replace("tracing.phase('train.feed')",
+                          "tracing.phase('train.fed')")
+    import tempfile
+    with tempfile.TemporaryDirectory() as d:
+        path = os.path.join(d, 'trainer_copy.py')
+        with open(path, 'w') as f:
+            f.write(mutated)
+        report = analysis.run_check([path], rules=['metric-naming'])
+    assert ['train.fed' in f.message for f in report.unsuppressed] == [True]
 
 
 # ---------------------------------------------------------------------------
