@@ -9,7 +9,9 @@ FLOPs for HBM — the right trade on TPU where attention bwd is
 bandwidth-bound; nothing O(S^2) is ever materialized in HBM).
 
 Shapes: q [B, Hq, Sq, D], k/v [B, Hkv, Sk, D]; grouped-query attention is
-expressed by Hq = G * Hkv (query heads grouped over kv heads).
+expressed by Hq = G * Hkv (query heads grouped over kv heads).  The
+reference folds the G query heads of a kv head into its query rows and
+contracts against K and V as they are stored; it never repeats them.
 """
 from __future__ import annotations
 
@@ -23,8 +25,11 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _expand_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
-    """GQA: repeat kv heads to match query heads (XLA turns this into a
-    broadcast; no HBM copy)."""
+    """GQA: repeat kv heads to match query heads.  A copy, not a view: on
+    the v5e the repeat in front of a contraction became a float32
+    `broadcast` of V, 23.7% of Yi-6B's decode step (ledger, PR 25).
+    `mha_reference` contracts over the stored heads instead; only ring
+    attention's block step (training over a `seq` axis) still repeats."""
     b, h_kv, s, d = k.shape
     if h_kv == num_q_heads:
         return k
@@ -49,19 +54,30 @@ def mha_reference(q: jax.Array,
     """
     orig_dtype = q.dtype
     scale = scale if scale is not None else q.shape[-1]**-0.5
-    k = _expand_kv(k, q.shape[1])
-    v = _expand_kv(v, q.shape[1])
+    b, h_q, s_q, d = q.shape
+    h_kv = k.shape[1]
+    group = h_q // h_kv
+    if group > 1:
+        # GQA: query head h reads kv head h // group (the order jnp.repeat
+        # over axis 1 gives), so the `group` query heads of one kv head
+        # are `group * Sq` query rows of it, contracted against K and V as
+        # they are stored: nothing [B, Hq, Sk, D] is ever built.
+        q = q.reshape(b, h_kv, group * s_q, d)
     logits = jnp.einsum('bhqd,bhkd->bhqk', q, k,
                         preferred_element_type=jnp.float32) * scale
     if causal:
         if segment_positions is None:
-            q_pos = jnp.arange(q.shape[2])[None, :]
+            q_pos = jnp.arange(s_q)[None, :]
             k_pos = jnp.arange(k.shape[2])[None, :]
         else:
             q_pos = segment_positions
             k_pos = (kv_positions if kv_positions is not None
                      else segment_positions)
         mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
+        if group > 1:
+            # The mask is tiled, not the positions: comparing `group`
+            # copies of the positions read 1% slower in Yi-6B's decode.
+            mask = jnp.tile(mask, (1, 1, group, 1))
         logits = jnp.where(mask, logits, -jnp.inf)
     probs = jax.nn.softmax(logits, axis=-1)
     # Fully-masked rows (possible for ring-attention shards) produce NaN
@@ -69,6 +85,8 @@ def mha_reference(q: jax.Array,
     probs = jnp.where(jnp.isnan(probs), 0.0, probs)
     out = jnp.einsum('bhqk,bhkd->bhqd', probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
+    if group > 1:
+        out = out.reshape(b, h_q, s_q, d)
     return out.astype(orig_dtype)
 
 

@@ -84,6 +84,150 @@ def test_pallas_flash_bwd_gqa_group_reduce():
     assert jnp.max(jnp.abs(dv - dv_ref)) < 5e-3
 
 
+def _repeat_attention(q, k, v, q_pos, k_pos):
+    """The independent reference for grouped-query attention: every KV
+    head repeated to its query heads, float32 throughout, the softmax
+    written out so that a fully masked row comes out as zeros."""
+    group = q.shape[1] // k.shape[1]
+    k, v = (jnp.repeat(t, group, axis=1) for t in (k, v))
+    logits = jnp.einsum('bhqd,bhkd->bhqk', q, k,
+                        precision='highest') * q.shape[-1]**-0.5
+    mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
+    top = jnp.max(jnp.where(mask, logits, -1e30), axis=-1, keepdims=True)
+    w = jnp.where(mask, jnp.exp(jnp.where(mask, logits - top, 0.0)), 0.0)
+    total = jnp.sum(w, axis=-1, keepdims=True)
+    probs = w / jnp.where(total == 0.0, 1.0, total)
+    return jnp.einsum('bhqk,bhkd->bhqd', probs, v, precision='highest')
+
+
+def _gqa_case(case, s_k):
+    """Three rows: (the keyword arguments mha_reference gets, q_pos,
+    k_pos)."""
+    k_pos = jnp.broadcast_to(jnp.arange(s_k)[None, :], (3, s_k))
+    if case == 'prefill':          # Sq == Sk, causal from the shapes
+        return {}, k_pos, k_pos
+    if case == 'decode':           # one query a row, each at its own place
+        q_pos = jnp.array([[3], [s_k - 1], [0]])
+    elif case == 'chunk':          # a chunk of 4 at a per-row offset
+        q_pos = jnp.array([[5], [0], [s_k - 4]]) + jnp.arange(4)[None, :]
+    elif case == 'masked_row':     # row 1 sits before every key
+        q_pos = jnp.array([[2, 3], [-2, -1], [6, 7]])
+    else:
+        raise ValueError(case)
+    return dict(segment_positions=q_pos, kv_positions=k_pos), q_pos, k_pos
+
+
+@pytest.mark.parametrize('direction', ['forward', 'grad'])
+@pytest.mark.parametrize('case', ['decode', 'prefill', 'chunk', 'masked_row'])
+@pytest.mark.parametrize('group', [1, 2, 8])
+def test_mha_reference_grouped_matches_repeat(group, case, direction):
+    """Grouped-query attention contracted over the KV heads as stored is
+    the repeat formulation: same values, same gradients, for every way the
+    model calls it."""
+    b, h_kv, s_k, d = 3, 2, 16, 8
+    kwargs, q_pos, k_pos = _gqa_case(case, s_k)
+    s_q = q_pos.shape[1]
+    kq, kk, kv, kw = jax.random.split(jax.random.PRNGKey(group), 4)
+    q = jax.random.normal(kq, (b, h_kv * group, s_q, d), jnp.float32)
+    k = jax.random.normal(kk, (b, h_kv, s_k, d), jnp.float32)
+    v = jax.random.normal(kv, (b, h_kv, s_k, d), jnp.float32)
+    got_fn = lambda q_, k_, v_: mha_reference(  # noqa: E731
+        q_, k_, v_, causal=True, **kwargs)
+    want_fn = lambda q_, k_, v_: _repeat_attention(  # noqa: E731
+        q_, k_, v_, q_pos, k_pos)
+    if direction == 'forward':
+        got, want = jax.jit(got_fn)(q, k, v), jax.jit(want_fn)(q, k, v)
+        assert got.shape == (b, h_kv * group, s_q, d)
+        assert jnp.allclose(got, want, atol=2e-5), \
+            float(jnp.max(jnp.abs(got - want)))
+        if case == 'masked_row':
+            assert not jnp.any(got[1])
+        return
+    if case == 'masked_row':
+        # softmax's gradient through a row of -inf is NaN whatever the
+        # head layout; the rows that see a key are what can be held.
+        q, q_pos = q[::2], q_pos[::2]
+        k, v, k_pos = k[::2], v[::2], k_pos[::2]
+        kwargs = dict(segment_positions=q_pos, kv_positions=k_pos)
+    w = jax.random.normal(kw, (q.shape[0], h_kv * group, s_q, d))
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * w)  # noqa: E731
+    got = jax.jit(jax.grad(loss(got_fn), argnums=(0, 1, 2)))(q, k, v)
+    want = jax.jit(jax.grad(loss(want_fn), argnums=(0, 1, 2)))(q, k, v)
+    for g_, w_, name in zip(got, want, 'qkv'):
+        assert g_.shape == w_.shape
+        assert jnp.allclose(g_, w_, atol=5e-5), \
+            (name, float(jnp.max(jnp.abs(g_ - w_))))
+
+
+def _shapes_in(jaxpr):
+    """Every intermediate's shape, through the nested jaxprs."""
+    for eqn in jaxpr.eqns:
+        for var in eqn.outvars:
+            yield tuple(getattr(var.aval, 'shape', ()))
+        for param in eqn.params.values():
+            for sub in (param if isinstance(param, (list, tuple))
+                        else [param]):
+                sub = getattr(sub, 'jaxpr', sub)
+                if hasattr(sub, 'eqns'):
+                    yield from _shapes_in(sub)
+
+
+def test_gqa_decode_step_builds_no_expanded_kv():
+    """A decode step of a GQA model holds its cache as [B, Hkv, S, D] and
+    nothing of the cache's length with all the query heads."""
+    from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama, init_params
+    cfg = LLAMA_CONFIGS['tiny']
+    assert cfg.n_heads > cfg.n_kv_heads
+    model, b = Llama(cfg), 2
+    variables = jax.eval_shape(
+        lambda: init_params(model, jax.random.PRNGKey(0)))
+    prompt = jnp.zeros((b, 4), jnp.int32)
+    _, state = jax.eval_shape(
+        lambda p: model.apply({'params': p}, prompt, decode=True,
+                              mutable=['cache']), variables['params'])
+
+    def step(params, cache, tokens, positions):
+        return model.apply({'params': params, 'cache': cache}, tokens,
+                           positions=positions, decode=True,
+                           mutable=['cache'])
+
+    jaxpr = jax.make_jaxpr(step)(
+        variables['params'], state['cache'],
+        jnp.zeros((b, 1), jnp.int32), jnp.full((b, 1), 4, jnp.int32))
+    shapes = set(_shapes_in(jaxpr.jaxpr))
+    stored = (b, cfg.n_kv_heads, cfg.max_seq_len, cfg.head_dim)
+    expanded = (b, cfg.n_heads, cfg.max_seq_len, cfg.head_dim)
+    assert stored in shapes         # the walk does reach the attention
+    assert expanded not in shapes
+
+
+def test_mha_reference_ungrouped_trace_unchanged():
+    """With as many KV heads as query heads the op traces to the plain
+    expression, letter for letter: no group axis of size 1 slips in."""
+    def plain(q, k, v, q_pos, k_pos):
+        logits = jnp.einsum('bhqd,bhkd->bhqk', q, k,
+                            preferred_element_type=jnp.float32
+                            ) * q.shape[-1]**-0.5
+        mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
+        logits = jnp.where(mask, logits, -jnp.inf)
+        probs = jax.nn.softmax(logits, axis=-1)
+        probs = jnp.where(jnp.isnan(probs), 0.0, probs)
+        out = jnp.einsum('bhqk,bhkd->bhqd', probs.astype(v.dtype), v,
+                         preferred_element_type=jnp.float32)
+        return out.astype(q.dtype)
+
+    def op(q, k, v, q_pos, k_pos):
+        return mha_reference(q, k, v, causal=True,
+                             segment_positions=q_pos, kv_positions=k_pos)
+
+    q, k, v = _qkv(b=2, h=4, s=16, d=8, dtype=jnp.bfloat16)
+    q = q[:, :, :1]
+    q_pos = jnp.array([[3], [9]])
+    k_pos = jnp.broadcast_to(jnp.arange(16)[None, :], (2, 16))
+    args = (q, k, v, q_pos, k_pos)
+    assert str(jax.make_jaxpr(op)(*args)) == str(jax.make_jaxpr(plain)(*args))
+
+
 @pytest.mark.parametrize('causal', [True, False])
 def test_ring_attention_exact(causal):
     mesh = build_mesh(plan_mesh(8, data=1, fsdp=8, tensor=1))

@@ -36,6 +36,11 @@ def params():
     return init_params(Llama(CFG), jax.random.PRNGKey(0))['params']
 
 
+@pytest.fixture(scope='module')
+def gqa_params():
+    return init_params(Llama(TINY_GQA), jax.random.PRNGKey(0))['params']
+
+
 def naive_greedy(cfg, params, prompt_ids, n_new):
     """Reference: full forward over the growing sequence each step,
     single-device model."""
@@ -125,10 +130,10 @@ def test_sharded_engine_matches_single_device(params):
     assert run(4) == [want1, want2]
 
 
-def test_sharded_engine_gqa(params):
+def test_sharded_engine_gqa(gqa_params):
     """GQA sharding (2 kv heads over tensor=2: one kv head per chip,
     two q heads attending to it) reproduces single-device greedy."""
-    prms = init_params(Llama(TINY_GQA), jax.random.PRNGKey(0))['params']
+    prms = gqa_params
     mesh = build_serve_mesh(2, n_heads=TINY_GQA.n_heads,
                             n_kv_heads=TINY_GQA.n_kv_heads)
     engine = DecodeEngine(Llama(TINY_GQA, mesh), prms,
@@ -139,6 +144,30 @@ def test_sharded_engine_gqa(params):
     while req.finished_at is None:
         engine.step()
     assert req.tokens() == naive_greedy(TINY_GQA, prms, prompt, 6)
+
+
+@pytest.mark.parametrize('steps_per_call', [1, 3])
+def test_contiguous_engine_gqa_pipelined_matches_naive(gqa_params,
+                                                       steps_per_call):
+    """The engine path, not only the op, on a GQA shape (4 q heads over
+    2 kv heads, one device): prefill, insert and the pipelined decode
+    loop with a staggered admission give the naive full-recompute
+    greedy tokens."""
+    prms = gqa_params
+    engine = DecodeEngine(Llama(TINY_GQA), prms,
+                          EngineConfig(n_slots=2, prefill_buckets=(8, 16),
+                                       steps_per_call=steps_per_call))
+    p1, p2 = [1, 2, 3], [7, 8, 9, 10, 11, 12, 13, 14, 15]
+    r1 = engine.submit(p1, 9)
+    for _ in range(2):
+        engine.step_pipelined()
+    r2 = engine.submit(p2, 6)
+    for _ in range(200):
+        engine.step_pipelined()
+        if r1.finished_at is not None and r2.finished_at is not None:
+            break
+    assert r1.tokens() == naive_greedy(TINY_GQA, prms, p1, 9)
+    assert r2.tokens() == naive_greedy(TINY_GQA, prms, p2, 6)
 
 
 def test_sharded_engine_slot_reuse_no_kv_leak(params):
