@@ -12,24 +12,10 @@ from __future__ import annotations
 
 from typing import List, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.harness import weights
-from benchmarks.reference import llama_ref
-
 Sample = Tuple[Sequence[int], Sequence[int]]      # (prompt, served tokens)
-
-
-def _model(dims, seed: int, dtype, precision: str) -> llama_ref.LayerwiseModel:
-    key = weights.seed_key(seed)
-    # The layer's index is traced: one program makes every layer's weights.
-    layer = jax.jit(lambda i: llama_ref.to_f32(
-        weights.layer_weights(key, dims, i, dtype)))
-    outer = jax.jit(lambda: llama_ref.to_f32(
-        weights.outer_weights(key, dims, dtype)))
-    return llama_ref.LayerwiseModel(dims, layer, outer, precision)
 
 
 def _pack(samples: List[Sample], longest: int, n_out: int):
@@ -49,17 +35,15 @@ def _pack(samples: List[Sample], longest: int, n_out: int):
     return rows, at, served, valid
 
 
-def reference_logits(dims, seed: int, dtype, samples: List[Sample],
+def reference_logits(family, dims, seed: int, dtype, samples: List[Sample],
                      shape: Tuple[int, int], precision: str = 'float32'):
     """Logits [N, T, vocab] at the served positions, with (served, valid).
     `shape` is the mix's (longest prompt + answer, longest answer)."""
     rows, at, served, valid = _pack(samples, *shape)
-    model = _model(dims, seed, dtype, precision)
+    model = family.reference(dims, seed, dtype, precision)
     hidden = model.hidden(jnp.asarray(rows))
     picked = jnp.take_along_axis(hidden, jnp.asarray(at)[:, :, None], axis=1)
-    with jax.default_matmul_precision('highest'):
-        logits = model._head(model._make_outer(), picked)  # pylint: disable=protected-access
-    return np.asarray(logits), served, valid
+    return np.asarray(model.logits_at(picked)), served, valid
 
 
 def gaps_below_best(logits: np.ndarray, tokens: np.ndarray,
@@ -70,20 +54,20 @@ def gaps_below_best(logits: np.ndarray, tokens: np.ndarray,
     return (best - own)[valid]
 
 
-def served_gap(dims, seed: int, dtype, samples: List[Sample],
+def served_gap(family, dims, seed: int, dtype, samples: List[Sample],
                shape: Tuple[int, int], control: str = None) -> dict:
     """The run's number, and with `control` the same number for the
     reference in that lower precision put in the program's place."""
-    logits, served, valid = reference_logits(dims, seed, dtype, samples,
-                                             shape)
+    logits, served, valid = reference_logits(family, dims, seed, dtype,
+                                             samples, shape)
     gaps = gaps_below_best(logits, served, valid)
     out = {'widest_gap': float(gaps.max()), 'mean_gap': float(gaps.mean()),
            'positions': int(valid.sum()),
            'finite': bool(np.isfinite(logits[valid]).all()),
            'off_best': int((gaps > 0).sum())}
     if control:
-        low, _, _ = reference_logits(dims, seed, dtype, samples, shape,
-                                     control)
+        low, _, _ = reference_logits(family, dims, seed, dtype, samples,
+                                     shape, control)
         gaps = gaps_below_best(logits, low.argmax(axis=-1).astype(np.int32),
                                valid)
         out['control'] = {'widest_gap': float(gaps.max()),

@@ -9,8 +9,9 @@ finds nothing to read; the harness then leaves the metric out.
 `ctx` keys: `samples` (name -> list, the client's readings), `spans` (name ->
 list of ms, the flight recorder's), `counters` (name -> after - before),
 `trace` (harness.trace's plain data or None), `trace_span` (seconds from the
-opening), `records`, `memory_peak_bytes`, `dims`, `config`, `mix`, `peaks`,
-`chips`, `values` (metrics already reduced in this run), `seconds`.
+opening), `records`, `memory_peak_bytes`, `family` (the configuration's
+module of `benchmarks/families/`), `dims`, `config`, `mix`, `peaks`, `chips`,
+`values` (metrics already reduced in this run), `seconds`.
 """
 from __future__ import annotations
 
@@ -119,7 +120,8 @@ def decode_roofline_pct(ctx, step_metric: str):
         return None
     load = live_load(ctx['records'], ctx['trace_span'])
     least = costs.least_seconds(
-        costs.decode_step_cost(ctx['dims'], load['slots'], load['positions']),
+        ctx['family'].decode_step_cost(ctx['dims'], load['slots'],
+                                       load['positions']),
         ctx['peaks'])
     print(f'decode_roofline_pct: bound by {least["bound"]}; live slots '
           f'{load["slots"]:.2f}, live positions {load["positions"]:.0f}, '
@@ -131,7 +133,8 @@ def train_mfu_pct(ctx):
     rate = ctx['samples'].get('train_tokens_per_s')
     if not rate or not ctx.get('peaks'):
         return None
-    flops = costs.train_flops_per_token(ctx['dims'], ctx['mix']['seq_len'])
+    flops = ctx['family'].train_flops_per_token(ctx['dims'],
+                                                ctx['mix']['seq_len'])
     return 100.0 * flops * rate[0] / (
         ctx['chips'] * ctx['peaks']['bf16_flops_per_s'])
 

@@ -2,17 +2,21 @@
 client's load, the window's readings, and `correct`."""
 from __future__ import annotations
 
+import dataclasses
 import gc
 import shutil
 import tempfile
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 
+from benchmarks import families
 from benchmarks.harness import check, loadgen, traffic, weights
 
 COMPILE_COUNTER = 'skytpu_engine_xla_compile_total'
+DTYPE = jnp.bfloat16        # weights, activations and cache as served
 
 
 def counters() -> dict:
@@ -44,34 +48,25 @@ def note(what: str, since: float) -> float:
     return now
 
 
-def build_engine(config: dict, dims: weights.Dims, seed: int, device):
+def build_engine(family, config: dict, dims, seed: int, device):
     import jax
-    import jax.numpy as jnp
     from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
-    from skypilot_tpu.models.llama import Llama, LlamaConfig
 
-    s = config['serve']
-    lcfg = LlamaConfig(
-        vocab_size=dims.vocab, dim=dims.hidden, n_layers=dims.layers,
-        n_heads=dims.heads, n_kv_heads=dims.kv_heads, ffn_dim=dims.ffn,
-        rope_theta=dims.rope_theta, norm_eps=dims.eps,
-        max_seq_len=s['max_seq_len'], tie_embeddings=False,
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
-    if lcfg.head_dim != dims.head_dim:
-        raise SystemExit('head_dim is not hidden_size / heads: the program '
-                         'cannot express this configuration')
+    model = families.need(
+        family, 'serve_model', 'a serving mix needs the module that '
+        '`DecodeEngine` is handed')(dims, config, DTYPE)
+    # Every key of `serve` that is a field of `EngineConfig`, by its name.
+    fields = {f.name for f in dataclasses.fields(EngineConfig)}
+    options = {k: tuple(v) if isinstance(v, list) else v
+               for k, v in config['serve'].items() if k in fields}
     t = time.perf_counter()
     with jax.default_device(device):
         params = jax.jit(
-            lambda k: weights.make_params(k, dims, jnp.bfloat16))(
+            lambda k: family.make_params(k, dims, DTYPE))(
                 weights.seed_key(seed))
         jax.block_until_ready(params)
         t = note('weights made on the device', t)
-        engine = DecodeEngine(Llama(lcfg), params, EngineConfig(
-            n_slots=s['n_slots'], prefill_buckets=tuple(s['prefill_buckets']),
-            steps_per_call=s['steps_per_call'],
-            max_prompt_len=s['max_prompt_len'],
-            kv_page_size=s.get('kv_page_size')))
+        engine = DecodeEngine(model, params, EngineConfig(**options))
         del params
         t = note('engine built (decode program, layout pass)', t)
         engine.prewarm()
@@ -149,15 +144,14 @@ def pick_for_check(whole, seed: int, n: int):
     return [longest] + [rest[i] for i in idx]
 
 
-def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
+def run_cell(*, family, config, mix, dims, seed, seconds, traced, devices,
              control=False, submit_wrapper=None):
     """Returns (ctx for the readers, result fields).  `submit_wrapper` is
     for the test that breaks the timed path underneath."""
     import jax
-    import jax.numpy as jnp
     from skypilot_tpu.server import tracing
 
-    engine = build_engine(config, dims, seed, devices[0])
+    engine = build_engine(family, config, dims, seed, devices[0])
     plan = traffic.plan_requests(mix, seed, seconds, dims.vocab)
     submit = engine.submit
     if submit_wrapper is not None:
@@ -201,7 +195,7 @@ def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
         longest_out = int(mix['output_tokens'].get(
             'max', mix['output_tokens'].get('value', 0)))
         verdict = check.served_gap(
-            dims, seed, jnp.bfloat16,
+            family, dims, seed, DTYPE,
             [(r.plan.prompt, r.tokens) for r in picked],
             (int(mix['prompt_tokens']['max']) + longest_out, longest_out),
             config['check']['control'] if control else None)
@@ -214,6 +208,9 @@ def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
           f'over {verdict.get("positions", 0)} positions of {len(picked)} '
           f'requests; requests whole {len(whole)} of {len(recs)} '
           f'(limit: all)')
+    verdict.update(requests_whole=len(whole), limits={
+        'widest_gap': limits['served_gap_limit'],
+        'mean_gap': limits['mean_gap_limit'], 'requests_whole': len(recs)})
     correct = bool(picked and verdict['finite'] and not failed and
                    verdict['widest_gap'] <= limits['served_gap_limit'] and
                    verdict['mean_gap'] <= limits['mean_gap_limit'])

@@ -7,10 +7,10 @@ import time
 
 import numpy as np
 
+from benchmarks import families
 from benchmarks.harness import traffic, weights
 from benchmarks.harness.serve import (Tracer, counters, memory_peak_bytes,
                                       note)
-from benchmarks.reference import train_ref
 
 CHECK_STEPS = 3
 
@@ -50,7 +50,7 @@ def _leaf_norms(tree) -> dict:
     norms = jax.jit(lambda t: jax.tree.map(
         lambda a: jnp.sqrt(jnp.sum(jnp.square(a.astype(jnp.float32)))), t))(
             tree)
-    return {k: float(v) for k, v in train_ref.flat(
+    return {k: float(v) for k, v in weights.flat(
         jax.device_get(norms)).items()}
 
 
@@ -63,47 +63,45 @@ def _adam_mu(opt_state):
     raise SystemExit('no Adam moments in the optimizer state')
 
 
-def build_trainer(config, dims, seed, devices, first_batch):
+def build_trainer(family, config, dims, seed, devices, first_batch):
     import jax
     import jax.numpy as jnp
-    from skypilot_tpu.models.llama import Llama, LlamaConfig
     from skypilot_tpu.parallel.mesh import build_mesh, plan_mesh
     from skypilot_tpu.train.trainer import TrainConfig, Trainer
 
     t = config['train']
     mesh = build_mesh(plan_mesh(len(devices), fsdp=t['fsdp']), devices)
-    lcfg = LlamaConfig(
-        vocab_size=dims.vocab, dim=dims.hidden, n_layers=dims.layers,
-        n_heads=dims.heads, n_kv_heads=dims.kv_heads, ffn_dim=dims.ffn,
-        rope_theta=dims.rope_theta, norm_eps=dims.eps,
-        max_seq_len=first_batch.shape[1], tie_embeddings=False,
-        attention_impl=t['attention_impl'])
-    trainer = Trainer(Llama(lcfg, mesh), mesh, jax.random.PRNGKey(0),
-                      first_batch, TrainConfig(**t['optimizer']))
+    model = families.need(
+        family, 'train_model', 'a training mix needs the module that '
+        '`Trainer` is handed')(dims, config, mesh, first_batch.shape[1])
+    trainer = Trainer(model, mesh, jax.random.PRNGKey(0), first_batch,
+                      TrainConfig(**t['optimizer']))
     # The benchmark's own seeded weights in the trainer's place and
     # shardings: the reference makes the same ones again from the seed.
-    make = jax.jit(lambda k: weights.make_params(k, dims, jnp.float32),
+    make = jax.jit(lambda k: family.make_params(k, dims, jnp.float32),
                    out_shardings=trainer.shardings.params)
     trainer.state = trainer.state.replace(params=make(weights.seed_key(seed)))
     delta = jax.jit(
         lambda p, k: jax.tree.map(
             lambda a, b: jnp.sqrt(jnp.sum(jnp.square(a - b))), p,
-            weights.make_params(k, dims, jnp.float32)),
+            family.make_params(k, dims, jnp.float32)),
         in_shardings=(trainer.shardings.params, None))
     return trainer, delta
 
 
-def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
+def run_cell(*, family, config, mix, dims, seed, seconds, traced, devices,
              control=False, step_wrapper=None):
     """Returns (ctx for the readers, result fields).  `step_wrapper` is for
     the test that breaks the timed path underneath."""
     import jax
+    import jax.numpy as jnp
 
     rows = int(mix['sequences_per_step'])
     feed = Feed(traffic.train_batches(mix, seed, dims.vocab, rows))
     first = np.zeros((rows, int(mix['seq_len'])), np.int32)  # a shape only
     t = time.perf_counter()
-    trainer, delta_fn = build_trainer(config, dims, seed, devices, first)
+    trainer, delta_fn = build_trainer(family, config, dims, seed, devices,
+                                      first)
     t = note('trainer built, seeded weights in place', t)
     if step_wrapper is not None:
         trainer.train_step = step_wrapper(trainer.train_step)
@@ -120,7 +118,7 @@ def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
 
     trainer.run(feed, num_steps=CHECK_STEPS, log_every=1,
                 log_fn=first_steps_log)
-    program['delta_norms'] = {k: float(v) for k, v in train_ref.flat(
+    program['delta_norms'] = {k: float(v) for k, v in weights.flat(
         jax.device_get(delta_fn(trainer.state.params,
                                 weights.seed_key(seed)))).items()}
     t = note('first three steps (compile) and their norms', t)
@@ -153,12 +151,14 @@ def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
     gc.collect()
     jax.clear_caches()
     t = note('window', t)
-    reference = train_ref.first_steps(dims, seed, kept, opt, devices)
+    reference = family.reference(dims, seed, jnp.float32).first_steps(
+        kept, opt, devices)
     note('reference, three steps', t)
     verdict, correct = compare(program, reference, config['check'])
     if control:
-        low = train_ref.first_steps(dims, seed, kept, opt, devices,
-                                    config['check']['control'])
+        low = family.reference(
+            dims, seed, jnp.float32, config['check']['control']).first_steps(
+                kept, opt, devices)
         print(f'control ({config["check"]["control"]}):')
         verdict['control'], _ = compare(low, reference, config['check'])
     ctx = {
@@ -174,14 +174,25 @@ def run_cell(*, config, mix, dims, seed, seconds, traced, devices,
     return ctx, info
 
 
+def worst_leaf_gap(program: dict, reference: dict) -> dict:
+    """The gap between the program's norm and the reference's, leaf by
+    leaf, against the reference's norm of that leaf or of the median leaf,
+    whichever is larger; the worst leaf, and (steadier from seed to seed)
+    the mean over the leaves."""
+    median = float(np.median(list(reference.values())))
+    gaps = {name: abs(program[name] - ref) / max(ref, median)
+            for name, ref in reference.items()}
+    where = max(gaps, key=gaps.get)
+    return {'gap': gaps[where], 'leaf': where,
+            'mean': float(np.mean(list(gaps.values())))}
+
+
 def compare(program: dict, reference: dict, limits: dict):
     """Each number compared, printed beside its limit."""
     loss_gaps = [abs(a - b) / abs(b) for a, b in
                  zip(program['losses'], reference['losses'])]
-    grad = train_ref.worst_leaf_gap(program['grad_norms'],
-                                    reference['grad_norms'])
-    delta = train_ref.worst_leaf_gap(program['delta_norms'],
-                                     reference['delta_norms'])
+    grad = worst_leaf_gap(program['grad_norms'], reference['grad_norms'])
+    delta = worst_leaf_gap(program['delta_norms'], reference['delta_norms'])
     verdict = {
         'loss_rel_gap': max(loss_gaps) if len(loss_gaps) == CHECK_STEPS
         else float('inf'),
@@ -192,11 +203,13 @@ def compare(program: dict, reference: dict, limits: dict):
         'losses': program['losses'], 'reference_losses': reference['losses'],
     }
     ok = True
+    verdict['limits'] = {}
     for name, limit_key in (('loss_rel_gap', 'loss_rel_limit'),
                             ('grad_norm_gap', 'grad_norm_limit'),
                             ('grad_norm_mean_gap', 'grad_norm_mean_limit'),
                             ('delta_norm_gap', 'delta_norm_limit')):
         value, limit = verdict[name], limits[limit_key]
+        verdict['limits'][name] = limit
         print(f'correct: {name} {value} (limit {limit})')
         ok = ok and bool(np.isfinite(value)) and value <= limit
     return verdict, ok

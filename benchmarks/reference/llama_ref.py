@@ -94,7 +94,7 @@ def attention(q, k, v):
 
 def layer_forward(w, x, *, theta, eps, matmul=plain_matmul):
     """One block. x: [B, S, hidden] float32; `w` one layer of the tree made
-    by `harness.weights.layer_weights`, in float32."""
+    by the family's `layer_weights`, in float32."""
     a = w['attn']
     h = rms_norm(x, w['attn_norm']['scale'], eps)
     q = matmul('bsd,dhk->bhsk', h, a['q_proj']['kernel'])
@@ -155,6 +155,7 @@ class LayerwiseModel:
                 x = self._layer(self._make_layer(i), x)
         return x
 
-    def logits(self, tokens):
+    def logits_at(self, hidden_rows):
+        """The output head over rows [B, T, hidden] picked from `hidden`."""
         with jax.default_matmul_precision('highest'):
-            return self._head(self._make_outer(), self.hidden(tokens))
+            return self._head(self._make_outer(), hidden_rows)

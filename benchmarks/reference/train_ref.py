@@ -10,23 +10,14 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Dict, List
+from typing import List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmarks.harness import weights
+from benchmarks.harness.weights import flat, seed_key
 from benchmarks.reference import llama_ref
-
-
-def flat(tree, prefix='') -> Dict[str, object]:
-    out = {}
-    for k in sorted(tree):
-        v = tree[k]
-        name = f'{prefix}/{k}' if prefix else k
-        out.update(flat(v, name) if isinstance(v, dict) else {name: v})
-    return out
 
 
 def learning_rate(count: int, opt: dict) -> float:
@@ -54,15 +45,17 @@ def _adamw(p, g, m, v, lr, count, b1, b2, wd, clip_scale):
     return p - lr * (m_hat / (jnp.sqrt(v_hat) + 1e-8) + wd * p), m, v
 
 
-def first_steps(dims: weights.Dims, seed: int, batches: List[np.ndarray],
-                opt: dict, devices, precision: str = 'float32') -> dict:
-    """Follow `len(batches)` steps from the seeded weights.  Returns the
-    loss of each step, the norm of each leaf of the first gradient as the
-    optimizer gets it (after clipping), and the norm of each leaf's change
-    over all the steps."""
+def first_steps(dims, seed: int, batches: List[np.ndarray], opt: dict,
+                devices, precision: str = 'float32', *, layer_weights,
+                outer_weights) -> dict:
+    """Follow `len(batches)` steps from the seeded weights, which the
+    family's `layer_weights` and `outer_weights` make.  Returns the loss of
+    each step, the norm of each leaf of the first gradient as the optimizer
+    gets it (after clipping), and the norm of each leaf's change over all
+    the steps."""
     mm = llama_ref.MATMULS[precision]
     n_dev = len(devices)
-    key = weights.seed_key(seed)
+    key = seed_key(seed)
     kw = dict(theta=dims.rope_theta, eps=dims.eps, matmul=mm)
     layer_fwd = jax.jit(functools.partial(llama_ref.layer_forward, **kw))
 
@@ -80,10 +73,10 @@ def first_steps(dims: weights.Dims, seed: int, batches: List[np.ndarray],
 
     def make_layer(i):
         with jax.default_device(devices[i % n_dev]):
-            return weights.layer_weights(key, dims, i, jnp.float32)
+            return layer_weights(key, dims, i, jnp.float32)
 
     with jax.default_device(devices[0]):
-        outer = weights.outer_weights(key, dims, jnp.float32)
+        outer = outer_weights(key, dims, jnp.float32)
     layers = [make_layer(i) for i in range(dims.layers)]
     moments = {}                 # leaf name -> (m, v) on the host
     losses, first_grad = [], None
@@ -159,7 +152,7 @@ def first_steps(dims: weights.Dims, seed: int, batches: List[np.ndarray],
             outer, layers = _unflatten(new, dims.layers)
     final = flat(dict(outer, **{f'layer_{i}': w
                                 for i, w in enumerate(layers)}))
-    start = flat(weights.outer_weights(key, dims, jnp.float32))
+    start = flat(outer_weights(key, dims, jnp.float32))
     delta = {}
     for name, p in final.items():
         if name.startswith('layer_'):
@@ -181,17 +174,3 @@ def _unflatten(flat_tree: dict, n_layers: int):
         node[parts[-1]] = leaf
     layers = [tree.pop(f'layer_{i}') for i in range(n_layers)]
     return tree, layers
-
-
-def worst_leaf_gap(program: Dict[str, float],
-                   reference: Dict[str, float]) -> dict:
-    """The gap between the program's norm and the reference's, leaf by
-    leaf, against the reference's norm of that leaf or of the median leaf,
-    whichever is larger; the worst leaf, and (steadier from seed to seed)
-    the mean over the leaves."""
-    median = float(np.median(list(reference.values())))
-    gaps = {name: abs(program[name] - ref) / max(ref, median)
-            for name, ref in reference.items()}
-    where = max(gaps, key=gaps.get)
-    return {'gap': gaps[where], 'leaf': where,
-            'mean': float(np.mean(list(gaps.values())))}

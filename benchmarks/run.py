@@ -27,7 +27,8 @@ sys.path.insert(0, ROOT)
 os.environ.setdefault('SKYTPU_TRACE_RING_SIZE', '65536')
 os.environ.setdefault('TPU_LOG_DIR', 'disabled')
 
-from benchmarks.harness import manifest, reducers, weights  # noqa: E402
+from benchmarks import families  # noqa: E402
+from benchmarks.harness import manifest, reducers  # noqa: E402
 from benchmarks.harness import trace as trace_lib  # noqa: E402
 
 
@@ -47,9 +48,10 @@ def claim_devices(chips: int, rehearse: bool):
 
 
 def shrink_for_rehearsal(config: dict, mix: dict) -> None:
-    """Tiny widths and lengths, in place: control flow only."""
+    """Tiny widths and lengths, in place: control flow only.  The widths
+    are the family's, the rest `rehearsal.json`'s."""
     tiny = manifest.load_json(manifest.BENCH_DIR, 'rehearsal.json')
-    config.update(tiny['config'])
+    config.update(families.load(config).REHEARSAL)
     for group in ('serve', 'train', 'check'):    # limits read at this size
         if group in config:
             config[group].update({k: v for k, v in tiny.get(group, {}).items()
@@ -97,16 +99,17 @@ def main(argv=None) -> int:
     from skypilot_tpu.perf import compile_telemetry
     compile_telemetry.install()
 
-    dims = weights.Dims.from_config(config)
+    family = families.load(config)
+    dims = family.dims(config)
     if mix['kind'] == 'train':
         from benchmarks.harness import train as driver
     else:
         from benchmarks.harness import serve as driver
     ctx, info = driver.run_cell(
-        config=config, mix=mix, dims=dims, seed=args.seed,
+        family=family, config=config, mix=mix, dims=dims, seed=args.seed,
         seconds=args.seconds, traced=bool(args.trace), devices=devices,
         control=bool(args.control))
-    ctx.update(dims=dims, config=config, mix=mix, peaks=peaks,
+    ctx.update(family=family, dims=dims, config=config, mix=mix, peaks=peaks,
                chips=cell['chips'], seconds=args.seconds, values={})
     ctx['samples']['setup_s'] = [info['t_open'] - _T0]
 
@@ -123,8 +126,7 @@ def main(argv=None) -> int:
               'memory_peak_bytes': ctx['memory_peak_bytes']}
     line = {'correct': info['correct'], 'attempted': info['attempted'],
             'failed': info['failed'], 'metrics': metrics, 'device': device,
-            'workload': args.workload, 'seed': args.seed,
-            'check': info['check']}
+            'workload': args.workload, 'seed': args.seed}
     for k in ('queue_at_open', 'queue_at_close'):
         if k in info:
             line[k] = info[k]
@@ -146,7 +148,11 @@ def main(argv=None) -> int:
         line['rehearsal'] = True
         line['rehearsal_metrics'] = line.pop('metrics')
         line['metrics'] = {}
+    # Each number compared, beside its limit: the line's last key, and the
+    # last line of standard error.
+    line['check'] = info['check']
     print(json.dumps(line), flush=True)
+    print(f'check: {json.dumps(info["check"])}', file=sys.stderr, flush=True)
     return 0
 
 

@@ -6,6 +6,7 @@ import os
 
 import pytest
 
+from benchmarks import families
 from benchmarks.harness import costs, manifest, weights
 
 MAN = manifest.manifest()
@@ -28,10 +29,18 @@ def config_file(name):
     return manifest.load_json(manifest.BENCH_DIR, 'configs', f'{name}.json')
 
 
+def dims_of(name):
+    cfg = config_file(name)
+    return families.load(cfg).dims(cfg)
+
+
+LLAMA = families.load({'architecture': 'LlamaForCausalLM'})
+
+
 @pytest.mark.parametrize('name', sorted(PUBLISHED))
 def test_param_count(name):
     cfg = config_file(name)
-    dims = weights.Dims.from_config(cfg)
+    dims = dims_of(name)
     assert dims.num_params() == PUBLISHED[name] == cfg['params_total']
     from skypilot_tpu.models.llama import LlamaConfig
     prog = LlamaConfig(vocab_size=dims.vocab, dim=dims.hidden,
@@ -44,9 +53,9 @@ def test_param_count(name):
 def test_round_totals():
     assert round(PUBLISHED['yi-6b'] / 1e9, 3) == 6.061
     assert round(PUBLISHED['yi-coder-1.5b'] / 1e9, 3) == 1.476
-    six = weights.Dims.from_config(config_file('yi-6b'))
+    six = dims_of('yi-6b')
     assert six.kv_bytes_per_position() == 65536
-    chat = weights.Dims.from_config(config_file('yi-coder-1.5b-chat'))
+    chat = dims_of('yi-coder-1.5b-chat')
     assert chat.kv_bytes_per_position() == 196608
 
 
@@ -55,23 +64,23 @@ def test_weights_match_the_programs_tree():
     import jax.numpy as jnp
     import flax.linen as nn
     from skypilot_tpu.models.llama import Llama, LlamaConfig
-    dims = weights.Dims(hidden=64, layers=2, heads=4, kv_heads=2,
-                        head_dim=16, ffn=128, vocab=256, rope_theta=1e4,
-                        eps=1e-5)
+    dims = LLAMA.Dims(hidden=64, layers=2, heads=4, kv_heads=2,
+                      head_dim=16, ffn=128, vocab=256, rope_theta=1e4,
+                      eps=1e-5)
     model = Llama(LlamaConfig(vocab_size=256, dim=64, n_layers=2, n_heads=4,
                               n_kv_heads=2, ffn_dim=128, max_seq_len=32,
                               remat=False))
     theirs = nn.meta.unbox(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))['params']
-    ours = jax.eval_shape(lambda: weights.make_params(
+    ours = jax.eval_shape(lambda: LLAMA.make_params(
         weights.seed_key(2**31 + 7), dims, jnp.float32))
     assert jax.tree.structure(theirs) == jax.tree.structure(ours)
     assert jax.tree.map(lambda a: a.shape, theirs) == \
         jax.tree.map(lambda a: a.shape, ours)
     # A layer made alone (as the reference makes it) is the tree's layer.
     key = weights.seed_key(11)
-    whole = weights.make_params(key, dims, jnp.bfloat16)
-    alone = weights.layer_weights(key, dims, 1, jnp.bfloat16)
+    whole = LLAMA.make_params(key, dims, jnp.bfloat16)
+    alone = LLAMA.layer_weights(key, dims, 1, jnp.bfloat16)
     assert all(jax.tree.leaves(jax.tree.map(
         lambda a, b: bool((a == b).all()), whole['layer_1'], alone)))
 
@@ -98,9 +107,9 @@ def test_manifest_meets_the_contract():
 
 
 def test_decode_cost_counts_live_positions_only():
-    dims = weights.Dims.from_config(config_file('yi-6b'))
-    none = costs.decode_step_cost(dims, 8, 0)
-    some = costs.decode_step_cost(dims, 8, 8 * 500)
+    dims = dims_of('yi-6b')
+    none = LLAMA.decode_step_cost(dims, 8, 0)
+    some = LLAMA.decode_step_cost(dims, 8, 8 * 500)
     assert none['bytes'] == 2 * dims.matmul_params()
     assert some['bytes'] - none['bytes'] == 65536 * 4000
     least = costs.least_seconds(some, manifest.peaks_for('TPU v5 lite'))

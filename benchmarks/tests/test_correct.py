@@ -8,13 +8,15 @@ skipping run.py's look for a chip and driving the rest of a run:
 * the timed path broken underneath (a served token altered where it is
   produced; a training step that returns its state unchanged; a part of the
   batch left out): `correct` comes out false.
+
+Every run goes through the configuration's family, as `run.py` does.
 """
 import copy
 
 import jax
 
-from benchmarks import run as run_lib
-from benchmarks.harness import manifest, serve, train, weights
+from benchmarks import families, run as run_lib
+from benchmarks.harness import manifest, serve, train
 
 MAN = manifest.manifest()
 SEEDS = (11, 2**31 + 12, 13)
@@ -25,20 +27,20 @@ def small(cell_name):
     config = copy.deepcopy(manifest.config_of(MAN, cell['config']))
     mix = copy.deepcopy(manifest.traffic_of(cell['traffic']))
     run_lib.shrink_for_rehearsal(config, mix)
-    return config, mix, weights.Dims.from_config(config)
+    return config, mix, families.load(config)
 
 
 def serve_once(seed, wrapper=None, control=False, cell='yi-6b.batch-backlog'):
-    config, mix, _ = small(cell)
+    config, mix, family = small(cell)
     # A little wider than the rehearsal's, and every request compared, so
     # that a run reads some hundreds of positions.  Read at this size on
     # seeds 11, 2**31 + 12, 13: sound runs 0.017-0.024, control 0.061-0.071.
     config.update(hidden_size=128, num_hidden_layers=4, head_dim=32,
                   intermediate_size=256, vocab_size=8192)
     mix['check_sample'] = 24
-    dims = weights.Dims.from_config(config)
     config['check'].update(served_gap_limit=0.04, mean_gap_limit=1.0)
-    _, info = serve.run_cell(config=config, mix=mix, dims=dims, seed=seed,
+    _, info = serve.run_cell(family=family, config=config, mix=mix,
+                             dims=family.dims(config), seed=seed,
                              seconds=8.0, traced=False,
                              devices=jax.devices()[:1], control=control,
                              submit_wrapper=wrapper)
@@ -46,12 +48,13 @@ def serve_once(seed, wrapper=None, control=False, cell='yi-6b.batch-backlog'):
 
 
 def train_once(seed, wrapper=None, control=False):
-    config, mix, dims = small('yi-coder-1.5b-1chip.pretrain-4k')
+    config, mix, family = small('yi-coder-1.5b-1chip.pretrain-4k')
     # Limits read at this size (sound runs: loss 6e-5, gradient 2e-3,
     # change 1e-3; control: gradient 9e-3 and more).
     config['check'].update(loss_rel_limit=1e-3, grad_norm_limit=5e-3,
                            grad_norm_mean_limit=1.0, delta_norm_limit=0.3)
-    _, info = train.run_cell(config=config, mix=mix, dims=dims, seed=seed,
+    _, info = train.run_cell(family=family, config=config, mix=mix,
+                             dims=family.dims(config), seed=seed,
                              seconds=1.0, traced=False,
                              devices=jax.devices()[:1], control=control,
                              step_wrapper=wrapper)

@@ -18,9 +18,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 
-from benchmarks import run as run_lib  # noqa: E402
+from benchmarks import families, run as run_lib  # noqa: E402
 from benchmarks.harness import loadgen, manifest, reducers, serve  # noqa: E402
-from benchmarks.harness import traffic, weights  # noqa: E402
+from benchmarks.harness import traffic  # noqa: E402
 
 
 def main() -> int:
@@ -41,9 +41,10 @@ def main() -> int:
     if not args.rehearse:
         from skypilot_tpu.utils import compile_cache
         compile_cache.enable()
-    dims = weights.Dims.from_config(config)
+    family = families.load(config)
+    dims = family.dims(config)
     t0 = time.perf_counter()
-    engine = serve.build_engine(config, dims, args.seed, devices[0])
+    engine = serve.build_engine(family, config, dims, args.seed, devices[0])
     print(json.dumps({'build_s': time.perf_counter() - t0,
                       'device': devices[0].device_kind}), flush=True)
     engine.start()
