@@ -371,6 +371,15 @@ class DecodeEngine:
             buckets = (max_len,)
         config = dataclasses.replace(config, prefill_buckets=buckets)
         self._validate_paging(config, max_len)
+        if getattr(model, 'recurrent_state', False) and (
+                config.kv_page_size is not None or config.speculation):
+            raise ValueError(
+                f'{type(model).__name__} keeps recurrent state beside its '
+                f'keys and values, and the page manager holds keys and '
+                f'values only: kv_page_size, speculation and KV transfer '
+                f'(submit_prefill / submit_adopt, which need pages) are '
+                f'not available with it; leave kv_page_size None and '
+                f'speculation 0')
         self.cfg = config
         self._rng = jax.random.PRNGKey(config.seed)
         self._prefill_q: 'queue.Queue[Request]' = queue.Queue()
@@ -479,8 +488,17 @@ class DecodeEngine:
         # after a swap; on the plain path the tree is the caller's and
         # is only ever dereferenced.
         self._params_owned = self._mesh is not None
+        self._stats_abs = (self._decode_stats_abs()
+                           if hasattr(model, 'publish_stats') and
+                           not self._paged else None)
         self._build_fns()
         self._init_cache()
+        # Leaves named k / v hold keys and values per position, any
+        # other leaf is per-slot recurrent state.
+        for kind, n_bytes in cost_model_lib.cache_bytes_by_kind(
+                self._cache).items():
+            metrics_lib.set_gauge('skytpu_engine_cache_bytes',
+                                  float(n_bytes), kind=kind)
         if (jax.default_backend() == 'tpu' and self._mesh is None and
                 not self._paged):
             # The AOT layout pass is specialized to the contiguous
@@ -500,7 +518,7 @@ class DecodeEngine:
         compile_telemetry.install()
         self._cost_model = cost_model_lib.EngineCostModel.from_engine_state(
             self.model.cfg, jax.tree_util.tree_leaves(self.params),
-            jax.tree_util.tree_leaves(self._cache),
+            self._cache,
             n_chips=self._mesh.size if self._mesh is not None else 1,
             kv_dtype=config.kv_dtype if self._paged else None)
 
@@ -685,6 +703,17 @@ class DecodeEngine:
             decode=True, mutable=['cache'])
         return cache['cache']
 
+    def _decode_stats_abs(self):
+        """The `stats` collection one decode step of the model sows,
+        abstractly (empty where it sows none)."""
+        def one_step(params):
+            tokens = jnp.zeros((self.cfg.n_slots, 1), jnp.int32)
+            _, out = self.model.apply(
+                {'params': params}, tokens, positions=tokens, decode=True,
+                mutable=['cache', 'stats'])
+            return out.get('stats', {})
+        return jax.eval_shape(one_step, self.params)
+
     # ----- jitted compute ----------------------------------------------------
     def _build_fns(self):
         model, temp = self.model, self.cfg.temperature
@@ -693,6 +722,17 @@ class DecodeEngine:
             if temp > 0.0:
                 return jax.random.categorical(rng, logits / temp, axis=-1)
             return jnp.argmax(logits, axis=-1)
+
+        def last_logits(logits, index):
+            """logits [N, P, V] at each row's `index` [N].  A model that
+            is told the rows' lengths may return the last valid
+            position's logits alone, [N, 1, V]: nothing else of a
+            prefill's logits is read, and at 32 rows of 1024 the rest is
+            gigabytes."""
+            if logits.shape[1] == 1:     # (chunk inserts read it so too)
+                return logits[:, 0]
+            return jnp.take_along_axis(
+                logits, index[:, None, None], axis=1)[:, 0]
 
         def prefill_insert(params, big_cache, last_toks, lens, tokens,
                            lengths, slots, valid, rng):
@@ -705,11 +745,12 @@ class DecodeEngine:
             bursts."""
             n, p = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
+            # `lengths`: a layer with recurrent state stops at each
+            # row's valid length (padding must not fold into it).
             logits, cache = model.apply(
                 {'params': params}, tokens, positions=positions,
-                decode=True, mutable=['cache'])
-            last = jnp.take_along_axis(
-                logits, (lengths - 1)[:, None, None], axis=1)[:, 0]  # [N,V]
+                decode=True, lengths=lengths, mutable=['cache'])
+            last = last_logits(logits, lengths - 1)                  # [N,V]
             firsts = sample(last, rng)                               # [N]
             # Padding rows replicate row 0, so their duplicate scatter
             # writes must carry row 0's VALUE too — under temperature
@@ -730,27 +771,44 @@ class DecodeEngine:
 
         steps = self.cfg.steps_per_call
         max_len = model.cfg.max_seq_len
+        # A model that sows a `stats` collection (models/moe.py: routing
+        # counts) has it summed over the call's steps on the device and
+        # returned beside the tokens; a model without one (Llama) keeps
+        # the program it had.
+        stats_abs = self._stats_abs
+        mutable = ['cache', 'stats'] if stats_abs else ['cache']
+
+        def stats0():
+            if not stats_abs:
+                return None
+            return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
+                                stats_abs)
 
         def decode(params, cache, last_tokens, lengths, rng):
             """`steps` tokens for every slot in one dispatch.  Returns
             out [steps+1, n_slots] (row 0 = the incoming last tokens, so
-            freshly admitted slots' first tokens ride the same fetch)."""
+            freshly admitted slots' first tokens ride the same fetch);
+            with a `stats` collection, (out, its sums over the steps)."""
             def body(carry, rng_t):
-                cache, last, lens = carry
+                cache, last, lens, stats = carry
                 # Clamp writes for slots running past the cap: confined
                 # to slots being retired (their cache is re-inserted).
                 positions = jnp.minimum(lens, max_len - 1)[:, None]
                 logits, new_cache = model.apply(
                     {'params': params, 'cache': cache},
                     last[:, None], positions=positions,
-                    decode=True, mutable=['cache'])
+                    decode=True, mutable=mutable)
                 nxt = sample(logits[:, 0, :], rng_t)         # [B]
-                return (new_cache['cache'], nxt, lens + 1), nxt
+                if stats is not None:
+                    stats = jax.tree.map(jnp.add, stats, new_cache['stats'])
+                return (new_cache['cache'], nxt, lens + 1, stats), nxt
 
-            (cache, last, lens), toks = jax.lax.scan(
-                body, (cache, last_tokens, lengths),
+            (cache, last, lens, stats), toks = jax.lax.scan(
+                body, (cache, last_tokens, lengths, stats0()),
                 jax.random.split(rng, steps))
             out = jnp.concatenate([last_tokens[None, :], toks], axis=0)
+            if stats is not None:
+                out = (out, stats)       # one fetch carries both
             return out, cache, last, lens                    # [T+1, B]
 
         def prefill_chunk(params, scratch, tokens, offset):
@@ -762,7 +820,8 @@ class DecodeEngine:
             positions = offset + jnp.arange(c)[None, :]
             _, cache = model.apply(
                 {'params': params, 'cache': scratch}, tokens,
-                positions=positions, decode=True, mutable=['cache'])
+                positions=positions, decode=True,
+                lengths=jnp.full((1,), c, jnp.int32), mutable=['cache'])
             return cache['cache']
 
         def prefill_chunk_insert(params, big_cache, last_toks, lens,
@@ -775,14 +834,17 @@ class DecodeEngine:
             into `slot` of the big cache.  Padding rows write garbage
             at positions >= total_len — masked (k_pos > q_pos) until
             the decode scatter overwrites them, the same invariant the
-            fused bucket path relies on."""
+            fused bucket path relies on; recurrent state carried in the
+            scratch stops at `length` (the model is told it)."""
             c = tokens.shape[1]
             positions = offset + jnp.arange(c)[None, :]
             logits, cache = model.apply(
                 {'params': params, 'cache': scratch}, tokens,
-                positions=positions, decode=True, mutable=['cache'])
-            last = jax.lax.dynamic_index_in_dim(logits, length - 1,
-                                                axis=1, keepdims=False)
+                positions=positions, decode=True,
+                lengths=jnp.reshape(length, (1,)), mutable=['cache'])
+            last = (logits[:, 0] if logits.shape[1] == 1 else
+                    jax.lax.dynamic_index_in_dim(logits, length - 1, axis=1,
+                                                 keepdims=False))
             first = sample(last, rng)                        # [1]
 
             def _ins(big, small):
@@ -1104,7 +1166,7 @@ class DecodeEngine:
             self._export_raw, in_shardings=(c_sh, r), out_shardings=d_sh)
 
     def _init_cache(self):
-        """Materialize the big cache by tracing a dummy decode batch.
+        """Materialize the big cache from a trace of a dummy decode batch.
         Under a mesh it is created ALREADY sharded (jit out_shardings) —
         at no point does a full cache exist on one device."""
         n = self.cfg.n_slots
@@ -1112,7 +1174,13 @@ class DecodeEngine:
             self._init_pool()
             return
         if self._mesh is None:
-            self._cache = self._make_cache(self.params)
+            # Zeros of the traced shapes (as _init_pool makes its pool):
+            # nothing reads a slot before its insert, and running the
+            # model here, op by op, cost a model of several kinds of
+            # layer a minute and a half of small compiles.
+            self._cache = jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype),
+                jax.eval_shape(self._make_cache, self.params))
             self._last_d = jnp.zeros((n,), jnp.int32)
             self._lens_d = jnp.zeros((n,), jnp.int32)
             return
@@ -1261,14 +1329,20 @@ class DecodeEngine:
         committed shardings under a mesh (default layouts otherwise —
         the chunk programs keep it there end to end)."""
         if self._scratch_fn is None:
-            make = lambda p: self._make_cache(p, 1)  # noqa: E731
+            # Zeros of the traced shapes, as `_init_cache` makes: K and V
+            # of a traced dummy token would be overwritten by the first
+            # chunk, a recurrent state that has folded it in would not.
+            scratch_abs = jax.eval_shape(lambda p: self._make_cache(p, 1),
+                                         self.params)
+            make = lambda: jax.tree.map(  # noqa: E731
+                lambda a: jnp.zeros(a.shape, a.dtype), scratch_abs)
             if self._scratch_shardings is not None:
                 self._scratch_fn = jax.jit(
                     make, out_shardings=self._scratch_shardings)
             else:
                 # skytpu: allow-recompile(compiles once per engine; a creation fn has no donatable input and the scratch rides default layouts end to end)
                 self._scratch_fn = jax.jit(make)
-        return self._scratch_fn(self.params)
+        return self._scratch_fn()
 
     def _chunk_for(self, width: int):
         """Intermediate-chunk executable for one chunk width, pinned to
@@ -2530,6 +2604,16 @@ class DecodeEngine:
         metrics_lib.set_gauge(metrics_lib.QUEUED_PREFILL_TOKENS_FAMILY,
                               float(max(sample[2], 0)))
 
+    def _fetch(self, out_d):
+        """The ONE device->host fetch of a decode call: (tokens [T+1, B],
+        the call's summed `stats` collection or None).  Where the model
+        sows stats they ride the same fetch, one small array more."""
+        if self._stats_abs:
+            # skytpu: allow-sync(the ONE fetch per step; the stats come with the tokens)
+            return jax.device_get(out_d)
+        # skytpu: allow-sync(the ONE device->host fetch per step — the engine's contract)
+        return np.asarray(out_d), None
+
     def step(self) -> int:  # skytpu: hot-entry
         """One SYNCHRONOUS engine iteration (admit + decode + process).
         Returns #active slots.  Exposed for tests and debugging; the
@@ -2555,11 +2639,12 @@ class DecodeEngine:
                 self._dispatch_decode()
         self._loop_busy_s += ph.seconds
         with tracing.phase('engine.loop.fetch') as ph:
-            # skytpu: allow-sync(the ONE device->host fetch per step — the engine's contract)
-            out = np.asarray(out_d)      # [T+1, B] — the ONE sync per step
+            out, stats = self._fetch(out_d)  # [T+1, B] — the ONE sync per step
         self._loop_device_s += ph.seconds
         t1 = time.perf_counter()
         with tracing.phase('engine.loop.emit') as ph:
+            if stats is not None:
+                self.model.publish_stats(stats)
             snapshot = {i: self._slots[i] for i in active}
             if self._spec_k:
                 # Speculative verify: the last output row is the
@@ -2623,15 +2708,17 @@ class DecodeEngine:
                 dispatched = (out_d, {i: self._slots[i] for i in active})
             chunked = self._step_chunked()   # queues behind the decode call
         self._loop_busy_s += ph.seconds
-        out = snapshot = None
+        out = snapshot = stats = None
         if self._inflight is not None:
             out_prev, snapshot = self._inflight
             self._inflight = None
             with tracing.phase('engine.loop.fetch') as ph:
-                # skytpu: allow-sync(the ONE fetch per step, one call late: syncs call k-1 while call k runs)
-                out = np.asarray(out_prev)
+                # (one call late: syncs call k-1 while call k runs)
+                out, stats = self._fetch(out_prev)
             self._loop_device_s += ph.seconds
         with tracing.phase('engine.loop.emit') as ph:
+            if stats is not None:
+                self.model.publish_stats(stats)
             if snapshot is not None:
                 self._process_rows(out, snapshot)
             self._release_retiring()

@@ -442,7 +442,13 @@ class Llama(nn.Module):
     def __call__(self, tokens: jax.Array,
                  positions: Optional[jax.Array] = None,
                  decode: bool = False,
-                 page_table: Optional[jax.Array] = None) -> jax.Array:
+                 page_table: Optional[jax.Array] = None,
+                 lengths: Optional[jax.Array] = None) -> jax.Array:
+        # `lengths` (each row's valid positions in a padded prefill) is
+        # the engine's to pass and a recurrent layer's to need: here
+        # padding lives at masked positions (_decode_attend) and it is
+        # not read.
+        del lengths
         cfg = self.cfg
         if positions is None:
             positions = jnp.broadcast_to(

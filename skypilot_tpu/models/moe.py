@@ -33,6 +33,7 @@ from typing import Any, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
@@ -163,3 +164,157 @@ class MoEMLP(nn.Module):
         out = self._constrain(out, ('dcn', 'data', 'fsdp', 'expert'),
                               None, None)
         return out.astype(x.dtype)
+
+
+# ----- dropless routing over a held share of the experts --------------------
+def route_top_k(scores: jax.Array, top_k: int, scaling: float = 1.0):
+    """scores [T, E] (f32) -> (expert ids [T, k], weights [T, k]): the k
+    largest scores of each token, normalised to sum to 1, times
+    `scaling`."""
+    top, idx = jax.lax.top_k(scores, top_k)
+    return idx, top / jnp.sum(top, axis=-1, keepdims=True) * scaling
+
+
+def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
+                    local_of: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+                    w_down: jax.Array, block: int):
+    """sum over the HELD experts e of weight_e * SwiGLU_e(x), for tokens x
+    [T, D] routed to `idx` [T, k] with `weights` [T, k].
+
+    `local_of` [E] maps an expert's id to its row of the held stacks
+    w_gate / w_up [n_held, D, F] and w_down [n_held, F, D], and to n_held
+    where the expert lives elsewhere.  No capacity and no drop: the
+    token-expert pairs are sorted by held expert, and a loop whose trip
+    count is the number of non-empty blocks of `block` pairs multiplies
+    each block by its own expert's weights.  An expert nobody chose costs
+    nothing, one that every token chose takes T / block blocks.
+
+    Returns (out [T, D] float32, counts [n_held + 1] int32: the pairs of
+    each held expert, and last the pairs routed elsewhere).
+    """
+    t, k = idx.shape
+    n_held = w_gate.shape[0]
+    m = t * k
+    keys = local_of[idx].reshape(m)
+    # The pairs by held expert: a counting sort (a pair's place is its
+    # expert's start plus its rank among that expert's pairs).  A
+    # comparison sort of 262,144 keys takes the TPU compiler 16 s a
+    # program.
+    one_hot = jax.nn.one_hot(keys, n_held + 1, dtype=jnp.int32)
+    rank = jnp.cumsum(one_hot, axis=0) - one_hot
+    counts = jnp.sum(one_hot, axis=0)
+    ends = jnp.cumsum(counts)
+    starts = ends - counts
+    place = starts[keys] + jnp.take_along_axis(rank, keys[:, None],
+                                               axis=1)[:, 0]
+    order = jnp.zeros((m,), jnp.int32).at[place].set(
+        jnp.arange(m, dtype=jnp.int32), unique_indices=True)
+    n_blocks = (counts[:n_held] + block - 1) // block
+    block_ends = jnp.cumsum(n_blocks)
+    flat_w = weights.reshape(m)
+
+    def body(b, acc):
+        e = jnp.sum(block_ends <= b)                 # this block's expert
+        first = starts[e] + (b - (block_ends[e] - n_blocks[e])) * block
+        rows = first + jnp.arange(block)
+        live = rows < ends[e]
+        pair = order[jnp.minimum(rows, m - 1)]
+        tok = pair // k
+        xb = x[tok]                                  # [block, D]
+        gate = xb @ jax.lax.dynamic_index_in_dim(w_gate, e, 0, False)
+        up = xb @ jax.lax.dynamic_index_in_dim(w_up, e, 0, False)
+        y = (nn.silu(gate) * up) @ jax.lax.dynamic_index_in_dim(
+            w_down, e, 0, False)
+        y = jnp.where(live[:, None],
+                      y.astype(jnp.float32) * flat_w[pair][:, None], 0.0)
+        return acc.at[tok].add(y)
+
+    out = jax.lax.fori_loop(0, block_ends[-1], body,
+                            jnp.zeros(x.shape, jnp.float32))
+    return out, counts
+
+
+class DroplessMoE(nn.Module):
+    """An expert layer that is told which experts it holds.
+
+    The router scores ALL `n_experts` (sigmoid, float32), each token takes
+    its `top_k` largest, weighted by score / sum of the k.  This module
+    holds the experts `held` (ids into the n_experts) and returns their
+    part of the result, `sum over held e of w_e E_e(x)`, plus the shared
+    expert: what one chip of an expert-parallel group computes before the
+    exchange.  What the absent experts would add is left out; nothing
+    here stands in for the other chips.  With `held` = all experts it is
+    the whole layer.  No token is ever dropped (`grouped_experts`).
+
+    Under `mutable=['stats']` it sows, a call: `expert_tokens` [n_held +
+    1] (pairs of each held expert, then pairs routed elsewhere) and
+    `touched` (held experts with at least one token).
+    """
+    dim: int
+    ffn_dim: int
+    n_experts: int
+    held: tuple
+    top_k: int = 8
+    n_shared: int = 1
+    routed_scaling: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    block: int = 256            # pairs a trip of the expert loop
+
+    @nn.compact
+    def __call__(self, x: jax.Array) -> jax.Array:           # [B, S, D]
+        b, s, d = x.shape
+        n_held = len(self.held)
+        # Router in float32 at full precision: a choice flipped by
+        # rounding changes which weights a token meets.
+        router = self.param('router', nn.initializers.lecun_normal(),
+                            (d, self.n_experts), self.param_dtype)
+        flat = x.reshape(b * s, d)
+        scores = jax.nn.sigmoid(jnp.dot(
+            flat.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        idx, weights = route_top_k(scores, self.top_k, self.routed_scaling)
+
+        def stack(name, shape):
+            return self.param(name, nn.initializers.lecun_normal(),
+                              (n_held,) + shape,
+                              self.param_dtype).astype(self.dtype)
+
+        local_of = np.full((self.n_experts,), n_held, np.int32)
+        local_of[list(self.held)] = np.arange(n_held)
+        xin = flat.astype(self.dtype)
+        stacks = (stack('w_gate', (d, self.ffn_dim)),
+                  stack('w_up', (d, self.ffn_dim)),
+                  stack('w_down', (self.ffn_dim, d)))
+        out, counts = grouped_experts(
+            xin, idx, weights, jnp.asarray(local_of), *stacks,
+            min(self.block, -(-(b * s) // 8) * 8))
+        self.sow('stats', 'expert_tokens', counts)
+        self.sow('stats', 'touched', jnp.sum(counts[:n_held] > 0))
+        if self.n_shared:
+            dense = lambda name, feat: nn.Dense(  # noqa: E731
+                feat, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name)
+            width = self.n_shared * self.ffn_dim
+            h = nn.silu(dense('shared_gate', width)(xin)) * \
+                dense('shared_up', width)(xin)
+            out = out + dense('shared_down', d)(h).astype(jnp.float32)
+        return out.reshape(b, s, d).astype(x.dtype)
+
+
+def publish_routing(held: tuple, expert_tokens, touched) -> None:
+    """One fetch's routing counts, to the /metrics registry (host side;
+    `expert_tokens` [n_held + 1] and `touched` as sown, summed over the
+    layers and steps of the fetch)."""
+    from skypilot_tpu.server import metrics as metrics_lib
+    n_held = len(held)
+    metrics_lib.inc_counter('skytpu_moe_pairs_total',
+                            float(expert_tokens[:n_held].sum()), where='held')
+    metrics_lib.inc_counter('skytpu_moe_pairs_total',
+                            float(expert_tokens[n_held]), where='elsewhere')
+    metrics_lib.inc_counter('skytpu_moe_experts_touched_total',
+                            float(touched))
+    for e, n in zip(held, expert_tokens[:n_held]):
+        if n:
+            metrics_lib.inc_counter('skytpu_moe_expert_tokens_total',
+                                    float(n), expert=str(e))

@@ -15,11 +15,24 @@ KV history of every active sequence.  The KV term scales with the
 CACHE ELEMENT WIDTH — the page pool's dtype is an input, so a future
 int8 KV cache shows up as a measured bytes/token halving, not a
 recalibration.
+
+A model's layers may differ (models/solar_open2.py: one softmax layer
+in four, the others carrying a recurrent state of fixed size).  The
+geometry is therefore read from the engine's CACHE, not from a model
+config's field names: leaves named `k` / `v` hold keys and values per
+position ([slots or pages, kv heads, positions, head_dim]) and count
+toward `kv_bytes_per_pos`; every other leaf is per-slot state, read and
+written whole each step (`state_bytes_per_slot`).  The weight stream is
+the installed tree's bytes, whatever the layers: for an expert layer it
+counts every held expert, touched or not (an upper bound on that part;
+the benchmark's own count, benchmarks/families/, follows the routing).
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional, Sequence
+
+import jax
 
 from skypilot_tpu.train import flops as flops_lib
 
@@ -35,6 +48,30 @@ HBM_GBPS = {
     'v4': 1228.0,
     'cpu': 100.0,
 }
+
+
+def _split_cache(cache):
+    """(leaves of keys and values, leaves of per-slot state) of a cache
+    tree, by the leaf's name: `k` / `v` anywhere on its path (an int8
+    pool's data and scales lie under them)."""
+    kv, state = [], []
+    for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+        names = {getattr(p, 'key', None) for p in path}
+        (kv if names & {'k', 'v'} else state).append(leaf)
+    return kv, state
+
+
+def _nbytes(leaves) -> int:
+    return int(sum(l.size * l.dtype.itemsize for l in leaves))
+
+
+def cache_bytes_by_kind(cache) -> dict:
+    """Bytes of the engine's cache by kind, kinds that hold nothing left
+    out: 'kv' (keys and values per position), 'recurrent' (per-slot
+    state of fixed size)."""
+    kv, state = _split_cache(cache)
+    sized = {'kv': _nbytes(kv), 'recurrent': _nbytes(state)}
+    return {k: v for k, v in sized.items() if v}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -59,14 +96,23 @@ class EngineCostModel:
     # kv_head, position) alongside the int8 payload; 0.0 for dense
     # pools).  Folded into kv_bytes_per_pos.
     kv_scale_bytes_per_pos: float = 0.0
+    # Layers that hold K and V per position (None: all n_layers), and
+    # the bytes of per-slot state that is not K and V (a recurrent
+    # layer's matrix and taps), summed over layers.
+    n_kv_layers: Optional[int] = None
+    state_bytes_per_slot: float = 0.0
 
     @classmethod
     def from_engine_state(cls, cfg, param_leaves: Sequence,
-                          cache_leaves: Sequence, n_chips: int = 1,
+                          cache, n_chips: int = 1,
                           chip: Optional[str] = None,
                           kv_dtype: Optional[str] = None
                           ) -> 'EngineCostModel':
-        """Build from live engine state.  Reads only leaf METADATA
+        """Build from live engine state: the model's config (its
+        parameter count, depth and width), the weight tree's leaves and
+        the cache TREE, whose K / V leaves [slots or pages, kv heads,
+        positions, head_dim] give the per-position geometry and whose
+        other leaves the per-slot state.  Reads only leaf METADATA
         (shape/dtype) — never leaf values, so no device sync.
 
         ``kv_dtype``: the engine's DECLARED page-pool element type
@@ -76,48 +122,68 @@ class EngineCostModel:
         happens to come first would silently misreport bytes/token.
         None (unpaged engines / direct callers) falls back to the
         first cache leaf's element width, as before."""
-        param_bytes = sum(l.size * l.dtype.itemsize for l in param_leaves)
+        kv_leaves, state_leaves = _split_cache(cache)
+        # K and V payloads are the 4-d leaves, two a layer (an int8
+        # pool's scales are 3-d).
+        payload = [l for l in kv_leaves if len(l.shape) == 4]
+        n_kv_layers = len(payload) // 2
+        n_kv_heads = payload[0].shape[1] if payload else 0
+        head_dim = payload[0].shape[3] if payload else 0
         scale_bytes = 0.0
         if kv_dtype is not None:
             kv_bytes = {'bf16': 2, 'int8': 1}[kv_dtype]
             if kv_dtype == 'int8':
                 # One f32 scale per (layer, K|V, kv_head, position).
-                scale_bytes = 2.0 * cfg.n_layers * cfg.n_kv_heads * 4
+                scale_bytes = 2.0 * n_kv_layers * n_kv_heads * 4
         else:
-            kv_bytes = (cache_leaves[0].dtype.itemsize if cache_leaves
-                        else 2)
+            kv_bytes = payload[0].dtype.itemsize if payload else 2
+        slots = state_leaves[0].shape[0] if state_leaves else 1
         return cls(n_params=cfg.num_params(), n_layers=cfg.n_layers,
-                   dim=cfg.dim, n_kv_heads=cfg.n_kv_heads,
-                   head_dim=cfg.head_dim, param_bytes=int(param_bytes),
+                   dim=cfg.dim, n_kv_heads=n_kv_heads, head_dim=head_dim,
+                   param_bytes=_nbytes(param_leaves),
                    kv_dtype_bytes=int(kv_bytes), n_chips=n_chips,
                    chip=chip or flops_lib.chip_kind(),
-                   kv_scale_bytes_per_pos=scale_bytes)
+                   kv_scale_bytes_per_pos=scale_bytes,
+                   n_kv_layers=n_kv_layers,
+                   state_bytes_per_slot=_nbytes(state_leaves) / slots)
 
     # ----- FLOPs -----------------------------------------------------
     def decode_flops_per_token(self, context_len: float) -> float:
         """Forward model FLOPs to decode one token at the given KV
         context length: 2N dense + the causal-attention term (the
-        forward third of flops_lib.train_flops_per_token's 6N+6LSD)."""
+        forward third of flops_lib.train_flops_per_token's 6N+6LSD),
+        over the layers that attend over a cache.  N is what the
+        model's config counts as held; for an expert layer that is
+        every held expert, not the few a token meets."""
         return 2.0 * self.n_params + \
-            2.0 * self.n_layers * context_len * self.dim
+            2.0 * self._kv_layers() * context_len * self.dim
 
     # ----- HBM bytes -------------------------------------------------
+    def _kv_layers(self) -> int:
+        return (self.n_layers if self.n_kv_layers is None
+                else self.n_kv_layers)
+
     def kv_bytes_per_pos(self) -> float:
-        """Bytes of K+V held per token position across all layers
-        (payload at the pool's element width + any quantization-scale
-        overhead)."""
-        return (2.0 * self.n_layers * self.n_kv_heads * self.head_dim *
+        """Bytes of K+V held per token position, summed over the layers
+        that hold K and V (all of Llama's; a model whose layers differ
+        counts its softmax layers only — its other layers' state does
+        not grow with the position and is `state_bytes_per_slot`).
+        Payload at the pool's element width + any quantization-scale
+        overhead."""
+        return (2.0 * self._kv_layers() * self.n_kv_heads * self.head_dim *
                 self.kv_dtype_bytes + self.kv_scale_bytes_per_pos)
 
     def decode_hbm_bytes_per_token(self, context_len: float,
                                    n_active: int) -> float:
         """HBM traffic attributed to one decoded token: the weight
         stream (read once per step, amortized over the batch) plus
-        this sequence's KV history read and its one-position write."""
+        this sequence's KV history read and its one-position write,
+        plus its recurrent state read and written whole."""
         weights = self.param_bytes / max(1, n_active)
         kv_read = self.kv_bytes_per_pos() * context_len
         kv_write = self.kv_bytes_per_pos()
-        return weights + kv_read + kv_write
+        return (weights + kv_read + kv_write +
+                2.0 * self.state_bytes_per_slot)
 
     def arith_intensity(self, context_len: float, n_active: int) -> float:
         """FLOPs per HBM byte at the given occupancy — distance from
@@ -161,6 +227,6 @@ class EngineCostModel:
         if peak_flops <= 0 or hbm <= 0:
             return 0.0
         fl = bucket * (2.0 * self.n_params +
-                       2.0 * self.n_layers * (bucket / 2.0) * self.dim)
+                       2.0 * self._kv_layers() * (bucket / 2.0) * self.dim)
         by = self.param_bytes + self.kv_bytes_per_pos() * bucket
         return max(fl / peak_flops, by / hbm)

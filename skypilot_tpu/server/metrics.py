@@ -121,6 +121,26 @@ _HELP = {
         'proposed, 0..1): the health signal of speculative decoding '
         '— near 0 the engine is doing plain decode plus wasted '
         'verify columns, near 1 each dispatch commits k+1 tokens',
+    'skytpu_engine_cache_bytes':
+        'Bytes of the engine\'s cache by kind, set once at build: '
+        'kind="kv" keys and values per position, kind="recurrent" '
+        'per-slot state of fixed size (a linear-attention layer\'s '
+        'matrix and convolution taps) — a kind that holds nothing is '
+        'absent',
+    'skytpu_moe_pairs_total':
+        'Token-expert pairs routed by decode steps, summed over expert '
+        'layers: where="held" to an expert this engine holds, '
+        'where="elsewhere" to one it does not (their part of the '
+        'result is another chip\'s); counted on the device and fetched '
+        'with the call\'s tokens, every row of the decode batch',
+    'skytpu_moe_experts_touched_total':
+        'Held experts that at least one token of a decode step reached, '
+        'summed over expert layers and steps: the expert weights a step '
+        'has to read',
+    'skytpu_moe_expert_tokens_total':
+        'Token-expert pairs of decode steps by held expert (its id '
+        'among all experts), summed over expert layers: the routing\'s '
+        'evenness',
     'skytpu_engine_batch_occupancy_ratio':
         'Active decode slots / total slots, sampled each loop step',
     'skytpu_engine_active_slots': 'Decode slots occupied this step',

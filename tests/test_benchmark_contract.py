@@ -1,0 +1,55 @@
+"""The benchmark's manifest against its contract, as far as files can show
+it (PERF.md section 7 (a)): `BENCHMARK.json` has nothing the driver would
+refuse, every configuration's family loads and counts the parameters its
+file states, every metric has its reader.  No JAX program runs here."""
+import os
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks import families  # noqa: E402
+from benchmarks.harness import manifest, reducers  # noqa: E402
+
+MAN = manifest.manifest()
+
+
+def test_manifest_has_no_problems():
+    assert manifest.problems(MAN) == []
+
+
+@pytest.mark.parametrize('name', [c['name'] for c in MAN['configs']])
+def test_configuration_loads_and_counts_its_parameters(name):
+    config = manifest.config_of(MAN, name)
+    family = families.load(config)
+    dims = family.dims(config)
+    assert dims.num_params() == config['params_total']
+    assert dims.layers == config['num_hidden_layers']
+    assert dims.vocab == config['vocab_size']
+    listed = next(c for c in MAN['configs'] if c['name'] == name)
+    assert listed['reduced'] == config['reduced']
+    assert listed['source'] == config['source']
+
+
+@pytest.mark.parametrize('cell', [w['name'] for w in MAN['workloads']])
+def test_cell_has_its_files_and_its_builder(cell):
+    entry = manifest.cell(MAN, cell)
+    mix = manifest.traffic_of(entry['traffic'])
+    family = families.load(manifest.config_of(MAN, entry['config']))
+    builder = 'train_model' if mix['kind'] == 'train' else 'serve_model'
+    assert hasattr(family, builder), (cell, builder)
+    assert len(manifest.metrics_of(MAN, cell, 'end_to_end')) >= 2
+    assert manifest.metrics_of(MAN, cell, 'per_layer')
+
+
+@pytest.mark.parametrize(
+    'metric', [m['name'] for m in MAN['end_to_end'] + MAN['per_layer']])
+def test_metric_has_its_reader(metric):
+    spec = manifest.reducer_spec(metric)
+    own = os.path.join(manifest.reducer_dir(metric), f'{metric}.py')
+    if os.path.exists(own):
+        with open(own, encoding='utf-8') as f:
+            assert 'def reduce(ctx' in f.read()
+    else:
+        assert callable(getattr(reducers, spec['reducer'], None)), spec

@@ -1,0 +1,340 @@
+"""Solar-Open2 on the serving path, at a tiny size on the CPU, against the
+plain reference (benchmarks/reference/solar_open2_ref.py): a layer pattern
+of one softmax and three linear-attention layers whose recurrent state
+lives in the engine's cache beside K and V, and a dropless expert layer
+that holds a share of the experts.
+
+Sizes: hidden 64, 4 heads of 16, 16 experts of width 32 with 4 held and 2
+a token, vocabulary 256, 4 layers in the pattern; float32 weights from the
+family's seed, so that the program and the reference differ by rounding
+order only.
+"""
+import copy
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from benchmarks import families  # noqa: E402
+from benchmarks.harness import check, manifest, weights  # noqa: E402
+from benchmarks.reference import solar_open2_ref  # noqa: E402
+from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig  # noqa: E402
+from skypilot_tpu.models import moe as moe_lib  # noqa: E402
+from skypilot_tpu.models.solar_open2 import SolarOpen2Config  # noqa: E402
+
+SEED = 2**31 + 30
+DTYPE = jnp.float32
+CONFIG_FILE = 'solar-open2-250b-ep8'
+
+
+def published_config():
+    return manifest.load_json(manifest.BENCH_DIR, 'configs',
+                              f'{CONFIG_FILE}.json')
+
+
+@pytest.fixture(scope='module')
+def tiny():
+    """(family, dims, config) at the family's rehearsal size."""
+    config = copy.deepcopy(published_config())
+    family = families.load(config)
+    config.update(family.REHEARSAL)
+    config['serve'].update(max_seq_len=64)
+    return family, family.dims(config), config
+
+
+@pytest.fixture(scope='module')
+def served(tiny):
+    """An engine over the seeded weights, and what it answered to prompts
+    of every path: alone in a bucket, three of different lengths admitted
+    as one padded group, and one longer than the largest bucket."""
+    family, dims, config = tiny
+    model = family.serve_model(dims, config, DTYPE)
+    params = jax.jit(lambda k: family.make_params(k, dims, DTYPE))(
+        weights.seed_key(SEED))
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=4, prefill_buckets=(8, 16), steps_per_call=3))
+    rng = np.random.default_rng(SEED)
+    answers = {}
+    for name, lengths in (('alone', [7]), ('group', [9, 12, 16]),
+                          ('chunked', [37])):
+        prompts = [rng.integers(0, dims.vocab, n).tolist() for n in lengths]
+        requests = [engine.submit(p, 6) for p in prompts]
+        for _ in range(200):
+            if all(r.finished_at is not None for r in requests):
+                break
+            engine.step_pipelined()
+        answers[name] = [(p, r.tokens()) for p, r in zip(prompts, requests)]
+    return engine, model, params, answers
+
+
+def gap_of(tiny, samples):
+    """How far the served tokens lie below the reference's best."""
+    family, dims, _ = tiny
+    return check.served_gap(family, dims, SEED, DTYPE, samples, (64, 6))
+
+
+@pytest.mark.parametrize('path', ['alone', 'group', 'chunked'])
+def test_served_tokens_are_the_references(tiny, served, path):
+    """(a) prefill then decode through DecodeEngine, (b) a padded group of
+    different lengths, (c) a chunked prefill: every served token is the
+    reference's own choice, up to float32 rounding."""
+    samples = served[3][path]
+    assert all(len(tokens) == 6 for _, tokens in samples)
+    verdict = gap_of(tiny, samples)
+    assert verdict['finite'] and verdict['positions'] == 6 * len(samples)
+    assert verdict['widest_gap'] < 1e-3, verdict
+
+
+def test_padding_does_not_reach_the_state(tiny, served):
+    """One padded prefill of rows of different lengths: the logits at each
+    row's last valid position, and the first decode step after it, are the
+    reference's for the unpadded row; with the lengths left out (padding
+    folded into the state) they are not."""
+    family, dims, _ = tiny
+    _, model, params, _ = served
+    rng = np.random.default_rng(5)
+    lengths = np.array([16, 5, 11, 2])
+    rows = rng.integers(0, dims.vocab, (4, 17))
+    ref = family.reference(dims, SEED, DTYPE)
+    want = np.asarray(ref.logits_at(ref.hidden(jnp.asarray(rows))))
+    padded = np.where(np.arange(16)[None, :] < lengths[:, None],
+                      rows[:, :16], 0)
+
+    def prefill_then_step(told):
+        logits, cache = model.apply(
+            {'params': params}, jnp.asarray(padded), decode=True,
+            lengths=told, mutable=['cache'])
+        nxt = rows[np.arange(4), lengths]
+        step, _ = model.apply(
+            {'params': params, 'cache': cache['cache']},
+            jnp.asarray(nxt)[:, None],
+            positions=jnp.asarray(lengths)[:, None], decode=True,
+            mutable=['cache'])
+        return np.asarray(logits), np.asarray(step[:, 0])
+
+    last, step = prefill_then_step(jnp.asarray(lengths))
+    assert last.shape == (4, 1, dims.vocab)
+    for i, n in enumerate(lengths):
+        np.testing.assert_allclose(last[i, 0], want[i, n - 1], atol=2e-4)
+        np.testing.assert_allclose(step[i], want[i, n], atol=2e-4)
+    _, wrong = prefill_then_step(None)
+    assert np.abs(wrong[0] - want[0, 16]).max() < 2e-4      # no padding
+    assert np.abs(wrong[1] - want[1, 5]).max() > 1e-2       # 11 padded
+
+
+def test_chunked_prefill_carries_the_state_from_zero(tiny, served):
+    """(c) a prompt of 37 through the engine's own chunk programs (16, 16,
+    then 5 padded to 8): the logits at its last position, the token the
+    insert sampled and a decode step from the slot it filled are the
+    reference's full forward.  The scratch starts from zeros: a state
+    that had folded in a traced dummy token would show here."""
+    family, dims, _ = tiny
+    engine, model, params, _ = served
+    assert all(slot is None for slot in engine._slots)
+    rows = np.random.default_rng(7).integers(0, dims.vocab, (1, 38))
+    ref = family.reference(dims, SEED, DTYPE)
+    want = np.asarray(ref.logits_at(ref.hidden(jnp.asarray(rows))))[0]
+    scratch = engine._new_scratch()
+    assert all(not np.asarray(leaf).any() for leaf in jax.tree.leaves(scratch))
+    for offset in (0, 16):
+        scratch = engine._chunk_for(16)(
+            params, scratch, jnp.asarray(rows[:, offset:offset + 16]),
+            jnp.asarray(offset, jnp.int32))
+    last = np.zeros((1, 8), np.int32)
+    last[0, :5] = rows[0, 32:37]
+    logits, _ = model.apply(
+        {'params': params, 'cache': scratch}, jnp.asarray(last),
+        positions=32 + jnp.arange(8)[None, :], decode=True,
+        lengths=jnp.asarray([5]), mutable=['cache'])
+    np.testing.assert_allclose(np.asarray(logits)[0, 0], want[36], atol=2e-4)
+    slot = 2
+    engine._cache, engine._last_d, engine._lens_d = engine._chunk_insert_for(
+        8)(params, engine._cache, engine._last_d, engine._lens_d, scratch,
+           jnp.asarray(last), jnp.asarray(5, jnp.int32),
+           jnp.asarray(32, jnp.int32), jnp.asarray(37, jnp.int32),
+           jnp.asarray(slot, jnp.int32), engine._next_rng())
+    assert int(engine._last_d[slot]) == want[36].argmax()
+    assert int(engine._lens_d[slot]) == 37
+    step, _ = model.apply(
+        {'params': params,
+         'cache': jax.tree.map(lambda a: a[slot:slot + 1], engine._cache)},
+        jnp.asarray(rows[:, 37:38]), positions=jnp.asarray([[37]]),
+        decode=True, mutable=['cache'])
+    np.testing.assert_allclose(np.asarray(step)[0, 0], want[37], atol=2e-4)
+
+
+def moe_layer(held, n_shared=1, block=16):
+    return moe_lib.DroplessMoE(
+        dim=64, ffn_dim=32, n_experts=16, held=tuple(held), top_k=2,
+        n_shared=n_shared, dtype=DTYPE, param_dtype=DTYPE, block=block)
+
+
+@pytest.fixture(scope='module')
+def moe_weights():
+    """A whole layer's weights: 16 experts, a shared one."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 40, 64), DTYPE)
+    params = moe_layer(range(16)).init(jax.random.PRNGKey(2), x)['params']
+    return x, params
+
+
+def share_of(params, held, with_shared):
+    held = list(held)
+    out = {'router': params['router'],
+           **{k: params[k][jnp.asarray(held)]
+              for k in ('w_gate', 'w_up', 'w_down')}}
+    if with_shared:
+        out.update({k: v for k, v in params.items() if 'shared' in k})
+    return out
+
+
+def test_no_token_is_dropped_when_all_pick_one_expert(moe_weights):
+    """(d) every token's first choice is expert 3: it takes all 80 tokens,
+    five blocks of 16, and the layer is still the dense sum."""
+    x, params = moe_weights
+    x = jnp.abs(x)
+    router = np.array(params['router'])
+    router[:, 3] = 1.0                # scores 1 on positive inputs
+    router[:, 5] = 0.5
+    params = dict(params, router=jnp.asarray(router))
+    held = share_of(params, (2, 3, 4, 5), True)
+    out, stats = moe_layer((2, 3, 4, 5)).apply({'params': held}, x,
+                                               mutable=['stats'])
+    counts = np.asarray(stats['stats']['expert_tokens'][0])
+    assert counts.tolist() == [0, 80, 0, 80, 0]
+    assert int(stats['stats']['touched'][0]) == 2
+    with jax.default_matmul_precision('highest'):
+        want = solar_open2_ref.expert_layer(
+            held, x, held=(2, 3, 4, 5), top_k=2, scaling=1.0,
+            matmul=solar_open2_ref.plain_matmul)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+
+
+def test_the_shares_add_up_to_the_uncut_layer(moe_weights):
+    """(e) four shares of four experts, the shared expert counted once,
+    give what the uncut reference gives for the whole layer; a share's
+    counts say how many pairs went elsewhere."""
+    x, params = moe_weights
+    total, held_pairs = 0.0, 0
+    for j in range(4):
+        held = range(4 * j, 4 * j + 4)
+        out, stats = moe_layer(held, n_shared=int(j == 0)).apply(
+            {'params': share_of(params, held, j == 0)}, x,
+            mutable=['stats'])
+        counts = np.asarray(stats['stats']['expert_tokens'][0])
+        assert counts.sum() == 80 * 2
+        held_pairs += counts[:4].sum()
+        total = total + out
+    assert held_pairs == 80 * 2
+    with jax.default_matmul_precision('highest'):
+        want = solar_open2_ref.expert_layer(
+            params, x, held=tuple(range(16)), top_k=2, scaling=1.0,
+            matmul=solar_open2_ref.plain_matmul)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(want),
+                               atol=1e-4)
+    whole = moe_layer(range(16)).apply({'params': params}, x)
+    np.testing.assert_allclose(np.asarray(whole), np.asarray(want),
+                               atol=1e-4)
+
+
+def test_a_decode_steps_few_tokens_go_through_the_same_loop(moe_weights):
+    """A decode step's 24 tokens are one block of the expert loop: the sum
+    is the reference's and the counts are the pairs of each held expert."""
+    x, params = moe_weights
+    few = x[:, :12]                                  # 24 tokens
+    ids = (2, 3, 4, 5, 9)
+    held = share_of(params, ids, True)
+    out, stats = moe_lib.DroplessMoE(
+        dim=64, ffn_dim=32, n_experts=16, held=ids, top_k=2, dtype=DTYPE,
+        param_dtype=DTYPE).apply({'params': held}, few, mutable=['stats'])
+    with jax.default_matmul_precision('highest'):
+        want = solar_open2_ref.expert_layer(
+            held, few, held=ids, top_k=2, scaling=1.0,
+            matmul=solar_open2_ref.plain_matmul)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-4)
+    counts = np.asarray(stats['stats']['expert_tokens'][0])
+    assert counts.sum() == 24 * 2
+    assert int(stats['stats']['touched'][0]) == (counts[:5] > 0).sum()
+
+
+def test_held_parameters_are_the_files_arithmetic_and_the_programs_tree(
+        tiny):
+    """(f) the configuration file's total, its arithmetic worked out
+    here, the family's count, the program's count and the seeded tree."""
+    config = published_config()
+    family = families.load(config)
+    dims = family.dims(config)
+    kda = (4 * 4096 * 8192 + 2 * (4096 * 128 + 128 * 8192) + 4096 * 64 +
+           3 * 8192 * 4 + 64 + 8192 + 128)
+    softmax = 3 * 4096 * 8192 + 2 * 4096 * 1024
+    besides = 4096 * 320 + 41 * 3 * 4096 * 1280 + 2 * 4096
+    total = (softmax + besides) + 3 * (kda + besides) + \
+        2 * 24576 * 4096 + 4096
+    assert (kda, softmax) == (137732288, 109051904)
+    assert total == config['params_total'] == dims.num_params()
+    model = family.serve_model(dims, config, jnp.bfloat16)
+    assert model.cfg.num_params() == total
+    tree = jax.eval_shape(lambda: family.make_params(
+        weights.seed_key(1), dims, jnp.bfloat16))
+    assert sum(leaf.size for leaf in jax.tree.leaves(tree)) == total
+    assert dims.state_bytes_per_slot() == 3 * (64 * 128 * 128 * 4 +
+                                               3 * 3 * 8192 * 2)
+    assert (config['published'], config['reduced']) == (
+        {'num_hidden_layers': 48, 'n_routed_experts': 320,
+         'vocab_size': 196608},
+        ['num_hidden_layers', 'n_routed_experts', 'vocab_size'])
+    # The tree the family makes is the tree the program initialises.
+    import flax.linen as nn
+    family, dims, config = tiny
+    model = family.serve_model(dims, config, DTYPE)
+    theirs = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))))['params']
+    ours = jax.eval_shape(lambda: family.make_params(
+        weights.seed_key(1), dims, DTYPE))
+    assert jax.tree.map(lambda a: a.shape, theirs) == \
+        jax.tree.map(lambda a: a.shape, ours)
+    assert isinstance(model.cfg, SolarOpen2Config)
+    assert model.cfg.num_params() == dims.num_params()
+
+
+def test_paging_speculation_and_transfer_are_refused(tiny, served):
+    """(g) two kinds of state in the page manager is a later PR: refused
+    at construction with the reason, never a silent fall-back."""
+    engine, model, params, _ = served
+    for options in (dict(kv_page_size=8),
+                    dict(kv_page_size=8, speculation=2)):
+        with pytest.raises(ValueError, match='keeps recurrent state beside '
+                           'its keys and values.*KV transfer'):
+            DecodeEngine(model, params, EngineConfig(
+                n_slots=2, prefill_buckets=(8, 16), **options))
+    with pytest.raises(RuntimeError, match='requires the paged KV cache'):
+        engine.submit_prefill([1, 2, 3])
+
+
+def test_cache_and_cost_model_carry_two_kinds_of_state(tiny, served):
+    """The engine's cache holds K and V of the one softmax layer and the
+    state and taps of three linear layers, and the cost model reads its
+    bytes from those leaves."""
+    _, dims, _ = tiny
+    engine = served[0]
+    shapes = {'/'.join(str(getattr(p, 'key', p)) for p in path): leaf.shape
+              for path, leaf in jax.tree_util.tree_flatten_with_path(
+                  engine._cache)[0]}
+    assert shapes['layer_0/attn/k'] == (4, 2, 64, 16)
+    assert shapes['layer_2/kda/state'] == (4, 4, 16, 16)
+    assert shapes['layer_3/kda/conv'] == (4, 3, 3, 4, 16)
+    cm = engine.perf_cost_model
+    assert cm.n_kv_layers == 1 and cm.n_layers == 4
+    assert cm.kv_bytes_per_pos() == dims.kv_bytes_per_position(4)
+    assert cm.state_bytes_per_slot == 3 * (4 * 16 * 16 * 4 + 3 * 3 * 64 * 4)
+    assert cm.decode_hbm_bytes_per_token(10, 2) == (
+        cm.param_bytes / 2 + 11 * cm.kv_bytes_per_pos() +
+        2 * cm.state_bytes_per_slot)
+    from skypilot_tpu.server import metrics as metrics_lib
+    text = metrics_lib.render()
+    assert 'skytpu_engine_cache_bytes{kind="recurrent"}' in text
+    assert 'skytpu_moe_pairs_total{where="held"}' in text
