@@ -252,14 +252,19 @@ class Request:
 
 
 class _Slot:
-    __slots__ = ('request', 'length', 'first_pending', 'wait_end', 'done',
-                 'pages', 'n_shared', 'toks')
+    __slots__ = ('request', 'length', 'device_length', 'first_pending',
+                 'wait_end', 'done', 'pages', 'n_shared', 'toks')
 
     def __init__(self, request: Request, length: int,
                  pages: Optional[List[int]] = None,
                  n_shared: int = 0) -> None:
         self.request = request
         self.length = length              # prompt len + emitted (host view)
+        # The device's `lens` entry of this slot at the next dispatch:
+        # the prompt's length at the insert, one more for every decode
+        # step dispatched since (a call ahead of `length` when
+        # pipelined).
+        self.device_length = length
         # True until the prefill-sampled first token has been emitted
         # (it arrives as row 0 of the next decode call's output).
         self.first_pending = True
@@ -466,6 +471,14 @@ class DecodeEngine:
         self._loop_busy_s = 0.0
         self._loop_device_s = 0.0
         self._loop_idle_s = 0.0
+        # K/V positions of the contiguous decode calls since the last
+        # flush (_count_kv_positions), and the positions a tile of the
+        # model's decode attention covers (None: it reads every slot
+        # whole).
+        self._kv_fetched = 0
+        self._kv_held = 0
+        kv_block = getattr(model, 'decode_kv_block', None)
+        self._kv_block: Optional[int] = kv_block() if kv_block else None
         self._setup_programs = 0    # engine.setup.compile spans so far
         # Minimum attribution window; benchmarks/tests shrink or grow
         # it to bracket exactly their measured region.
@@ -784,11 +797,17 @@ class DecodeEngine:
             return jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype),
                                 stats_abs)
 
-        def decode(params, cache, last_tokens, lengths, rng):
+        def decode(params, cache, last_tokens, lengths, held, rng):
             """`steps` tokens for every slot in one dispatch.  Returns
             out [steps+1, n_slots] (row 0 = the incoming last tokens, so
             freshly admitted slots' first tokens ride the same fetch);
-            with a `stats` collection, (out, its sums over the steps)."""
+            with a `stats` collection, (out, its sums over the steps).
+            `held` [n_slots] says which slots hold a request by the
+            host's view at dispatch; the others' lengths start from
+            zero, so that attention bounded by the lengths
+            (ops/attention.py decode_attention) reads nothing stale of
+            a slot whose request has gone."""
+            lengths = jnp.where(held.astype(bool), lengths, 0)
             def body(carry, rng_t):
                 cache, last, lens, stats = carry
                 # Clamp writes for slots running past the cap: confined
@@ -1092,7 +1111,7 @@ class DecodeEngine:
                 out_shardings=(c_sh, r, r))
             self._decode = jax.jit(
                 decode, donate_argnums=(1, 2, 3),
-                in_shardings=(p_sh, c_sh, r, r, r),
+                in_shardings=(p_sh, c_sh, r, r, r, r),
                 out_shardings=(r, c_sh, r, r))
             s_sh = self._scratch_shardings
             self._prefill_chunk = jax.jit(
@@ -1245,13 +1264,14 @@ class DecodeEngine:
             jax.jit(
                 self._decode_raw, donate_argnums=(1, 2, 3),
                 in_shardings=(auto, Format(Layout.AUTO), Format(Layout.AUTO),
-                              Format(Layout.AUTO), Format(Layout.AUTO)),
+                              Format(Layout.AUTO), Format(Layout.AUTO),
+                              Format(Layout.AUTO)),
                 # Donated inputs require matching AUTO outputs (out row 0
                 # is host-fetched; its layout is immaterial).
                 out_shardings=(Format(Layout.AUTO), Format(Layout.AUTO),
                                Format(Layout.AUTO), Format(Layout.AUTO))),
             _abs(self.params), _abs(self._cache), _abs(self._last_d),
-            _abs(self._lens_d), rng_abs, kind='decode')
+            _abs(self._lens_d), _abs(self._lens_d), rng_abs, kind='decode')
         fmts, _ = compiled.input_formats
         self._fmt_params, self._fmt_cache = fmts[0], fmts[1]
         self._fmt_last, self._fmt_lens = fmts[2], fmts[3]
@@ -1851,7 +1871,9 @@ class DecodeEngine:
         else:
             _, self._cache, self._last_d, self._lens_d = self._warm(
                 'decode', self._decode, self.params, self._cache,
-                self._last_d, self._lens_d, self._next_rng())
+                self._last_d, self._lens_d,
+                jnp.zeros((self.cfg.n_slots,), jnp.int32),
+                self._next_rng())
 
     def start(self):
         self._thread = threading.Thread(target=self._loop,
@@ -1969,8 +1991,39 @@ class DecodeEngine:
             return self._decode(self.params, self._cache, self._pt(),
                                 self._last_d, self._lens_d,
                                 self._next_rng())
+        # Which slots hold a request, as the host sees them now: one
+        # admitted by a prefill already dispatched is in _slots (its
+        # insert runs before this call on the device), one retired is
+        # not, and the program counts an empty slot's length from zero.
+        n, steps = self.cfg.n_slots, self.cfg.steps_per_call
+        held = np.zeros((n,), np.int32)
+        lens = np.zeros((n,), np.int64)
+        for i, slot in enumerate(self._slots):
+            if slot is not None:
+                held[i] = 1
+                lens[i] = slot.device_length
+                slot.device_length += steps
+        self._count_kv_positions(lens)
         return self._decode(self.params, self._cache, self._last_d,
-                            self._lens_d, self._next_rng())
+                            self._lens_d, jnp.asarray(held),
+                            self._next_rng())
+
+    def _count_kv_positions(self, lens: np.ndarray) -> None:
+        """One contiguous decode call's K/V positions, summed for the
+        next flush: `held` is what the cache holds, every slot whole,
+        and `fetched` what the steps' attention asks for: whole tiles up
+        to the row each step writes where the model's kernel is bounded
+        by the lengths (`lens`: the device's at the call's start, empty
+        slots zero), everything held where it is not."""
+        steps, max_len = self.cfg.steps_per_call, self.model.cfg.max_seq_len
+        whole = lens.size * max_len * steps
+        self._kv_held += whole
+        if self._kv_block is None:
+            self._kv_fetched += whole
+            return
+        read = np.minimum(lens[:, None] + np.arange(steps), max_len - 1) + 1
+        self._kv_fetched += (int((-(-read // self._kv_block)).sum()) *
+                             self._kv_block)
 
     def _propose_drafts(self) -> np.ndarray:
         """Host-side n-gram drafts [n_slots, k] for the next verify
@@ -2567,6 +2620,14 @@ class DecodeEngine:
         if idle:
             metrics_lib.inc_counter(
                 'skytpu_engine_loop_wait_seconds_total', idle, on='idle')
+        if self._kv_held:
+            metrics_lib.inc_counter(
+                'skytpu_engine_decode_kv_positions_total',
+                float(self._kv_fetched), kind='fetched')
+            metrics_lib.inc_counter(
+                'skytpu_engine_decode_kv_positions_total',
+                float(self._kv_held), kind='held')
+            self._kv_fetched = self._kv_held = 0
 
     def _sample_gauges(self, n_active: int) -> None:
         """Loop-thread occupancy/queue gauges; skipped when unchanged so

@@ -153,6 +153,12 @@ def _constrain_activations(x: jax.Array, mesh: Optional[Mesh],
     return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
+def _decode_kv_block(cfg: LlamaConfig, mesh: Optional[Mesh]) -> Optional[int]:
+    """`attn_lib.decode_kv_block` for this model's cache leaves."""
+    return attn_lib.decode_kv_block(cfg.n_kv_heads, cfg.head_dim,
+                                    cfg.max_seq_len, cfg.dtype, mesh)
+
+
 class OneHotEmbed(nn.Embed):
     """Embedding lookup as a one-hot matmul.
 
@@ -296,9 +302,36 @@ class Attention(nn.Module):
             # ~2x cache HBM; scatter updates one row in place under
             # donation).
             pos = positions[:, 0]                               # [B]
-            b_idx = jnp.arange(b)
-            ck.value = ck.value.at[b_idx, :, pos, :].set(k[:, :, 0, :])
-            cv.value = cv.value.at[b_idx, :, pos, :].set(v[:, :, 0, :])
+            if _decode_kv_block(cfg, self.mesh) is None:
+                b_idx = jnp.arange(b)
+                ck.value = ck.value.at[b_idx, :, pos, :].set(k[:, :, 0, :])
+                cv.value = cv.value.at[b_idx, :, pos, :].set(v[:, :, 0, :])
+            else:
+                # The kernel's operands are row-major [B, Hkv, S, D].
+                # The scatter above, whose window is a position's heads,
+                # makes the TPU compiler lay the cache out position-
+                # major and copy each leaf whole in front of every
+                # kernel call (a scratch compile shows it, whatever
+                # layout the program's arguments are given); rows of D
+                # scattered over (slot x head, position) leave the
+                # cache as the kernel reads it.
+                h_kv = cfg.n_kv_heads
+                bh_idx = jnp.arange(b * h_kv)
+                bh_pos = jnp.repeat(pos, h_kv)
+
+                def write(cache, row):
+                    flat = cache.reshape(b * h_kv, max_len, cfg.head_dim)
+                    flat = flat.at[bh_idx, bh_pos, :].set(
+                        row.reshape(b * h_kv, cfg.head_dim))
+                    return flat.reshape(cache.shape)
+
+                ck.value = write(ck.value, k)
+                cv.value = write(cv.value, v)
+            # The one-row step reads a slot up to the row just written
+            # (a Pallas kernel bounded by the lengths on one TPU device,
+            # this same mask through XLA elsewhere).
+            return ck.value, cv.value, attn_lib.decode_attention(
+                q, ck.value, cv.value, pos + 1, self.mesh)
         k_all, v_all = ck.value, cv.value
         k_pos = jnp.arange(max_len)[None, :]
         out = attn_lib.mha_reference(
@@ -492,6 +525,12 @@ class Llama(nn.Module):
                     nn.initializers.lecun_normal(), ('embed', 'vocab')),
                 name='lm_head')(x)
         return logits.astype(jnp.float32)
+
+    def decode_kv_block(self) -> Optional[int]:
+        """For the engine's `decode_kv_positions` counter: the positions
+        a tile of the decode step's attention covers, None where it
+        reads every slot whole."""
+        return _decode_kv_block(self.cfg, self.mesh)
 
 
 def init_params(model: Llama, rng: jax.Array, batch: int = 1,

@@ -12,6 +12,19 @@ Shapes: q [B, Hq, Sq, D], k/v [B, Hkv, Sk, D]; grouped-query attention is
 expressed by Hq = G * Hkv (query heads grouped over kv heads).  The
 reference folds the G query heads of a kv head into its query rows and
 contracts against K and V as they are stored; it never repeats them.
+
+`decode_attention` is the decode step's attention: one query row a head
+against a slot's cache leaves, positions below the slot's length.  On one
+TPU device, where `decode_kv_block` finds a tiling for the cache's shape
+(head size a multiple of 128, `max_seq_len` a multiple of 128), it is the
+Pallas kernel of `ops/pallas/decode_attention.py`, which fetches a slot's
+K and V up to its length and nothing of an empty slot beyond one tile.
+Elsewhere (the CPU, a mesh of several devices, other shapes) it is
+`mha_reference` under the positions mask, which reads every position of
+every slot.  The choice hangs on the backend and on shapes, as
+`flash_attention`'s does.  `mha_reference` itself was left alone: prefill,
+a chunk against a cache, the paged gather, verify and training keep the
+programs they had, and the kernel's tests have their ground truth.
 """
 from __future__ import annotations
 
@@ -88,6 +101,41 @@ def mha_reference(q: jax.Array,
     if group > 1:
         out = out.reshape(b, h_q, s_q, d)
     return out.astype(orig_dtype)
+
+
+def decode_kv_block(n_kv_heads: int, head_dim: int, seq_len: int,
+                    dtype=jnp.bfloat16,
+                    mesh: Optional[Mesh] = None) -> Optional[int]:
+    """The positions one tile of `decode_attention`'s kernel covers for a
+    cache `[B, n_kv_heads, seq_len, head_dim]`, or None where it reads the
+    whole cache through XLA: off the TPU, under a mesh of several devices
+    (XLA cannot partition a Mosaic call), or for shapes the kernel's
+    tiling cannot take."""
+    if jax.default_backend() != 'tpu':
+        return None
+    if mesh is not None and mesh.size > 1:
+        return None
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    return pallas_da.block_len(n_kv_heads, head_dim, seq_len,
+                               jnp.dtype(dtype).itemsize)
+
+
+def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+                     lengths: jax.Array,
+                     mesh: Optional[Mesh] = None) -> jax.Array:
+    """The decode step's attention: q [B, Hq, 1, D] against the cache
+    leaves [B, Hkv, S, D] as they are stored, over the positions
+    `< lengths[b]` (the row written this step included)."""
+    b, h_kv, s, d = k_cache.shape
+    block = decode_kv_block(h_kv, d, s, k_cache.dtype, mesh)
+    if block is not None:
+        from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+        return pallas_da.decode_attention_fwd(q, k_cache, v_cache, lengths,
+                                              block=block)
+    return mha_reference(
+        q, k_cache, v_cache, causal=True,
+        segment_positions=(lengths - 1)[:, None],
+        kv_positions=jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

@@ -1,0 +1,146 @@
+"""Pallas TPU kernel for the decode step's attention: one query row a
+head against a slot's cached K and V, read up to the slot's length.
+
+The XLA path (`mha_reference` under the positions mask) reads every
+position of every slot's cache leaf whatever the slot holds; a decode
+step is bound by the bytes it reads, and a serving batch holds a fraction
+of `n_slots x max_seq_len`.  Here the lengths are scalar-prefetched and
+the grid is (slot, KV block): a tile is all KV heads of one block of
+positions, `[Hkv, block, D]` of K and of V.  The tile's block index is
+clamped to the slot's last live block, so the steps past a slot's length
+ask for the tile already there (the pipeline fetches a tile only when its
+index changes) and compute nothing (`pl.when`); a slot of length zero
+computes nothing and returns zeros.  Online softmax in float32 scratch,
+as in `flash_attention.py`; bf16 in, float32 accumulation, bf16 out.
+
+GQA: the `group` query heads of a KV head are `group` query rows of it
+(`mha_reference`'s fold); K and V are contracted as they are stored.  The
+rows are padded to the sublane tile, so MHA's one row a head is 8 rows of
+which one is read.
+
+Operand layout: a Mosaic call fixes its operands' layouts, so a program
+that holds this kernel keeps the cache row-major `[B, Hkv, S, D]` with D
+on the lanes and positions on the sublanes (where XLA alone chose
+position-major for the decode program).
+"""
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+_NEG_INF = -1e30
+_TILE_BYTES = 1 << 20     # of K (and of V) a grid step; twice double-buffered
+_MAX_BLOCK = 512          # a slot half full should not read all of itself
+_MIN_BLOCK = 128
+_Q_ROWS = 8               # the float32 sublane tile
+
+
+def block_len(n_kv_heads: int, head_dim: int, seq_len: int,
+              itemsize: int = 2) -> Optional[int]:
+    """Positions in a tile: the largest power of two that divides
+    `seq_len`, keeps `[Hkv, block, D]` within about a megabyte and is at
+    most `_MAX_BLOCK`; None where the tiling cannot take the shapes (the
+    caller then reads the cache through XLA)."""
+    if head_dim % 128:
+        return None
+    block = _MAX_BLOCK
+    while block >= _MIN_BLOCK:
+        if (seq_len % block == 0 and
+                n_kv_heads * block * head_dim * itemsize <= _TILE_BYTES):
+            return block
+        block //= 2
+    return None
+
+
+def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+            scale: float, block: int):
+    b = pl.program_id(0)
+    j = pl.program_id(1)
+    length = lens_ref[b]
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * block < length)
+    def _compute():
+        q = q_ref[0]                                   # (Hkv, rows, D)
+        k = k_ref[0]                                   # (Hkv, block, D)
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale  # (Hkv, rows, block)
+        k_pos = j * block + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        s = jnp.where(k_pos < length, s, _NEG_INF)
+        m_prev = m_scr[:]                              # (Hkv, rows, 128)
+        m_cur = jnp.max(s, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new[:, :, :1])
+        correction = jnp.exp(m_prev[:, :, :1] - m_new[:, :, :1])
+        l_scr[:] = l_scr[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
+        acc_scr[:] = acc_scr[:] * correction + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)        # (Hkv, rows, D)
+        m_scr[:] = m_new
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _finalize():
+        # A slot of length zero keeps l = 0: zeros, not NaN.
+        l = l_scr[:, :, :1]
+        o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+            o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=('block', 'interpret'))
+def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
+                         v_cache: jax.Array, lengths: jax.Array,
+                         block: Optional[int] = None,
+                         interpret: bool = False) -> jax.Array:
+    """q [B, Hq, 1, D] against k/v [B, Hkv, S, D], positions
+    `< lengths[b]` -> [B, Hq, 1, D].  `block` defaults to `block_len`'s."""
+    b, hq, s_q, d = q.shape
+    _, hkv, s, _ = k_cache.shape
+    assert s_q == 1, 'the decode step has one query row a head'
+    if block is None:
+        block = block_len(hkv, d, s, k_cache.dtype.itemsize)
+    if block is None or s % block:
+        raise ValueError(f'no KV block for Hkv={hkv} D={d} S={s}')
+    group = hq // hkv
+    rows = -(-group // _Q_ROWS) * _Q_ROWS
+    # Query head h reads kv head h // group: [B, Hkv, group, D], padded
+    # with rows that are computed and never read.
+    q = jnp.pad(q.reshape(b, hkv, group, d),
+                ((0, 0), (0, 0), (0, rows - group), (0, 0)))
+    n_blocks = s // block
+
+    def kv_index(i, j, lens):
+        last = jnp.maximum(pl.cdiv(lens[i], block) - 1, 0)
+        return (i, 0, jnp.minimum(j, last), 0)
+
+    row_spec = pl.BlockSpec((1, hkv, rows, d), lambda i, j, lens: (i, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, hkv, block, d), kv_index)
+    out = pl.pallas_call(
+        functools.partial(_kernel, scale=d**-0.5, block=block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, n_blocks),
+            in_specs=[row_spec, kv_spec, kv_spec],
+            out_specs=row_spec,
+            scratch_shapes=[
+                pltpu.VMEM((hkv, rows, 128), jnp.float32),
+                pltpu.VMEM((hkv, rows, 128), jnp.float32),
+                pltpu.VMEM((hkv, rows, d), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, rows, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary')),
+        name='decode_attention',
+        interpret=interpret,
+    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
+    return out[:, :, :group].reshape(b, hq, 1, d)

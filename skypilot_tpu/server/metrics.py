@@ -170,6 +170,14 @@ _HELP = {
         'on="device" is the one fetch per step (the device is the '
         'bottleneck, as it should be), on="idle" the 1 ms sleeps of '
         'an engine with nothing to do',
+    'skytpu_engine_decode_kv_positions_total':
+        'K/V positions of the contiguous decode calls, slots x positions '
+        'x steps, flushed with the loop seconds: kind="held" what the '
+        'cache holds (n_slots x max_seq_len a step), kind="fetched" '
+        'what the steps\' attention asks for — whole tiles up to each '
+        'slot\'s length where the decode attention is bounded by the '
+        'lengths (an empty slot\'s length starts from zero), the same '
+        'as held where it reads every slot whole',
     'skytpu_engine_xla_compile_total':
         'XLA backend compiles observed in this process '
         '(jax.monitoring): increments after engine warmup are '

@@ -364,3 +364,110 @@ def test_engine_pipelined_threaded_loop(model_and_params):
         assert r2.tokens() == want2
     finally:
         engine.stop()
+
+
+def test_engine_empty_slots_count_from_zero_and_held_ones_do_not(
+        model_and_params):
+    """The decode program is told which slots hold a request, by the
+    host's view at dispatch, and counts an empty slot's length from
+    zero.  Under the pipelined loop: a slot retired and later re-admitted
+    yields the tokens a fresh engine gives; a slot admitted in one
+    iteration (its insert queued behind the call in flight) is held in
+    the next call, not zeroed by it; the device's lengths stay what the
+    host mirrors (`_Slot.device_length`) at every dispatch."""
+    model, params = model_and_params
+    config = EngineConfig(n_slots=3, steps_per_call=3,
+                          prefill_buckets=(8, 16))
+    steps = config.steps_per_call
+    prompts = {'a': ([1, 2, 3], 4), 'b': ([7, 8, 9, 10], 16),
+               'c': ([4, 4, 4, 4, 4], 7), 'd': ([11, 12], 5)}
+
+    def alone(name):
+        engine = DecodeEngine(model, params, config)
+        req = engine.submit(*prompts[name])
+        while req.finished_at is None:
+            engine.step()
+        return req.tokens()
+
+    engine = DecodeEngine(model, params, config)
+    reqs = {}
+    zeroed = admitted_then_held = 0
+
+    def iterate():
+        nonlocal zeroed, admitted_then_held
+        empty = [i for i, s in enumerate(engine._slots) if s is None]
+        fresh = [i for i, s in enumerate(engine._slots)
+                 if s is not None and s.device_length == s.length
+                 and s.first_pending]
+        engine.step_pipelined()
+        lens = np.asarray(engine._lens_d)
+        dispatched = engine._inflight is not None
+        for i, slot in enumerate(engine._slots):
+            if slot is not None:
+                assert lens[i] == slot.device_length, (i, lens)
+        if dispatched:
+            for i in empty:
+                if engine._slots[i] is None:
+                    assert lens[i] == steps, (i, lens)
+                    zeroed += 1
+            for i in fresh:     # admitted in the iteration before this call
+                if engine._slots[i] is not None:
+                    assert lens[i] == engine._slots[i].length + steps
+                    admitted_then_held += 1
+
+    reqs['a'] = engine.submit(*prompts['a'])
+    reqs['b'] = engine.submit(*prompts['b'])
+    while reqs['a'].finished_at is None:
+        iterate()
+    iterate()                       # a's slot stands empty for a call
+    reqs['c'] = engine.submit(*prompts['c'])      # ... and is taken again
+    iterate()
+    reqs['d'] = engine.submit(*prompts['d'])
+    for _ in range(200):
+        iterate()
+        if all(r.finished_at is not None for r in reqs.values()):
+            break
+    assert zeroed and admitted_then_held >= 3
+    for name, req in reqs.items():
+        assert req.tokens() == alone(name), name
+
+
+def _kv_positions():
+    import re
+    from skypilot_tpu.server import metrics as metrics_lib
+    series = re.compile(
+        r'^skytpu_engine_decode_kv_positions_total\{kind="(\w+)"\} (\S+)$')
+    return {m.group(1): float(m.group(2))
+            for m in map(series.match, metrics_lib.render().splitlines())
+            if m}
+
+
+def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
+    """`skytpu_engine_decode_kv_positions_total` over a known schedule:
+    `held` is slots x max_seq_len x steps a call; `fetched` the same
+    where the attention reads every slot whole (here, the CPU), and whole
+    tiles up to each step's row where a kernel's block length is known
+    (set by hand: the test's steering, not an option)."""
+    model, params = model_and_params
+    config = EngineConfig(n_slots=2, steps_per_call=3,
+                          prefill_buckets=(8, 16))
+    engine = DecodeEngine(model, params, config)
+    assert engine._kv_block is None
+    before = _kv_positions()
+    req = engine.submit([1, 2, 3, 4, 5], 7)
+    engine.step()
+    engine._flush_loop_seconds()
+    whole = 2 * CFG.max_seq_len * 3
+    after = _kv_positions()
+    assert after['held'] - before.get('held', 0.0) == whole
+    assert after['fetched'] - before.get('fetched', 0.0) == whole
+
+    engine._kv_block = 4
+    engine.step()          # lengths 8 and (empty) 0 at the call's start
+    engine._flush_loop_seconds()
+    assert req.finished_at is not None
+    last = _kv_positions()
+    assert last['held'] - after['held'] == whole
+    # Rows 9, 10, 11 of the held slot: 3 tiles each; rows 1, 2, 3 of the
+    # empty one: a tile each.
+    assert last['fetched'] - after['fetched'] == (3 * 3 + 3 * 1) * 4

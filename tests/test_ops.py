@@ -294,3 +294,81 @@ def test_pallas_flash_compiles_for_v5e(v5e_chip, shape, direction):
         lowered = flash_attention_bwd.lower(q, kv, kv, q, lse, q,
                                             causal=True)
     assert 'tpu_custom_call' in lowered.compile().as_text()
+
+
+# The decode step's attention at the serving cells' shapes [B, Hq, Hkv, S]:
+# Yi-Coder (MHA), Yi-6B (8 query heads a KV head), and Solar-Open2's softmax
+# layer, whose 1,664 positions take the smallest block.
+_DECODE_SHAPES = [(16, 16, 16, 1024), (8, 32, 4, 1024), (32, 64, 8, 1664)]
+
+
+@pytest.mark.parametrize('shape', _DECODE_SHAPES,
+                         ids=lambda s: 'x'.join(map(str, s)))
+def test_pallas_decode_attention_compiles_for_v5e(v5e_chip, shape):
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    b, hq, hkv, s = shape
+
+    def sds(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    cache = sds(b, hkv, s, 128)
+    compiled = pallas_da.decode_attention_fwd.lower(
+        sds(b, hq, 1, 128), cache, cache, sds(b, dtype=jnp.int32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+def test_decode_program_keeps_the_cache_as_the_kernel_reads_it(
+        v5e_chip, monkeypatch):
+    """The engine's decode program, two layers at Yi-Coder's widths with
+    the kernel in it, compiled with the layouts left to the compiler as
+    `_optimize_layouts` leaves them: the cache comes out row-major
+    [B, Hkv, S, D] and no temporary is as large as a cache leaf.  (With
+    the row written by a scatter whose window is a position's heads the
+    compiler laid the cache out position-major and copied every leaf in
+    front of every kernel call.)"""
+    import flax.linen as nn
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
+    from skypilot_tpu.models.llama import Llama, LlamaConfig
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+
+    # `jax.default_backend()` is the CPU here: steer the choice itself.
+    monkeypatch.setattr(
+        attn_lib, 'decode_kv_block',
+        lambda h, d, s, dtype=jnp.bfloat16, mesh=None: pallas_da.block_len(
+            h, d, s, jnp.dtype(dtype).itemsize))
+    cfg = LlamaConfig(vocab_size=64000, dim=2048, n_layers=2, n_heads=16,
+                      n_kv_heads=16, ffn_dim=5504,
+                      max_seq_len=1024, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    model = Llama(cfg)
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))['params']))
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=4, steps_per_call=8, prefill_buckets=(128,)))
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    lens = shapes(engine._lens_d)
+    compiled = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(engine._cache), auto, auto, auto,
+                      auto),
+        out_shardings=(auto, autos(engine._cache), auto, auto)).lower(
+            shapes(params), shapes(engine._cache), shapes(engine._last_d),
+            lens, lens, shapes(engine._rng)).compile()
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
+        == cfg.n_layers
+    formats, _ = compiled.input_formats
+    leaf = jax.tree.leaves(engine._cache)[0]
+    for fmt in jax.tree.leaves(formats[1]):
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3)
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf.nbytes
