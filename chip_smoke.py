@@ -417,8 +417,8 @@ def phase_train(seed: int, sz: dict) -> dict:
 
     from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama
     from skypilot_tpu.parallel.mesh import build_mesh, plan_mesh
+    from skypilot_tpu.perf.cost_model import chip_kind
     from skypilot_tpu.server import metrics as metrics_lib
-    from skypilot_tpu.train.flops import chip_kind
     from skypilot_tpu.train.trainer import TrainConfig, Trainer
 
     cfg = LLAMA_CONFIGS[sz['train_model']]

@@ -566,36 +566,6 @@ def top_cmd(db_url, service, interval, iterations, window):
                                  iterations=iterations, window=window))
 
 
-@cli.command('perf')
-@click.option('--check', 'check_flag', is_flag=True,
-              help='Exit non-zero if any regression check fails '
-                   '(the CI perf-gate mode).')
-@click.option('--baseline', type=click.Path(exists=True), default=None,
-              help='Benchmark baseline JSON (default: the latest '
-                   'BENCH_*.json in the repo root).')
-@click.option('--as-json', is_flag=True, help='Emit the raw report.')
-def perf_cmd(check_flag, baseline, as_json):
-    """Perf-regression gate: fresh probe vs the committed baseline.
-
-    Runs a short in-process serve probe (tiny model — runs anywhere,
-    including CPU CI), checks the live MFU / bytes-per-token gauges
-    agree with the cost model within tolerance, compares throughput
-    against the latest BENCH_*.json within declared tolerances
-    (cross-hardware comparisons are skipped, not failed), and renders
-    a per-prefill-bucket observed-vs-roofline report.
-    """
-    import json as json_lib
-
-    from skypilot_tpu.perf import gate as gate_lib
-    report = gate_lib.run(baseline_path=baseline)
-    if as_json:
-        click.echo(json_lib.dumps(report, indent=2, sort_keys=True))
-    else:
-        click.echo(gate_lib.render_report(report), nl=False)
-    if check_flag and not report['ok']:
-        raise SystemExit(1)
-
-
 @cli.command('rotate-keys')
 def rotate_keys():
     """Rotate the framework SSH keypair across every UP cluster.

@@ -10,8 +10,7 @@ production code path, so the simulator proves fleet behavior at
 scales hardware quota won't allow and its per-run profile report says
 which control-plane hot path to make event-driven next.
 
-Entry points: ``python -m skypilot_tpu.fleetsim`` (CLI),
-``bench.py bench_fleet`` (the BENCH artifact), and the
+Entry points: ``python -m skypilot_tpu.fleetsim`` (CLI) and the
 tests/test_fleetsim* suite.
 """
 from skypilot_tpu.fleetsim.scenario import (LBSever, LeaseholderKill,

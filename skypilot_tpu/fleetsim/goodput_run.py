@@ -9,7 +9,7 @@ controller-style downtime writes for an injected mid-run preemption,
 per-host step-time scrapes downsampled through the telemetry store's
 host sub-label, skew derivation, and the `goodput_low`/`straggler`
 alert rules on the multi-window engine.  The run returns everything
-the bench artifact and the tests pin: the badput breakdown, the exact
+the tests pin: the badput breakdown, the exact
 ledger-vs-sim-wall agreement, the preemption/relaunch intervals, the
 derived skew, and the alert transitions.
 
@@ -54,7 +54,7 @@ def run_goodput_sim(scenario: Optional[GoodputScenario] = None,
     """Run the scenario; returns the pinned result dict.
 
     ``ledger_dsn``/``store_dsn`` default to in-repo temp-style sqlite
-    paths ONLY when given — callers (bench, tests) should pass
+    paths ONLY when given — callers (tests) should pass
     explicit paths; the Postgres conformance job passes DSNs.
     """
     sc = scenario or GoodputScenario()

@@ -26,8 +26,8 @@ from skypilot_tpu.server import metrics as metrics_lib
 _DB_FAMILY = 'skytpu_db_op_seconds'
 _SIM_FAMILY = 'skytpu_fleetsim_control_seconds'
 # The ready-view cache counter rides along as zero-cost rows
-# (cache.ready_view[hit] / [miss]): BENCH_r07's #1 hot path was
-# replicas.ready_view re-querying the full table every tick, and the
+# (cache.ready_view[hit] / [miss]): without the cache
+# replicas.ready_view re-queries the full table every tick, and the
 # hit/miss split is the per-run proof the cache is doing the work.
 _CACHE_FAMILY = 'skytpu_serve_ready_view_cache_total'
 

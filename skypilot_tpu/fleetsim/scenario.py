@@ -15,7 +15,7 @@ Three event kinds, each firing exactly once at its scheduled sim time:
   (its admission view freezes); traffic anycasts to the survivors.
 
 Scenarios load from YAML/dicts (``Scenario.from_config``) so CI jobs
-and the bench share one description format; ``canonical()`` returns
+and the CLI share one description format; ``canonical()`` returns
 the published FLEET scenario documented next to slo_sim's FLEET_*
 constants.
 """

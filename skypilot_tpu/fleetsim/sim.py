@@ -127,7 +127,7 @@ class FleetConfig:
 
 def fleet_config(smoke: bool = False, seed: Optional[int] = None,
                  db: Optional[str] = None) -> FleetConfig:
-    """The canonical run (bench/README numbers), or the CI-sized smoke
+    """The canonical run, or the CI-sized smoke
     twin: same structure — diurnal envelope, burst, storm, leaseholder
     kill, LB sever — an order of magnitude smaller and shorter."""
     if not smoke:
@@ -188,8 +188,8 @@ class FleetResult:
         default_factory=list)
 
     def headline(self) -> str:
-        """The README/bench claim, verbatim (test_readme_bench pins
-        this exact format both directions)."""
+        """One line for the CLI.  Simulated figures: replica latencies
+        are an analytic model's constants, not device times."""
         base = (f'sustains {self.sustained_qps_at_slo:.0f} req/s at '
                 f'SLO with {self.peak_replicas} virtual replicas '
                 f'across {self.pools} pools')

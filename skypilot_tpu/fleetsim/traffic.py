@@ -11,7 +11,7 @@ spreads sessions over replicas rather than being dialed in.
 
 All randomness flows through ONE ``random.Random`` minted by
 slo_sim.make_rng(seed) — the generator is byte-reproducible from the
-CLI/bench ``--seed``.
+CLI's ``--seed``.
 """
 from __future__ import annotations
 

@@ -22,7 +22,7 @@ from the controller's existing federated-LB scrapes:
 
 The fleetsim chaos run ingests sim-time telemetry through the same
 code path, so the canonical storm's alert timeline is test-pinned
-(tests/test_fleetsim.py) and auditable in the bench artifact.
+(tests/test_fleetsim.py).
 """
 from skypilot_tpu.obs.alerts import AlertEngine
 from skypilot_tpu.obs.alerts import AlertRule

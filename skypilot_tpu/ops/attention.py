@@ -37,20 +37,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 
-def _expand_kv(k: jax.Array, num_q_heads: int) -> jax.Array:
-    """GQA: repeat kv heads to match query heads.  A copy, not a view: on
-    the v5e the repeat in front of a contraction became a float32
-    `broadcast` of V, 23.7% of Yi-6B's decode step (ledger, PR 25).
-    `mha_reference` contracts over the stored heads instead; only ring
-    attention's block step (training over a `seq` axis) still repeats."""
-    b, h_kv, s, d = k.shape
-    if h_kv == num_q_heads:
-        return k
-    group = num_q_heads // h_kv
-    k = jnp.repeat(k, group, axis=1)
-    return k
-
-
 def mha_reference(q: jax.Array,
                   k: jax.Array,
                   v: jax.Array,

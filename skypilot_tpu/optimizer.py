@@ -44,8 +44,8 @@ class OptimizeTarget(enum.Enum):
     COST_PER_FLOP = 'cost_per_flop'
 
 
-# Fraction of peak the optimizer assumes a tuned workload achieves; the
-# bench's measured MFU (bench.py) is the source for this default.
+# Fraction of peak the optimizer assumes a tuned workload achieves: a
+# planning constant (BASELINE.json's north-star bar), not a measurement.
 ASSUMED_MFU = 0.45
 
 
@@ -68,7 +68,7 @@ def cost_per_million_tokens(candidate: 'resources_lib.Resources',
                             mfu: float = ASSUMED_MFU) -> Optional[float]:
     """Training $/1M tokens for a dense model of `params_billion`
     parameters at `mfu` (6·N FLOPs/token), on this placement (public
-    what-if helper for planning; bench.py reports the measured analog)."""
+    what-if helper for planning)."""
     tpu = candidate.tpu
     if tpu is None or params_billion <= 0:
         return None

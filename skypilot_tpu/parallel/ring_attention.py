@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from skypilot_tpu.ops import attention as attn_lib
-
 _NEG_INF = -1e30
 
 
@@ -36,22 +34,28 @@ def _block_attend(q, k, v, q_pos, k_pos, causal):
     arbitrary shard rotation.
     """
     scale = q.shape[-1]**-0.5
-    k = attn_lib._expand_kv(k, q.shape[1])  # pylint: disable=protected-access
-    v = attn_lib._expand_kv(v, q.shape[1])  # pylint: disable=protected-access
+    b, h_q, s_q, d = q.shape
+    h_kv = k.shape[1]
+    group = h_q // h_kv
+    # GQA as `ops/attention.py mha_reference` has it: the `group` query
+    # heads of a kv head are `group * Sq` query rows of it, contracted
+    # against K and V as they are stored; the mask is tiled over the group.
+    q = q.reshape(b, h_kv, group * s_q, d)
     s = jnp.einsum('bhqd,bhkd->bhqk', q, k,
                    preferred_element_type=jnp.float32) * scale
     if causal:
         mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
-        s = jnp.where(mask, s, _NEG_INF)
-    m = jnp.max(s, axis=-1)                                   # [B,H,Sq]
+        s = jnp.where(jnp.tile(mask, (1, 1, group, 1)), s, _NEG_INF)
+    m = jnp.max(s, axis=-1)                                   # [B,Hkv,g*Sq]
     p = jnp.exp(s - m[..., None])
     # Fully-masked rows: m = NEG_INF → p = exp(0) = 1 per column, which is
     # wrong; zero them via the l=0 signal instead.
     p = jnp.where(m[..., None] <= _NEG_INF / 2, 0.0, p)
-    l = jnp.sum(p, axis=-1)                                   # [B,H,Sq]
+    l = jnp.sum(p, axis=-1)                                   # [B,Hkv,g*Sq]
     num = jnp.einsum('bhqk,bhkd->bhqd', p.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
-    return num, m, l
+    return (num.reshape(b, h_q, s_q, d), m.reshape(b, h_q, s_q),
+            l.reshape(b, h_q, s_q))
 
 
 def _combine(acc, num, m_acc, m_blk, l_acc, l_blk):

@@ -1,20 +1,25 @@
-"""Static per-dispatch device-cost model for the decode engine.
+"""The program's cost arithmetic: chip peaks, the trainer's model-FLOP
+count and the decode engine's static per-dispatch cost model.
 
-Everything here is derived from quantities the HOST already knows —
+One table of peaks per chip kind (`CHIP_PEAKS`) feeds both live
+utilization gauges: the trainer's `skytpu_train_mfu_percent`
+(`estimate_mfu`: 6N dense forward and backward FLOPs a token plus the
+causal-attention term, over the slice's peak bf16 throughput) and the
+engine's `skytpu_engine_mfu` (`EngineCostModel.mfu`: the forward third
+of the same count, 2N a token).  The benchmark keeps its own count
+(benchmarks/families/, benchmarks/peaks.json) and reads none of this.
+
+The engine's side is derived from quantities the HOST already knows:
 model config, weight-tree byte size, KV-cache element width, batch
-occupancy, mean context length — so the engine loop can attribute
-FLOPs and HBM bytes to every decoded token without touching the
-device.  The conventions match `train/flops.py` (2N forward dense
-FLOPs per token; the trainer's 6N is the fwd+bwd triple), so the live
-`skytpu_engine_mfu` gauge, `bench.py` and the trainer's
-`skytpu_train_mfu_percent` all report the same quantity.
+occupancy, mean context length.  The engine loop can therefore
+attribute FLOPs and HBM bytes to every decoded token without touching
+the device.
 
 The bytes side is the decode roofline: each decode step streams the
 full weight tree once (amortized over the active batch) and reads the
 KV history of every active sequence.  The KV term scales with the
-CACHE ELEMENT WIDTH — the page pool's dtype is an input, so a future
-int8 KV cache shows up as a measured bytes/token halving, not a
-recalibration.
+CACHE ELEMENT WIDTH: the page pool's dtype is an input, so an int8 KV
+cache changes bytes/token by its width, not by a recalibration.
 
 A model's layers may differ (models/solar_open2.py: one softmax layer
 in four, the others carrying a recurrent state of fixed size).  The
@@ -34,20 +39,62 @@ from typing import Optional, Sequence
 
 import jax
 
-from skypilot_tpu.train import flops as flops_lib
 
-# Per-chip HBM bandwidth, GB/s (same table bench.py's per-bandwidth
-# baseline comparison uses).  'cpu' is nominal, so the accounting runs
-# in the CPU tests, same convention as PEAK_BF16_TFLOPS['cpu']; never a
-# device number, and it goes with ROADMAP A0(b).
-HBM_GBPS = {
-    'v5litepod': 819.0,
-    'v5e': 819.0,
-    'v6e': 1640.0,
-    'v5p': 2765.0,
-    'v4': 1228.0,
-    'cpu': 100.0,
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    bf16_tflops: float      # per chip
+    hbm_gbps: float         # per chip
+
+
+# Published per-chip peaks by `chip_kind()` name.  'cpu' is nominal, so
+# that the accounting runs in the CPU tests; never a device number.
+CHIP_PEAKS = {
+    'v5litepod': ChipPeaks(197.0, 819.0),
+    'v5e': ChipPeaks(197.0, 819.0),
+    'v6e': ChipPeaks(918.0, 1640.0),
+    'v5p': ChipPeaks(459.0, 2765.0),
+    'v4': ChipPeaks(275.0, 1228.0),
+    'cpu': ChipPeaks(1.0, 100.0),
 }
+
+
+def chip_kind() -> str:
+    """Normalized device-kind name of the first local device.  A TPU
+    this table does not know is an error, not a 1 TFLOP/s 'cpu'."""
+    dev = jax.devices()[0]
+    kind = dev.device_kind.lower().replace(' ', '')
+    for name in CHIP_PEAKS:
+        if name in kind:
+            return name
+    if 'lite' in kind:      # 'TPU v5 lite'
+        return 'v5litepod'
+    if dev.platform == 'tpu':
+        raise ValueError(
+            f'unknown TPU device_kind {dev.device_kind!r}: add its peaks '
+            f'to CHIP_PEAKS')
+    return 'cpu'
+
+
+def train_flops_per_token(n_params: int, n_layers: int, dim: int,
+                          seq_len: int) -> float:
+    """fwd+bwd model FLOPs per trained token: 6N dense + causal
+    attention term."""
+    return 6 * n_params + 6 * n_layers * seq_len * dim
+
+
+def estimate_mfu(tokens_per_s: float, n_params: int, n_layers: int,
+                 dim: int, seq_len: int, n_chips: int = 1,
+                 kind: Optional[str] = None) -> float:
+    """Achieved model TFLOP/s as % of the slice's peak bf16 TFLOP/s.
+
+    Returns 0.0 on unrecognized hardware rather than a bogus ratio."""
+    peaks = CHIP_PEAKS.get(kind or chip_kind())
+    if peaks is None or tokens_per_s <= 0:
+        return 0.0
+    achieved_tflops = (tokens_per_s *
+                       train_flops_per_token(n_params, n_layers, dim,
+                                             seq_len) / 1e12)
+    return 100.0 * achieved_tflops / (peaks.bf16_tflops * max(1, n_chips))
 
 
 def _split_cache(cache):
@@ -142,7 +189,7 @@ class EngineCostModel:
                    dim=cfg.dim, n_kv_heads=n_kv_heads, head_dim=head_dim,
                    param_bytes=_nbytes(param_leaves),
                    kv_dtype_bytes=int(kv_bytes), n_chips=n_chips,
-                   chip=chip or flops_lib.chip_kind(),
+                   chip=chip or chip_kind(),
                    kv_scale_bytes_per_pos=scale_bytes,
                    n_kv_layers=n_kv_layers,
                    state_bytes_per_slot=_nbytes(state_leaves) / slots)
@@ -151,7 +198,7 @@ class EngineCostModel:
     def decode_flops_per_token(self, context_len: float) -> float:
         """Forward model FLOPs to decode one token at the given KV
         context length: 2N dense + the causal-attention term (the
-        forward third of flops_lib.train_flops_per_token's 6N+6LSD),
+        forward third of train_flops_per_token's 6N+6LSD),
         over the layers that attend over a cache.  N is what the
         model's config counts as held; for an expert layer that is
         every held expert, not the few a token meets."""
@@ -193,10 +240,11 @@ class EngineCostModel:
 
     # ----- roofline --------------------------------------------------
     def _peaks(self):
-        peak_flops = (flops_lib.PEAK_BF16_TFLOPS.get(self.chip, 0.0) *
-                      1e12 * self.n_chips)
-        hbm_bytes_s = HBM_GBPS.get(self.chip, 0.0) * 1e9 * self.n_chips
-        return peak_flops, hbm_bytes_s
+        peaks = CHIP_PEAKS.get(self.chip)
+        if peaks is None:
+            return 0.0, 0.0
+        return (peaks.bf16_tflops * 1e12 * self.n_chips,
+                peaks.hbm_gbps * 1e9 * self.n_chips)
 
     def mfu(self, tokens_per_s: float, context_len: float) -> float:
         """Achieved decode model FLOPs as % of the slice's peak."""

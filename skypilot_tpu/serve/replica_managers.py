@@ -488,10 +488,9 @@ class ReplicaManager:
         """Cached live-replica snapshot backing the read-only views
         (ready_replicas / num_live).
 
-        These views are hammered — the fleetsim decision loop calls
-        them several times per tick, and `replicas.ready_view` was the
-        #1 entry in BENCH_r07's per-run profile because every call
-        re-queried the full replicas table.  The snapshot is keyed on
+        These views are hammered: the controller's decision loop calls
+        them several times per tick, and without the snapshot every
+        call re-queries the full replicas table.  The snapshot is keyed on
         serve_state.replicas_version() (exact invalidation: any
         replica write in this process bumps it) plus the
         SKYTPU_READY_VIEW_TTL_S backstop for out-of-process writers.

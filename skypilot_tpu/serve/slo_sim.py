@@ -1,6 +1,6 @@
 """Virtual-replica latency simulation for the SLO-autoscaling loop.
 
-Backs ``bench.py bench_slo_ramp`` and the load-tier tests: N virtual
+Backs fleetsim and the load-tier tests: N virtual
 replicas with an analytic decode-latency model, producing the SAME
 Prometheus exposition text the controller scrapes from a real LB's
 federated /metrics — so the autoscaler under test consumes
@@ -111,9 +111,8 @@ def run_ramp(autoscaler, service: VirtualService,
     return history
 
 
-# The canonical SLO-vs-QPS comparison scenario, shared by bench.py's
-# bench_slo_ramp and the load-tier tests so the README's pinned bench
-# numbers and the asserting test provably describe the SAME experiment.
+# The canonical SLO-vs-QPS comparison scenario of the load-tier tests
+# (tests/test_load.py).
 DEFAULT_TARGET_TPOT_MS = 15.0
 DEFAULT_TICK_S = 10.0
 DEFAULT_BASE_TPOT_S = 0.010
@@ -288,10 +287,8 @@ class MixedPoolService(VirtualService):
                                   decode_replicas))
 
 
-# The canonical disaggregation scenario, shared by bench.py's
-# bench_disagg and its test twin (tests/test_serve_disagg.py) so the
-# README's pinned numbers and the asserting tests provably describe
-# the SAME experiment.  Saturated mixed long/short traffic: the
+# The canonical disaggregation scenario of tests/test_serve_disagg.py.
+# Saturated mixed long/short traffic: the
 # prompt-token mean models 70% short (256-token) / 30% long
 # (~4100-token) requests — heavy enough prefill that a monolithic
 # pool's cross-phase steal breaks the TPOT SLO at the plateau, while
@@ -308,17 +305,17 @@ DISAGG_PEAK_QPS = 40.0
 DISAGG_TICK_S = 10.0
 
 # The canonical FLEET scenario (skypilot_tpu/fleetsim/), documented
-# next to its DISAGG_* siblings because bench_fleet, the fleetsim CLI
-# and the test suite must all describe the SAME experiment.  One
+# next to its DISAGG_* siblings because the fleetsim CLI
+# and the test suite must describe the SAME experiment.  One
 # virtual replica here is deliberately SMALL (a single-host spot
-# decode engine, ~2 req/s at SLO) so the bench's diurnal peak of
+# decode engine, ~2 req/s at SLO) so the run's diurnal peak of
 # roughly a thousand req/s genuinely needs a four-digit decode pool —
 # the point of the fleet simulator is control-plane behavior at a
 # replica count hardware quota won't allow, not latency fidelity of
 # any one replica.  Traffic: Poisson arrivals at FLEET_BASE_QPS
 # modulated by a sinusoidal diurnal envelope (amplitude
 # FLEET_DIURNAL_AMPLITUDE, period FLEET_DIURNAL_PERIOD_S — compressed
-# so a bench horizon of a few simulated minutes spans a full "day")
+# so a horizon of a few simulated minutes spans a full "day")
 # plus scripted burst multipliers; multi-turn sessions (geometric turn
 # count, exponential think time) over a large user population give
 # every turn a shared system prefix + its own history, so prefix-cache
@@ -334,7 +331,7 @@ FLEET_TARGET_TTFT_MS = 300.0
 FLEET_TARGET_TPOT_MS = 25.0
 FLEET_BASE_QPS = 1500.0         # diurnal mean arrival rate
 FLEET_DIURNAL_AMPLITUDE = 0.6   # peak = base * (1 + amplitude)
-FLEET_DIURNAL_PERIOD_S = 240.0  # one compressed "day" per bench run
+FLEET_DIURNAL_PERIOD_S = 240.0  # one compressed "day" per run
 FLEET_MEAN_TURNS = 4.0          # geometric session length
 FLEET_MEAN_THINK_S = 8.0        # exponential inter-turn think time
 FLEET_USERS = 2_000_000         # user-id population sampled from
@@ -380,8 +377,8 @@ def make_rng(seed: Optional[int] = None) -> random.Random:
 
     Every stochastic choice in a fleet run (arrival thinning, session
     turn counts, think times, storm victim sampling) draws from a
-    single ``random.Random`` minted here, plumbed from the CLI/bench
-    ``--seed`` flag — so every published fleet number is
+    single ``random.Random`` minted here, plumbed from the CLI's
+    ``--seed`` flag — so every fleet run is
     byte-reproducible from its command line."""
     return random.Random(FLEET_SEED if seed is None else seed)
 

@@ -223,7 +223,8 @@ _HELP = {
         'checkpoint saves, input stalls — is excluded from the '
         'denominator)',
     'skytpu_train_mfu_percent':
-        'Estimated model FLOPs utilization (bench.py accounting)',
+        'Estimated model FLOPs utilization: 6N + 6 L s d FLOPs a token '
+        '(perf/cost_model.py estimate_mfu) over the slice\'s peak bf16',
     # ----- training goodput plane (obs/goodput.py) -------------------------
     'skytpu_train_goodput_percent':
         'Share of this run\'s classified wall-clock spent in '
@@ -286,8 +287,7 @@ _HELP = {
     'skytpu_obs_ingest_seconds':
         'Wall time to downsample one federated scrape into the '
         'telemetry store (parse + delta extraction + one batched '
-        'transaction), by service — the bench_obs_overhead '
-        'per-scrape cost lives in this histogram',
+        'transaction), by service',
     'skytpu_obs_alerts_total':
         'SLO alert transitions by rule and transition (fire / clear) '
         '— the counter twin of the durable obs_alerts rows',
@@ -337,7 +337,7 @@ _BUCKETS: Dict[str, Tuple[float, ...]] = {
 }
 
 # Family names referenced OUTSIDE the exporting process (the LB's
-# admission control, the SLO autoscaler, and the bench sim all read
+# admission control, the SLO autoscaler, and the simulators all read
 # this gauge out of scraped exposition text): shared constants so a
 # rename cannot silently sever a consumer (the fail-open readers would
 # just find nothing).

@@ -323,18 +323,18 @@ class Trainer:
                 self._badput_exported[cat] = total
 
     def _export_throughput(self, tokens_per_s: float, batch) -> None:
-        """tokens/sec + estimated-MFU gauges (bench.py's FLOP
-        accounting via train/flops.py).  Models without a LlamaConfig-
-        shaped cfg just skip the MFU gauge."""
+        """tokens/sec + estimated-MFU gauges (perf/cost_model.py's
+        count).  Models without a LlamaConfig-shaped cfg just skip the
+        MFU gauge."""
+        from skypilot_tpu.perf import cost_model
         from skypilot_tpu.server import metrics as metrics_lib
-        from skypilot_tpu.train import flops as flops_lib
         metrics_lib.set_gauge('skytpu_train_tokens_per_second',
                               tokens_per_s)
         cfg = getattr(self.model, 'cfg', None)
         if batch is None or cfg is None or self._n_params is None:
             return
         try:
-            mfu = flops_lib.estimate_mfu(
+            mfu = cost_model.estimate_mfu(
                 tokens_per_s, self._n_params, cfg.n_layers, cfg.dim,
                 seq_len=batch.shape[-1], n_chips=self.mesh.size)
         except (AttributeError, TypeError):

@@ -1,7 +1,7 @@
 """Persistent XLA compile cache, turned on by process entry points.
 
 A cold 32-layer program is mostly compile, so every process that owns a
-device (the inference server, bench.py, chip_smoke.py's children, the
+device (the inference server, chip_smoke.py's children, the
 `run:` scripts of the training examples) calls `enable()` before its
 first compile.  Never called at import or from a library constructor:
 the CPU tests stay uncached.

@@ -18,7 +18,7 @@ training twin of test_obs.py):
 - the trainer hot loop stays sync-free and recompile-free with the
   goodput instrumentation in it (counted, not assumed);
 - `skytpu jobs top` snapshot/render, live and as a dead-job postmortem;
-- the zero-hardware goodput sim that bench_goodput pins.
+- the zero-hardware goodput sim (fleetsim/goodput_run.py).
 """
 import math
 import random
@@ -550,7 +550,7 @@ def test_jobs_top_dead_job_postmortem_without_store(tmp_path):
 
 
 # ---------------------------------------------------------------------------
-# The zero-hardware goodput sim (what bench_goodput pins)
+# The zero-hardware goodput sim
 # ---------------------------------------------------------------------------
 def test_goodput_sim_tiles_exactly_and_detects_the_planted_straggler(
         dsn):

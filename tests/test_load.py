@@ -12,7 +12,8 @@ import time
 
 import requests as requests_lib
 
-from test_chaos import chaos_server  # noqa: F401  (fixture reuse)
+# Fixture reuse: `chaos_server` depends on `chaos_backend_url`.
+from test_chaos import chaos_backend_url, chaos_server  # noqa: F401
 
 
 def _post_launch(port, i):
@@ -89,9 +90,7 @@ def test_concurrent_load(chaos_server):  # noqa: F811
 # sleeps.
 import pytest
 
-# Scenario constants + driver live in slo_sim (the exact config
-# bench.py's bench_slo_ramp runs, so the bench numbers the README pins
-# and this asserting test describe the SAME experiment).
+# Scenario constants + driver live in slo_sim.
 from skypilot_tpu.serve.slo_sim import (DEFAULT_TARGET_TPOT_MS as
                                         TARGET_TPOT_MS)
 

@@ -692,26 +692,45 @@ def test_scale_down_needs_projection_headroom():
     assert d.decode.target_num_replicas < 4
 
 
-# ----- the bench twin (same scenario constants as bench_disagg) ---------------
+# ----- the canonical scenario (slo_sim's DISAGG_* constants) -------------------
 def test_disagg_sim_beats_monolithic_and_survives_preemption():
-    """The acceptance numbers, mechanically: at equal chip budget the
-    mixed pool yields lower $/SLO-met than the homogeneous pool, an
-    injected decode-pool preemption mid-plateau does not breach the
-    TPOT SLO (and the re-plan restores the pool), while a pool sized
-    without headroom WOULD breach — both directions."""
-    import bench
-    out = bench.bench_disagg(plateau_ticks=6)
-    assert out['slo_met_frac_disagg'] > out['slo_met_frac_monolithic']
-    assert out['usd_per_1k_slo_met_disagg'] is not None
-    assert out['usd_per_1k_slo_met_monolithic'] is None or \
-        out['usd_per_1k_slo_met_disagg'] < \
-        out['usd_per_1k_slo_met_monolithic']
-    assert out['preemption_tpot_ok'] is True
-    assert out['preemption_max_tpot_ms'] <= out['target_tpot_ms']
-    assert out['preemption_replan_restored_pool'] is True
-    assert out['no_headroom_preemption_breaches'] is True
-    assert out['disagg']['cost_per_hr'] < out['monolithic'][
-        'cost_per_hr']            # spot decode pool: cheaper chips too
+    """On the simulator's phase-cost model: at the peak the monolithic
+    pool breaks the TPOT target where some split of the same chips
+    meets both targets; under the per-pool autoscaler an injected
+    decode-pool preemption mid-plateau does not breach the TPOT target
+    (and the re-plan restores the pool), while a pool sized without
+    headroom WOULD breach.  Both directions."""
+    from skypilot_tpu.serve import slo_sim
+    chips = slo_sim.DISAGG_TOTAL_CHIPS
+    peak = slo_sim.DISAGG_PEAK_QPS
+    target_ttft_ms = slo_sim.DISAGG_TARGET_TTFT_MS
+    target_tpot_ms = slo_sim.DISAGG_TARGET_TPOT_MS
+    svc = slo_sim.make_disagg_service()
+
+    def ttft_ms(prefill, decode):
+        return svc.latencies_pools(peak, prefill, decode)[0] * 1e3
+
+    def tpot_ms(prefill, decode):
+        return svc.latencies_pools(peak, prefill, decode)[1] * 1e3
+
+    assert svc.latencies_monolithic(peak, chips)[1] * 1e3 > target_tpot_ms
+    assert any(ttft_ms(p, chips - p) <= target_ttft_ms and
+               tpot_ms(p, chips - p) <= target_tpot_ms
+               for p in range(1, chips))
+
+    ramp = slo_sim.disagg_ramp(6)
+    preempt_tick = len(ramp) - 3
+    hist = slo_sim.run_disagg_ramp(
+        slo_sim.make_disagg_autoscaler(spot_headroom=1),
+        slo_sim.make_disagg_service(), ramp, preempt_tick=preempt_tick)
+    assert max(t for _, _, _, _, t in hist[preempt_tick:]) <= target_tpot_ms
+    assert hist[preempt_tick + 1][2] >= hist[preempt_tick][2] + 1
+    # Counterfactual, static by construction: a decode pool sized
+    # EXACTLY to its target (the smallest that meets it at the peak, no
+    # spot headroom) breaches the moment one replica is preempted.
+    d_slo = next(d for d in range(1, chips + 1)
+                 if tpot_ms(2, d) <= target_tpot_ms)
+    assert tpot_ms(2, max(1, d_slo - 1)) > target_tpot_ms
 
 
 def test_phase_latency_model_decouples_pools():
