@@ -25,6 +25,28 @@ with fsdp=1.  Pairing expert parallelism with ZeRO-sharded dense params
 (fsdp > 1) currently makes XLA bounce the residual's backward through a
 full repartition (replicate-then-shard) — correct but slow; keep the
 dense params expert-axis-replicated instead.
+
+Beside it stands the serving path's layer, `DroplessMoE`: one chip's share
+of an expert-parallel layer.  It is told which experts it holds, routes
+over all of them (sigmoid scores, the k largest, normalised), and returns
+the held experts' part of the sum plus the shared expert; no capacity, no
+drop, and what the absent experts would add is another chip's.  Its sum,
+`grouped_experts`, has one meaning and two ways to be computed, chosen by
+what the code can see (`expert_tile`: the backend, the mesh, the dtype and
+the shapes; nothing a configuration sets):
+
+- a decode step's few tokens (no more than one block) on one TPU device
+  are bound by the bytes of the experts they reach, so one Pallas call
+  (`ops/pallas/grouped_experts.py`) streams those experts' weight tiles
+  through a scalar-prefetched table of their rows, each expert once,
+  against all the tokens, weighted by a [n_held, T] table of routing
+  weights that is zero where a token did not choose the expert;
+- anything else (a prefill's thousands of tokens, the CPU, a mesh, other
+  dtypes or widths) sorts the token-expert pairs by expert and loops over
+  the non-empty blocks of pairs, a block against its expert's weights.
+
+Both count what they routed (`expert_tokens`, `touched`), and
+`kernel_trips` says who multiplied.
 """
 from __future__ import annotations
 
@@ -175,34 +197,86 @@ def route_top_k(scores: jax.Array, top_k: int, scaling: float = 1.0):
     return idx, top / jnp.sum(top, axis=-1, keepdims=True) * scaling
 
 
+def expert_tile(n_tokens: int, block: int, w_gate: jax.Array,
+                mesh: Optional[Mesh] = None) -> Optional[int]:
+    """The F columns one grid step of the decode kernel covers
+    (`ops/pallas/grouped_experts.py`) for stacks like `w_gate`
+    [n_held, D, F], or None where `grouped_experts` goes through the block
+    loop: more tokens than one block, off the TPU, under a mesh of several
+    devices (XLA cannot partition a Mosaic call), stacks that are not
+    bf16, or widths the kernel's tiling cannot take."""
+    if (n_tokens > block or w_gate.dtype != jnp.bfloat16 or
+            jax.default_backend() != 'tpu' or
+            (mesh is not None and mesh.size > 1)):
+        return None
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+    return pallas_ge.tile_f(w_gate.shape[1], w_gate.shape[2],
+                            w_gate.dtype.itemsize)
+
+
 def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
                     local_of: jax.Array, w_gate: jax.Array, w_up: jax.Array,
-                    w_down: jax.Array, block: int):
+                    w_down: jax.Array, block: int,
+                    mesh: Optional[Mesh] = None):
     """sum over the HELD experts e of weight_e * SwiGLU_e(x), for tokens x
     [T, D] routed to `idx` [T, k] with `weights` [T, k].
 
     `local_of` [E] maps an expert's id to its row of the held stacks
     w_gate / w_up [n_held, D, F] and w_down [n_held, F, D], and to n_held
-    where the expert lives elsewhere.  No capacity and no drop: the
-    token-expert pairs are sorted by held expert, and a loop whose trip
-    count is the number of non-empty blocks of `block` pairs multiplies
-    each block by its own expert's weights.  An expert nobody chose costs
-    nothing, one that every token chose takes T / block blocks.
+    where the expert lives elsewhere.  No capacity and no drop: every
+    held expert that some token chose is multiplied, with every token
+    that chose it.  An expert nobody chose costs nothing.  Who multiplies
+    follows the shape (`expert_tile`): the few tokens of a decode step go
+    through one kernel call that streams the reached experts' weights,
+    anything else through the block loop.
 
     Returns (out [T, D] float32, counts [n_held + 1] int32: the pairs of
-    each held expert, and last the pairs routed elsewhere).
+    each held expert, and last the pairs routed elsewhere, and the
+    experts the kernel multiplied: 0 where the loop did).
     """
-    t, k = idx.shape
+    n_held = w_gate.shape[0]
+    keys = local_of[idx]                                     # [T, k]
+    one_hot = jax.nn.one_hot(keys.reshape(-1), n_held + 1, dtype=jnp.int32)
+    counts = jnp.sum(one_hot, axis=0)
+    tile = expert_tile(x.shape[0], block, w_gate, mesh)
+    if tile is None:
+        out = _block_loop(x, keys, weights, one_hot, counts, w_gate, w_up,
+                          w_down, block)
+        return out, counts, jnp.zeros((), jnp.int32)
+    # One block an expert, all tokens in it: c[e, t] is token t's weight
+    # for the expert of row e, zero where it did not choose it, and the
+    # reached experts' rows stand first in `rows`.
+    held_rows = jnp.arange(n_held)
+    c = jnp.sum(jnp.where(keys[None] == held_rows[:, None, None],
+                          weights[None], 0.0), axis=-1)      # [n_held, T]
+    reached = counts[:n_held] > 0
+    place = jnp.cumsum(reached) - 1
+    rows = jnp.sum(jnp.where(reached[None, :] &
+                             (place[None, :] == held_rows[:, None]),
+                             held_rows[None, :], 0), axis=1)
+    n_reached = jnp.sum(reached)
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+    out = pallas_ge.grouped_experts_fwd(x, c, rows, n_reached, w_gate, w_up,
+                                        w_down, tile=tile)
+    return out, counts, n_reached
+
+
+def _block_loop(x, keys, weights, one_hot, counts, w_gate, w_up, w_down,
+                block: int):
+    """`grouped_experts` for any number of tokens: the token-expert pairs
+    are sorted by held expert, and a loop whose trip count is the number
+    of non-empty blocks of `block` pairs multiplies each block by its own
+    expert's weights.  An expert that every token chose takes T / block
+    blocks.  `one_hot` [T * k, n_held + 1] and `counts` are `keys`'."""
+    t, k = keys.shape
     n_held = w_gate.shape[0]
     m = t * k
-    keys = local_of[idx].reshape(m)
+    keys = keys.reshape(m)
     # The pairs by held expert: a counting sort (a pair's place is its
     # expert's start plus its rank among that expert's pairs).  A
     # comparison sort of 262,144 keys takes the TPU compiler 16 s a
     # program.
-    one_hot = jax.nn.one_hot(keys, n_held + 1, dtype=jnp.int32)
     rank = jnp.cumsum(one_hot, axis=0) - one_hot
-    counts = jnp.sum(one_hot, axis=0)
     ends = jnp.cumsum(counts)
     starts = ends - counts
     place = starts[keys] + jnp.take_along_axis(rank, keys[:, None],
@@ -229,9 +303,8 @@ def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
                       y.astype(jnp.float32) * flat_w[pair][:, None], 0.0)
         return acc.at[tok].add(y)
 
-    out = jax.lax.fori_loop(0, block_ends[-1], body,
-                            jnp.zeros(x.shape, jnp.float32))
-    return out, counts
+    return jax.lax.fori_loop(0, block_ends[-1], body,
+                             jnp.zeros(x.shape, jnp.float32))
 
 
 class DroplessMoE(nn.Module):
@@ -247,8 +320,10 @@ class DroplessMoE(nn.Module):
     the whole layer.  No token is ever dropped (`grouped_experts`).
 
     Under `mutable=['stats']` it sows, a call: `expert_tokens` [n_held +
-    1] (pairs of each held expert, then pairs routed elsewhere) and
-    `touched` (held experts with at least one token).
+    1] (pairs of each held expert, then pairs routed elsewhere), `touched`
+    (held experts with at least one token) and `kernel_trips` (those of
+    them that the decode kernel multiplied: `touched` where it runs, 0
+    where the block loop does).
     """
     dim: int
     ffn_dim: int
@@ -260,6 +335,7 @@ class DroplessMoE(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     block: int = 256            # pairs a trip of the expert loop
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:           # [B, S, D]
@@ -286,11 +362,12 @@ class DroplessMoE(nn.Module):
         stacks = (stack('w_gate', (d, self.ffn_dim)),
                   stack('w_up', (d, self.ffn_dim)),
                   stack('w_down', (self.ffn_dim, d)))
-        out, counts = grouped_experts(
+        out, counts, kernel_trips = grouped_experts(
             xin, idx, weights, jnp.asarray(local_of), *stacks,
-            min(self.block, -(-(b * s) // 8) * 8))
+            min(self.block, -(-(b * s) // 8) * 8), self.mesh)
         self.sow('stats', 'expert_tokens', counts)
         self.sow('stats', 'touched', jnp.sum(counts[:n_held] > 0))
+        self.sow('stats', 'kernel_trips', kernel_trips)
         if self.n_shared:
             dense = lambda name, feat: nn.Dense(  # noqa: E731
                 feat, use_bias=False, dtype=self.dtype,
@@ -302,10 +379,11 @@ class DroplessMoE(nn.Module):
         return out.reshape(b, s, d).astype(x.dtype)
 
 
-def publish_routing(held: tuple, expert_tokens, touched) -> None:
+def publish_routing(held: tuple, expert_tokens, touched,
+                    kernel_trips) -> None:
     """One fetch's routing counts, to the /metrics registry (host side;
-    `expert_tokens` [n_held + 1] and `touched` as sown, summed over the
-    layers and steps of the fetch)."""
+    `expert_tokens` [n_held + 1], `touched` and `kernel_trips` as sown,
+    summed over the layers and steps of the fetch)."""
     from skypilot_tpu.server import metrics as metrics_lib
     n_held = len(held)
     metrics_lib.inc_counter('skytpu_moe_pairs_total',
@@ -314,6 +392,10 @@ def publish_routing(held: tuple, expert_tokens, touched) -> None:
                             float(expert_tokens[n_held]), where='elsewhere')
     metrics_lib.inc_counter('skytpu_moe_experts_touched_total',
                             float(touched))
+    metrics_lib.inc_counter('skytpu_moe_expert_trips_total',
+                            float(kernel_trips), path='kernel')
+    metrics_lib.inc_counter('skytpu_moe_expert_trips_total',
+                            float(touched - kernel_trips), path='loop')
     for e, n in zip(held, expert_tokens[:n_held]):
         if n:
             metrics_lib.inc_counter('skytpu_moe_expert_tokens_total',

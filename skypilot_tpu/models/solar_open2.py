@@ -48,6 +48,7 @@ from typing import Any, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh
 
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.llama import RMSNorm
@@ -359,6 +360,7 @@ class GatedAttention(nn.Module):
 class Block(nn.Module):
     cfg: SolarOpen2Config
     index: int
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x, positions, decode, lengths):
@@ -376,7 +378,8 @@ class Block(nn.Module):
             held=cfg.held_experts, top_k=cfg.experts_per_token,
             n_shared=cfg.n_shared_experts,
             routed_scaling=cfg.routed_scaling,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, name='moe')(h)
+            dtype=cfg.dtype, param_dtype=cfg.param_dtype, mesh=self.mesh,
+            name='moe')(h)
 
 
 class SolarOpen2(nn.Module):
@@ -385,6 +388,9 @@ class SolarOpen2(nn.Module):
     S > 1 the logits are those of each row's last valid position alone,
     [B, 1, vocab]."""
     cfg: SolarOpen2Config
+    # The mesh the program is partitioned over, if any: the expert layer's
+    # decode kernel is for one device (models/moe.py `expert_tile`).
+    mesh: Optional[Mesh] = None
     # Read by DecodeEngine: per-slot state that is not keys and values, so
     # the paged manager, speculation and KV transfer cannot hold it yet.
     recurrent_state = True
@@ -403,8 +409,8 @@ class SolarOpen2(nn.Module):
                      embedding_init=nn.initializers.normal(stddev=1.0),
                      name='embed')(tokens)
         for i in range(cfg.n_layers):
-            x = Block(cfg, i, name=f'layer_{i}')(x, positions, decode,
-                                                 lengths)
+            x = Block(cfg, i, self.mesh, name=f'layer_{i}')(
+                x, positions, decode, lengths)
         if lengths is not None and x.shape[1] > 1:
             # A prefill reads one position's logits a row, the last valid
             # one: the head runs on that position alone ([B, 1, vocab]).
@@ -422,4 +428,5 @@ class SolarOpen2(nn.Module):
         moe_lib.publish_routing(
             self.cfg.held_experts,
             sum(moe['expert_tokens'][0] for moe in layers),
-            sum(moe['touched'][0] for moe in layers))
+            sum(moe['touched'][0] for moe in layers),
+            sum(moe['kernel_trips'][0] for moe in layers))

@@ -137,6 +137,12 @@ _HELP = {
         'Held experts that at least one token of a decode step reached, '
         'summed over expert layers and steps: the expert weights a step '
         'has to read',
+    'skytpu_moe_expert_trips_total':
+        'Held experts that decode steps reached, by who multiplied them: '
+        'path="kernel" the grouped decode kernel (one call streams a '
+        'step\'s reached experts), path="loop" the block loop; the two '
+        'add up to skytpu_moe_experts_touched_total, and kernel at 0 '
+        'says the mechanism did not engage',
     'skytpu_moe_expert_tokens_total':
         'Token-expert pairs of decode steps by held expert (its id '
         'among all experts), summed over expert layers: the routing\'s '

@@ -372,3 +372,42 @@ def test_decode_program_keeps_the_cache_as_the_kernel_reads_it(
     for fmt in jax.tree.leaves(formats[1]):
         assert fmt.layout.major_to_minor == (0, 1, 2, 3)
     assert compiled.memory_analysis().temp_size_in_bytes < leaf.nbytes
+
+
+def test_decode_program_keeps_the_expert_stacks_as_the_kernel_reads_them(
+        v5e_chip, monkeypatch):
+    """Solar-Open2's expert layer at the cell's widths (32 tokens, 40 held
+    experts of 4096 x 1280), decode-shaped with the grouped kernel in it
+    and every layout left to the compiler as `_optimize_layouts` leaves
+    them: the stacks stay row-major as they are made (what the prefill's
+    block loop reads too) and no temporary is as large as one stack."""
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.models import moe as moe_lib
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+
+    # `jax.default_backend()` is the CPU here: steer the choice itself.
+    monkeypatch.setattr(
+        moe_lib, 'expert_tile',
+        lambda n_tokens, block, w_gate, mesh=None: pallas_ge.tile_f(
+            w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
+    layer = moe_lib.DroplessMoE(
+        dim=4096, ffn_dim=1280, n_experts=320, held=tuple(range(40)),
+        top_k=8, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    x = jax.ShapeDtypeStruct((32, 1, 4096), jnp.bfloat16, sharding=v5e_chip)
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros(x.shape, x.dtype))['params'])
+    auto = Format(Layout.AUTO, v5e_chip)
+    compiled = jax.jit(
+        lambda params, x: layer.apply({'params': params}, x),
+        in_shardings=(jax.tree.map(lambda _: auto, params), auto),
+        out_shardings=auto).lower(
+            jax.tree.map(lambda p: jax.ShapeDtypeStruct(
+                p.shape, p.dtype, sharding=v5e_chip), params), x).compile()
+    assert compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
+    formats, _ = compiled.input_formats
+    for name in ('w_gate', 'w_up', 'w_down'):
+        assert formats[0][name].layout.major_to_minor == (0, 1, 2)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        params['w_gate'].size * 2

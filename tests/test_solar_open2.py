@@ -10,10 +10,12 @@ family's seed, so that the program and the reference differ by rounding
 order only.
 """
 import copy
+import functools
 import os
 import sys
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -259,6 +261,183 @@ def test_a_decode_steps_few_tokens_go_through_the_same_loop(moe_weights):
     counts = np.asarray(stats['stats']['expert_tokens'][0])
     assert counts.sum() == 24 * 2
     assert int(stats['stats']['touched'][0]) == (counts[:5] > 0).sum()
+
+
+@pytest.fixture
+def kernel_forced(monkeypatch):
+    """The decode kernel wherever its shapes allow, in interpret mode:
+    `jax.default_backend()` is the CPU here, so the test steers the
+    choice itself (the rule's own cases are below)."""
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+    monkeypatch.setattr(
+        moe_lib, 'expert_tile',
+        lambda n_tokens, block, w_gate, mesh=None: None
+        if n_tokens > block else pallas_ge.tile_f(
+            w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
+    monkeypatch.setattr(
+        pallas_ge, 'grouped_experts_fwd',
+        functools.partial(pallas_ge.grouped_experts_fwd, interpret=True))
+
+
+# case: (tokens, held ids, the two experts every token is made to pick
+# or None, dtype, pairs of each held expert or None, atol)
+KERNEL_CASES = {
+    'all_pick_one_held_expert':
+        (24, (2, 3, 4, 5), (3, 12), jnp.float32, [0, 24, 0, 0], 1e-4),
+    'none_reaches_a_held_expert':
+        (24, (2, 3, 4, 5), (10, 12), jnp.float32, [0, 0, 0, 0], 1e-4),
+    'reached_rows_not_first_and_with_gaps':
+        (24, (0, 1, 2, 3, 4, 5, 6, 9), (3, 9), jnp.float32,
+         [0, 0, 0, 24, 0, 0, 0, 24], 1e-4),
+    'tokens_not_a_multiple_of_8': (21, (2, 3, 4, 5, 9), None, jnp.float32,
+                                   None, 1e-4),
+    'tokens_fill_the_block': (16, (2, 3, 4, 5, 9), None, jnp.float32, None,
+                              1e-4),
+    'bf16_as_served': (32, (2, 3, 4, 5, 9), None, jnp.bfloat16, None, 2e-2),
+}
+
+
+@pytest.mark.parametrize('case', list(KERNEL_CASES))
+def test_the_decode_kernel_sums_what_the_reference_sums(kernel_forced, case):
+    """A decode step's tokens through the grouped kernel (interpret mode):
+    the layer is the reference's at `highest`, the counts are the pairs of
+    each held expert, and every reached expert was the kernel's."""
+    n_tokens, ids, picks, dtype, pairs, atol = KERNEL_CASES[case]
+    layer = moe_lib.DroplessMoE(
+        dim=128, ffn_dim=256, n_experts=16, held=ids, top_k=2, dtype=dtype,
+        param_dtype=dtype, block=16 if case == 'tokens_fill_the_block'
+        else 256)
+    x = jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (1, n_tokens, 128),
+                                  dtype))
+    params = layer.init(jax.random.PRNGKey(4), x)['params']
+    if picks:
+        router = np.array(params['router'])
+        router[:, picks[0]] = 1.0             # scores 1 on positive inputs
+        router[:, picks[1]] = 0.5
+        params = dict(params, router=jnp.asarray(router))
+    out, stats = layer.apply({'params': params}, x, mutable=['stats'])
+    counts = np.asarray(stats['stats']['expert_tokens'][0])
+    assert counts.sum() == n_tokens * 2
+    if pairs is not None:
+        assert counts[:-1].tolist() == pairs
+    touched = int((counts[:-1] > 0).sum())
+    assert int(stats['stats']['touched'][0]) == touched
+    assert int(stats['stats']['kernel_trips'][0]) == touched
+    f32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
+    with jax.default_matmul_precision('highest'):
+        want = solar_open2_ref.expert_layer(
+            f32, x.astype(jnp.float32), held=ids, top_k=2, scaling=1.0,
+            matmul=solar_open2_ref.plain_matmul)
+        if case == 'none_reaches_a_held_expert':
+            shared = solar_open2_ref.swiglu(
+                x[0], f32['shared_gate']['kernel'], f32['shared_up']['kernel'],
+                f32['shared_down']['kernel'], solar_open2_ref.plain_matmul)
+            np.testing.assert_allclose(np.asarray(want)[0],
+                                       np.asarray(shared), atol=1e-6)
+    np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(want),
+                               atol=atol)
+
+
+@pytest.mark.parametrize('why', ['more_tokens_than_a_block', 'the_cpu',
+                                 'a_two_device_mesh'])
+def test_the_rule_sends_everything_else_to_the_block_loop(monkeypatch, why):
+    """`expert_tile` engages the kernel on one TPU device for bf16 stacks
+    and no more tokens than a block; T > block, the CPU and a mesh of two
+    devices go through the loop (`kernel_trips` 0)."""
+    from jax.sharding import Mesh
+    w_gate = jax.ShapeDtypeStruct((4, 128, 256), jnp.bfloat16)
+    if why != 'the_cpu':
+        monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+        assert moe_lib.expert_tile(32, 32, w_gate) == 256
+        assert moe_lib.expert_tile(32, 32, jax.ShapeDtypeStruct(
+            (4, 128, 256), jnp.float32)) is None
+        assert moe_lib.expert_tile(32, 32, jax.ShapeDtypeStruct(
+            (4, 128, 192), jnp.bfloat16)) is None
+    mesh = (Mesh(np.array(jax.devices()[:2]), ('expert',))
+            if why == 'a_two_device_mesh' else None)
+    n_tokens, block = (40, 16) if why == 'more_tokens_than_a_block' \
+        else (16, 16)
+    assert moe_lib.expert_tile(n_tokens, block, w_gate, mesh) is None
+    layer = moe_lib.DroplessMoE(
+        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5), top_k=2,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, block=block, mesh=mesh)
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, n_tokens, 128),
+                          jnp.bfloat16)
+    params = layer.init(jax.random.PRNGKey(6), x)['params']
+    _, stats = layer.apply({'params': params}, x, mutable=['stats'])
+    assert int(stats['stats']['kernel_trips'][0]) == 0
+    assert int(stats['stats']['touched'][0]) > 0
+
+
+def test_the_stacks_reach_the_kernel_as_they_are_stored(kernel_forced):
+    """A decode-shaped call with the kernel in it: the three expert stacks
+    that enter the `pallas_call` are the layer's parameters themselves (no
+    transpose, convert, slice or gather of a stack stands between), so the
+    program keeps one copy of them in the layout they are stored in."""
+    layer = moe_lib.DroplessMoE(
+        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5), top_k=2,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    x = jnp.zeros((32, 1, 128), jnp.bfloat16)
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
+                                               x)['params'])
+    closed = jax.make_jaxpr(
+        lambda params, x: layer.apply({'params': params}, x))(params, x)
+    names = [jax.tree_util.keystr(path) for path, _ in
+             jax.tree_util.tree_flatten_with_path(params)[0]]
+    stored = dict(zip(closed.jaxpr.invars, names))
+
+    def calls(jaxpr, outer):
+        """(pallas_call equation, its operands as variables of the
+        outermost jaxpr or None) under `jaxpr`."""
+        for eqn in jaxpr.eqns:
+            args = [outer.get(v) if isinstance(v, jax.extend.core.Var) else None
+                    for v in eqn.invars]
+            if eqn.primitive.name == 'pallas_call':
+                yield eqn, args
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub, dict(zip(sub.invars, args)))
+
+    found = list(calls(closed.jaxpr, {v: v for v in closed.jaxpr.invars}))
+    assert len(found) == 1
+    eqn, args = found[0]
+    assert [stored.get(v) for v in args[-3:]] == [
+        "['w_gate']", "['w_up']", "['w_down']"]
+    assert [v.aval.shape for v in eqn.invars[-3:]] == [
+        (4, 128, 256), (4, 128, 256), (4, 256, 128)]
+
+
+def test_the_trips_counter_says_who_multiplied(monkeypatch):
+    """`publish_routing` splits the experts a fetch reached by who
+    multiplied them, and the yardstick's reader gives the kernel's share:
+    nothing for a program without the counter, 0 where every step fell
+    back to the loop."""
+    from benchmarks.harness import reducers
+    from skypilot_tpu.server import metrics as metrics_lib
+
+    def trips():
+        return {path: float(line.rpartition(' ')[2])
+                for line in metrics_lib.render().splitlines()
+                for path in ('kernel', 'loop')
+                if line.startswith(
+                    f'skytpu_moe_expert_trips_total{{path="{path}"}}')}
+
+    before = trips()
+    counts = np.array([3, 0, 5, 24])
+    moe_lib.publish_routing((2, 3, 4), counts, 2, 0)
+    moe_lib.publish_routing((2, 3, 4), counts * 3, 6, 6)
+    after = trips()
+    assert after['kernel'] - before.get('kernel', 0.0) == 6
+    assert after['loop'] - before.get('loop', 0.0) == 2
+
+    def read(text):
+        monkeypatch.setattr(metrics_lib, 'render', lambda: text)
+        return reducers.reduce_metric('moe_kernel_trips_pct', {})
+
+    assert read('skytpu_moe_experts_touched_total 8\n') is None
+    assert read('skytpu_moe_expert_trips_total{path="kernel"} 0\n'
+                'skytpu_moe_expert_trips_total{path="loop"} 716\n') == 0.0
+    assert read('skytpu_moe_expert_trips_total{path="kernel"} 6\n'
+                'skytpu_moe_expert_trips_total{path="loop"} 2\n') == 75.0
 
 
 def test_held_parameters_are_the_files_arithmetic_and_the_programs_tree(
