@@ -376,15 +376,17 @@ class DecodeEngine:
             buckets = (max_len,)
         config = dataclasses.replace(config, prefill_buckets=buckets)
         self._validate_paging(config, max_len)
-        if getattr(model, 'recurrent_state', False) and (
-                config.kv_page_size is not None or config.speculation):
+        # A model whose cache the page manager cannot hold says why
+        # (`unpaged_cache`: the clause that follows its name below).
+        unpaged = getattr(model, 'unpaged_cache', None)
+        if unpaged and (config.kv_page_size is not None or
+                        config.speculation):
             raise ValueError(
-                f'{type(model).__name__} keeps recurrent state beside its '
-                f'keys and values, and the page manager holds keys and '
-                f'values only: kv_page_size, speculation and KV transfer '
-                f'(submit_prefill / submit_adopt, which need pages) are '
-                f'not available with it; leave kv_page_size None and '
-                f'speculation 0')
+                f'{type(model).__name__} {unpaged}, and the page manager '
+                f'holds keys and values only: kv_page_size, speculation '
+                f'and KV transfer (submit_prefill / submit_adopt, which '
+                f'need pages) are not available with it; leave '
+                f'kv_page_size None and speculation 0')
         self.cfg = config
         self._rng = jax.random.PRNGKey(config.seed)
         self._prefill_q: 'queue.Queue[Request]' = queue.Queue()
@@ -506,10 +508,12 @@ class DecodeEngine:
                            not self._paged else None)
         self._build_fns()
         self._init_cache()
-        # Leaves named k / v hold keys and values per position, any
-        # other leaf is per-slot recurrent state.
+        # By kind (perf/cost_model.py): keys and values a position (the
+        # leaves named k / v), a latent a position (the leaves the model
+        # names in `latent_leaves`), per-slot recurrent state (the rest).
+        latent = getattr(self.model, 'latent_leaves', ())
         for kind, n_bytes in cost_model_lib.cache_bytes_by_kind(
-                self._cache).items():
+                self._cache, latent).items():
             metrics_lib.set_gauge('skytpu_engine_cache_bytes',
                                   float(n_bytes), kind=kind)
         if (jax.default_backend() == 'tpu' and self._mesh is None and
@@ -533,7 +537,7 @@ class DecodeEngine:
             self.model.cfg, jax.tree_util.tree_leaves(self.params),
             self._cache,
             n_chips=self._mesh.size if self._mesh is not None else 1,
-            kv_dtype=config.kv_dtype if self._paged else None)
+            latent=latent)
 
     @property
     def healthy(self) -> bool:
@@ -760,9 +764,26 @@ class DecodeEngine:
             positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
             # `lengths`: a layer with recurrent state stops at each
             # row's valid length (padding must not fold into it).
-            logits, cache = model.apply(
-                {'params': params}, tokens, positions=positions,
-                decode=True, lengths=lengths, mutable=['cache'])
+            def rows(tokens, positions, lengths):
+                logits, cache = model.apply(
+                    {'params': params}, tokens, positions=positions,
+                    decode=True, lengths=lengths, mutable=['cache'])
+                return logits, cache['cache']
+
+            # A model may say how many rows of a prefill it can hold at
+            # once (`prefill_rows`, a divisor of every admitted group's
+            # power of two): more go through it that many at a time,
+            # every row on its own as in one pass.
+            at_once = getattr(model, 'prefill_rows', None) or n
+            if n > at_once:
+                logits, cache = jax.tree.map(
+                    lambda t: t.reshape((n,) + t.shape[2:]),
+                    jax.lax.map(lambda xs: rows(*xs), jax.tree.map(
+                        lambda t: t.reshape((n // at_once, at_once) +
+                                            t.shape[1:]),
+                        (tokens, positions, lengths))))
+            else:
+                logits, cache = rows(tokens, positions, lengths)
             last = last_logits(logits, lengths - 1)                  # [N,V]
             firsts = sample(last, rng)                               # [N]
             # Padding rows replicate row 0, so their duplicate scatter
@@ -777,8 +798,7 @@ class DecodeEngine:
                 # [n_slots, H, max_len, D] at each row's slot index.
                 return big.at[slots].set(small)
 
-            big_cache = jax.tree_util.tree_map(_ins, big_cache,
-                                               cache['cache'])
+            big_cache = jax.tree_util.tree_map(_ins, big_cache, cache)
             return (big_cache, last_toks.at[slots].set(firsts),
                     lens.at[slots].set(lengths))
 
@@ -2926,9 +2946,20 @@ class DecodeEngine:
             self._flush_loop_seconds()
 
     def _run_loop(self):  # skytpu: hot-entry
+        idle, seen = False, 0
         while not self._stop.is_set():
+            # An idle engine whose queue grew since its last look, and
+            # does not fill the slots yet, looks again a millisecond
+            # later before it admits: requests that arrive together are
+            # then prefilled as ONE group.  A group's prefill is one
+            # program (seconds on a long bucket); admitting the few that
+            # the first look happened to see puts the rest a whole
+            # program behind them, and the two groups then stall each
+            # other's decode at every turnover.
+            queued = self._prefill_q.qsize() if idle else 0
+            growing, seen = seen < queued < self.cfg.n_slots, queued
             try:
-                n = self.step_pipelined()
+                n = 0 if growing else self.step_pipelined()
             except BaseException as e:  # pylint: disable=broad-except
                 # A dead loop thread must not strand callers: fail every
                 # in-flight and queued request, flip unhealthy (the HTTP
@@ -2978,7 +3009,8 @@ class DecodeEngine:
                             req.out.put(None)
                     self._queued_tokens = 0
                 return
-            if n == 0:
+            idle = n == 0
+            if idle:
                 with tracing.phase('engine.loop.idle') as ph:
                     time.sleep(0.001)
                 self._loop_idle_s += ph.seconds

@@ -391,9 +391,9 @@ class SolarOpen2(nn.Module):
     # The mesh the program is partitioned over, if any: the expert layer's
     # decode kernel is for one device (models/moe.py `expert_tile`).
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine: per-slot state that is not keys and values, so
-    # the paged manager, speculation and KV transfer cannot hold it yet.
-    recurrent_state = True
+    # Read by DecodeEngine: why the paged manager, speculation and KV
+    # transfer cannot hold this model's cache yet.
+    unpaged_cache = 'keeps recurrent state beside its keys and values'
 
     @nn.compact
     def __call__(self, tokens: jax.Array,

@@ -25,6 +25,15 @@ every slot.  The choice hangs on the backend and on shapes, as
 `flash_attention`'s does.  `mha_reference` itself was left alone: prefill,
 a chunk against a cache, the paged gather, verify and training keep the
 programs they had, and the kernel's tests have their ground truth.
+
+`latent_decode_attention` is the same step for latent attention (MLA with
+the up-projection absorbed into the query): every head against one shared
+key a position, the cached latent beside its rotated part, of which the
+latent is also the value.  It is engaged the same way (`latent_kv_block`:
+the backend, the mesh's size, the shapes) and is the kernel of
+`ops/pallas/latent_decode_attention.py`, which fetches a tile of the latent
+once for the scores and the weighted sum; elsewhere it is
+`latent_attention_reference`, plain `jnp` over every position.
 """
 from __future__ import annotations
 
@@ -122,6 +131,56 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
         q, k_cache, v_cache, causal=True,
         segment_positions=(lengths - 1)[:, None],
         kv_positions=jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)))
+
+
+def latent_kv_block(latent_dim: int, seq_len: int,
+                    mesh: Optional[Mesh] = None) -> Optional[int]:
+    """The positions one tile of `latent_decode_attention`'s kernel covers
+    for a cache `[B, seq_len, latent_dim]`, or None where it reads the
+    whole cache through XLA (as `decode_kv_block`)."""
+    if jax.default_backend() != 'tpu':
+        return None
+    if mesh is not None and mesh.size > 1:
+        return None
+    from skypilot_tpu.ops.pallas import latent_decode_attention as pallas_la
+    return pallas_la.block_len(latent_dim, seq_len)
+
+
+def latent_attention_reference(q_lat: jax.Array, q_pe: jax.Array,
+                               c_kv: jax.Array, k_pe: jax.Array,
+                               lengths: jax.Array) -> jax.Array:
+    """XLA latent attention over every position of every slot, masked to
+    `< lengths[b]` (the ground truth of the kernel's tests).  A slot of
+    length zero gives zeros."""
+    scores = (jnp.einsum('bhc,bsc->bhs', q_lat, c_kv,
+                         preferred_element_type=jnp.float32) +
+              jnp.einsum('bhr,bsr->bhs', q_pe, k_pe,
+                         preferred_element_type=jnp.float32))
+    live = jnp.arange(c_kv.shape[1])[None, :] < lengths[:, None]
+    probs = jax.nn.softmax(jnp.where(live[:, None, :], scores, -jnp.inf),
+                           axis=-1)
+    probs = jnp.where(jnp.isnan(probs), 0.0, probs)
+    return jnp.einsum('bhs,bsc->bhc', probs.astype(c_kv.dtype), c_kv,
+                      preferred_element_type=jnp.float32).astype(c_kv.dtype)
+
+
+def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
+                            c_kv: jax.Array, k_pe: jax.Array,
+                            lengths: jax.Array,
+                            mesh: Optional[Mesh] = None) -> jax.Array:
+    """The decode step of latent attention: q_lat [B, H, C] and q_pe
+    [B, H, R], the softmax scale already in them, against the cache
+    leaves c_kv [B, S, C] and k_pe [B, S, R] as they are stored, over the
+    positions `< lengths[b]` (the row written this step included) ->
+    o_lat [B, H, C], the weighted sum of the latent a head."""
+    q_lat, q_pe = q_lat.astype(c_kv.dtype), q_pe.astype(k_pe.dtype)
+    block = latent_kv_block(c_kv.shape[2], c_kv.shape[1], mesh)
+    if block is not None:
+        from skypilot_tpu.ops.pallas import latent_decode_attention as \
+            pallas_la
+        return pallas_la.latent_decode_attention_fwd(
+            q_lat, q_pe, c_kv, k_pe, lengths, block=block)
+    return latent_attention_reference(q_lat, q_pe, c_kv, k_pe, lengths)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))

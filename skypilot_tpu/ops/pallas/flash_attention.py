@@ -103,11 +103,14 @@ def flash_attention_fwd(q: jax.Array,
                         block_size: int = 512,
                         interpret: bool = False,
                         return_residuals: bool = False):
-    """q [B,Hq,S,D], k/v [B,Hkv,S,D] → [B,Hq,S,D].  GQA via head repeat
-    (broadcast, fused by XLA before the kernel).  With
+    """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv] → [B,Hq,S,Dv].  GQA via
+    head repeat (broadcast, fused by XLA before the kernel).  Dv may
+    differ from D (latent attention's prefill: keys of 192, values of
+    128); the backward kernels take Dv = D only.  With
     `return_residuals=True` also returns the row logsumexp [B,Hq,S] f32
     for the backward kernels."""
     b, hq, s, d = q.shape
+    dv = v.shape[-1]
     hkv = k.shape[1]
     if hkv != hq:
         k = jnp.repeat(k, hq // hkv, axis=1)
@@ -119,13 +122,13 @@ def flash_attention_fwd(q: jax.Array,
         raise ValueError(f'seq len {s} must divide block size {block_q}')
     q3 = q.reshape(b * hq, s, d)
     k3 = k.reshape(b * hq, s, d)
-    v3 = v.reshape(b * hq, s, d)
+    v3 = v.reshape(b * hq, s, dv)
     grid = (b * hq, s // block_q, s // block_k)
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
                                with_lse=return_residuals)
-    out_specs = pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0))
-    out_shape = jax.ShapeDtypeStruct((b * hq, s, d), q.dtype)
+    out_specs = pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0))
+    out_shape = jax.ShapeDtypeStruct((b * hq, s, dv), q.dtype)
     if return_residuals:
         out_specs = [
             out_specs,
@@ -141,17 +144,17 @@ def flash_attention_fwd(q: jax.Array,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, qi, kj: (bh, kj, 0)),
         ],
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),   # running max m
             pltpu.VMEM((block_q, 1), jnp.float32),     # denominator l
-            pltpu.VMEM((block_q, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((block_q, dv), jnp.float32),    # output accumulator
         ],
         cost_estimate=pl.CostEstimate(
-            flops=4 * b * hq * s * s * d // (2 if causal else 1),
+            flops=2 * b * hq * s * s * (d + dv) // (2 if causal else 1),
             bytes_accessed=(q3.size + k3.size + v3.size) * q.dtype.itemsize,
             transcendentals=b * hq * s * s,
         ),
@@ -159,8 +162,8 @@ def flash_attention_fwd(q: jax.Array,
     )(q3, k3, v3)
     if return_residuals:
         o, lse = out
-        return o.reshape(b, hq, s, d), lse[:, :, 0].reshape(b, hq, s)
-    return out.reshape(b, hq, s, d)
+        return o.reshape(b, hq, s, dv), lse[:, :, 0].reshape(b, hq, s)
+    return out.reshape(b, hq, s, dv)
 
 
 # ----- backward ---------------------------------------------------------------

@@ -22,15 +22,27 @@ CACHE ELEMENT WIDTH: the page pool's dtype is an input, so an int8 KV
 cache changes bytes/token by its width, not by a recalibration.
 
 A model's layers may differ (models/solar_open2.py: one softmax layer
-in four, the others carrying a recurrent state of fixed size).  The
+in four, the others carrying a recurrent state of fixed size), and what
+is cached a position need not be keys and values a head
+(models/openpangu_moe.py: one latent a position and layer).  The
 geometry is therefore read from the engine's CACHE, not from a model
-config's field names: leaves named `k` / `v` hold keys and values per
-position ([slots or pages, kv heads, positions, head_dim]) and count
-toward `kv_bytes_per_pos`; every other leaf is per-slot state, read and
-written whole each step (`state_bytes_per_slot`).  The weight stream is
-the installed tree's bytes, whatever the layers: for an expert layer it
-counts every held expert, touched or not (an upper bound on that part;
-the benchmark's own count, benchmarks/families/, follows the routing).
+config's field names, and from the leaves' bytes, not from their axes.
+A leaf is of one of three kinds:
+
+- `k` / `v` by name (anywhere on its path: an int8 pool's data and
+  scales lie under them): keys and values per position, [slots or pages,
+  kv heads, positions, head_dim];
+- a leaf the model names in `latent_leaves` (the engine hands the names
+  on as `latent`): a latent per position, [slots, positions, width], no
+  head axis, keys and values the same bytes;
+- any other leaf: per-slot state of fixed size, read and written whole
+  each step (`state_bytes_per_slot`).
+
+The first two grow with the context: their bytes a position, scales and
+all, are `cache_bytes_per_pos`.  The weight stream is the installed
+tree's bytes, whatever the layers: for an expert layer it counts every
+held expert, touched or not (an upper bound on that part; the
+benchmark's own count, benchmarks/families/, follows the routing).
 """
 from __future__ import annotations
 
@@ -97,28 +109,36 @@ def estimate_mfu(tokens_per_s: float, n_params: int, n_layers: int,
     return 100.0 * achieved_tflops / (peaks.bf16_tflops * max(1, n_chips))
 
 
-def _split_cache(cache):
-    """(leaves of keys and values, leaves of per-slot state) of a cache
-    tree, by the leaf's name: `k` / `v` anywhere on its path (an int8
-    pool's data and scales lie under them)."""
-    kv, state = [], []
+def _split_cache(cache, latent: Sequence[str] = ()) -> dict:
+    """The leaves of a cache tree by kind ('kv', 'latent', 'recurrent'):
+    `k` / `v` by name, `latent` the names the model gave its latent
+    leaves (the module's docstring has the rule)."""
+    kinds = {'kv': [], 'latent': [], 'recurrent': []}
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
         names = {getattr(p, 'key', None) for p in path}
-        (kv if names & {'k', 'v'} else state).append(leaf)
-    return kv, state
+        kind = ('kv' if names & {'k', 'v'} else
+                'latent' if names & set(latent) else 'recurrent')
+        kinds[kind].append(leaf)
+    return kinds
 
 
 def _nbytes(leaves) -> int:
     return int(sum(l.size * l.dtype.itemsize for l in leaves))
 
 
-def cache_bytes_by_kind(cache) -> dict:
+def cache_bytes_by_kind(cache, latent: Sequence[str] = ()) -> dict:
     """Bytes of the engine's cache by kind, kinds that hold nothing left
-    out: 'kv' (keys and values per position), 'recurrent' (per-slot
-    state of fixed size)."""
-    kv, state = _split_cache(cache)
-    sized = {'kv': _nbytes(kv), 'recurrent': _nbytes(state)}
+    out: 'kv' (keys and values per position), 'latent' (a latent per
+    position), 'recurrent' (per-slot state of fixed size)."""
+    sized = {kind: _nbytes(leaves)
+             for kind, leaves in _split_cache(cache, latent).items()}
     return {k: v for k, v in sized.items() if v}
+
+
+# Where a per-position leaf keeps its positions: [slots or pages, heads,
+# positions, ...] for keys and values (and an int8 pool's scales),
+# [slots, positions, width] for a latent.
+_POSITIONS_AXIS = {'kv': 2, 'latent': 1}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -132,20 +152,17 @@ class EngineCostModel:
     n_params: int           # model parameters (embeddings included)
     n_layers: int
     dim: int
-    n_kv_heads: int
-    head_dim: int
     param_bytes: int        # total bytes of the installed weight tree
-    kv_dtype_bytes: int     # element width of the KV cache / page pool
+    # Bytes the cache holds for one token position, summed over the
+    # layers that cache per position and over kinds (K and V a head, or
+    # a latent), at the cache's own element width, an int8 pool's f32
+    # scales included.
+    cache_bytes_per_pos: float
     n_chips: int = 1
     chip: str = 'cpu'
-    # Quantization-scale overhead, bytes per token position across all
-    # layers (int8 pools store one f32 absmax scale per (layer, K|V,
-    # kv_head, position) alongside the int8 payload; 0.0 for dense
-    # pools).  Folded into kv_bytes_per_pos.
-    kv_scale_bytes_per_pos: float = 0.0
-    # Layers that hold K and V per position (None: all n_layers), and
-    # the bytes of per-slot state that is not K and V (a recurrent
-    # layer's matrix and taps), summed over layers.
+    # Layers that attend over cached positions (None: all n_layers), and
+    # the bytes of per-slot state that does not grow with the position
+    # (a recurrent layer's matrix and taps), summed over layers.
     n_kv_layers: Optional[int] = None
     state_bytes_per_slot: float = 0.0
 
@@ -153,46 +170,31 @@ class EngineCostModel:
     def from_engine_state(cls, cfg, param_leaves: Sequence,
                           cache, n_chips: int = 1,
                           chip: Optional[str] = None,
-                          kv_dtype: Optional[str] = None
-                          ) -> 'EngineCostModel':
+                          latent: Sequence[str] = ()) -> 'EngineCostModel':
         """Build from live engine state: the model's config (its
         parameter count, depth and width), the weight tree's leaves and
-        the cache TREE, whose K / V leaves [slots or pages, kv heads,
-        positions, head_dim] give the per-position geometry and whose
-        other leaves the per-slot state.  Reads only leaf METADATA
-        (shape/dtype) — never leaf values, so no device sync.
-
-        ``kv_dtype``: the engine's DECLARED page-pool element type
-        ('bf16'/'int8').  The declaration is authoritative over leaf
-        inspection — an int8 pool's flat leaves interleave int8 data
-        with f32 scales, and inferring the width from whichever leaf
-        happens to come first would silently misreport bytes/token.
-        None (unpaged engines / direct callers) falls back to the
-        first cache leaf's element width, as before."""
-        kv_leaves, state_leaves = _split_cache(cache)
-        # K and V payloads are the 4-d leaves, two a layer (an int8
-        # pool's scales are 3-d).
-        payload = [l for l in kv_leaves if len(l.shape) == 4]
-        n_kv_layers = len(payload) // 2
-        n_kv_heads = payload[0].shape[1] if payload else 0
-        head_dim = payload[0].shape[3] if payload else 0
-        scale_bytes = 0.0
-        if kv_dtype is not None:
-            kv_bytes = {'bf16': 2, 'int8': 1}[kv_dtype]
-            if kv_dtype == 'int8':
-                # One f32 scale per (layer, K|V, kv_head, position).
-                scale_bytes = 2.0 * n_kv_layers * n_kv_heads * 4
-        else:
-            kv_bytes = payload[0].dtype.itemsize if payload else 2
-        slots = state_leaves[0].shape[0] if state_leaves else 1
+        the cache TREE.  A per-position leaf of any kind gives its bytes
+        a position (its bytes over its slots or pages times its
+        positions: an int8 pool's data and f32 scales are both leaves,
+        so the pool's element width is counted, not declared); the
+        other leaves give the per-slot state.  Reads only leaf METADATA
+        (shape/dtype) — never leaf values, so no device sync."""
+        kinds = _split_cache(cache, latent)
+        per_pos = sum(
+            _nbytes([leaf]) / (leaf.shape[0] * leaf.shape[axis])
+            for kind, axis in _POSITIONS_AXIS.items()
+            for leaf in kinds[kind])
+        # Two leaves a layer of either kind: K and V, or the latent and
+        # its rotated part (a quantized pool's scales are 3-d).
+        n_kv_layers = (sum(len(l.shape) == 4 for l in kinds['kv']) +
+                       len(kinds['latent'])) // 2
+        state = kinds['recurrent']
+        slots = state[0].shape[0] if state else 1
         return cls(n_params=cfg.num_params(), n_layers=cfg.n_layers,
-                   dim=cfg.dim, n_kv_heads=n_kv_heads, head_dim=head_dim,
-                   param_bytes=_nbytes(param_leaves),
-                   kv_dtype_bytes=int(kv_bytes), n_chips=n_chips,
-                   chip=chip or chip_kind(),
-                   kv_scale_bytes_per_pos=scale_bytes,
-                   n_kv_layers=n_kv_layers,
-                   state_bytes_per_slot=_nbytes(state_leaves) / slots)
+                   dim=cfg.dim, param_bytes=_nbytes(param_leaves),
+                   cache_bytes_per_pos=float(per_pos), n_chips=n_chips,
+                   chip=chip or chip_kind(), n_kv_layers=n_kv_layers,
+                   state_bytes_per_slot=_nbytes(state) / slots)
 
     # ----- FLOPs -----------------------------------------------------
     def decode_flops_per_token(self, context_len: float) -> float:
@@ -211,14 +213,11 @@ class EngineCostModel:
                 else self.n_kv_layers)
 
     def kv_bytes_per_pos(self) -> float:
-        """Bytes of K+V held per token position, summed over the layers
-        that hold K and V (all of Llama's; a model whose layers differ
-        counts its softmax layers only — its other layers' state does
-        not grow with the position and is `state_bytes_per_slot`).
-        Payload at the pool's element width + any quantization-scale
-        overhead."""
-        return (2.0 * self._kv_layers() * self.n_kv_heads * self.head_dim *
-                self.kv_dtype_bytes + self.kv_scale_bytes_per_pos)
+        """Bytes the cache holds per token position (`cache_bytes_per_pos`:
+        K and V of the layers that hold them, or a latent; a model whose
+        other layers carry state that does not grow with the position
+        counts that as `state_bytes_per_slot`)."""
+        return self.cache_bytes_per_pos
 
     def decode_hbm_bytes_per_token(self, context_len: float,
                                    n_active: int) -> float:

@@ -123,10 +123,11 @@ _HELP = {
         'verify columns, near 1 each dispatch commits k+1 tokens',
     'skytpu_engine_cache_bytes':
         'Bytes of the engine\'s cache by kind, set once at build: '
-        'kind="kv" keys and values per position, kind="recurrent" '
-        'per-slot state of fixed size (a linear-attention layer\'s '
-        'matrix and convolution taps) — a kind that holds nothing is '
-        'absent',
+        'kind="kv" keys and values per position, kind="latent" a '
+        'latent per position in their place (latent attention: one '
+        'vector a layer, no head axis), kind="recurrent" per-slot '
+        'state of fixed size (a linear-attention layer\'s matrix and '
+        'convolution taps) — a kind that holds nothing is absent',
     'skytpu_moe_pairs_total':
         'Token-expert pairs routed by decode steps, summed over expert '
         'layers: where="held" to an expert this engine holds, '

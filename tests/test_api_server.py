@@ -32,7 +32,9 @@ def api_server(tmp_home, enable_all_clouds, monkeypatch):
 
     t = threading.Thread(target=run, daemon=True)
     t.start()
-    deadline = time.time() + 10
+    # Under six workers beside JAX tests the server has taken more than
+    # 10 s to come up (ROADMAP C10): the wait ends when the port is there.
+    deadline = time.time() + 60
     while 'port' not in server_holder and time.time() < deadline:
         time.sleep(0.05)
     url = f'http://127.0.0.1:{server_holder["port"]}'

@@ -53,3 +53,23 @@ def test_metric_has_its_reader(metric):
             assert 'def reduce(ctx' in f.read()
     else:
         assert callable(getattr(reducers, spec['reducer'], None)), spec
+
+
+MOE_METRICS = [m['name'] for m in MAN['per_layer']
+               if m['name'].startswith('moe_')]
+
+
+@pytest.mark.parametrize('metric', MOE_METRICS)
+def test_every_cell_of_an_expert_family_reports_the_expert_metric(metric):
+    """A family that holds part of an expert layer says so through
+    `touched_experts` (what the `moe_*` readers ask of it): each of its
+    cells is in each `moe_*` metric's list, so a new expert cell cannot
+    leave the expert layer unread."""
+    expert_cells = [
+        w['name'] for w in MAN['workloads'] if hasattr(
+            families.load(manifest.config_of(MAN, w['config'])),
+            'touched_experts')]
+    assert len(expert_cells) >= 2 and len(MOE_METRICS) >= 4
+    listed = next(m for m in MAN['per_layer'] if m['name'] == metric)
+    assert set(expert_cells) <= set(listed['workloads']), (metric,
+                                                           expert_cells)

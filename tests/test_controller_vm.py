@@ -54,7 +54,7 @@ def _local_task(run, name='vmjob'):
     return t
 
 
-def _wait(job_id, statuses, timeout=120):
+def _wait(job_id, statuses, timeout=300):    # ends when reached (C10)
     deadline = time.time() + timeout
     while time.time() < deadline:
         recs = {r['job_id']: r for r in jobs_core.queue(all_users=True)}

@@ -2,6 +2,7 @@
 full-forward greedy generation exactly (same argmax tokens), including
 when requests are admitted mid-flight into a running decode batch."""
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -138,6 +139,42 @@ def test_engine_threaded_loop(model_and_params):
         assert all(o == want for o in outs)
     finally:
         engine.stop()
+
+
+@pytest.mark.parametrize('arriving', [3, 4])
+def test_idle_loop_gathers_requests_that_arrive_together(
+        model_and_params, monkeypatch, arriving):
+    """Requests that reach an idle engine one look apart are prefilled as
+    one group (fewer than the slots: once the queue stops growing; as many
+    as the slots: at once), not as the one the first look saw and the
+    rest a whole prefill behind it.  The loop's own idle sleep submits
+    them, so every look finds the queue one longer."""
+    from skypilot_tpu.inference import engine as engine_lib
+
+    model, params = model_and_params
+    engine = DecodeEngine(model, params,
+                          EngineConfig(n_slots=4, prefill_buckets=(8,)))
+    groups, reqs = [], []
+    admit_group = engine._admit_group
+    monkeypatch.setattr(engine, '_admit_group', lambda bucket, group: (
+        groups.append(len(group)), admit_group(bucket, group))[1])
+    sleep = time.sleep
+
+    def sleep_and_submit(seconds):
+        if len(reqs) < arriving and threading.current_thread() is engine._thread:
+            reqs.append(engine.submit([2, 4, 6], 3))
+        sleep(seconds)
+
+    monkeypatch.setattr(engine_lib.time, 'sleep', sleep_and_submit)
+    engine.start()
+    try:
+        deadline = time.time() + 60
+        while len(reqs) < arriving and time.time() < deadline:
+            sleep(0.01)
+        assert all(len(r.tokens()) == 3 for r in reqs)
+    finally:
+        engine.stop()
+    assert groups == [arriving]
 
 
 def test_engine_rejects_oversized_prompt(model_and_params):

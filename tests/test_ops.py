@@ -411,3 +411,95 @@ def test_decode_program_keeps_the_expert_stacks_as_the_kernel_reads_them(
         assert formats[0][name].layout.major_to_minor == (0, 1, 2)
     assert compiled.memory_analysis().temp_size_in_bytes < \
         params['w_gate'].size * 2
+
+
+def test_pallas_latent_decode_attention_compiles_for_v5e(v5e_chip):
+    """The latent decode kernel at openPangu-Ultra-MoE's cell: 32 slots of
+    4,736 positions (four tiles of 1,024 and a ragged fifth), 128 heads
+    against a latent of 512 + 64."""
+    from skypilot_tpu.ops.pallas import latent_decode_attention as pallas_la
+
+    def sds(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    compiled = pallas_la.latent_decode_attention_fwd.lower(
+        sds(32, 128, 512), sds(32, 128, 64), sds(32, 4736, 512),
+        sds(32, 4736, 64), sds(32, dtype=jnp.int32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+def test_pallas_flash_takes_values_narrower_than_keys_on_v5e(v5e_chip):
+    """Latent attention's prefill: keys of 128 + 64, values of 128."""
+    def sds(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16, sharding=v5e_chip)
+
+    compiled = flash_attention_fwd.lower(
+        sds(1, 128, 4096, 192), sds(1, 128, 4096, 192),
+        sds(1, 128, 4096, 128), causal=True).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+    assert 'bf16[128,4096,128]' in compiled.as_text()
+
+
+def test_decode_program_keeps_the_latent_cache_as_the_kernel_reads_it(
+        v5e_chip, monkeypatch):
+    """The engine's decode program of openPangu-Ultra-MoE at the cell's
+    widths (a dense and an expert layer, 8 slots of 4,736 positions) with
+    both decode kernels in it and every layout left to the compiler as
+    `_optimize_layouts` leaves them: the latent comes out row-major
+    [B, S, width] as the kernel reads it, nothing makes a copy of a cache
+    leaf, and no temporary is as large as one."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
+    from skypilot_tpu.models import moe as moe_lib
+    from skypilot_tpu.models.openpangu_moe import (OpenPanguMoE,
+                                                   OpenPanguMoEConfig)
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+    from skypilot_tpu.ops.pallas import latent_decode_attention as pallas_la
+
+    # `jax.default_backend()` is the CPU here: steer the choices themselves.
+    monkeypatch.setattr(
+        attn_lib, 'latent_kv_block',
+        lambda c_dim, s, mesh=None: pallas_la.block_len(c_dim, s))
+    monkeypatch.setattr(
+        moe_lib, 'expert_tile',
+        lambda n_tokens, block, w_gate, mesh=None: pallas_ge.tile_f(
+            w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
+    cfg = OpenPanguMoEConfig(
+        vocab_size=19200, n_layers=2, n_dense_layers=1,
+        held_experts=tuple(range(16)), max_seq_len=4736,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model = OpenPanguMoE(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))['params'])
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=8, steps_per_call=8, prefill_buckets=(128,)))
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    lens = shapes(engine._lens_d)
+    compiled = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(engine._cache), auto, auto, auto,
+                      auto),
+        out_shardings=(auto, autos(engine._cache), auto, auto)).lower(
+            shapes(params), shapes(engine._cache), shapes(engine._last_d),
+            lens, lens, shapes(engine._rng)).compile()
+    text = compiled.as_text()
+    # A latent kernel a layer, the expert kernel in the expert layer.
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert not re.search(r'= bf16\[8,4736,(512|64)\]\S* (copy|transpose)\(',
+                         text)
+    formats, _ = compiled.input_formats
+    for fmt in jax.tree.leaves(formats[1]):
+        assert fmt.layout.major_to_minor == (0, 1, 2)
+    small = min(leaf.nbytes for leaf in jax.tree.leaves(engine._cache))
+    assert compiled.memory_analysis().temp_size_in_bytes < small

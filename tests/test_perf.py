@@ -93,11 +93,11 @@ def tiny_engine_model():
 
 # ----- cost model (pure arithmetic) -------------------------------------------
 def test_cost_model_hand_arithmetic():
-    cm = cost_model_lib.EngineCostModel(
-        n_params=100, n_layers=2, dim=8, n_kv_heads=2, head_dim=4,
-        param_bytes=400, kv_dtype_bytes=2, n_chips=1, chip='v5e')
-    assert cm.decode_flops_per_token(10) == 2 * 100 + 2 * 2 * 10 * 8
     # K+V, per layer, per kv head, per head_dim element, 2 bytes each.
+    cm = cost_model_lib.EngineCostModel(
+        n_params=100, n_layers=2, dim=8, param_bytes=400,
+        cache_bytes_per_pos=2 * 2 * 2 * 4 * 2, n_chips=1, chip='v5e')
+    assert cm.decode_flops_per_token(10) == 2 * 100 + 2 * 2 * 10 * 8
     assert cm.kv_bytes_per_pos() == 2 * 2 * 2 * 4 * 2
     # weights amortized over the batch + kv history read + 1-pos write.
     assert cm.decode_hbm_bytes_per_token(10, n_active=4) == \
@@ -112,14 +112,67 @@ def test_cost_model_hand_arithmetic():
     assert cm.prefill_seconds(16) > 0
 
 
+class _Cfg:
+    n_layers, dim = 2, 8
+
+    @staticmethod
+    def num_params():
+        return 100
+
+
+def _leaf(shape, dtype):
+    import jax
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
 def test_cost_model_kv_dtype_width_halves_kv_bytes():
-    """The int8-KV future: cache element width is an INPUT, so a
-    narrower page pool lands as a measured bytes/token drop."""
-    wide = cost_model_lib.EngineCostModel(
-        n_params=100, n_layers=2, dim=8, n_kv_heads=2, head_dim=4,
-        param_bytes=400, kv_dtype_bytes=2)
-    narrow = dataclasses.replace(wide, kv_dtype_bytes=1)
-    assert narrow.kv_bytes_per_pos() == wide.kv_bytes_per_pos() / 2
+    """The cache's element width is read off its leaves, so a narrower
+    page pool lands as a measured bytes/token drop: int8 pages carry
+    half the payload of bf16 ones, plus their f32 scales."""
+    import jax.numpy as jnp
+    shape = (5, 2, 16, 4)                 # [pages, kv heads, positions, D]
+
+    def layers(leaf):
+        return {f'layer_{i}': {'attn': {'k': leaf, 'v': leaf}}
+                for i in range(2)}
+
+    def build(cache):
+        return cost_model_lib.EngineCostModel.from_engine_state(
+            _Cfg, [_leaf((100,), jnp.float32)], cache, chip='v5e')
+
+    wide = build(layers(_leaf(shape, jnp.bfloat16)))
+    narrow = build(layers({'data': _leaf(shape, jnp.int8),
+                           'scale': _leaf(shape[:3], jnp.float32)}))
+    assert wide.kv_bytes_per_pos() == 2 * 2 * 2 * 4 * 2
+    assert narrow.kv_bytes_per_pos() == \
+        wide.kv_bytes_per_pos() / 2 + 2 * 2 * 2 * 4
+    assert wide.n_kv_layers == narrow.n_kv_layers == 2
+
+
+def test_cost_model_counts_a_latent_per_position_without_heads():
+    """A latent a position ([slots, positions, width], no head axis) is
+    a third kind of leaf: per position like K and V, not per-slot state
+    read and written whole each step.  Which leaves hold one is the
+    model's to say (`latent_leaves`), whatever it calls them: unnamed,
+    the same leaves count as per-slot state."""
+    import jax.numpy as jnp
+    cache = {f'layer_{i}': {'attn': {
+        'lat': _leaf((3, 64, 32), jnp.bfloat16),
+        'rot': _leaf((3, 64, 8), jnp.bfloat16)}} for i in range(2)}
+
+    def build(**kw):
+        return cost_model_lib.EngineCostModel.from_engine_state(
+            _Cfg, [_leaf((100,), jnp.float32)], cache, chip='v5e', **kw)
+
+    cm = build(latent=('lat', 'rot'))
+    assert cm.kv_bytes_per_pos() == 2 * (32 + 8) * 2
+    assert cm.n_kv_layers == 2 and cm.state_bytes_per_slot == 0
+    n_bytes = 2 * 3 * 64 * (32 + 8) * 2
+    assert cost_model_lib.cache_bytes_by_kind(cache, ('lat', 'rot')) == {
+        'latent': n_bytes}
+    assert build().state_bytes_per_slot == n_bytes / 3
+    assert cost_model_lib.cache_bytes_by_kind(cache) == {
+        'recurrent': n_bytes}
 
 
 @pytest.mark.parametrize('path', BENCH_CONFIGS, ids=lambda p: p.stem)
