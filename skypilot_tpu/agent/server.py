@@ -60,7 +60,15 @@ class AgentScheduler:
 
     def _loop(self) -> None:
         while not self._stop.is_set():
-            job = job_queue.next_pending()
+            try:
+                job = job_queue.next_pending()
+            except Exception:  # pylint: disable=broad-except
+                # The store can refuse a read (this thread and the event
+                # loop's open a fresh jobs.db at the same moment).  The
+                # scheduler has to outlive that: with it gone every job
+                # submitted later stays PENDING and its launch waits for
+                # ever.
+                job = None
             if job is None:
                 self._stop.wait(1.0)
                 continue

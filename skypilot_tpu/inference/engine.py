@@ -474,13 +474,16 @@ class DecodeEngine:
         self._loop_device_s = 0.0
         self._loop_idle_s = 0.0
         # K/V positions of the contiguous decode calls since the last
-        # flush (_count_kv_positions), and the positions a tile of the
+        # flush (_count_kv_positions), the positions a tile of the
         # model's decode attention covers (None: it reads every slot
-        # whole).
+        # whole), and whether its one-row step takes `live` and reads
+        # nothing of a slot that holds no request.
         self._kv_fetched = 0
         self._kv_held = 0
+        self._kv_empty = 0
         kv_block = getattr(model, 'decode_kv_block', None)
         self._kv_block: Optional[int] = kv_block() if kv_block else None
+        self._takes_live: bool = getattr(model, 'decode_takes_live', False)
         self._setup_programs = 0    # engine.setup.compile spans so far
         # Minimum attribution window; benchmarks/tests shrink or grow
         # it to bracket exactly their measured region.
@@ -810,6 +813,7 @@ class DecodeEngine:
         # the program it had.
         stats_abs = self._stats_abs
         mutable = ['cache', 'stats'] if stats_abs else ['cache']
+        takes_live = self._takes_live
 
         def stats0():
             if not stats_abs:
@@ -823,11 +827,17 @@ class DecodeEngine:
             freshly admitted slots' first tokens ride the same fetch);
             with a `stats` collection, (out, its sums over the steps).
             `held` [n_slots] says which slots hold a request by the
-            host's view at dispatch; the others' lengths start from
-            zero, so that attention bounded by the lengths
+            host's view at dispatch, for the whole call (a request
+            admitted meanwhile is inserted by its prefill before the
+            next).  A model that takes it (`decode_takes_live`) reads
+            nothing of the others' cache; for any model their lengths
+            start from zero, so that attention bounded by the lengths
             (ops/attention.py decode_attention) reads nothing stale of
             a slot whose request has gone."""
-            lengths = jnp.where(held.astype(bool), lengths, 0)
+            held = held.astype(bool)
+            lengths = jnp.where(held, lengths, 0)
+            live = {'live': held} if takes_live else {}
+
             def body(carry, rng_t):
                 cache, last, lens, stats = carry
                 # Clamp writes for slots running past the cap: confined
@@ -836,7 +846,7 @@ class DecodeEngine:
                 logits, new_cache = model.apply(
                     {'params': params, 'cache': cache},
                     last[:, None], positions=positions,
-                    decode=True, mutable=mutable)
+                    decode=True, mutable=mutable, **live)
                 nxt = sample(logits[:, 0, :], rng_t)         # [B]
                 if stats is not None:
                     stats = jax.tree.map(jnp.add, stats, new_cache['stats'])
@@ -2014,7 +2024,8 @@ class DecodeEngine:
         # Which slots hold a request, as the host sees them now: one
         # admitted by a prefill already dispatched is in _slots (its
         # insert runs before this call on the device), one retired is
-        # not, and the program counts an empty slot's length from zero.
+        # not; the program counts an empty slot's length from zero and
+        # tells a model that takes it which slots those are.
         n, steps = self.cfg.n_slots, self.cfg.steps_per_call
         held = np.zeros((n,), np.int32)
         lens = np.zeros((n,), np.int64)
@@ -2023,18 +2034,23 @@ class DecodeEngine:
                 held[i] = 1
                 lens[i] = slot.device_length
                 slot.device_length += steps
-        self._count_kv_positions(lens)
+        self._count_kv_positions(lens, held.astype(bool))
         return self._decode(self.params, self._cache, self._last_d,
                             self._lens_d, jnp.asarray(held),
                             self._next_rng())
 
-    def _count_kv_positions(self, lens: np.ndarray) -> None:
+    def _count_kv_positions(self, lens: np.ndarray,
+                            held: np.ndarray) -> None:
         """One contiguous decode call's K/V positions, summed for the
         next flush: `held` is what the cache holds, every slot whole,
         and `fetched` what the steps' attention asks for: whole tiles up
         to the row each step writes where the model's kernel is bounded
         by the lengths (`lens`: the device's at the call's start, empty
-        slots zero), everything held where it is not."""
+        slots zero), everything held where it is not.  A slot that holds
+        no request (`held` [n_slots] bool is false) counts from zero, a
+        tile a step, unless the model's step is told so and reads
+        nothing of it: those tiles are then `empty`, what the slot would
+        have fetched, and not `fetched`."""
         steps, max_len = self.cfg.steps_per_call, self.model.cfg.max_seq_len
         whole = lens.size * max_len * steps
         self._kv_held += whole
@@ -2042,8 +2058,10 @@ class DecodeEngine:
             self._kv_fetched += whole
             return
         read = np.minimum(lens[:, None] + np.arange(steps), max_len - 1) + 1
-        self._kv_fetched += (int((-(-read // self._kv_block)).sum()) *
-                             self._kv_block)
+        tiles = (-(-read // self._kv_block)).sum(axis=1) * self._kv_block
+        skipped = int(tiles[~held].sum()) if self._takes_live else 0
+        self._kv_empty += skipped
+        self._kv_fetched += int(tiles.sum()) - skipped
 
     def _propose_drafts(self) -> np.ndarray:
         """Host-side n-gram drafts [n_slots, k] for the next verify
@@ -2647,7 +2665,10 @@ class DecodeEngine:
             metrics_lib.inc_counter(
                 'skytpu_engine_decode_kv_positions_total',
                 float(self._kv_held), kind='held')
-            self._kv_fetched = self._kv_held = 0
+            metrics_lib.inc_counter(
+                'skytpu_engine_decode_kv_positions_total',
+                float(self._kv_empty), kind='empty')
+            self._kv_fetched = self._kv_held = self._kv_empty = 0
 
     def _sample_gauges(self, n_active: int) -> None:
         """Loop-thread occupancy/queue gauges; skipped when unchanged so

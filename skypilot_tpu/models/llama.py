@@ -200,7 +200,8 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
                  decode: bool = False,
-                 page_table: Optional[jax.Array] = None) -> jax.Array:
+                 page_table: Optional[jax.Array] = None,
+                 live: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
         dense = lambda name, heads, logical: nn.DenseGeneral(  # noqa: E731
             features=(heads, cfg.head_dim), axis=-1, use_bias=False,
@@ -220,7 +221,7 @@ class Attention(nn.Module):
             k, v, attn_out = self._paged_attend(q, k, v, positions,
                                                 page_table)
         elif decode:
-            k, v, attn_out = self._decode_attend(q, k, v, positions)
+            k, v, attn_out = self._decode_attend(q, k, v, positions, live)
         else:
             attn_out = self._attend(q, k, v)
         out = attn_out.transpose(0, 2, 1, 3)  # [B, S, H, D]
@@ -241,7 +242,7 @@ class Attention(nn.Module):
             return attn_lib.flash_attention_on_mesh(q, k, v, self.mesh)
         return attn_lib.mha_reference(q, k, v, causal=True)
 
-    def _decode_attend(self, q, k, v, positions):
+    def _decode_attend(self, q, k, v, positions, live=None):
         """Decode with a KV cache (serving path), driven entirely by the
         caller-supplied per-slot `positions` [B, S] — there is no shared
         index, so a continuous-batching engine can run heterogeneous slot
@@ -250,7 +251,10 @@ class Attention(nn.Module):
         is a CHUNK of a long prompt's prefill: the chunk's K/V land at
         their absolute positions and q attends over the full cache
         (earlier chunks + itself), so prompts longer than any single
-        dispatch accumulate chunk by chunk.
+        dispatch accumulate chunk by chunk.  `live` [B] bool, where the
+        engine gives it, says which rows of the decode step hold a
+        request: the others read nothing and get zeros (their row is
+        still written, at a position nothing reads).
 
         Invariant that makes bucket-padded prefill safe: every step
         attends only k_pos <= q_pos, writes at q_pos, and inserts
@@ -329,9 +333,11 @@ class Attention(nn.Module):
                 cv.value = write(cv.value, v)
             # The one-row step reads a slot up to the row just written
             # (a Pallas kernel bounded by the lengths on one TPU device,
-            # this same mask through XLA elsewhere).
+            # this same mask through XLA elsewhere), and nothing of a
+            # row that holds no request: a length of zero.
+            lens = pos + 1 if live is None else jnp.where(live, pos + 1, 0)
             return ck.value, cv.value, attn_lib.decode_attention(
-                q, ck.value, cv.value, pos + 1, self.mesh)
+                q, ck.value, cv.value, lens, self.mesh)
         k_all, v_all = ck.value, cv.value
         k_pos = jnp.arange(max_len)[None, :]
         out = attn_lib.mha_reference(
@@ -445,13 +451,15 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x: jax.Array, positions: jax.Array,
                  decode: bool = False,
-                 page_table: Optional[jax.Array] = None) -> jax.Array:
+                 page_table: Optional[jax.Array] = None,
+                 live: Optional[jax.Array] = None) -> jax.Array:
         cfg = self.cfg
         cp = cfg.attention_impl == 'ring'
         x = _constrain_activations(x, self.mesh, cp)
         x = x + Attention(cfg, self.mesh, name='attn')(
             RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
-                    name='attn_norm')(x), positions, decode, page_table)
+                    name='attn_norm')(x), positions, decode, page_table,
+            live)
         if cfg.n_experts > 0:
             from skypilot_tpu.models.moe import MoEMLP
             mlp = MoEMLP(dim=cfg.dim, ffn_dim=cfg.ffn_dim,
@@ -471,12 +479,18 @@ class Llama(nn.Module):
     cfg: LlamaConfig
     mesh: Optional[Mesh] = None
 
+    # Read by DecodeEngine: the one-row step takes `live` [B], which
+    # rows hold a request, and its attention reads nothing of the others
+    # (ops/attention.py decode_attention: a length of zero).
+    decode_takes_live = True
+
     @nn.compact
     def __call__(self, tokens: jax.Array,
                  positions: Optional[jax.Array] = None,
                  decode: bool = False,
                  page_table: Optional[jax.Array] = None,
-                 lengths: Optional[jax.Array] = None) -> jax.Array:
+                 lengths: Optional[jax.Array] = None,
+                 live: Optional[jax.Array] = None) -> jax.Array:
         # `lengths` (each row's valid positions in a padded prefill) is
         # the engine's to pass and a recurrent layer's to need: here
         # padding lives at masked positions (_decode_attend) and it is
@@ -504,15 +518,17 @@ class Llama(nn.Module):
             block = nn.remat(
                 Block, static_argnums=(3,),  # (self, x, positions, decode)
                 policy=policy)
+        # Keep the historical 3-arg call where there is nothing more to
+        # pass (the remat wrapper's static_argnums indexing depends on
+        # it).
+        more = ()
+        if live is not None:
+            more = (page_table, live)
+        elif page_table is not None:
+            more = (page_table,)
         for i in range(cfg.n_layers):
-            if page_table is None:
-                # Keep the historical 3-arg call (the remat wrapper's
-                # static_argnums indexing depends on it).
-                x = block(cfg, self.mesh, name=f'layer_{i}')(
-                    x, positions, decode)
-            else:
-                x = block(cfg, self.mesh, name=f'layer_{i}')(
-                    x, positions, decode, page_table)
+            x = block(cfg, self.mesh, name=f'layer_{i}')(
+                x, positions, decode, *more)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
                     name='final_norm')(x)
         if cfg.tie_embeddings:

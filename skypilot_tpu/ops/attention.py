@@ -18,10 +18,11 @@ against a slot's cache leaves, positions below the slot's length.  On one
 TPU device, where `decode_kv_block` finds a tiling for the cache's shape
 (head size a multiple of 128, `max_seq_len` a multiple of 128), it is the
 Pallas kernel of `ops/pallas/decode_attention.py`, which fetches a slot's
-K and V up to its length and nothing of an empty slot beyond one tile.
+K and V up to its length and nothing of an empty slot (a length of zero).
 Elsewhere (the CPU, a mesh of several devices, other shapes) it is
 `mha_reference` under the positions mask, which reads every position of
-every slot.  The choice hangs on the backend and on shapes, as
+every slot.  Both give an empty slot zeros.  The choice hangs on the
+backend and on shapes, as
 `flash_attention`'s does.  `mha_reference` itself was left alone: prefill,
 a chunk against a cache, the paged gather, verify and training keep the
 programs they had, and the kernel's tests have their ground truth.
@@ -120,7 +121,8 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      mesh: Optional[Mesh] = None) -> jax.Array:
     """The decode step's attention: q [B, Hq, 1, D] against the cache
     leaves [B, Hkv, S, D] as they are stored, over the positions
-    `< lengths[b]` (the row written this step included)."""
+    `< lengths[b]` (the row written this step included).  A length of
+    zero is a slot that holds no request: zeros."""
     b, h_kv, s, d = k_cache.shape
     block = decode_kv_block(h_kv, d, s, k_cache.dtype, mesh)
     if block is not None:

@@ -9,9 +9,13 @@ the grid is (slot, KV block): a tile is all KV heads of one block of
 positions, `[Hkv, block, D]` of K and of V.  The tile's block index is
 clamped to the slot's last live block, so the steps past a slot's length
 ask for the tile already there (the pipeline fetches a tile only when its
-index changes) and compute nothing (`pl.when`); a slot of length zero
-computes nothing and returns zeros.  Online softmax in float32 scratch,
-as in `flash_attention.py`; bf16 in, float32 accumulation, bf16 out.
+index changes) and compute nothing (`pl.when`).  A length of zero is a
+slot that holds no request: its steps ask for the tile that is resident
+when the grid reaches it (`resident_tiles`: the last live tile of the
+nearest live slot before it), so it fetches nothing, computes nothing and
+returns zeros; a batch of empty slots fetches one tile in all.  Online
+softmax in float32 scratch, as in `flash_attention.py`; bf16 in, float32
+accumulation, bf16 out.
 
 GQA: the `group` query heads of a KV head are `group` query rows of it
 (`mha_reference`'s fold); K and V are contracted as they are stored.  The
@@ -57,8 +61,35 @@ def block_len(n_kv_heads: int, head_dim: int, seq_len: int,
     return None
 
 
-def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
-            scale: float, block: int):
+def resident_tiles(lengths: jax.Array, block: int):
+    """(slot, tile) [B] each: where a slot's grid steps look once they have
+    nothing of their own left to read.  A live slot's are its own last
+    live tile.  An empty slot's (length zero) are those of the nearest
+    live slot before it, which is what the pipeline holds when the grid
+    gets there; empty slots in front of the first live one look at that
+    slot's first tile, which its own first step then finds fetched.  With
+    every slot empty that is slot 0's first tile, the one fetch a call
+    cannot do without.  (A chip that splits the slot axis over two cores
+    starts the second half with nothing resident: one more fetch there.)"""
+    index = jnp.arange(lengths.shape[0], dtype=jnp.int32)
+    live = lengths > 0
+    before = jax.lax.cummax(jnp.where(live, index, -1))
+    slot = jnp.where(before >= 0, before, jnp.argmax(live)).astype(jnp.int32)
+    last = jnp.maximum(pl.cdiv(lengths, block) - 1, 0)
+    return slot, jnp.where(before >= 0, last[slot], 0).astype(jnp.int32)
+
+
+def _kv_index(i, j, lens, slot, tile):
+    """The K (and V) block of grid step (i, j): the slot's own tile j up
+    to its last live one, and only that one (`resident_tiles`) of an
+    empty slot."""
+    own = jnp.where(lens[i] > 0, j, tile[i])
+    return (slot[i], 0, jnp.minimum(own, tile[i]), 0)
+
+
+def _kernel(lens_ref, slot_ref, tile_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
+            l_scr, acc_scr, *, scale: float, block: int):
+    del slot_ref, tile_ref               # the index maps' operands
     b = pl.program_id(0)
     j = pl.program_id(1)
     length = lens_ref[b]
@@ -91,7 +122,7 @@ def _kernel(lens_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _finalize():
-        # A slot of length zero keeps l = 0: zeros, not NaN.
+        # An empty slot keeps l = 0: zeros, not NaN.
         l = l_scr[:, :, :1]
         o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
             o_ref.dtype)
@@ -103,7 +134,8 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
                          block: Optional[int] = None,
                          interpret: bool = False) -> jax.Array:
     """q [B, Hq, 1, D] against k/v [B, Hkv, S, D], positions
-    `< lengths[b]` -> [B, Hq, 1, D].  `block` defaults to `block_len`'s."""
+    `< lengths[b]` -> [B, Hq, 1, D]; zeros where `lengths[b]` is zero.
+    `block` defaults to `block_len`'s."""
     b, hq, s_q, d = q.shape
     _, hkv, s, _ = k_cache.shape
     assert s_q == 1, 'the decode step has one query row a head'
@@ -119,16 +151,13 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
                 ((0, 0), (0, 0), (0, rows - group), (0, 0)))
     n_blocks = s // block
 
-    def kv_index(i, j, lens):
-        last = jnp.maximum(pl.cdiv(lens[i], block) - 1, 0)
-        return (i, 0, jnp.minimum(j, last), 0)
-
-    row_spec = pl.BlockSpec((1, hkv, rows, d), lambda i, j, lens: (i, 0, 0, 0))
-    kv_spec = pl.BlockSpec((1, hkv, block, d), kv_index)
+    lengths = lengths.astype(jnp.int32)
+    row_spec = pl.BlockSpec((1, hkv, rows, d), lambda i, j, *_: (i, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, hkv, block, d), _kv_index)
     out = pl.pallas_call(
         functools.partial(_kernel, scale=d**-0.5, block=block),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=3,
             grid=(b, n_blocks),
             in_specs=[row_spec, kv_spec, kv_spec],
             out_specs=row_spec,
@@ -142,5 +171,5 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
             dimension_semantics=('parallel', 'arbitrary')),
         name='decode_attention',
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_cache, v_cache)
+    )(lengths, *resident_tiles(lengths, block), q, k_cache, v_cache)
     return out[:, :, :group].reshape(b, hq, 1, d)

@@ -183,8 +183,10 @@ _HELP = {
         'cache holds (n_slots x max_seq_len a step), kind="fetched" '
         'what the steps\' attention asks for — whole tiles up to each '
         'slot\'s length where the decode attention is bounded by the '
-        'lengths (an empty slot\'s length starts from zero), the same '
-        'as held where it reads every slot whole',
+        'lengths, the same as held where it reads every slot whole; '
+        'kind="empty" the tiles, one a step, that slots holding no '
+        'request did not fetch because the model\'s step was told so '
+        '(where it is not, they count from zero into fetched)',
     'skytpu_engine_xla_compile_total':
         'XLA backend compiles observed in this process '
         '(jax.monitoring): increments after engine warmup are '

@@ -52,6 +52,32 @@ def test_event_loop_runs_roster(tmp_home, monkeypatch):
     assert fired, 'event loop never ticked'
 
 
+def test_scheduler_outlives_a_store_error(tmp_home, monkeypatch):
+    """A read the store refuses (the scheduler's thread and the event
+    loop's open a fresh jobs.db at the same moment) does not end the
+    scheduler: with it gone every later job would stay PENDING and its
+    launch wait for ever."""
+    import sqlite3
+    from skypilot_tpu.agent import job_queue
+    from skypilot_tpu.agent import server as agent_server
+    calls = []
+
+    def next_pending():
+        calls.append(1)
+        if len(calls) == 1:
+            raise sqlite3.OperationalError('database is locked')
+
+    monkeypatch.setattr(job_queue, 'next_pending', next_pending)
+    sched = agent_server.AgentScheduler()
+    sched.start()
+    deadline = time.time() + 10
+    while len(calls) < 2 and time.time() < deadline:
+        time.sleep(0.05)
+    alive = sched._thread.is_alive()
+    sched.stop()
+    assert len(calls) >= 2 and alive
+
+
 def test_check_reports_storage_split(api_server):
     checks = requests_lib.get(f'{api_server}/check').json()
     for name, info in checks.items():

@@ -49,6 +49,60 @@ def test_kernel_matches_masked_reference(heads, length):
     assert (out[2] == 0).all()           # length 0: zeros, not NaN
 
 
+# Slots that hold no request (a length of zero) in front of, between
+# and behind live ones, and nothing but them.
+EMPTY_SLOTS = {
+    'front': [0, 0, BLOCK + 1, S],
+    'between': [S, 0, 0, 1, 0, BLOCK],
+    'behind': [BLOCK + 1, 3, 0, 0],
+    'mixed': [0, S - 1, 0, BLOCK, 0],
+    'all': [0, 0, 0],
+}
+
+
+@pytest.mark.parametrize('lengths', EMPTY_SLOTS.values(), ids=EMPTY_SLOTS)
+@pytest.mark.parametrize('heads', [(16, 16), (32, 4)],
+                         ids=lambda h: f'{h[0]}on{h[1]}')
+def test_empty_slots_give_zeros_and_leave_the_live_rows_alone(heads, lengths):
+    q, k, v, lengths = _inputs(*heads, lengths)
+    # What a retired request left in an empty slot would show if read.
+    empty = (lengths == 0)[:, None, None, None]
+    k = jnp.where(empty, 1e4, k).astype(k.dtype)
+    v = jnp.where(empty, -1e4, v).astype(v.dtype)
+    out = pallas_da.decode_attention_fwd(q, k, v, lengths, block=BLOCK,
+                                         interpret=True)
+    ref = _masked_reference(q, k, v, lengths)
+    out, ref = np.asarray(out, np.float32), np.asarray(ref, np.float32)
+    live = np.asarray(lengths) > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=2e-2, rtol=2e-2)
+    assert (out[~live] == 0).all() and (ref[~live] == 0).all()
+
+
+@pytest.mark.parametrize('lengths', EMPTY_SLOTS.values(), ids=EMPTY_SLOTS)
+def test_an_empty_slot_asks_for_the_tile_already_there(lengths):
+    """The index map over the whole grid, in the order the pipeline walks
+    it (a tile is fetched when its index differs from the step before):
+    a live slot asks for its own tiles up to its last live one, an empty
+    slot for what the step before it asked for, at every `j`, so the call
+    fetches the live slots' tiles and nothing else (one tile where no
+    slot is live)."""
+    lengths = np.asarray(lengths, np.int32)
+    slot, tile = (np.asarray(a) for a in pallas_da.resident_tiles(
+        jnp.asarray(lengths), BLOCK))
+    n_blocks = S // BLOCK
+    walk = [tuple(int(x) for x in pallas_da._kv_index(i, j, lengths, slot,
+                                                       tile))
+            for i in range(len(lengths)) for j in range(n_blocks)]
+    for step, index in enumerate(walk):
+        i, j = divmod(step, n_blocks)
+        if lengths[i]:
+            assert index == (i, 0, min(j, -(-lengths[i] // BLOCK) - 1), 0)
+        elif step:
+            assert index == walk[step - 1]
+    fetches = 1 + sum(a != b for a, b in zip(walk, walk[1:]))
+    assert fetches == max(int((-(-lengths // BLOCK)).sum()), 1)
+
+
 def test_kernel_reads_nothing_past_the_length():
     """Positions at and past a slot's length do not reach the result:
     what a retired request left there (here values that would swamp the

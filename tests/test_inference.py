@@ -469,6 +469,32 @@ def test_engine_empty_slots_count_from_zero_and_held_ones_do_not(
         assert req.tokens() == alone(name), name
 
 
+def test_decode_step_reads_nothing_of_a_row_that_is_not_live(
+        model_and_params):
+    """`live` through `model.apply` (what `decode` passes for `held`): a
+    live row's logits and cache are what they are without it, to the
+    bit; the other row attends to nothing (zeros from attention),
+    not to its own freshly written row."""
+    model, params = model_and_params
+    _, state = model.apply(
+        {'params': params}, jnp.asarray([[1, 2, 3, 4], [5, 6, 7, 8]]),
+        decode=True, mutable=['cache'])
+
+    def step(**live):
+        return model.apply(
+            {'params': params, 'cache': state['cache']},
+            jnp.asarray([[9], [9]]), positions=jnp.asarray([[4], [0]]),
+            decode=True, mutable=['cache'], **live)
+
+    logits, after = step()
+    told, after_told = step(live=jnp.asarray([True, False]))
+    np.testing.assert_array_equal(np.asarray(told[0]), np.asarray(logits[0]))
+    assert not np.array_equal(np.asarray(told[1]), np.asarray(logits[1]))
+    assert np.isfinite(np.asarray(told)).all()
+    jax.tree.map(lambda a, b: np.testing.assert_array_equal(a[0], b[0]),
+                 after_told['cache'], after['cache'])
+
+
 def _kv_positions():
     import re
     from skypilot_tpu.server import metrics as metrics_lib
@@ -484,7 +510,10 @@ def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
     `held` is slots x max_seq_len x steps a call; `fetched` the same
     where the attention reads every slot whole (here, the CPU), and whole
     tiles up to each step's row where a kernel's block length is known
-    (set by hand: the test's steering, not an option)."""
+    (set by hand: the test's steering, not an option).  A slot that
+    holds no request is `empty` where the model's step is told which
+    rows are live (Llama), and counts from zero into `fetched` where it
+    is not: the two sum to the same."""
     model, params = model_and_params
     config = EngineConfig(n_slots=2, steps_per_call=3,
                           prefill_buckets=(8, 16))
@@ -505,6 +534,19 @@ def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
     assert req.finished_at is not None
     last = _kv_positions()
     assert last['held'] - after['held'] == whole
-    # Rows 9, 10, 11 of the held slot: 3 tiles each; rows 1, 2, 3 of the
-    # empty one: a tile each.
-    assert last['fetched'] - after['fetched'] == (3 * 3 + 3 * 1) * 4
+    # Rows 9, 10, 11 of the held slot: 3 tiles each; the empty one
+    # fetches nothing, where it would have fetched a tile a step (rows
+    # 1, 2, 3).
+    assert engine._takes_live
+    assert last['fetched'] - after['fetched'] == 3 * 3 * 4
+    assert last['empty'] - after.get('empty', 0.0) == 3 * 1 * 4
+    assert after.get('empty', 0.0) == before.get('empty', 0.0)
+
+    # A model whose step does not take the signal: the empty slots' tiles
+    # are fetched, and counted so.
+    engine._takes_live = False
+    engine._count_kv_positions(np.array([8, 0]), np.array([True, False]))
+    engine._flush_loop_seconds()
+    untold = _kv_positions()
+    assert untold['fetched'] - last['fetched'] == (3 * 3 + 3 * 1) * 4
+    assert untold['empty'] == last['empty']

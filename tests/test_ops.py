@@ -325,7 +325,10 @@ def test_decode_program_keeps_the_cache_as_the_kernel_reads_it(
     [B, Hkv, S, D] and no temporary is as large as a cache leaf.  (With
     the row written by a scatter whose window is a position's heads the
     compiler laid the cache out position-major and copied every leaf in
-    front of every kernel call.)"""
+    front of every kernel call.)  The kernel's scalar operands (the
+    lengths and where an empty slot looks) are made once a step: every
+    layer's call takes the same three."""
+    import re
     import flax.linen as nn
     from jax.experimental.layout import Format, Layout
     from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
@@ -365,13 +368,20 @@ def test_decode_program_keeps_the_cache_as_the_kernel_reads_it(
         out_shardings=(auto, autos(engine._cache), auto, auto)).lower(
             shapes(params), shapes(engine._cache), shapes(engine._last_d),
             lens, lens, shapes(engine._rng)).compile()
-    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') \
-        == cfg.n_layers
+    text = compiled.as_text()
+    calls = re.findall(r' custom-call\(([^)]*)\), '
+                       r'custom_call_target="tpu_custom_call"', text)
+    assert len(calls) == cfg.n_layers
+    scalars = {tuple(call.split(', ')[:3]) for call in calls}
+    assert len(scalars) == 1 and len(set(scalars.pop())) == 3
     formats, _ = compiled.input_formats
     leaf = jax.tree.leaves(engine._cache)[0]
     for fmt in jax.tree.leaves(formats[1]):
         assert fmt.layout.major_to_minor == (0, 1, 2, 3)
     assert compiled.memory_analysis().temp_size_in_bytes < leaf.nbytes
+    shape = ','.join(map(str, leaf.shape))
+    assert f'bf16[{shape}]' in text
+    assert not re.search(rf'= bf16\[{shape}\]\S* (copy|transpose)\(', text)
 
 
 def test_decode_program_keeps_the_expert_stacks_as_the_kernel_reads_them(
