@@ -101,6 +101,29 @@ lengths, the [T+1, n_slots] output) stays replicated: the host loop is
 IDENTICAL under a mesh — same one sync per step, same pipelining, same
 slot bookkeeping.  `mesh=None` is the exact single-device path
 (including the TPU layout pinning below), byte-for-byte unchanged.
+
+Generation by blocks (a model that declares a `block_length`, e.g.
+models/sdar_moe.py: generation by diffusion over blocks of positions): a
+decode step is a PASS over a block a slot, not a token a slot.  The device
+carries, a slot, the block's tokens, which of them are still masked, and
+the block's start; a pass runs the block's rows against the cache of the
+blocks before it and the block itself, then by the slot's phase either
+unmasks positions by the model's schedule (`block_schedule.choose`) or, with
+nothing masked, commits: the block's K and V are in the cache, the block
+is the output, the next block starts all masked.  Slots at different
+phases share a pass.  A call scans `steps_per_call` passes and hands the
+host, a pass and slot, the block and whether it was committed
+(`_process_blocks` emits committed blocks in order); prefill_insert writes
+the prompt's whole blocks, samples nothing, and seeds the slot's first
+block with the prompt's last `L % block_length` tokens.  Everything the
+host counts (`length`, `_finished`, the handoff, the decode-tokens
+counter, the K/V positions) follows TOKENS; rows of `out` are passes.
+Answers of one length admitted together end over two adjacent calls by
+their prompts' `L % block_length`, so an admission waits one call where
+the larger part of a group ends in the next one (`_hold_admission`) and
+the group's successors are one prefill again.  A model without a
+`block_length` gets the programs described above, to the byte, and the
+loop's admissions as they were.
 """
 from __future__ import annotations
 
@@ -240,6 +263,10 @@ class Request:
     downstream_max_new: int = 0
     kv_export: Optional[dict] = None
     adopt: Optional[tuple] = None
+    # Generation by blocks: the absolute positions in the order they took
+    # their tokens (within a pass, by position); None for a model that
+    # generates a token a step.
+    unmask_order: Optional[List[int]] = None
 
     def tokens(self) -> List[int]:
         """Drain: block until the request finishes, return all tokens."""
@@ -253,13 +280,20 @@ class Request:
 
 class _Slot:
     __slots__ = ('request', 'length', 'device_length', 'first_pending',
-                 'wait_end', 'done', 'pages', 'n_shared', 'toks')
+                 'wait_end', 'done', 'pages', 'n_shared', 'toks',
+                 'block_skip', 'block_masked')
 
     def __init__(self, request: Request, length: int,
                  pages: Optional[List[int]] = None,
-                 n_shared: int = 0) -> None:
+                 n_shared: int = 0, block: Optional[int] = None) -> None:
         self.request = request
         self.length = length              # prompt len + emitted (host view)
+        # Generation by blocks: the prompt's tokens at the head of the
+        # first block (they are not output), and the positions still
+        # masked in the slot's block at the start of the next call to be
+        # fetched (the handoff counts passes from it).
+        self.block_skip = length % block if block else 0
+        self.block_masked = block - self.block_skip if block else 0
         # The device's `lens` entry of this slot at the next dispatch:
         # the prompt's length at the insert, one more for every decode
         # step dispatched since (a call ahead of `length` when
@@ -353,6 +387,21 @@ def _ngram_continuation(hist: List[int], k: int, max_ngram: int = 3,
     return out
 
 
+def _passes_to_tokens(masked_left: int, passes: int, block: int,
+                      per_pass: int) -> int:
+    """Tokens that `passes` passes commit AT THE LEAST, from a block with
+    `masked_left` positions masked, if a denoising pass unmasks `per_pass`
+    positions (every schedule unmasks at least that many): whole blocks
+    only, each its denoising passes and one commit pass."""
+    tokens = 0
+    while True:
+        passes -= -(-masked_left // per_pass) + 1
+        if passes < 0:
+            return tokens
+        tokens += block
+        masked_left = block
+
+
 class DecodeEngine:
     """Slot-based continuous batching over a Llama-family model.
 
@@ -383,10 +432,25 @@ class DecodeEngine:
                         config.speculation):
             raise ValueError(
                 f'{type(model).__name__} {unpaged}, and the page manager '
-                f'holds keys and values only: kv_page_size, speculation '
-                f'and KV transfer (submit_prefill / submit_adopt, which '
-                f'need pages) are not available with it; leave '
-                f'kv_page_size None and speculation 0')
+                f'holds keys and values only, one token a sequence and '
+                f'step: kv_page_size, speculation and KV transfer '
+                f'(submit_prefill / submit_adopt, which need pages) are '
+                f'not available with it; leave kv_page_size None and '
+                f'speculation 0')
+        # Generation by blocks: a model that declares a `block_length`
+        # is served a pass over a block a step (the module docstring),
+        # by its `block_schedule`.
+        self._block: Optional[int] = getattr(model, 'block_length', None)
+        self._schedule = (model.block_schedule if self._block else None)
+        self._admission_held = False     # _hold_admission, last iteration
+        if self._block:
+            off = [v for v in buckets + (max_len,) if v % self._block]
+            if off:
+                raise ValueError(
+                    f'{type(model).__name__} generates by blocks of '
+                    f'{self._block} positions aligned to absolute '
+                    f'positions: every prefill bucket and max_seq_len '
+                    f'must be multiples of it; offending values: {off}')
         self.cfg = config
         self._rng = jax.random.PRNGKey(config.seed)
         self._prefill_q: 'queue.Queue[Request]' = queue.Queue()
@@ -754,6 +818,22 @@ class DecodeEngine:
             return jnp.take_along_axis(
                 logits, index[:, None, None], axis=1)[:, 0]
 
+        def a_group_at_a_time(rows, *args):
+            """`rows(*args)` over a prefill's N rows.  A model may say how
+            many rows of a prefill it can hold at once (`prefill_rows`, a
+            divisor of every admitted group's power of two): more go
+            through it that many at a time, every row on its own as in
+            one pass."""
+            n = args[0].shape[0]
+            at_once = getattr(model, 'prefill_rows', None) or n
+            if n <= at_once:
+                return rows(*args)
+            return jax.tree.map(
+                lambda t: t.reshape((n,) + t.shape[2:]),
+                jax.lax.map(lambda xs: rows(*xs), jax.tree.map(
+                    lambda t: t.reshape((n // at_once, at_once) +
+                                        t.shape[1:]), args)))
+
         def prefill_insert(params, big_cache, last_toks, lens, tokens,
                            lengths, slots, valid, rng):
             """Fused BATCHED prefill + slot insert: N prompts of one
@@ -773,20 +853,8 @@ class DecodeEngine:
                     decode=True, lengths=lengths, mutable=['cache'])
                 return logits, cache['cache']
 
-            # A model may say how many rows of a prefill it can hold at
-            # once (`prefill_rows`, a divisor of every admitted group's
-            # power of two): more go through it that many at a time,
-            # every row on its own as in one pass.
-            at_once = getattr(model, 'prefill_rows', None) or n
-            if n > at_once:
-                logits, cache = jax.tree.map(
-                    lambda t: t.reshape((n,) + t.shape[2:]),
-                    jax.lax.map(lambda xs: rows(*xs), jax.tree.map(
-                        lambda t: t.reshape((n // at_once, at_once) +
-                                            t.shape[1:]),
-                        (tokens, positions, lengths))))
-            else:
-                logits, cache = rows(tokens, positions, lengths)
+            logits, cache = a_group_at_a_time(rows, tokens, positions,
+                                              lengths)
             last = last_logits(logits, lengths - 1)                  # [N,V]
             firsts = sample(last, rng)                               # [N]
             # Padding rows replicate row 0, so their duplicate scatter
@@ -822,7 +890,8 @@ class DecodeEngine:
                                 stats_abs)
 
         def decode(params, cache, last_tokens, lengths, held, rng):
-            """`steps` tokens for every slot in one dispatch.  Returns
+            """`steps` steps for every slot in one dispatch, a token a
+            slot and step: a row of `out` is a token a slot.  Returns
             out [steps+1, n_slots] (row 0 = the incoming last tokens, so
             freshly admitted slots' first tokens ride the same fetch);
             with a `stats` collection, (out, its sums over the steps).
@@ -859,6 +928,107 @@ class DecodeEngine:
             if stats is not None:
                 out = (out, stats)       # one fetch carries both
             return out, cache, last, lens                    # [T+1, B]
+
+        # ----- generation by blocks ------------------------------------------
+        # (a model that declares `block_length`: the module docstring)
+        blk, schedule = self._block, self._schedule
+
+        def prefill_insert_blocks(params, big_cache, block, starts, tokens,
+                                  lengths, slots, valid, rng):
+            """`prefill_insert` for generation by blocks: the prompts run
+            under the mask by blocks and their K and V are inserted; no
+            token is sampled (a prompt's logits are not read).  A prompt
+            of L tokens has L // blk whole blocks in the cache; its last
+            L % blk tokens open the slot's first block, whose other
+            positions start masked, at `starts` = L - L % blk.  Rows of
+            the cache past the whole blocks hold what the padded rows
+            wrote: nothing reads them before the block's passes overwrite
+            them."""
+            del valid, rng               # padding rows carry row 0's values
+            n, p = tokens.shape
+            positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
+
+            def rows(tokens, positions, lengths):
+                _, cache = model.apply(
+                    {'params': params}, tokens, positions=positions,
+                    decode=True, lengths=lengths, mutable=['cache'])
+                return cache['cache']
+
+            cache = a_group_at_a_time(rows, tokens, positions, lengths)
+            first = lengths - lengths % blk                          # [N]
+            offs = jnp.arange(blk)[None, :]
+            opened = jnp.take_along_axis(
+                tokens, jnp.minimum(first[:, None] + offs, p - 1), axis=1)
+            masked = (offs >= (lengths % blk)[:, None]).astype(jnp.int32)
+            big_cache = jax.tree_util.tree_map(
+                lambda big, small: big.at[slots].set(small), big_cache,
+                cache)
+            return (big_cache,
+                    {'tok': block['tok'].at[slots].set(opened),
+                     'masked': block['masked'].at[slots].set(masked)},
+                    starts.at[slots].set(first))
+
+        def decode_blocks(params, cache, block, starts, held, rng):
+            """`steps` PASSES over every slot's block in one dispatch
+            (generation by blocks).  The carry holds, a slot, the block's
+            tokens, which are masked, and its start.  A pass embeds the
+            block (the mask token where masked), writes its K and V at
+            `start .. start + blk`, attends the rows over the positions
+            `< start + blk` and takes logits at every row; then a slot
+            with masked positions unmasks some by the schedule
+            (`schedule.choose`), and a slot with none has run its clean
+            block: it commits (the block is output, `start += blk`, the
+            next block all masked).  Returns out [steps, n_slots,
+            2 * blk + 3] int32, a pass and slot: the block's tokens after
+            the pass (the committed block on a commit), which positions
+            took their token in it, whether it committed, the start and
+            the count of masked positions after it.  There is no row 0 of
+            carried-in tokens: a prefill samples none.  `held` as in
+            `decode`: the other slots start from zero and a pass reads
+            nothing of their cache."""
+            held = held.astype(bool)
+            starts = jnp.where(held, starts, 0)
+            offs = jnp.arange(blk)[None, :]
+
+            def body(carry, rng_t):
+                cache, tok, masked, start, stats = carry
+                positions = jnp.minimum(start[:, None] + offs, max_len - 1)
+                logits, new_cache = model.apply(
+                    {'params': params, 'cache': cache}, tok,
+                    positions=positions, decode=True, masked=masked,
+                    live=held, mutable=mutable)
+                chosen = sample(logits, rng_t).astype(jnp.int32)  # [B, blk]
+                conf = jnp.exp(
+                    jnp.take_along_axis(logits, chosen[..., None],
+                                        axis=-1)[..., 0] -
+                    jax.nn.logsumexp(logits, axis=-1))
+                commit = ~jnp.any(masked, axis=1)                    # [B]
+                take = schedule.choose(conf, masked)
+                shown = jnp.where(take, chosen, tok)
+                left = masked & ~take
+                row = jnp.concatenate(
+                    [shown, take.astype(jnp.int32),
+                     commit[:, None].astype(jnp.int32),
+                     (start + blk * commit)[:, None],
+                     jnp.where(commit, blk, jnp.sum(left, axis=1))[:, None]],
+                    axis=1)
+                if stats is not None:
+                    stats = jax.tree.map(jnp.add, stats, new_cache['stats'])
+                return (new_cache['cache'], shown,
+                        left | commit[:, None], start + blk * commit,
+                        stats), row
+
+            (cache, tok, masked, starts, stats), out = jax.lax.scan(
+                body, (cache, block['tok'], block['masked'].astype(bool),
+                       starts, stats0()),
+                jax.random.split(rng, steps))
+            if stats is not None:
+                out = (out, stats)       # one fetch carries both
+            return (out, cache,
+                    {'tok': tok, 'masked': masked.astype(jnp.int32)}, starts)
+
+        if blk:
+            prefill_insert, decode = prefill_insert_blocks, decode_blocks
 
         def prefill_chunk(params, scratch, tokens, offset):
             """One INTERMEDIATE chunk of a long prompt: tokens [1, C]
@@ -1214,6 +1384,16 @@ class DecodeEngine:
         self._export_pages = jax.jit(
             self._export_raw, in_shardings=(c_sh, r), out_shardings=d_sh)
 
+    def _last_zeros(self):
+        """The device's per-slot token state before any insert: the last
+        sampled token a slot, or for generation by blocks the block's
+        tokens and which of them are masked."""
+        n = self.cfg.n_slots
+        if self._block:
+            return {'tok': jnp.zeros((n, self._block), jnp.int32),
+                    'masked': jnp.zeros((n, self._block), jnp.int32)}
+        return jnp.zeros((n,), jnp.int32)
+
     def _init_cache(self):
         """Materialize the big cache from a trace of a dummy decode batch.
         Under a mesh it is created ALREADY sharded (jit out_shardings) —
@@ -1230,13 +1410,13 @@ class DecodeEngine:
             self._cache = jax.tree.map(
                 lambda a: jnp.zeros(a.shape, a.dtype),
                 jax.eval_shape(self._make_cache, self.params))
-            self._last_d = jnp.zeros((n,), jnp.int32)
+            self._last_d = self._last_zeros()
             self._lens_d = jnp.zeros((n,), jnp.int32)
             return
         self._cache = jax.jit(
             self._make_cache,
             out_shardings=self._cache_shardings)(self.params)
-        self._last_d = jax.device_put(jnp.zeros((n,), jnp.int32),
+        self._last_d = jax.device_put(self._last_zeros(),
                                       self._repl)
         self._lens_d = jax.device_put(jnp.zeros((n,), jnp.int32),
                                       self._repl)
@@ -1456,6 +1636,9 @@ class DecodeEngine:
         limit = self.model.cfg.max_seq_len - 1
         if self.cfg.max_prompt_len is not None:
             limit = min(limit, self.cfg.max_prompt_len)
+        if self._block:
+            # No chunked prefill for blocks: a prompt fits one bucket.
+            limit = min(limit, self.cfg.prefill_buckets[-1])
         return limit
 
     @property
@@ -2034,7 +2217,10 @@ class DecodeEngine:
                 held[i] = 1
                 lens[i] = slot.device_length
                 slot.device_length += steps
-        self._count_kv_positions(lens, held.astype(bool))
+        if not self._block:
+            # (Generation by blocks: where a pass reads to is the
+            # device's to say; _process_blocks counts from the fetch.)
+            self._count_kv_positions(lens, held.astype(bool))
         return self._decode(self.params, self._cache, self._last_d,
                             self._lens_d, jnp.asarray(held),
                             self._next_rng())
@@ -2052,12 +2238,17 @@ class DecodeEngine:
         nothing of it: those tiles are then `empty`, what the slot would
         have fetched, and not `fetched`."""
         steps, max_len = self.cfg.steps_per_call, self.model.cfg.max_seq_len
-        whole = lens.size * max_len * steps
+        read = np.minimum(lens[:, None] + np.arange(steps), max_len - 1) + 1
+        self._count_kv_read(read, held)
+
+    def _count_kv_read(self, read: np.ndarray, held: np.ndarray) -> None:
+        """`_count_kv_positions` from `read` [n_slots, steps]: the
+        positions each step's attention reads up to, a slot."""
+        whole = read.size * self.model.cfg.max_seq_len
         self._kv_held += whole
         if self._kv_block is None:
             self._kv_fetched += whole
             return
-        read = np.minimum(lens[:, None] + np.arange(steps), max_len - 1) + 1
         tiles = (-(-read // self._kv_block)).sum(axis=1) * self._kv_block
         skipped = int(tiles[~held].sum()) if self._takes_live else 0
         self._kv_empty += skipped
@@ -2125,7 +2316,9 @@ class DecodeEngine:
         t1 = time.perf_counter()
         for j, (slot_id, req, pages) in enumerate(group):
             self._slots[slot_id] = _Slot(req, len(req.prompt_ids),
-                                         pages=pages)
+                                         pages=pages, block=self._block)
+            if self._block:
+                req.unmask_order = []
             if self._paged:
                 self._page_tables[slot_id] = pt_rows[j]
                 self._pt_dirty = True
@@ -2755,6 +2948,8 @@ class DecodeEngine:
                 # garbage.
                 self._process_rows(out[:-1], snapshot, counts=out[-1],
                                    verify_span=(t0, t1))
+            elif self._block:
+                self._process_blocks(out, snapshot, (t0, t1))
             else:
                 self._process_rows(out, snapshot)
             self._release_retiring()
@@ -2805,14 +3000,16 @@ class DecodeEngine:
             self._sample_gauges(len(active))
             dispatched = None
             if active:
+                t_dispatch = time.perf_counter()
                 out_d, self._cache, self._last_d, self._lens_d = \
                     self._dispatch_decode()
-                dispatched = (out_d, {i: self._slots[i] for i in active})
+                dispatched = (out_d, {i: self._slots[i] for i in active},
+                              t_dispatch)
             chunked = self._step_chunked()   # queues behind the decode call
         self._loop_busy_s += ph.seconds
         out = snapshot = stats = None
         if self._inflight is not None:
-            out_prev, snapshot = self._inflight
+            out_prev, snapshot, t_prev = self._inflight
             self._inflight = None
             with tracing.phase('engine.loop.fetch') as ph:
                 # (one call late: syncs call k-1 while call k runs)
@@ -2821,7 +3018,10 @@ class DecodeEngine:
         with tracing.phase('engine.loop.emit') as ph:
             if stats is not None:
                 self.model.publish_stats(stats)
-            if snapshot is not None:
+            if snapshot is not None and self._block:
+                self._process_blocks(out, snapshot,
+                                     (t_prev, time.perf_counter()))
+            elif snapshot is not None:
                 self._process_rows(out, snapshot)
             self._release_retiring()
             self._inflight = dispatched
@@ -2829,23 +3029,27 @@ class DecodeEngine:
         with tracing.phase('engine.loop.admit') as ph:
             # Admissions AFTER processing: retired slots are free now,
             # and slots whose occupant will PROVABLY finish inside the
-            # call just dispatched (its remaining max_new fits the rows
-            # that call delivers) hand off to a successor with zero
-            # garbage calls — the successor's prefill queues behind the
-            # in-flight call.
+            # call just dispatched (its remaining max_new fits the
+            # TOKENS that call delivers at the least: a row of `out` a
+            # token, or for generation by blocks the blocks its passes
+            # commit) hand off to a successor with zero garbage calls —
+            # the successor's prefill queues behind the in-flight call.
             handoff = []
+            next_call = 0    # occupants that end inside the call after it
             if dispatched is not None:
-                steps = self.cfg.steps_per_call
                 for i, slot in dispatched[1].items():
                     if self._slots[i] is not slot or slot.done:
                         continue
-                    rows_to_come = steps + (1 if slot.first_pending else 0)
                     remaining = (slot.request.max_new_tokens -
                                  slot.request.emitted)
-                    if remaining <= rows_to_come:
+                    if remaining <= self._tokens_to_come(slot):
                         handoff.append(i)
+                    elif self._block and \
+                            remaining <= self._tokens_to_come(slot, calls=2):
+                        next_call += 1
             self._step_adopt()
-            self._admit_free(handoff)
+            if not self._hold_admission(len(handoff), next_call):
+                self._admit_free(handoff)
         self._loop_busy_s += ph.seconds
         return len(active) + (1 if chunked else 0)
 
@@ -2863,16 +3067,7 @@ class DecodeEngine:
         slot i; the rest are rejected drafts.  ``verify_span`` is the
         (dispatch, fetch) perf_counter bracket for the engine.verify
         flight-recorder span of traced requests."""
-        now = time.perf_counter()
-        # A slot whose first token is pending and that this call did not
-        # carry was admitted behind it: its prefill sat on the device
-        # until the call just fetched had run.  The LAST such fetch is
-        # where its engine.prefill_wait ends (a chunked insert goes out
-        # with two calls still ahead of it).
-        for i, slot in enumerate(self._slots):
-            if (slot is not None and slot.first_pending and
-                    snapshot.get(i) is not slot):
-                slot.wait_end = now
+        now = self._stamp_prefill_waits(snapshot)
         emitted = 0
         spec_proposed = spec_accepted = 0
         for i, slot in snapshot.items():
@@ -2892,59 +3087,12 @@ class DecodeEngine:
                         proposed=self._spec_k, accepted=m - 1)
             start = 0
             if slot.first_pending:
-                slot.first_pending = False
-                slot.request.first_token_at = now
-                metrics_lib.observe_hist(
-                    metrics_lib.ENGINE_TTFT_FAMILY,
-                    now - slot.request.submitted_at)
-                rid = slot.request.request_id
-                if rid is not None:
-                    # The decode call the first token rode: from the
-                    # prefill dispatch's end to the host observing the
-                    # token — closes the TTFT tiling.  Its two parts
-                    # tile it: the prefill waiting behind the call in
-                    # flight at admission (zero length when there was
-                    # none), then the sampled token riding the next
-                    # whole call.
-                    start_at = (slot.request.prefill_end_at
-                                if slot.request.prefill_end_at is not None
-                                else slot.request.submitted_at)
-                    wait_end = (slot.wait_end if slot.wait_end is not None
-                                else start_at)
-                    tracing.record_span(rid, 'engine.dispatch', start_at,
-                                        now, slot=i)
-                    tracing.record_span(rid, 'engine.prefill_wait',
-                                        start_at, wait_end, slot=i)
-                    tracing.record_span(rid, 'engine.first_token_ride',
-                                        wait_end, now, slot=i)
-                    # Decode-batch membership + the measured TTFT the
-                    # decomposition is checked against.
-                    tracing.record_instant(
-                        rid, 'engine.first_token', now, slot=i,
-                        batch=len(snapshot),
-                        ttft_s=round(now - slot.request.submitted_at,
-                                     6))
+                self._first_token(i, slot, now, len(snapshot))
             else:
                 start = 1                # row 0 was emitted last step
             for t in range(start, limit):
-                tok = int(out[t, i])
-                slot.length += 1
-                # Device-cost attribution: this token's context length
-                # and decode-batch size (token-weighted accumulators
-                # _sample_perf folds into the live gauges).
-                self._perf_tokens += 1
-                self._perf_ctx_sum += slot.length
-                self._perf_occ_sum += len(snapshot)
-                if slot.pages is not None:
-                    # Retire donates prompt+generated pages to the
-                    # prefix cache (it needs the generated token ids)
-                    # and a prefill-role request's KV export needs its
-                    # sampled first token.
-                    slot.toks.append(tok)
-                self._emit(slot.request, tok)
                 emitted += 1
-                if self._finished(slot, tok):
-                    self._retire(i, slot)
+                if self._emit_token(i, slot, int(out[t, i]), len(snapshot)):
                     break                # rest of this call's tokens: waste
         if emitted:
             metrics_lib.inc_counter('skytpu_engine_decode_tokens_total',
@@ -2959,6 +3107,178 @@ class DecodeEngine:
             metrics_lib.set_gauge('skytpu_engine_spec_acceptance',
                                   spec_accepted / spec_proposed)
 
+
+    def _stamp_prefill_waits(self, snapshot: Dict[int, _Slot]) -> float:
+        """The clock at a fetch, stamped on the slots it ends a wait for.
+        A slot whose first token is pending and that the call just
+        fetched did not carry was admitted behind it: its prefill sat on
+        the device until that call had run.  The LAST such fetch is where
+        its engine.prefill_wait ends (a chunked insert goes out with two
+        calls still ahead of it)."""
+        now = time.perf_counter()
+        for i, slot in enumerate(self._slots):
+            if (slot is not None and slot.first_pending and
+                    snapshot.get(i) is not slot):
+                slot.wait_end = now
+        return now
+
+    def _first_token(self, i: int, slot: _Slot, now: float,
+                     batch: int) -> None:
+        """The bookkeeping of a slot's first emitted token, at the fetch
+        that carried it (`now`): TTFT and the spans that tile it."""
+        slot.first_pending = False
+        slot.request.first_token_at = now
+        metrics_lib.observe_hist(metrics_lib.ENGINE_TTFT_FAMILY,
+                                 now - slot.request.submitted_at)
+        rid = slot.request.request_id
+        if rid is None:
+            return
+        # The decode call the first token rode: from the prefill
+        # dispatch's end to the host observing the token — closes the
+        # TTFT tiling.  Its two parts tile it: the prefill waiting
+        # behind the call in flight at admission (zero length when
+        # there was none), then the sampled token riding the next whole
+        # call (for generation by blocks: the calls up to the one that
+        # committed the first block).
+        start_at = (slot.request.prefill_end_at
+                    if slot.request.prefill_end_at is not None
+                    else slot.request.submitted_at)
+        wait_end = slot.wait_end if slot.wait_end is not None else start_at
+        tracing.record_span(rid, 'engine.dispatch', start_at, now, slot=i)
+        tracing.record_span(rid, 'engine.prefill_wait', start_at, wait_end,
+                            slot=i)
+        tracing.record_span(rid, 'engine.first_token_ride', wait_end, now,
+                            slot=i)
+        # Decode-batch membership + the measured TTFT the decomposition
+        # is checked against.
+        tracing.record_instant(
+            rid, 'engine.first_token', now, slot=i, batch=batch,
+            ttft_s=round(now - slot.request.submitted_at, 6))
+
+    def _emit_token(self, i: int, slot: _Slot, tok: int, batch: int) -> bool:
+        """One token of slot i to its request; True when it was the
+        request's last (the slot is retired)."""
+        slot.length += 1
+        # Device-cost attribution: this token's context length and
+        # decode-batch size (token-weighted accumulators _sample_perf
+        # folds into the live gauges).
+        self._perf_tokens += 1
+        self._perf_ctx_sum += slot.length
+        self._perf_occ_sum += batch
+        if slot.pages is not None:
+            # Retire donates prompt+generated pages to the prefix cache
+            # (it needs the generated token ids) and a prefill-role
+            # request's KV export needs its sampled first token.
+            slot.toks.append(tok)
+        self._emit(slot.request, tok)
+        if self._finished(slot, tok):
+            self._retire(i, slot)
+            return True
+        return False
+
+    def _process_blocks(self, out: np.ndarray, snapshot: Dict[int, _Slot],
+                        span: tuple) -> None:
+        """`_process_rows` for generation by blocks: `out` [passes,
+        n_slots, 2 * blk + 3] as `decode_blocks` returns it.  A slot's
+        committed blocks are emitted in order: the first from the prompt's
+        `L % blk` tokens on (they opened it), the last cut at
+        `max_new_tokens` or at an end token.  Counted here, from what the
+        device says it did: the slot-passes of slots that hold a request
+        by kind, the K/V positions each pass read
+        (`start + blk`; a slot without a request starts from zero), and
+        for a traced request the call as an `engine.blocks` span (`span`:
+        the call's dispatch and fetch)."""
+        blk = self._block
+        now = self._stamp_prefill_waits(snapshot)
+        committed = out[:, :, 2 * blk].astype(bool)          # [passes, n]
+        before = out[:, :, 2 * blk + 1] - blk * committed    # a pass's start
+        held = np.zeros((out.shape[1],), bool)
+        held[list(snapshot)] = True
+        self._count_kv_read(
+            np.minimum(np.where(held[None, :], before, 0) + blk,
+                       self.model.cfg.max_seq_len).T, held)
+        emitted = denoise = commits = 0
+        for i, slot in snapshot.items():
+            if slot.done:
+                continue                 # retired earlier: rows are garbage
+            req = slot.request
+            passes = blocks = tokens = 0     # this slot's, of the call
+            for t in range(out.shape[0]):
+                passes += 1
+                if not committed[t, i]:
+                    denoise += 1
+                    req.unmask_order.extend(
+                        int(before[t, i]) + int(j)
+                        for j in np.flatnonzero(out[t, i, blk:2 * blk]))
+                    continue
+                commits += 1
+                blocks += 1
+                if slot.first_pending:
+                    self._first_token(i, slot, now, len(snapshot))
+                skip, slot.block_skip = slot.block_skip, 0
+                ended = False
+                for tok in out[t, i, skip:blk]:
+                    emitted += 1
+                    tokens += 1
+                    ended = self._emit_token(i, slot, int(tok),
+                                             len(snapshot))
+                    if ended:
+                        break            # the rest of the block: waste
+                if ended:
+                    break                # and of the call's passes
+            slot.block_masked = int(out[-1, i, 2 * blk + 2])
+            if req.request_id is not None:
+                tracing.record_span(req.request_id, 'engine.blocks',
+                                    span[0], span[1], slot=i,
+                                    passes=passes, blocks=blocks,
+                                    tokens=tokens)
+        if emitted:
+            metrics_lib.inc_counter('skytpu_engine_decode_tokens_total',
+                                    float(emitted))
+        if denoise:
+            metrics_lib.inc_counter('skytpu_engine_block_passes_total',
+                                    float(denoise), kind='denoise')
+        if commits:
+            metrics_lib.inc_counter('skytpu_engine_block_passes_total',
+                                    float(commits), kind='commit')
+
+    def _hold_admission(self, free_soon: int, next_call: int) -> bool:
+        """Generation by blocks: whether this iteration's admission waits
+        for the next one.  Requests admitted together with answers of one
+        length do not end together: a request's passes depend on its
+        prompt's `L % block` (the first block opens part filled, the last
+        is cut), so a group retires over two adjacent calls, and two
+        groups a call apart then stall each other's passes behind their
+        prefills at every turnover from there on.  So when at least as
+        many occupants end for certain inside the NEXT call
+        (`next_call`) as slots are free or end inside this one
+        (`free_soon`), the free ones wait one call (one call's passes of
+        those slots lost) and all are admitted as one prefill group.
+        Never two iterations in a row, so that answers of mixed lengths,
+        where some slot always ends in the next call, cannot starve the
+        queue."""
+        free = free_soon + sum(s is None for s in self._slots)
+        hold = bool(self._block and not self._admission_held and
+                    0 < free <= next_call)
+        self._admission_held = hold
+        return hold
+
+    def _tokens_to_come(self, slot: _Slot, calls: int = 1) -> int:
+        """Tokens the call just dispatched (with `calls` 2: and the one
+        after it) delivers to `slot` AT THE LEAST (the handoff's bound).
+        A token a step: the call's rows of
+        `out`, and the prefill-sampled token if it is still pending.
+        Generation by blocks: the blocks that the call's passes commit
+        for certain, from the slot's masked positions at its start (a
+        denoising pass unmasks at least one position, the fixed
+        schedules their k), the first block less the prompt's tokens at
+        its head."""
+        steps = self.cfg.steps_per_call * calls
+        if not self._block:
+            return steps + (1 if slot.first_pending else 0)
+        tokens = _passes_to_tokens(slot.block_masked, steps, self._block,
+                                   self._schedule.least_per_pass(self._block))
+        return max(tokens - slot.block_skip, 0)
 
     def _loop(self):  # skytpu: hot-entry
         try:

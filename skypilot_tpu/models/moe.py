@@ -28,7 +28,8 @@ dense params expert-axis-replicated instead.
 
 Beside it stands the serving path's layer, `DroplessMoE`: one chip's share
 of an expert-parallel layer.  It is told which experts it holds, routes
-over all of them (sigmoid scores, the k largest, normalised), and returns
+over all of them (sigmoid scores, or a softmax over them where the layer
+says so; the k largest, normalised), and returns
 the held experts' part of the sum plus the shared expert; no capacity, no
 drop, and what the absent experts would add is another chip's.  Its sum,
 `grouped_experts`, has one meaning and two ways to be computed, chosen by
@@ -310,7 +311,8 @@ def _block_loop(x, keys, weights, one_hot, counts, w_gate, w_up, w_down,
 class DroplessMoE(nn.Module):
     """An expert layer that is told which experts it holds.
 
-    The router scores ALL `n_experts` (sigmoid, float32), each token takes
+    The router scores ALL `n_experts` (float32; `scoring` 'sigmoid', each
+    expert on its own, or 'softmax' over all of them), each token takes
     its `top_k` largest, weighted by score / sum of the k.  This module
     holds the experts `held` (ids into the n_experts) and returns their
     part of the result, `sum over held e of w_e E_e(x)`, plus the shared
@@ -336,6 +338,7 @@ class DroplessMoE(nn.Module):
     param_dtype: Any = jnp.float32
     block: int = 256            # pairs a trip of the expert loop
     mesh: Optional[Mesh] = None
+    scoring: str = 'sigmoid'    # or 'softmax' over all the experts
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:           # [B, S, D]
@@ -346,7 +349,10 @@ class DroplessMoE(nn.Module):
         router = self.param('router', nn.initializers.lecun_normal(),
                             (d, self.n_experts), self.param_dtype)
         flat = x.reshape(b * s, d)
-        scores = jax.nn.sigmoid(jnp.dot(
+        score = {'sigmoid': jax.nn.sigmoid,
+                 'softmax': lambda z: jax.nn.softmax(z, axis=-1)}[
+                     self.scoring]
+        scores = score(jnp.dot(
             flat.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
         idx, weights = route_top_k(scores, self.top_k, self.routed_scaling)

@@ -53,13 +53,15 @@ def mha_reference(q: jax.Array,
                   causal: bool = True,
                   scale: Optional[float] = None,
                   segment_positions: Optional[jax.Array] = None,
-                  kv_positions: Optional[jax.Array] = None) -> jax.Array:
+                  kv_positions: Optional[jax.Array] = None,
+                  mask_block: int = 1) -> jax.Array:
     """XLA multi-head attention (numerically the ground truth for the
     Pallas kernel's tests).
 
     segment_positions/kv_positions: optional absolute positions
     [B, Sq] / [B, Sk] for causal masking when q/k are *shards* of a longer
-    sequence (ring attention uses this).
+    sequence (ring attention uses this).  `mask_block` B > 1 makes the
+    causal mask one by blocks: position i sees j iff j // B <= i // B.
     """
     orig_dtype = q.dtype
     scale = scale if scale is not None else q.shape[-1]**-0.5
@@ -82,6 +84,8 @@ def mha_reference(q: jax.Array,
             q_pos = segment_positions
             k_pos = (kv_positions if kv_positions is not None
                      else segment_positions)
+        if mask_block > 1:
+            q_pos, k_pos = q_pos // mask_block, k_pos // mask_block
         mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
         if group > 1:
             # The mask is tiled, not the positions: comparing `group`
@@ -119,19 +123,24 @@ def decode_kv_block(n_kv_heads: int, head_dim: int, seq_len: int,
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array,
                      mesh: Optional[Mesh] = None) -> jax.Array:
-    """The decode step's attention: q [B, Hq, 1, D] against the cache
+    """The decode step's attention: q [B, Hq, R, D] against the cache
     leaves [B, Hkv, S, D] as they are stored, over the positions
-    `< lengths[b]` (the row written this step included).  A length of
-    zero is a slot that holds no request: zeros."""
+    `< lengths[b]` (the rows written this step included).  R is 1 for a
+    step of one token a slot, and a block's length for a pass over a
+    block, whose R rows all read the same positions (inside a block
+    nothing is masked).  A length of zero is a slot that holds no
+    request: zeros."""
     b, h_kv, s, d = k_cache.shape
     block = decode_kv_block(h_kv, d, s, k_cache.dtype, mesh)
     if block is not None:
         from skypilot_tpu.ops.pallas import decode_attention as pallas_da
         return pallas_da.decode_attention_fwd(q, k_cache, v_cache, lengths,
                                               block=block)
+    q_pos = (lengths - 1)[:, None]
+    if q.shape[2] > 1:
+        q_pos = jnp.broadcast_to(q_pos, (b, q.shape[2]))
     return mha_reference(
-        q, k_cache, v_cache, causal=True,
-        segment_positions=(lengths - 1)[:, None],
+        q, k_cache, v_cache, causal=True, segment_positions=q_pos,
         kv_positions=jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)))
 
 
@@ -185,44 +194,53 @@ def latent_decode_attention(q_lat: jax.Array, q_pe: jax.Array,
     return latent_attention_reference(q_lat, q_pe, c_kv, k_pe, lengths)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q: jax.Array,
                     k: jax.Array,
                     v: jax.Array,
                     causal: bool = True,
-                    block_size: int = 512) -> jax.Array:
-    """Flash attention: Pallas kernel on TPU, XLA reference elsewhere."""
-    return _flash_fwd_impl(q, k, v, causal, block_size)
+                    block_size: int = 512,
+                    mask_block: int = 1) -> jax.Array:
+    """Flash attention: Pallas kernel on TPU, XLA reference elsewhere.
+    `mask_block` B > 1 is the mask by blocks (`mha_reference`); at 1 the
+    causal program as it was."""
+    return _flash_fwd_impl(q, k, v, causal, block_size, mask_block)
 
 
-def _flash_fwd_impl(q, k, v, causal, block_size):
+def _flash_fwd_impl(q, k, v, causal, block_size, mask_block=1):
     if jax.default_backend() == 'tpu':
         from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
         return pallas_fa.flash_attention_fwd(q, k, v, causal=causal,
-                                             block_size=block_size)
-    return mha_reference(q, k, v, causal=causal)
+                                             block_size=block_size,
+                                             mask_block=mask_block)
+    return mha_reference(q, k, v, causal=causal, mask_block=mask_block)
 
 
-def _flash_fwd(q, k, v, causal, block_size):
+def _flash_fwd(q, k, v, causal, block_size, mask_block=1):
     if jax.default_backend() == 'tpu':
         from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
         out, lse = pallas_fa.flash_attention_fwd(
             q, k, v, causal=causal, block_size=block_size,
-            return_residuals=True)
+            return_residuals=True, mask_block=mask_block)
         return out, (q, k, v, out, lse)
-    out = mha_reference(q, k, v, causal=causal)
+    out = mha_reference(q, k, v, causal=causal, mask_block=mask_block)
     return out, (q, k, v, None, None)
 
 
-def _flash_bwd(causal, block_size, residuals, g):
+def _flash_bwd(causal, block_size, mask_block, residuals, g):
     q, k, v, out, lse = residuals
     if out is None:
         # XLA path (non-TPU): recompute under vjp; XLA fuses this into a
         # bandwidth-friendly bwd.
         _, vjp_fn = jax.vjp(
-            lambda q_, k_, v_: mha_reference(q_, k_, v_, causal=causal),
+            lambda q_, k_, v_: mha_reference(q_, k_, v_, causal=causal,
+                                             mask_block=mask_block),
             q, k, v)
         return vjp_fn(g)
+    if mask_block > 1:
+        raise NotImplementedError(
+            'the Pallas backward kernels are causal only: the mask by '
+            'blocks is a serving path (no model trains under it here)')
     from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
     # flash_attention_bwd returns dk/dv already group-reduced to Hkv heads.
     return pallas_fa.flash_attention_bwd(
@@ -234,7 +252,8 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 
 def flash_attention_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
                             mesh: Optional[Mesh],
-                            causal: bool = True) -> jax.Array:
+                            causal: bool = True,
+                            mask_block: int = 1) -> jax.Array:
     """`flash_attention` inside a program partitioned over `mesh`.
 
     XLA cannot partition a Mosaic kernel ("wrap the call in a
@@ -245,14 +264,15 @@ def flash_attention_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
     is the same.
     """
     if mesh is None or mesh.size == 1:
-        return flash_attention(q, k, v, causal)
-    return _flash_attention_sharded(q, k, v, mesh=mesh, causal=causal)
+        return flash_attention(q, k, v, causal, 512, mask_block)
+    return _flash_attention_sharded(q, k, v, mesh=mesh, causal=causal,
+                                    mask_block=mask_block)
 
 
 # Jitted like ring_attention, so that an eager caller (model.init under a
 # mesh) compiles the shard_map once and not once a layer.
-@functools.partial(jax.jit, static_argnames=('mesh', 'causal'))
-def _flash_attention_sharded(q, k, v, mesh, causal):
+@functools.partial(jax.jit, static_argnames=('mesh', 'causal', 'mask_block'))
+def _flash_attention_sharded(q, k, v, mesh, causal, mask_block=1):
     from skypilot_tpu.parallel import sharding as sharding_lib
     rules = dict(sharding_lib.DEFAULT_RULES)
     batch_axes = tuple(a for a in rules['batch'] if a in mesh.shape)
@@ -263,6 +283,7 @@ def _flash_attention_sharded(q, k, v, mesh, causal):
              head_axis if (q.shape[1] % n_heads == 0 and
                            k.shape[1] % n_heads == 0) else None)
     return jax.shard_map(
-        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal),
+        lambda q_, k_, v_: flash_attention(q_, k_, v_, causal, 512,
+                                           mask_block),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
         check_vma=False)(q, k, v)

@@ -20,7 +20,10 @@ accumulation, bf16 out.
 GQA: the `group` query heads of a KV head are `group` query rows of it
 (`mha_reference`'s fold); K and V are contracted as they are stored.  The
 rows are padded to the sublane tile, so MHA's one row a head is 8 rows of
-which one is read.
+which one is read.  A pass over a block (generation by diffusion over
+blocks: R query rows a slot, all of which read the positions below the one
+length, the block's own rows included) folds its R rows in beside them:
+`group * R` rows a KV head, the same tiles, the same bound.
 
 Operand layout: a Mosaic call fixes its operands' layouts, so a program
 that holds this kernel keeps the cache row-major `[B, Hkv, S, D]` with D
@@ -133,20 +136,19 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
                          v_cache: jax.Array, lengths: jax.Array,
                          block: Optional[int] = None,
                          interpret: bool = False) -> jax.Array:
-    """q [B, Hq, 1, D] against k/v [B, Hkv, S, D], positions
-    `< lengths[b]` -> [B, Hq, 1, D]; zeros where `lengths[b]` is zero.
-    `block` defaults to `block_len`'s."""
+    """q [B, Hq, R, D] against k/v [B, Hkv, S, D], positions
+    `< lengths[b]` for every one of the R rows -> [B, Hq, R, D]; zeros
+    where `lengths[b]` is zero.  `block` defaults to `block_len`'s."""
     b, hq, s_q, d = q.shape
     _, hkv, s, _ = k_cache.shape
-    assert s_q == 1, 'the decode step has one query row a head'
     if block is None:
         block = block_len(hkv, d, s, k_cache.dtype.itemsize)
     if block is None or s % block:
         raise ValueError(f'no KV block for Hkv={hkv} D={d} S={s}')
-    group = hq // hkv
+    group = hq // hkv * s_q
     rows = -(-group // _Q_ROWS) * _Q_ROWS
-    # Query head h reads kv head h // group: [B, Hkv, group, D], padded
-    # with rows that are computed and never read.
+    # Query head h reads kv head h // group: [B, Hkv, group (x R), D],
+    # padded with rows that are computed and never read.
     q = jnp.pad(q.reshape(b, hkv, group, d),
                 ((0, 0), (0, 0), (0, rows - group), (0, 0)))
     n_blocks = s // block
@@ -172,4 +174,4 @@ def decode_attention_fwd(q: jax.Array, k_cache: jax.Array,
         name='decode_attention',
         interpret=interpret,
     )(lengths, *resident_tiles(lengths, block), q, k_cache, v_cache)
-    return out[:, :, :group].reshape(b, hq, 1, d)
+    return out[:, :, :group].reshape(b, hq, s_q, d)

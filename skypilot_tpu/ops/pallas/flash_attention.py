@@ -36,7 +36,7 @@ _NEG_INF = -1e30
 
 def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                scale: float, causal: bool, block_q: int, block_k: int,
-               with_lse: bool = False):
+               with_lse: bool = False, mask_block: int = 1):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -52,8 +52,16 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    # Causal: whole block above the diagonal contributes nothing.
-    diag_ok = (not causal) or (kj * block_k <= qi * block_q + block_q - 1)
+    # Causal: whole block above the diagonal contributes nothing.  Under
+    # the mask by blocks (`mask_block` > 1: position i sees j iff
+    # j // mask_block <= i // mask_block) the diagonal is that of the
+    # mask's blocks; at 1 the program is the causal one, as it was.
+    if mask_block > 1:
+        diag_ok = (not causal) or (
+            (kj * block_k) // mask_block <=
+            (qi * block_q + block_q - 1) // mask_block)
+    else:
+        diag_ok = (not causal) or (kj * block_k <= qi * block_q + block_q - 1)
 
     @pl.when(diag_ok)
     def _compute():
@@ -67,6 +75,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                      jax.lax.broadcasted_iota(jnp.int32, s.shape, 0))
             k_pos = (kj * block_k +
                      jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+            if mask_block > 1:
+                q_pos, k_pos = q_pos // mask_block, k_pos // mask_block
             s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
         m_prev = m_scr[:]                              # (bq, 128)
         m_cur = jnp.max(s, axis=-1, keepdims=True)     # (bq, 1)
@@ -95,20 +105,24 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
 
 @functools.partial(jax.jit,
                    static_argnames=('causal', 'block_size', 'interpret',
-                                    'return_residuals'))
+                                    'return_residuals', 'mask_block'))
 def flash_attention_fwd(q: jax.Array,
                         k: jax.Array,
                         v: jax.Array,
                         causal: bool = True,
                         block_size: int = 512,
                         interpret: bool = False,
-                        return_residuals: bool = False):
+                        return_residuals: bool = False,
+                        mask_block: int = 1):
     """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv] → [B,Hq,S,Dv].  GQA via
     head repeat (broadcast, fused by XLA before the kernel).  Dv may
     differ from D (latent attention's prefill: keys of 192, values of
     128); the backward kernels take Dv = D only.  With
     `return_residuals=True` also returns the row logsumexp [B,Hq,S] f32
-    for the backward kernels."""
+    for the backward kernels.  `mask_block` B > 1 (with `causal`) is the
+    mask by blocks of generation by diffusion over blocks: position i sees
+    j iff j // B <= i // B, both ways inside a block and causal from block
+    to block; the backward kernels are causal only."""
     b, hq, s, d = q.shape
     dv = v.shape[-1]
     hkv = k.shape[1]
@@ -126,7 +140,9 @@ def flash_attention_fwd(q: jax.Array,
     grid = (b * hq, s // block_q, s // block_k)
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               with_lse=return_residuals)
+                               with_lse=return_residuals,
+                               **({'mask_block': mask_block}
+                                  if mask_block > 1 else {}))
     out_specs = pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0))
     out_shape = jax.ShapeDtypeStruct((b * hq, s, dv), q.dtype)
     if return_residuals:

@@ -187,6 +187,14 @@ _HELP = {
         'kind="empty" the tiles, one a step, that slots holding no '
         'request did not fetch because the model\'s step was told so '
         '(where it is not, they count from zero into fetched)',
+    'skytpu_engine_block_passes_total':
+        'Generation by blocks: passes over a block, a slot and pass, of '
+        'slots that hold a request, up to the pass that ends it: '
+        'kind="denoise" a pass that unmasks positions of the block by '
+        'the model\'s schedule, kind="commit" the pass over the clean '
+        'block that leaves its K and V in the cache and decides no '
+        'token (this series is also the count of blocks committed).  '
+        'decode_tokens_total over their sum is the tokens a pass yields',
     'skytpu_engine_xla_compile_total':
         'XLA backend compiles observed in this process '
         '(jax.monitoring): increments after engine warmup are '

@@ -139,6 +139,12 @@ SPAN_HELP = {
         'call (attrs: proposed, accepted).  A decode-phase span — '
         'NOT part of the TTFT tiling, which first_token closes before '
         'any verify runs',
+    'engine.blocks':
+        'Generation by blocks: one fetched decode call covering this '
+        'request\'s slot, from its dispatch to its fetch (attrs: the '
+        'passes the slot ran in it up to the request\'s end, the blocks '
+        'committed and the tokens emitted).  A decode-phase span; the '
+        'TTFT tiling closes at the call that commits the first block',
     # ----- engine loop phases (profiler sessions only; never in the ring) ---
     'engine.loop.dispatch':
         'Loop phase: weight-swap install, the decode dispatch and at '

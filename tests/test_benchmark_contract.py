@@ -57,6 +57,10 @@ def test_metric_has_its_reader(metric):
 
 MOE_METRICS = [m['name'] for m in MAN['per_layer']
                if m['name'].startswith('moe_')]
+# Two readers of one counter, by what a serving step is: a token a slot,
+# or (a family whose sizes have a `block`) a pass over a block a slot.
+PER_STEP, PER_PASS = ('moe_experts_touched_per_step',
+                      'moe_experts_touched_per_pass')
 
 
 @pytest.mark.parametrize('metric', MOE_METRICS)
@@ -64,12 +68,24 @@ def test_every_cell_of_an_expert_family_reports_the_expert_metric(metric):
     """A family that holds part of an expert layer says so through
     `touched_experts` (what the `moe_*` readers ask of it): each of its
     cells is in each `moe_*` metric's list, so a new expert cell cannot
-    leave the expert layer unread."""
-    expert_cells = [
-        w['name'] for w in MAN['workloads'] if hasattr(
-            families.load(manifest.config_of(MAN, w['config'])),
-            'touched_experts')]
-    assert len(expert_cells) >= 2 and len(MOE_METRICS) >= 4
-    listed = next(m for m in MAN['per_layer'] if m['name'] == metric)
-    assert set(expert_cells) <= set(listed['workloads']), (metric,
-                                                           expert_cells)
+    leave the expert layer unread.  The experts reached a step are read by
+    the one of the two readers that divides by the step's rows: a cell is
+    in that one's list and not in the other's."""
+    families_of = {
+        w['name']: families.load(manifest.config_of(MAN, w['config']))
+        for w in MAN['workloads']}
+    expert_cells = {name for name, family in families_of.items()
+                    if hasattr(family, 'touched_experts')}
+    by_blocks = {
+        name for name in expert_cells if getattr(families_of[name].dims(
+            manifest.config_of(MAN, manifest.cell(MAN, name)['config'])),
+            'block', None)}
+    assert len(expert_cells) >= 3 and len(MOE_METRICS) >= 5 and by_blocks
+    listed = set(next(m for m in MAN['per_layer']
+                      if m['name'] == metric)['workloads'])
+    if metric == PER_PASS:
+        assert listed == by_blocks, (metric, by_blocks)
+    elif metric == PER_STEP:
+        assert listed == expert_cells - by_blocks, (metric, expert_cells)
+    else:
+        assert expert_cells <= listed, (metric, expert_cells)

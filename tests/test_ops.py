@@ -513,3 +513,91 @@ def test_decode_program_keeps_the_latent_cache_as_the_kernel_reads_it(
         assert fmt.layout.major_to_minor == (0, 1, 2)
     small = min(leaf.nbytes for leaf in jax.tree.leaves(engine._cache))
     assert compiled.memory_analysis().temp_size_in_bytes < small
+
+
+def test_decode_program_of_blocks_keeps_the_cache_as_the_kernel_reads_it(
+        v5e_chip, monkeypatch):
+    """The engine's decode program for generation by blocks (SDAR-MoE at
+    the cell's widths: two layers, 8 slots of 1,408 positions, passes of 4
+    rows a slot) with both decode kernels in it and every layout left to
+    the compiler as `_optimize_layouts` leaves them: the cache comes out
+    row-major [B, Hkv, S, D] as the kernel reads it (a block's 4 rows are
+    written as rows of D over (slot x head, position)), nothing makes a
+    copy of a cache leaf or of an expert stack, and no temporary is as
+    large as a cache leaf.  The prefill under the mask by blocks compiles
+    with the flash kernel in it."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
+    from skypilot_tpu.models import moe as moe_lib
+    from skypilot_tpu.models.sdar_moe import SDARMoE, SDARMoEConfig
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+
+    # `jax.default_backend()` is the CPU here: steer the choices themselves.
+    monkeypatch.setattr(
+        attn_lib, 'decode_kv_block',
+        lambda h, d, s, dtype=jnp.bfloat16, mesh=None: pallas_da.block_len(
+            h, d, s, jnp.dtype(dtype).itemsize))
+    monkeypatch.setattr(
+        moe_lib, 'expert_tile',
+        lambda n_tokens, block, w_gate, mesh=None: None
+        if n_tokens > block else pallas_ge.tile_f(
+            w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
+    monkeypatch.setattr(
+        attn_lib, '_flash_fwd_impl',
+        lambda q, k, v, causal, block_size, mask_block=1:
+        pallas_fa.flash_attention_fwd(q, k, v, causal=causal,
+                                      block_size=block_size,
+                                      mask_block=mask_block))
+    cfg = SDARMoEConfig(vocab_size=8192, n_layers=2, max_seq_len=1408,
+                        remasking='sequential', dtype=jnp.bfloat16,
+                        param_dtype=jnp.bfloat16)
+    model = SDARMoE(cfg)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))['params'])
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=8, steps_per_call=5, prefill_buckets=(1024,)))
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    lens = shapes(engine._lens_d)
+    state = (shapes(params), shapes(engine._cache), shapes(engine._last_d),
+             lens)
+    compiled = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(engine._cache),
+                      autos(engine._last_d), auto, auto, auto),
+        out_shardings=(auto, autos(engine._cache), autos(engine._last_d),
+                       auto)).lower(
+                           *state, lens, shapes(engine._rng)).compile()
+    text = compiled.as_text()
+    # A decode-attention kernel and an expert kernel a layer.
+    assert text.count('custom_call_target="tpu_custom_call"') == 4
+    assert not re.search(r'= bf16\[8,4,1408,128\]\S* (copy|transpose)\(',
+                         text)
+    assert not re.search(r'= bf16\[128,(2048,768|768,2048)\]\S* '
+                         r'(copy|transpose)\(', text)
+    formats, _ = compiled.input_formats
+    for fmt in jax.tree.leaves(formats[1]):
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3)
+    leaf = jax.tree.leaves(engine._cache)[0]
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf.nbytes
+    # The prefill of a group: the flash kernel under the mask by blocks in
+    # the first layer (the last layer's attention feeds logits that a
+    # prefill of blocks does not read, and is not computed).
+    toks = jax.ShapeDtypeStruct((4, 1024), jnp.int32, sharding=v5e_chip)
+    vec = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=v5e_chip)
+    prefill = jax.jit(engine._prefill_raw, donate_argnums=(1, 2, 3)).lower(
+        *state, toks, vec, vec, vec, shapes(engine._rng)).compile()
+    assert prefill.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 1
