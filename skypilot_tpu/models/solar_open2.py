@@ -34,7 +34,13 @@ Prefill runs the recurrence chunk-wise (`kda_chunk` positions a step of a
 `lax.scan`, matrix products inside; `chunk_delta_rule`), decode is one
 update a token (`delta_rule_step`).  Every exponent taken is <= 0: decays
 between two positions of a chunk are formed pairwise, not as a quotient of
-cumulative products, which overflows where a channel forgets fast.
+cumulative products, which overflows where a channel forgets fast.  A
+decode step is bound by the bytes of the float32 state, so on one TPU
+device with head sizes that are multiples of 128 it takes the update
+through one Pallas call that reads each head's tile once and writes it
+once in place (`ops/pallas/delta_rule_step.py`, chosen by
+`kda_step_heads` from what it can see); anything else keeps
+`delta_rule_step`, which XLA runs as several sweeps of the state.
 
 The expert layer is `models/moe.py` `DroplessMoE`: told which experts it
 holds, it routes over all of them and computes its own experts' part.
@@ -120,6 +126,22 @@ def delta_rule_step(state, q, k, v, a, beta):
     return o * (q.shape[-1] ** -0.5), state
 
 
+def kda_step_heads(state: jax.Array, positions: int,
+                   mesh: Optional[Mesh] = None) -> Optional[int]:
+    """The heads one grid step of the decode kernel updates
+    (`ops/pallas/delta_rule_step.py`) for a state like `state` [B, H, dk,
+    dv], or None where the update goes through XLA: a call over more than
+    one position (prefill's `chunk_delta_rule`), off the TPU, under a mesh
+    of several devices (XLA cannot partition a Mosaic call), a state that
+    is not float32, or head sizes the kernel's tiling cannot take."""
+    if (positions != 1 or state.dtype != jnp.float32 or
+            jax.default_backend() != 'tpu' or
+            (mesh is not None and mesh.size > 1)):
+        return None
+    from skypilot_tpu.ops.pallas import delta_rule_step as pallas_dr
+    return pallas_dr.block_heads(*state.shape[1:])
+
+
 def chunk_delta_rule(state, q, k, v, a, beta, chunk: int):
     """S positions, `chunk` at a step.  state [B, H, dk, dv] f32; q, k, a
     [B, S, H, dk]; v [B, S, H, dv]; beta [B, S, H]; S a multiple of
@@ -195,12 +217,14 @@ def _by_rows(fn, rows: int, *args):
 
 
 def kda_mix(seq, a, beta, gate, lengths, state, *, conv_w, a_log, dt_bias,
-            norm_scale, eps: float, chunk: int):
+            norm_scale, eps: float, chunk: int,
+            mesh: Optional[Mesh] = None):
     """The layer between its projections, in float32.  seq [B, taps + S,
     3, H, hd]: q, k and v before the convolution, the taps of earlier
     calls in front; a, gate [B, S, H, hd] and beta [B, S, H] as projected;
     lengths [B]; state [B, H, hd, hd].  Returns (gated output [B, S, H,
-    hd] in seq's type, new state)."""
+    hd] in seq's type, new state).  One position's update is the kernel's
+    where `kda_step_heads` says so."""
     n_taps = conv_w.shape[1]
     s = seq.shape[1] - (n_taps - 1)
     mixed = nn.silu(sum(
@@ -215,8 +239,14 @@ def kda_mix(seq, a, beta, gate, lengths, state, *, conv_w, a_log, dt_bias,
     a = jnp.where(valid[:, :, None, None], a, 0.0)
     beta = jnp.where(valid[:, :, None], beta, 0.0)
     if s == 1:
-        o, state = delta_rule_step(state, q[:, 0], k[:, 0], v[:, 0], a[:, 0],
-                                   beta[:, 0])
+        step = delta_rule_step
+        heads = kda_step_heads(state, s, mesh)
+        if heads is not None:
+            from skypilot_tpu.ops.pallas import delta_rule_step as pallas_dr
+            step = functools.partial(pallas_dr.delta_rule_step_fwd,
+                                     heads=heads)
+        o, state = step(state, q[:, 0], k[:, 0], v[:, 0], a[:, 0],
+                        beta[:, 0])
         o = o[:, None]
     else:
         chunk = min(chunk, s)
@@ -234,6 +264,7 @@ def kda_mix(seq, a, beta, gate, lengths, state, *, conv_w, a_log, dt_bias,
 
 class KimiDeltaAttention(nn.Module):
     cfg: SolarOpen2Config
+    mesh: Optional[Mesh] = None
 
     @nn.compact
     def __call__(self, x: jax.Array, decode: bool,
@@ -277,7 +308,8 @@ class KimiDeltaAttention(nn.Module):
             lengths = jnp.full((b,), s, jnp.int32)
         seq = jnp.concatenate([before, qkv], axis=1)   # [B, taps + S, ...]
         mix = functools.partial(kda_mix, eps=cfg.norm_eps,
-                                chunk=cfg.kda_chunk, **weights)
+                                chunk=cfg.kda_chunk, mesh=self.mesh,
+                                **weights)
         o, s1 = _by_rows(mix, _PREFILL_ROWS, seq, a, beta, gate, lengths, s0) \
             if s > 1 else mix(seq, a, beta, gate, lengths, s0)
         if decode:
@@ -370,7 +402,8 @@ class Block(nn.Module):
         if self.index in cfg.gqa_layers:
             x = x + GatedAttention(cfg, name='attn')(h, positions, decode)
         else:
-            x = x + KimiDeltaAttention(cfg, name='kda')(h, decode, lengths)
+            x = x + KimiDeltaAttention(cfg, self.mesh, name='kda')(
+                h, decode, lengths)
         h = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
                     name='moe_norm')(x)
         return x + moe_lib.DroplessMoE(
@@ -388,8 +421,9 @@ class SolarOpen2(nn.Module):
     S > 1 the logits are those of each row's last valid position alone,
     [B, 1, vocab]."""
     cfg: SolarOpen2Config
-    # The mesh the program is partitioned over, if any: the expert layer's
-    # decode kernel is for one device (models/moe.py `expert_tile`).
+    # The mesh the program is partitioned over, if any: the decode kernels
+    # of the expert layer (models/moe.py `expert_tile`) and of the KDA
+    # state (`kda_step_heads`) are for one device.
     mesh: Optional[Mesh] = None
     # Read by DecodeEngine: why the paged manager, speculation and KV
     # transfer cannot hold this model's cache yet.
@@ -424,9 +458,29 @@ class SolarOpen2(nn.Module):
     def publish_stats(self, stats) -> None:
         """A decode call's summed `stats` collection (host arrays), to the
         /metrics registry: the layers' counts added up, one update."""
+        cfg = self.cfg
         layers = [layer['moe'] for layer in stats.values()]
+        pairs = sum(moe['expert_tokens'][0] for moe in layers)
         moe_lib.publish_routing(
-            self.cfg.held_experts,
-            sum(moe['expert_tokens'][0] for moe in layers),
+            cfg.held_experts, pairs,
             sum(moe['touched'][0] for moe in layers),
             sum(moe['kernel_trips'][0] for moe in layers))
+        # Every expert layer routed each (slot, step) of the call to
+        # `experts_per_token` experts, and every KDA layer updated all its
+        # heads' states at each; who updated is what the program was
+        # traced with.
+        slot_steps = int(pairs.sum()) // (len(layers) * cfg.experts_per_token)
+        publish_state_updates(
+            slot_steps * (cfg.n_layers - len(cfg.gqa_layers)) * cfg.kda_heads,
+            kda_step_heads(jax.ShapeDtypeStruct(
+                (1, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
+                jnp.float32), 1, self.mesh) is not None)
+
+
+def publish_state_updates(head_states: int, by_kernel: bool) -> None:
+    """A decode call's KDA head-states updated (slots x KDA layers x heads
+    x steps), to the /metrics registry under the path that updated them."""
+    from skypilot_tpu.server import metrics as metrics_lib
+    for path, took in (('kernel', by_kernel), ('xla', not by_kernel)):
+        metrics_lib.inc_counter('skytpu_kda_state_updates_total',
+                                float(head_states * took), path=path)

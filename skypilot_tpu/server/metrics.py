@@ -144,6 +144,14 @@ _HELP = {
         'step\'s reached experts), path="loop" the block loop; the two '
         'add up to skytpu_moe_experts_touched_total, and kernel at 0 '
         'says the mechanism did not engage',
+    'skytpu_kda_state_updates_total':
+        'Per-head recurrent states of Kimi Delta Attention layers that '
+        'decode steps updated (slots x KDA layers x heads x steps), by '
+        'who updated them: path="kernel" the Pallas call that reads a '
+        'head\'s tile once and writes it once, path="xla" the sweeps XLA '
+        'makes of delta_rule_step; which one a program took is fixed '
+        'when it is traced, and kernel at 0 says the mechanism did not '
+        'engage',
     'skytpu_moe_expert_tokens_total':
         'Token-expert pairs of decode steps by held expert (its id '
         'among all experts), summed over expert layers: the routing\'s '

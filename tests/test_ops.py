@@ -423,6 +423,64 @@ def test_decode_program_keeps_the_expert_stacks_as_the_kernel_reads_them(
         params['w_gate'].size * 2
 
 
+def test_decode_program_keeps_the_kda_state_as_the_kernel_reads_it(
+        v5e_chip, monkeypatch):
+    """Solar-Open2's Kimi Delta Attention layer at the cell's shapes (32
+    slots, 64 heads of 128 x 128 float32), decode-shaped with the state
+    kernel in it, the cache donated and every layout left to the compiler
+    as `_optimize_layouts` leaves them: one Mosaic call, the state leaf
+    row-major in and out, the new state in the buffer of the old, and no
+    temporary as large as the leaf (134 MB): no copy of it anywhere."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.models import solar_open2 as solar_lib
+    from skypilot_tpu.ops.pallas import delta_rule_step as pallas_dr
+
+    # `jax.default_backend()` is the CPU here: steer the choice itself.
+    monkeypatch.setattr(
+        solar_lib, 'kda_step_heads',
+        lambda state, positions, mesh=None: pallas_dr.block_heads(
+            *state.shape[1:]))
+    layer = solar_lib.KimiDeltaAttention(solar_lib.SolarOpen2Config(
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16))
+    x = jnp.zeros((32, 1, 4096), jnp.bfloat16)
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), x, True, None))
+    params, cache = variables['params'], variables['cache']
+    assert cache['state'].shape == (32, 64, 128, 128)
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=v5e_chip), tree)
+
+    def step(params, cache, x):
+        out, new = layer.apply({'params': params, 'cache': cache}, x, True,
+                               None, mutable=['cache'])
+        return out, new['cache']
+
+    compiled = jax.jit(
+        step, donate_argnums=(1,),
+        in_shardings=(autos(params), autos(cache), auto),
+        out_shardings=(auto, autos(cache))).lower(
+            shapes(params), shapes(cache), shapes(x)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert 'kda_state_update' in text
+    formats_in, _ = compiled.input_formats
+    assert formats_in[1]['state'].layout.major_to_minor == (0, 1, 2, 3)
+    assert compiled.output_formats[1]['state'].layout.major_to_minor == \
+        (0, 1, 2, 3)
+    leaf = cache['state'].size * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < leaf
+    assert compiled.memory_analysis().alias_size_in_bytes >= leaf
+    assert not re.search(r'= f32\[32,64,128,128\]\S* (copy|transpose)\(',
+                         text)
+
+
 def test_pallas_latent_decode_attention_compiles_for_v5e(v5e_chip):
     """The latent decode kernel at openPangu-Ultra-MoE's cell: 32 slots of
     4,736 positions (four tiles of 1,024 and a ragged fifth), 128 heads

@@ -27,7 +27,11 @@ from benchmarks.harness import check, manifest, weights  # noqa: E402
 from benchmarks.reference import solar_open2_ref  # noqa: E402
 from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig  # noqa: E402
 from skypilot_tpu.models import moe as moe_lib  # noqa: E402
-from skypilot_tpu.models.solar_open2 import SolarOpen2Config  # noqa: E402
+from skypilot_tpu.models import solar_open2 as solar_lib  # noqa: E402
+from skypilot_tpu.models.solar_open2 import (SolarOpen2Config,  # noqa: E402
+                                             delta_rule_step,
+                                             kda_step_heads)
+from skypilot_tpu.ops.pallas import delta_rule_step as pallas_dr  # noqa: E402
 
 SEED = 2**31 + 30
 DTYPE = jnp.float32
@@ -50,10 +54,47 @@ def tiny():
 
 
 @pytest.fixture(scope='module')
+def wide(tiny):
+    """`tiny` with the linear layers' heads at the published 128, the
+    size the decode kernel's tiling takes."""
+    family, _, config = tiny
+    config = copy.deepcopy(config)
+    config['linear_attn_config']['head_dim'] = 128
+    return family, family.dims(config), config
+
+
+@pytest.fixture(scope='module')
+def kda_kernel_forced():
+    """The KDA decode kernel wherever its shapes allow, in interpret mode,
+    for the rest of the module: `jax.default_backend()` is the CPU here,
+    so the tests steer the choice themselves (the rule's own cases hold
+    the function they imported).  Heads of 16 still go through XLA."""
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(
+            solar_lib, 'kda_step_heads',
+            lambda state, positions, mesh=None: None if positions != 1
+            else pallas_dr.block_heads(*state.shape[1:]))
+        patch.setattr(
+            pallas_dr, 'delta_rule_step_fwd',
+            functools.partial(pallas_dr.delta_rule_step_fwd, interpret=True))
+        yield
+
+
+@pytest.fixture(scope='module')
+def served_by_kernel(wide, kda_kernel_forced):
+    """`served` at heads of 128 with the kernel in every decode step."""
+    return serve(wide)
+
+
+@pytest.fixture(scope='module')
 def served(tiny):
     """An engine over the seeded weights, and what it answered to prompts
     of every path: alone in a bucket, three of different lengths admitted
     as one padded group, and one longer than the largest bucket."""
+    return serve(tiny)
+
+
+def serve(tiny):
     family, dims, config = tiny
     model = family.serve_model(dims, config, DTYPE)
     params = jax.jit(lambda k: family.make_params(k, dims, DTYPE))(
@@ -80,11 +121,18 @@ def gap_of(tiny, samples):
     return check.served_gap(family, dims, SEED, DTYPE, samples, (64, 6))
 
 
+STEP_PATHS = {'xla': ('tiny', 'served'),
+              'kernel': ('wide', 'served_by_kernel')}
+
+
+@pytest.mark.parametrize('step', list(STEP_PATHS))
 @pytest.mark.parametrize('path', ['alone', 'group', 'chunked'])
-def test_served_tokens_are_the_references(tiny, served, path):
+def test_served_tokens_are_the_references(request, path, step):
     """(a) prefill then decode through DecodeEngine, (b) a padded group of
     different lengths, (c) a chunked prefill: every served token is the
-    reference's own choice, up to float32 rounding."""
+    reference's own choice, up to float32 rounding; with the decode step's
+    state update through XLA and through the kernel."""
+    tiny, served = map(request.getfixturevalue, STEP_PATHS[step])
     samples = served[3][path]
     assert all(len(tokens) == 6 for _, tokens in samples)
     verdict = gap_of(tiny, samples)
@@ -92,11 +140,13 @@ def test_served_tokens_are_the_references(tiny, served, path):
     assert verdict['widest_gap'] < 1e-3, verdict
 
 
-def test_padding_does_not_reach_the_state(tiny, served):
+@pytest.mark.parametrize('step', list(STEP_PATHS))
+def test_padding_does_not_reach_the_state(request, step):
     """One padded prefill of rows of different lengths: the logits at each
     row's last valid position, and the first decode step after it, are the
     reference's for the unpadded row; with the lengths left out (padding
     folded into the state) they are not."""
+    tiny, served = map(request.getfixturevalue, STEP_PATHS[step])
     family, dims, _ = tiny
     _, model, params, _ = served
     rng = np.random.default_rng(5)
@@ -438,6 +488,148 @@ def test_the_trips_counter_says_who_multiplied(monkeypatch):
                 'skytpu_moe_expert_trips_total{path="loop"} 716\n') == 0.0
     assert read('skytpu_moe_expert_trips_total{path="kernel"} 6\n'
                 'skytpu_moe_expert_trips_total{path="loop"} 2\n') == 75.0
+
+
+def kda_step_inputs(seed=0, slots=2, heads=4, size=128):
+    """(state, q, k, v, a, beta) as a decode step's KDA layer meets them:
+    unit q and k, decays in (0, 1), beta in (0, 2)."""
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+
+    def unit(x):
+        return x / jnp.linalg.norm(x, axis=-1, keepdims=True)
+
+    shape = (slots, heads, size)
+    return (0.1 * jax.random.normal(keys[0], shape + (size,), jnp.float32),
+            unit(jax.random.normal(keys[1], shape, jnp.float32)),
+            unit(jax.random.normal(keys[2], shape, jnp.float32)),
+            jax.random.normal(keys[3], shape, jnp.float32),
+            -jnp.exp(jax.random.normal(keys[4], shape, jnp.float32) - 2.0),
+            2.0 * jax.nn.sigmoid(jax.random.normal(keys[5], shape[:2],
+                                                   jnp.float32)))
+
+
+@pytest.mark.parametrize('case', [
+    'a_plain_step', 'a_padded_row_keeps_its_state_bit_for_bit',
+    'beta_two_is_a_reflection', 'a_channel_that_forgets_fast',
+    'three_steps_through_the_aliased_state'])
+def test_the_state_kernel_is_delta_rule_step(case):
+    """The decode kernel (interpret mode) against `delta_rule_step` on
+    seeded float32 inputs, 2 slots x 4 heads of 128 x 128: the output and
+    the new state agree to rounding, and a row with a = 0, beta = 0 (a
+    padded position, an empty slot) keeps its state to the bit."""
+    kernel = jax.jit(functools.partial(pallas_dr.delta_rule_step_fwd,
+                                       interpret=True), donate_argnums=0)
+    state, q, k, v, a, beta = kda_step_inputs()
+    steps = 3 if case == 'three_steps_through_the_aliased_state' else 1
+    if case == 'a_padded_row_keeps_its_state_bit_for_bit':
+        a, beta = a.at[1].set(0.0), beta.at[1].set(0.0)
+    elif case == 'beta_two_is_a_reflection':
+        beta = jnp.full_like(beta, 2.0)
+    elif case == 'a_channel_that_forgets_fast':
+        a = a.at[:, :, ::5].set(-30.0)
+    inputs = [(q, k, v, a, beta)] + [kda_step_inputs(seed=step)[1:]
+                                     for step in range(1, steps)]
+    before = np.asarray(state)
+    want, got = state, jnp.array(state)
+    for step in inputs:
+        want_o, want = delta_rule_step(want, *step)
+        got_o, got = kernel(got, *step)
+        assert got_o.dtype == got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o),
+                                   atol=2e-6, rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-6, rtol=1e-5)
+    assert np.abs(np.asarray(got) - before).max() > 1e-2
+    if case == 'a_padded_row_keeps_its_state_bit_for_bit':
+        np.testing.assert_array_equal(np.asarray(got)[1], before[1])
+        np.testing.assert_array_equal(np.asarray(want)[1], before[1])
+
+
+@pytest.mark.parametrize('why', ['the_cpu', 'a_two_device_mesh',
+                                 'more_than_one_position',
+                                 'a_head_size_that_is_no_multiple_of_128'])
+def test_the_rule_sends_everything_else_to_delta_rule_step(monkeypatch, why):
+    """`kda_step_heads` engages the kernel on one TPU device for one
+    position against a float32 state whose head sizes are multiples of
+    128; the CPU, a mesh of two devices, a prefill's positions and other
+    head sizes keep XLA's `delta_rule_step` / `chunk_delta_rule`, and the
+    layer's jaxpr then holds no `pallas_call`."""
+    from jax.sharding import Mesh
+    # The rule itself, whatever a module fixture has put in its place.
+    monkeypatch.setattr(solar_lib, 'kda_step_heads', kda_step_heads)
+    state = jax.ShapeDtypeStruct((2, 64, 128, 128), jnp.float32)
+    if why != 'the_cpu':
+        monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+        assert kda_step_heads(state, 1) == pallas_dr.block_heads(
+            64, 128, 128) <= 32
+        assert kda_step_heads(state, 1, Mesh(
+            np.array(jax.devices()[:1]), ('expert',))) is not None
+        assert kda_step_heads(jax.ShapeDtypeStruct(
+            state.shape, jnp.bfloat16), 1) is None
+    mesh = (Mesh(np.array(jax.devices()[:2]), ('expert',))
+            if why == 'a_two_device_mesh' else None)
+    positions = 8 if why == 'more_than_one_position' else 1
+    size = 64 if why.startswith('a_head_size') else 128
+    state = jax.ShapeDtypeStruct((2, 4, size, size), jnp.float32)
+    assert kda_step_heads(state, positions, mesh) is None
+    cfg = SolarOpen2Config(
+        vocab_size=64, dim=64, n_layers=1, gqa_layers=(), kda_heads=4,
+        kda_head_dim=size, kda_rank=8, n_experts=4, held_experts=(0, 1),
+        experts_per_token=2, expert_dim=32, dtype=DTYPE, param_dtype=DTYPE)
+    layer = solar_lib.KimiDeltaAttention(cfg, mesh)
+    x = jnp.zeros((2, positions, 64), DTYPE)
+    variables = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0), x, True, None))
+    text = str(jax.make_jaxpr(lambda v, x: layer.apply(
+        v, x, True, None, mutable=['cache']))(variables, x))
+    assert 'pallas_call' not in text
+
+
+def test_the_updates_counter_says_who_updated(tiny, served, monkeypatch):
+    """`publish_stats` counts the KDA head-states a decode call updated
+    (slots x KDA layers x heads x steps) under the path its program took,
+    and the yardstick's reader gives the kernel's share: nothing for a
+    program without the counter, 0 where every step went through XLA."""
+    from benchmarks.harness import reducers
+    from skypilot_tpu.server import metrics as metrics_lib
+    _, model, _, _ = served
+    cfg = model.cfg
+
+    def updates():
+        return {path: float(line.rpartition(' ')[2])
+                for line in metrics_lib.render().splitlines()
+                for path in ('kernel', 'xla')
+                if line.startswith(
+                    f'skytpu_kda_state_updates_total{{path="{path}"}}')}
+
+    def stats_of(slots, steps):
+        pairs = np.zeros(len(cfg.held_experts) + 1, np.int64)
+        pairs[-1] = slots * steps * cfg.experts_per_token
+        return {f'layer_{i}': {'moe': {
+            'expert_tokens': (pairs,), 'touched': (np.int64(0),),
+            'kernel_trips': (np.int64(0),)}} for i in range(cfg.n_layers)}
+
+    kda_layers = cfg.n_layers - len(cfg.gqa_layers)
+    assert (kda_layers, cfg.kda_heads) == (3, 4)
+    before = updates()
+    model.publish_stats(stats_of(4, 3))                 # the CPU: XLA
+    monkeypatch.setattr(solar_lib, 'kda_step_heads', lambda *_: 4)
+    model.publish_stats(stats_of(4, 8))
+    after = updates()
+    assert after['xla'] - before.get('xla', 0.0) == 4 * 3 * 3 * 4
+    assert after['kernel'] - before.get('kernel', 0.0) == 4 * 3 * 4 * 8
+
+    def read(text):
+        monkeypatch.setattr(metrics_lib, 'render', lambda: text)
+        return reducers.reduce_metric('kda_kernel_updates_pct', {})
+
+    assert read('skytpu_moe_experts_touched_total 8\n') is None
+    assert read('skytpu_kda_state_updates_total{path="kernel"} 0\n'
+                'skytpu_kda_state_updates_total{path="xla"} 6144\n') == 0.0
+    assert read('skytpu_kda_state_updates_total{path="kernel"} 6144\n'
+                'skytpu_kda_state_updates_total{path="xla"} 0\n') == 100.0
+    assert read('skytpu_kda_state_updates_total{path="kernel"} 3\n'
+                'skytpu_kda_state_updates_total{path="xla"} 1\n') == 75.0
 
 
 def test_held_parameters_are_the_files_arithmetic_and_the_programs_tree(
