@@ -154,6 +154,12 @@ logger = sky_logging.init_logger(__name__)
 # Flight-recorder request id of the engine.setup.* spans: fixed, so
 # /debug/requests/engine-setup shows what a start's set-up was made of.
 SETUP_REQUEST_ID = 'engine-setup'
+# ... and of the engine.call spans: /debug/requests/engine-loop is the
+# device's timeline by call (SPAN_HELP says how a call's span is cut).
+LOOP_REQUEST_ID = 'engine-loop'
+# A fetch that returns sooner than this found its call already done:
+# the host was late, not the device (SPAN_HELP, engine.call).
+_FETCH_AT_ONCE_S = 0.001
 
 
 def _named(fn, name: str):
@@ -461,7 +467,8 @@ class DecodeEngine:
         self._submit_lock = threading.Lock()
         self._slots: List[Optional[_Slot]] = [None] * config.n_slots
         # In-flight decode call (pipelined loop): (device out, snapshot
-        # of the slots it covers).  Processed one iteration later.
+        # of the slots it covers, its dispatch stamp, its _open_call).
+        # Processed one iteration later.
         self._inflight = None
         # Long prompts (beyond the largest bucket) queue here and go
         # through chunked prefill, one at a time.
@@ -537,6 +544,23 @@ class DecodeEngine:
         self._loop_busy_s = 0.0
         self._loop_device_s = 0.0
         self._loop_idle_s = 0.0
+        # The ledger of device time by call (_open_call / _close_call):
+        # the decode calls dispatched so far (the next one's `seq`), the
+        # programs dispatched since the last of them with the first's
+        # dispatch stamp, the last fetch's return and the call it
+        # fetched, the latest device-bound interval of a call that
+        # carried nothing, the last call's (seconds, carried) while the
+        # next fetch has yet to say whether they were device time, and
+        # the sums since the last flush.
+        self._call_seq = 0
+        self._carried: List[dict] = []
+        self._carried_t0 = 0.0
+        self._call_end: Optional[float] = None
+        self._call_fetched = -1
+        self._decode_call_s = 0.0
+        self._call_pending: Optional[tuple] = None
+        self._device_s: Dict[str, float] = {}
+        self._calls_n = {'device': 0, 'host': 0}
         # K/V positions of the contiguous decode calls since the last
         # flush (_count_kv_positions), the positions a tile of the
         # model's decode attention covers (None: it reads every slot
@@ -2302,6 +2326,7 @@ class DecodeEngine:
             pt_rows[n:] = pt_rows[0]
         prefill = self._prefill_for(bucket, padded_n)
         t0 = time.perf_counter()
+        self._carry(t0, 'prefill', bucket, padded_n, n)
         if self._paged:
             self._cache, self._last_d, self._lens_d = prefill(
                 self.params, self._cache, self._last_d, self._lens_d,
@@ -2344,7 +2369,7 @@ class DecodeEngine:
                                         req.submitted_at, t0)
                 tracing.record_span(req.request_id, 'engine.prefill',
                                     t0, t1, bucket=bucket, slot=slot_id,
-                                    group=len(group))
+                                    group=len(group), call=self._call_seq)
                 req.prefill_end_at = t1
         n_tokens = sum(len(r.prompt_ids) for _, r, _pg in group)
         with self._submit_lock:
@@ -2434,6 +2459,7 @@ class DecodeEngine:
         device->host copy."""
         req = slot.request
         t0 = time.perf_counter()
+        self._carry(t0, 'export')
         leaves = self._export_pages(
             self._cache, jnp.asarray(self._pt_row(slot.pages)))
         t1 = time.perf_counter()
@@ -2493,6 +2519,7 @@ class DecodeEngine:
                                   np.int32)
             scatter_row[:n_kv] = pages[:n_kv]
             row = self._pt_row(pages)
+            self._carry(t0, 'adopt')
             (self._cache, self._last_d,
              self._lens_d) = self._adopt_insert(
                  self._cache, self._last_d, self._lens_d, data,
@@ -2623,6 +2650,7 @@ class DecodeEngine:
             self._chunked = _ChunkedPrefill(req, self._new_scratch())
             return True
         t0 = time.perf_counter()
+        self._carry(t0, 'gather')
         scratch = self._gather_prefix(self._cache,
                                       jnp.asarray(self._pt_row(pages)))
         t1 = time.perf_counter()
@@ -2682,6 +2710,7 @@ class DecodeEngine:
         rid = cp.request.request_id
         if rem > chunk:
             t0 = time.perf_counter()
+            self._carry(t0, 'chunk', chunk)
             buf = np.zeros((1, chunk), np.int32)
             buf[0] = prompt[cp.offset:cp.offset + chunk]
             cp.scratch = self._chunk_for(chunk)(
@@ -2727,6 +2756,7 @@ class DecodeEngine:
                 row = self._pt_row(pages_all)
             bucket = self._bucket(rem)
             t0 = time.perf_counter()
+            self._carry(t0, 'chunk', bucket)
             buf = np.zeros((1, bucket), np.int32)
             buf[0, :rem] = prompt[cp.offset:]
             if self._paged:
@@ -2835,9 +2865,13 @@ class DecodeEngine:
                               hbm_bytes)
         metrics_lib.set_gauge('skytpu_engine_arith_intensity', intensity)
 
-    def _flush_loop_seconds(self) -> None:
+    def _flush_loop_seconds(self, final: bool = False) -> None:
         """The loop-phase sums since the last flush, to the registry
-        (loop thread, at the perf window's cadence and at loop exit)."""
+        (loop thread, at the perf window's cadence and at loop exit:
+        `final`, where the last call's seconds wait for no verdict)."""
+        if final and self._call_pending is not None:
+            self._count_device_time(*self._call_pending)
+            self._call_pending = None
         busy, device, idle = (self._loop_busy_s, self._loop_device_s,
                               self._loop_idle_s)
         self._loop_busy_s = self._loop_device_s = self._loop_idle_s = 0.0
@@ -2862,6 +2896,15 @@ class DecodeEngine:
                 'skytpu_engine_decode_kv_positions_total',
                 float(self._kv_empty), kind='empty')
             self._kv_fetched = self._kv_held = self._kv_empty = 0
+        for program, s in self._device_s.items():
+            metrics_lib.inc_counter('skytpu_engine_device_seconds_total',
+                                    s, program=program)
+        self._device_s = {}
+        for bound, n in self._calls_n.items():
+            if n:
+                metrics_lib.inc_counter('skytpu_engine_calls_total',
+                                        float(n), bound=bound)
+                self._calls_n[bound] = 0
 
     def _sample_gauges(self, n_active: int) -> None:
         """Loop-thread occupancy/queue gauges; skipped when unchanged so
@@ -2899,6 +2942,93 @@ class DecodeEngine:
         metrics_lib.set_gauge(metrics_lib.QUEUED_PREFILL_TOKENS_FAMILY,
                               float(max(sample[2], 0)))
 
+    def _carry(self, t0: float, kind: str, bucket: int = 0, rows: int = 1,
+               held: int = 1) -> None:
+        """A program dispatched at `t0` that is no decode call: it rides
+        in front of the next one, whose engine.call span lists it
+        (`rows` as compiled, `held` of them with a request)."""
+        if not self._carried:
+            self._carried_t0 = t0
+        self._carried.append({'kind': kind, 'bucket': bucket, 'rows': rows,
+                              'held': held})
+
+    def _open_call(self, t_dispatch: float, live: int) -> tuple:
+        """The decode call dispatched at `t_dispatch`, for _close_call
+        at its fetch: (seq, what it carries, the dispatch of the first
+        program of its interval, the slots in its snapshot)."""
+        carried, self._carried = self._carried, []
+        call = (self._call_seq, carried,
+                self._carried_t0 if carried else t_dispatch, live)
+        self._call_seq += 1
+        return call
+
+    def _close_call(self, call: tuple, fetch: tracing.phase) -> None:
+        """One engine.call span at the return of `call`'s fetch (the
+        engine.loop.fetch phase `fetch`).  The interval opens at the
+        previous fetch's return, or at the dispatch of its own first
+        program where that came later: nothing was in flight then, the
+        device was idle until it.  `bound` is `host` where the fetch
+        found the call done.  Three signs, against `alone`, what the
+        latest call that carried nothing took: the fetch returned at
+        once; or the whole interval is under half of `alone`, which the
+        device cannot have run the call in (the fetch before it came
+        back late); or the call carried nothing and the host stayed
+        away, from the last fetch's return to this fetch's start, longer
+        than `alone` (a found-done call's fetch costs 1-4 ms when the
+        interpreter is contended right after a hold, so the first sign
+        misses it).  A call's seconds wait for the next fetch's verdict
+        (_count_device_time)."""
+        seq, carried, opened, live = call
+        start = (opened if self._call_end is None
+                 else max(opened, self._call_end))
+        end = self._call_end = fetch.end
+        self._call_fetched = seq
+        took, alone = end - start, self._decode_call_s
+        before, self._call_pending = self._call_pending, None
+        if took < 0.5 * alone:
+            bound = 'host'
+            if not carried:
+                # The yardstick follows the calls: left too long, it
+                # misjudges a call or two alone and no more.
+                self._decode_call_s = max(took, 0.5 * alone)
+        elif fetch.seconds < _FETCH_AT_ONCE_S or (
+                alone and not carried and took - fetch.seconds > alone):
+            bound = 'host'
+        else:
+            bound = 'device'
+            if before is not None:
+                self._count_device_time(*before)
+            self._call_pending = (took, carried)
+        self._calls_n[bound] += 1
+        tracing.record_span(
+            LOOP_REQUEST_ID, 'engine.call', start, end, seq=seq,
+            steps=self.cfg.steps_per_call, live=live, carried=carried,
+            waited_s=round(fetch.seconds, 6), bound=bound)
+
+    def _count_device_time(self, took: float, carried: List[dict]) -> None:
+        """A device-bound call's seconds by program, for the next flush.
+        Called once the NEXT fetch has waited too: a hold of the loop
+        thread mostly begins inside a fetch (the thread waits there with
+        the interpreter's lock released, and returns when it gets the
+        lock back), so the call before a host-bound one is as long as the
+        hold and no device time either.  A call that carried something
+        gives `decode` what the latest call that carried nothing took
+        and the rest to what it carried, program by program in equal
+        parts."""
+        sums = self._device_s
+        decode = took
+        if carried:
+            decode = min(self._decode_call_s, took)
+            part = (took - decode) / len(carried)
+            for program in carried:
+                kind = program['kind']
+                sums[kind] = sums.get(kind, 0.0) + part
+        else:
+            self._decode_call_s = took
+            metrics_lib.observe_hist('skytpu_engine_decode_call_seconds',
+                                     took)
+        sums['decode'] = sums.get('decode', 0.0) + decode
+
     def _fetch(self, out_d):
         """The ONE device->host fetch of a decode call: (tokens [T+1, B],
         the call's summed `stats` collection or None).  Where the model
@@ -2930,13 +3060,15 @@ class DecodeEngine:
             return 0
         t0 = time.perf_counter()
         with tracing.phase('engine.loop.dispatch') as ph:
+            call = self._open_call(t0, len(active))
             out_d, self._cache, self._last_d, self._lens_d = \
                 self._dispatch_decode()
         self._loop_busy_s += ph.seconds
         with tracing.phase('engine.loop.fetch') as ph:
             out, stats = self._fetch(out_d)  # [T+1, B] — the ONE sync per step
         self._loop_device_s += ph.seconds
-        t1 = time.perf_counter()
+        t1 = ph.end
+        self._close_call(call, ph)
         with tracing.phase('engine.loop.emit') as ph:
             if stats is not None:
                 self.model.publish_stats(stats)
@@ -3004,23 +3136,25 @@ class DecodeEngine:
                 out_d, self._cache, self._last_d, self._lens_d = \
                     self._dispatch_decode()
                 dispatched = (out_d, {i: self._slots[i] for i in active},
-                              t_dispatch)
+                              t_dispatch,
+                              self._open_call(t_dispatch, len(active)))
             chunked = self._step_chunked()   # queues behind the decode call
         self._loop_busy_s += ph.seconds
         out = snapshot = stats = None
         if self._inflight is not None:
-            out_prev, snapshot, t_prev = self._inflight
+            out_prev, snapshot, t_prev, call = self._inflight
             self._inflight = None
             with tracing.phase('engine.loop.fetch') as ph:
                 # (one call late: syncs call k-1 while call k runs)
                 out, stats = self._fetch(out_prev)
             self._loop_device_s += ph.seconds
+            t_fetched = ph.end
+            self._close_call(call, ph)
         with tracing.phase('engine.loop.emit') as ph:
             if stats is not None:
                 self.model.publish_stats(stats)
             if snapshot is not None and self._block:
-                self._process_blocks(out, snapshot,
-                                     (t_prev, time.perf_counter()))
+                self._process_blocks(out, snapshot, (t_prev, t_fetched))
             elif snapshot is not None:
                 self._process_rows(out, snapshot)
             self._release_retiring()
@@ -3153,7 +3287,8 @@ class DecodeEngine:
         # is checked against.
         tracing.record_instant(
             rid, 'engine.first_token', now, slot=i, batch=batch,
-            ttft_s=round(now - slot.request.submitted_at, 6))
+            ttft_s=round(now - slot.request.submitted_at, 6),
+            call=self._call_fetched)
 
     def _emit_token(self, i: int, slot: _Slot, tok: int, batch: int) -> bool:
         """One token of slot i to its request; True when it was the
@@ -3231,7 +3366,7 @@ class DecodeEngine:
                 tracing.record_span(req.request_id, 'engine.blocks',
                                     span[0], span[1], slot=i,
                                     passes=passes, blocks=blocks,
-                                    tokens=tokens)
+                                    tokens=tokens, call=self._call_fetched)
         if emitted:
             metrics_lib.inc_counter('skytpu_engine_decode_tokens_total',
                                     float(emitted))
@@ -3284,7 +3419,7 @@ class DecodeEngine:
         try:
             self._run_loop()
         finally:
-            self._flush_loop_seconds()
+            self._flush_loop_seconds(final=True)
 
     def _run_loop(self):  # skytpu: hot-entry
         idle, seen = False, 0

@@ -185,6 +185,37 @@ _HELP = {
         'on="device" is the one fetch per step (the device is the '
         'bottleneck, as it should be), on="idle" the 1 ms sleeps of '
         'an engine with nothing to do',
+    'skytpu_engine_device_seconds_total':
+        'Seconds of the device by the program it ran, as the HOST '
+        'reckons them, flushed with the loop seconds: the intervals '
+        'between the returns of successive decode fetches (the '
+        'engine.call spans) whose fetch waited, so that its return is '
+        'the moment the device finished, and whose NEXT fetch waited '
+        'too (a hold of the loop thread ends the span before the '
+        'host-bound one late).  It rests on the device running one '
+        'stream in dispatch order and on the loop asking for call k '
+        'with k+1 already dispatched.  program="decode": a '
+        'call that carried nothing, whole; a call that carried '
+        'prefill, chunk, gather, adopt or export programs gives decode '
+        'what the latest call that carried nothing took (at most its '
+        'own interval) and the rest to what it carried, program by '
+        'program in equal parts.  rate(program="prefill") / rate(all) '
+        'is the share of this replica\'s chip that prefill takes',
+    'skytpu_engine_decode_call_seconds':
+        'Device time of one decode call (steps_per_call steps) that '
+        'carried nothing: the engine.call spans that count as device '
+        'time (skytpu_engine_device_seconds_total) and that no prefill, '
+        'chunk or transfer program rode in front of, one observation a '
+        'call',
+    'skytpu_engine_calls_total':
+        'Decode calls fetched, by what the fetch found: bound="device" '
+        'it waited for the device (as it should), bound="host" the '
+        'call was already done (the fetch returned in under a '
+        'millisecond, the interval since the last fetch is under half '
+        'a decode call, or the host stayed away longer than a decode '
+        'call takes): the device had run out of dispatched '
+        'work while the loop thread was held (a profiler\'s stop, a '
+        'long emit, a starved core)',
     'skytpu_engine_decode_kv_positions_total':
         'K/V positions of the contiguous decode calls, slots x positions '
         'x steps, flushed with the loop seconds: kind="held" what the '
@@ -333,6 +364,11 @@ _BUCKETS: Dict[str, Tuple[float, ...]] = {
     'skytpu_engine_inter_token_seconds':
         (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
          0.5, 1.0),
+    # A decode call is steps_per_call steps of 5-17 ms: 10 ms to 1 s,
+    # fine enough below 150 ms to tell a step's tenth.
+    'skytpu_engine_decode_call_seconds':
+        (0.01, 0.015, 0.02, 0.03, 0.04, 0.05, 0.06, 0.07, 0.08, 0.1,
+         0.12, 0.15, 0.2, 0.3, 0.5, 0.75, 1.0, 2.5),
     'skytpu_lb_request_duration_seconds': DEFAULT_BUCKETS,
     # Sub-millisecond floor: local sqlite ops are microseconds, a
     # loaded Postgres round-trip is milliseconds — both tails matter.

@@ -44,6 +44,20 @@ on the device trace's own clock, and it hands its elapsed seconds back
 to the caller, which keeps its own sums.  It never enters the ring: a
 phase per loop iteration would evict the request spans the ring is for.
 
+The engine's CALLS into the device are ring events under the fixed
+request id ``engine-loop`` (beside ``engine-setup``, which holds a
+start's set-up spans): one ``engine.call`` span a fetched decode call,
+an eighth of a step's rate, with the programs that rode in front of it
+(``carried``) and whether the fetch waited (``bound``).  The spans tile
+a busy engine's time, so ``GET /debug/requests/engine-loop?format=chrome``
+is the device's timeline by call with no profiler attached;
+``engine.prefill``, ``engine.first_token`` and ``engine.blocks`` name
+the call (``call`` = its ``seq``) that held a request's prefill program
+or whose fetch carried its token or block.  Their sums are the families
+``skytpu_engine_device_seconds_total{program}``,
+``skytpu_engine_calls_total{bound}`` and the histogram
+``skytpu_engine_decode_call_seconds`` (server/metrics.py).
+
 Knob: ``SKYTPU_TRACE_RING_SIZE`` — events retained per process
 (default 8192; 0 disables recording entirely).
 """
@@ -145,6 +159,38 @@ SPAN_HELP = {
         'passes the slot ran in it up to the request\'s end, the blocks '
         'committed and the tokens emitted).  A decode-phase span; the '
         'TTFT tiling closes at the call that commits the first block',
+    # ----- the engine's ledger of device time (rid "engine-loop") -----------
+    'engine.call':
+        'One fetched decode call and what rode in front of it, as the '
+        'device ran them: from the previous fetch\'s return (or, where '
+        'nothing was in flight when the first program of the interval '
+        'went out, from that program\'s dispatch) to this fetch\'s '
+        'return.  The device runs one stream in dispatch order and the '
+        'pipelined loop asks for call k with k+1 already dispatched, so '
+        'where the fetch waited its return is the moment the device '
+        'finished k and the span is device time.  attrs: seq (calls '
+        'fetched so far), steps (steps_per_call; passes for generation '
+        'by blocks), live (slots in the call\'s snapshot), carried (the '
+        'programs dispatched since the previous decode dispatch, in '
+        'order: kind prefill | chunk | gather | adopt | export, bucket, '
+        'rows as compiled, held = rows that hold a request), waited_s '
+        '(the engine.loop.fetch phase\'s seconds) and bound: "device" '
+        'where the fetch waited, "host" where it found the call done, '
+        'by three signs: it returned at once, in under a millisecond '
+        '(what the copy of a few KB and the interpreter\'s way there '
+        'cost an idle host; a call is 39-136 ms); or the whole interval '
+        'is under half of what the latest call that carried nothing '
+        'took, which the device cannot have run the call in (the fetch '
+        'before it came back late); or the call carried nothing and the '
+        'host stayed away from the last fetch\'s return to this '
+        'fetch\'s start longer than such a call takes (a found-done '
+        'call\'s fetch costs 1-4 ms on a host that has seconds of '
+        'tokens to hand out: measured, PERF.md PR 39).  A host-bound '
+        'span is not device time, the span after it starts late, and '
+        'the span BEFORE it is as long as a hold that began inside its '
+        'fetch (the thread waits there with the interpreter\'s lock '
+        'released, and returns when it gets the lock back): readers, '
+        'and the families, set that one aside too',
     # ----- engine loop phases (profiler sessions only; never in the ring) ---
     'engine.loop.dispatch':
         'Loop phase: weight-swap install, the decode dispatch and at '
@@ -319,14 +365,15 @@ class phase:  # pylint: disable=invalid-name
 
     During a profiler session the phase is a host event on the clock of
     the device trace; with none open it costs an inactive TraceMe and
-    two perf_counter reads.  The elapsed seconds go back to the caller,
+    two perf_counter reads.  The elapsed seconds (and `end`, the second
+    read: where the profiler's event ends too) go back to the caller,
     never to the ring (see the module docstring)."""
-    __slots__ = ('seconds', '_annotation', '_start')
+    __slots__ = ('seconds', 'end', '_annotation', '_start')
 
     def __init__(self, name: str) -> None:
         cls = _trace_annotation()
         self._annotation = cls(name) if cls is not None else None
-        self.seconds = 0.0
+        self.seconds = self.end = 0.0
 
     def __enter__(self) -> 'phase':
         if self._annotation is not None:
@@ -335,7 +382,8 @@ class phase:  # pylint: disable=invalid-name
         return self
 
     def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._start
+        self.end = time.perf_counter()
+        self.seconds = self.end - self._start
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
 

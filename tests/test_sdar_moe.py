@@ -366,6 +366,23 @@ def test_counters_and_span_follow_tokens_and_passes(engines):
     assert sum(e['attrs']['tokens'] for e in spans) == 10
     assert sum(e['attrs']['blocks'] for e in spans) == 3
     assert sum(e['attrs']['passes'] for e in spans) == 13
+    # Each names the fetched call it is a slot's view of (ISSUE 39): the
+    # engine.call span of that seq ends where it ends, and the first
+    # committed block's call is the one the first token names.
+    from skypilot_tpu.inference.engine import LOOP_REQUEST_ID
+    calls = {e['attrs']['seq']: e for e in tracing.events_for(LOOP_REQUEST_ID)
+             if e['name'] == 'engine.call'}
+    seqs = [e['attrs']['call'] for e in spans]
+    assert seqs == sorted(set(seqs)) and set(seqs) <= set(calls)
+    for e in spans:
+        call = calls[e['attrs']['call']]
+        assert e['ts'] + e['dur_ms'] / 1e3 == pytest.approx(
+            call['ts'] + call['dur_ms'] / 1e3, abs=3e-6)
+        assert call['attrs']['steps'] == engine.cfg.steps_per_call
+    first = [e for e in tracing.events_for('blocks-1')
+             if e['name'] == 'engine.first_token']
+    assert first[0]['attrs']['call'] == next(
+        e['attrs']['call'] for e in spans if e['attrs']['blocks'])
     assert request.first_token_at is not None
     assert [e['name'] for e in tracing.events_for('blocks-1')].count(
         'engine.dispatch') == 1
