@@ -16,11 +16,13 @@ TPU-first design:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from skypilot_tpu.inference import kv_quant
@@ -42,11 +44,22 @@ class LlamaConfig:
     dtype: Any = jnp.bfloat16          # compute dtype
     param_dtype: Any = jnp.float32
     remat: bool = True                 # checkpoint each block
-    # What the per-block checkpoint keeps: 'none' recomputes everything
-    # (min HBM), 'dots' saves matmul outputs and recomputes elementwise
-    # only (~flops of a plain fwd in bwd; the right default once flash
-    # attention stopped being the memory hog).
-    remat_policy: str = 'none'         # 'none' | 'dots'
+    # What the per-block checkpoint keeps besides the block's input.
+    # 'fit', the default: what fits in `remat_keep_bytes`, dearest first
+    # (`keep_plan`): the flash kernel's output and logsumexp in every
+    # layer, then q/k/v, gate/up and the post-attention stream a layer at
+    # a time.  'none' keeps nothing and the backward pass runs the whole
+    # forward again (min HBM; also what 'fit' is with no bytes).  'dots'
+    # keeps every matmul's output whatever that takes and recomputes the
+    # elementwise work; it never kept the attention kernel's output (a
+    # Pallas call is no dot), so the flash forward still ran twice.
+    remat_policy: str = 'fit'          # 'fit' | 'none' | 'dots'
+    # Bytes of named activations a device may keep under 'fit'.  None is
+    # "whoever runs the step says": `Trainer` counts what the device has
+    # left beside the state, the gradients and the loss's temporaries
+    # (train/trainer.py activation_budget) and sets it; a model run
+    # without one keeps nothing.
+    remat_keep_bytes: Optional[int] = None
     attention_impl: str = 'flash'      # 'flash' | 'xla' | 'ring'
     # MoE: n_experts > 0 swaps every block's MLP for a top-k
     # mixture-of-experts (models/moe.py); experts shard over the mesh's
@@ -107,6 +120,106 @@ LLAMA_CONFIGS: Dict[str, LlamaConfig] = {
                              n_heads=32, n_kv_heads=32, ffn_dim=11008,
                              rope_theta=10000.0, max_seq_len=4096),
 }
+
+
+# What a block's checkpoint can keep, by group, dearest first: the device
+# time the backward pass spent making a GB of each again on one v5e at
+# 4 x 4,096 tokens (PERF.md section 6, PR 40: the flash kernel's output
+# 82 ms a GB, q/k/v 21, the others by count a little under that).  A
+# group is the names that spare one piece of recompute only together
+# (`ops/attention.py _flash_fwd` names the first).
+KEEP_GROUPS: Dict[str, Tuple[str, ...]] = {
+    'attn_out': ('attn_out', 'attn_lse'),
+    'qkv': ('attn_q', 'attn_k', 'attn_v'),
+    'gate_up': ('mlp_gate', 'mlp_up'),
+    'stream': ('attn_stream',),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class KeepPlan:
+    """What the blocks keep for the backward pass of one step."""
+    layers: Tuple[Tuple[str, ...], ...]  # groups kept, layer by layer
+    kept_bytes: Dict[str, int]           # on one device, by group
+    forward_flops: float                 # the blocks' forward, whole batch
+    recomputed_flops: float              # of it, run again by the backward
+
+    def policy(self, layer: int):
+        names = [n for g in self.layers[layer] for n in KEEP_GROUPS[g]]
+        if not names:
+            return jax.checkpoint_policies.nothing_saveable
+        return jax.checkpoint_policies.save_only_these_names(*names)
+
+
+def device_share(cfg: LlamaConfig, mesh, batch: int,
+                 seq: int) -> Tuple[int, int]:
+    """(tokens of a [batch, seq] step one device holds, the devices that
+    share its heads, FFN columns and vocabulary): tokens divide over the
+    mesh's batch axes and the rest over 'tensor', where they divide at
+    all (as `_constrain_activations` and `flash_attention_on_mesh` shard
+    them)."""
+    mesh_shape = dict(mesh.shape) if mesh is not None else {}
+    shards = math.prod(mesh_shape.get(a, 1)
+                       for a in ('dcn', 'data', 'fsdp', 'expert'))
+    if cfg.attention_impl != 'ring' and batch % shards:
+        shards = 1
+    tp = mesh_shape.get('tensor', 1)
+    if cfg.n_heads % tp or cfg.n_kv_heads % tp:
+        tp = 1
+    return batch * seq // shards, tp
+
+
+def keep_plan(cfg: LlamaConfig, mesh, batch: int, seq: int) -> KeepPlan:
+    """Turn `cfg.remat_keep_bytes` into the groups each block keeps of a
+    [batch, seq] step, and count what the choice costs.
+
+    Bytes are one device's (`device_share`).  Every kept byte is alive
+    at the loss, so which layers keep a group is free: the first ones
+    do.  A group that does not fit is passed over for a smaller one
+    further down.  FLOPs are the whole batch's, two a multiply-add,
+    causal attention at half its square; the block's last matmul
+    (down_proj) is dead in the recompute and never counted in it.
+    """
+    local, tp = device_share(cfg, mesh, batch, seq)
+    tokens = batch * seq
+    act = jnp.dtype(cfg.dtype).itemsize
+    d, f, hd = cfg.dim, cfg.ffn_dim, cfg.head_dim
+    qkv_heads = cfg.n_heads + 2 * cfg.n_kv_heads
+    moe = cfg.n_experts > 0
+    ffn_flops = 2.0 * tokens * d * f * (cfg.moe_top_k if moe else 1)
+    # group -> (bytes a device keeps, FLOPs of the recompute it spares)
+    groups = {
+        'attn_out': (local * cfg.n_heads // tp * (hd * act + 4),
+                     2.0 * batch * cfg.n_heads * seq * seq * hd),
+        'qkv': (local * qkv_heads // tp * hd * act,
+                2.0 * tokens * d * qkv_heads * hd),
+        'gate_up': (local * 2 * f // tp * act, 2 * ffn_flops),
+        'stream': (local * d * act, 2.0 * tokens * cfg.n_heads * hd * d),
+    }
+    forward = sum(flops for _, flops in groups.values()) + ffn_flops
+    can_keep = [g for g in KEEP_GROUPS
+                if not (g == 'attn_out' and cfg.attention_impl != 'flash')
+                and not (g == 'gate_up' and moe)]
+    layers = [[] for _ in range(cfg.n_layers)]
+    if not cfg.remat:
+        # No checkpoint: autodiff keeps these and more, nothing runs twice.
+        layers = [list(groups)] * cfg.n_layers
+    elif cfg.remat_policy == 'dots':
+        # Every matmul's output whatever it takes; never the kernel's.
+        layers = [[g for g in can_keep if g != 'attn_out']] * cfg.n_layers
+    elif cfg.remat_policy == 'fit':
+        left = cfg.remat_keep_bytes or 0
+        for g in can_keep:
+            for kept in layers:
+                if groups[g][0] <= left:
+                    kept.append(g)
+                    left -= groups[g][0]
+    kept_bytes = {g: groups[g][0] * sum(g in kept for kept in layers)
+                  for g in groups}
+    recomputed = sum(flops for kept in layers for g, (_, flops)
+                     in groups.items() if g not in kept)
+    return KeepPlan(tuple(map(tuple, layers)), kept_bytes,
+                    cfg.n_layers * forward, recomputed)
 
 
 def _rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
@@ -216,6 +329,9 @@ class Attention(nn.Module):
         q, k, v = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         q = _rope(q, positions, cfg.rope_theta)
         k = _rope(k, positions, cfg.rope_theta)
+        # As the kernel and its backward pass read them (KEEP_GROUPS).
+        q, k, v = (checkpoint_name(t, name) for t, name in
+                   zip((q, k, v), KEEP_GROUPS['qkv']))
 
         if decode and page_table is not None:
             k, v, attn_out = self._paged_attend(q, k, v, positions,
@@ -440,6 +556,8 @@ class MLP(nn.Module):
                 nn.initializers.lecun_normal(), logical), name=name)
         gate = dense('gate_proj', cfg.ffn_dim, ('embed', 'mlp'))(x)
         up = dense('up_proj', cfg.ffn_dim, ('embed', 'mlp'))(x)
+        gate, up = (checkpoint_name(t, name) for t, name in
+                    zip((gate, up), KEEP_GROUPS['gate_up']))
         return dense('down_proj', cfg.dim, ('mlp', 'embed'))(
             nn.silu(gate) * up)
 
@@ -460,6 +578,7 @@ class Block(nn.Module):
             RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
                     name='attn_norm')(x), positions, decode, page_table,
             live)
+        x = checkpoint_name(x, KEEP_GROUPS['stream'][0])
         if cfg.n_experts > 0:
             from skypilot_tpu.models.moe import MoEMLP
             mlp = MoEMLP(dim=cfg.dim, ffn_dim=cfg.ffn_dim,
@@ -510,14 +629,17 @@ class Llama(nn.Module):
                 nn.initializers.normal(stddev=1.0), ('vocab', 'embed')),
             name='embed')
         x = embed(tokens)
-        block = Block
+        blocks = [Block] * cfg.n_layers
         if cfg.remat and not decode:
-            policy = (jax.checkpoint_policies.nothing_saveable
-                      if cfg.remat_policy == 'none' else
-                      jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-            block = nn.remat(
+            if cfg.remat_policy == 'dots':
+                policies = [jax.checkpoint_policies
+                            .dots_with_no_batch_dims_saveable] * cfg.n_layers
+            else:
+                plan = keep_plan(cfg, self.mesh, *tokens.shape)
+                policies = [plan.policy(i) for i in range(cfg.n_layers)]
+            blocks = [nn.remat(
                 Block, static_argnums=(3,),  # (self, x, positions, decode)
-                policy=policy)
+                policy=policy) for policy in policies]
         # Keep the historical 3-arg call where there is nothing more to
         # pass (the remat wrapper's static_argnums indexing depends on
         # it).
@@ -526,7 +648,7 @@ class Llama(nn.Module):
             more = (page_table, live)
         elif page_table is not None:
             more = (page_table,)
-        for i in range(cfg.n_layers):
+        for i, block in enumerate(blocks):
             x = block(cfg, self.mesh, name=f'layer_{i}')(
                 x, positions, decode, *more)
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,

@@ -44,6 +44,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -217,14 +218,19 @@ def _flash_fwd_impl(q, k, v, causal, block_size, mask_block=1):
 
 
 def _flash_fwd(q, k, v, causal, block_size, mask_block=1):
+    # `out` and `lse` are named for a checkpoint around the caller
+    # (models/llama.py keep_plan): a policy that keeps both keeps the
+    # kernel out of the backward pass; either alone does not, the kernel
+    # makes them together.  Outside a checkpoint a name is the identity.
     if jax.default_backend() == 'tpu':
         from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
         out, lse = pallas_fa.flash_attention_fwd(
             q, k, v, causal=causal, block_size=block_size,
             return_residuals=True, mask_block=mask_block)
-        return out, (q, k, v, out, lse)
+        out = checkpoint_name(out, 'attn_out')
+        return out, (q, k, v, out, checkpoint_name(lse, 'attn_lse'))
     out = mha_reference(q, k, v, causal=causal, mask_block=mask_block)
-    return out, (q, k, v, None, None)
+    return checkpoint_name(out, 'attn_out'), (q, k, v, None, None)
 
 
 def _flash_bwd(causal, block_size, mask_block, residuals, g):

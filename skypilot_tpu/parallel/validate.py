@@ -190,24 +190,18 @@ def validate_placement(accelerator: str,
     state_bytes = _sharded_bytes(abstract, shardings, mesh)
     breakdown['params+optimizer_state'] = state_bytes
 
-    # Gradients are live alongside params during apply_gradients.
+    # The step's temporaries at its fullest moment (the logits at the
+    # loss, or every parameter's gradient beside one block's working
+    # set) and the named activations the blocks keep: the trainer's own
+    # count (train/trainer.py activation_budget spends what this leaves).
     params_bytes = _sharded_bytes(abstract.params, shardings.params, mesh)
-    breakdown['gradients'] = params_bytes
-
-    # Activation estimate (with remat: ~one layer's activations + the
-    # per-layer residual stream checkpoints; without: all layers).
-    batch_per_dev = batch / max(
-        plan.dcn * plan.data * plan.fsdp * plan.expert, 1)
-    hidden_bytes = batch_per_dev * seq * cfg.dim * 2      # bf16
-    ffn_mult = (cfg.ffn_dim / cfg.dim if getattr(cfg, 'ffn_dim', None)
-                else 3.5)
-    per_layer = hidden_bytes * (4 + 2 * ffn_mult) / max(plan.tensor, 1)
-    layers_live = 2 if remat else cfg.n_layers
-    act_bytes = int(hidden_bytes * cfg.n_layers        # residual ckpts
-                    + per_layer * layers_live
-                    + batch_per_dev * seq * cfg.vocab_size * 4
-                    / max(plan.tensor, 1))             # logits f32
-    breakdown['activations_est'] = act_bytes
+    from skypilot_tpu.models.llama import keep_plan
+    from skypilot_tpu.train.trainer import step_temporary_bytes
+    kept = keep_plan(dataclasses.replace(cfg, remat=remat), mesh, batch,
+                     seq).kept_bytes
+    act_bytes = step_temporary_bytes(
+        cfg, mesh, batch, seq, grad_bytes=params_bytes) + sum(kept.values())
+    breakdown['step_temporaries_est'] = act_bytes
 
     if compile:
         from skypilot_tpu.train.trainer import make_sharded_train_step
@@ -236,7 +230,7 @@ def validate_placement(accelerator: str,
                          ma.temp_size_in_bytes)
         mode = 'compiled'
     else:
-        per_device = state_bytes + params_bytes + act_bytes
+        per_device = state_bytes + act_bytes
         mode = 'analytic'
 
     fits = per_device <= hbm * _USABLE_HBM_FRACTION

@@ -281,6 +281,20 @@ _HELP = {
     'skytpu_train_mfu_percent':
         'Estimated model FLOPs utilization: 6N + 6 L s d FLOPs a token '
         '(perf/cost_model.py estimate_mfu) over the slice\'s peak bf16',
+    'skytpu_train_kept_activation_bytes':
+        'Bytes of named activations the blocks\' checkpoints keep on a '
+        'device for the backward pass, by group (what: attn_out / qkv / '
+        'gate_up / stream; models/llama.py keep_plan), chosen once when '
+        'the trainer is built from what the device has left',
+    'skytpu_train_forward_flops_total':
+        'FLOPs of the blocks\' forward pass over the steps logged so '
+        'far, by the model\'s own count (two a multiply-add, causal '
+        'attention at half its square; head and loss not in it)',
+    'skytpu_train_recomputed_flops_total':
+        'Of skytpu_train_forward_flops_total, what the backward pass '
+        'runs a second time under the activations the checkpoints keep '
+        '(0 with everything kept; every matmul but down_proj, and the '
+        'attention kernel, with nothing kept)',
     # ----- training goodput plane (obs/goodput.py) -------------------------
     'skytpu_train_goodput_percent':
         'Share of this run\'s classified wall-clock spent in '

@@ -27,7 +27,17 @@ import optax
 from flax.training import train_state as flax_train_state
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.parallel import sharding as sharding_lib
+
+# The part of a device's memory the activation budget leaves alone.  The
+# runtime reserves the step program's temporaries as one arena that stays
+# reserved between steps, so this is all that is left for arrays made
+# beside the state while the trainer lives (batches in flight, an
+# evaluation's outputs) and for the loaded programs (27-220 MB a step
+# program by the TPU compiler's count).  On a v5e at pretrain-4k the step
+# ran with 37 MB to spare (PERF.md section 6, PR 40); 0.5 GB is a choice.
+_HBM_MARGIN = 1 / 32
 
 
 class TrainState(flax_train_state.TrainState):
@@ -127,8 +137,72 @@ def make_sharded_train_step(
     )
 
 
+def step_temporary_bytes(cfg, mesh, batch: int, seq: int,
+                         grad_bytes: int) -> int:
+    """What one device holds at the fullest moment of a [batch, seq] step
+    of a `LlamaConfig`-shaped model under per-block checkpoints, besides
+    the state and the named activations the blocks keep
+    (`models/llama.py keep_plan`; those are alive all through and add to
+    this byte for byte).  An upper bound by count, held against the TPU
+    compiler's `memory_analysis()` of the whole step by
+    `tests/test_ops.py`.
+
+    The step is fullest at one of two moments.  At the loss: the float32
+    logits beside their gradient in the compute type, with no gradient of
+    a parameter alive yet.  Or in the first block's backward pass: every
+    parameter's gradient beside one block's working set (q, k, v, the
+    attention output and their gradients; gate, up, their product and
+    gradients), the logits long freed.  Either way every block's input
+    is held, with the final norm's input, output and float32 copy.  At
+    the loss the count is within 2% of the compiler's; in the backward
+    pass it is generous (by then the later blocks' inputs are freed, and
+    the compiler's working set is smaller), so a step that is fullest
+    there is handed less than would fit.
+    """
+    tokens, tp = llama_lib.device_share(cfg, mesh, batch, seq)
+    act = jnp.dtype(cfg.dtype).itemsize
+    at_loss = tokens * (cfg.vocab_size // tp) * (4 + act)
+    in_backward = grad_bytes + tokens * (
+        8 * cfg.dim + 6 * cfg.ffn_dim) // tp * act
+    return ((cfg.n_layers + 4) * tokens * cfg.dim * act +
+            max(at_loss, in_backward))
+
+
+def _bytes_limit(device) -> Optional[int]:
+    """What the device says it can hold; None where it says nothing."""
+    return (device.memory_stats() or {}).get('bytes_limit')
+
+
+def activation_budget(cfg, mesh, state: TrainState, batch: int,
+                      seq: int) -> int:
+    """Bytes of named activations the blocks may keep on each device:
+    what the device's limit leaves after the state this device holds, the
+    step's temporaries by count and a margin.  Zero where the device
+    reports no limit (the CPU), so the program there is the one that
+    keeps nothing."""
+    device = mesh.local_devices[0]
+    limit = _bytes_limit(device)
+    if not limit:
+        return 0
+
+    def held(tree) -> int:
+        return sum(shard.data.nbytes for leaf in jax.tree.leaves(tree)
+                   for shard in leaf.addressable_shards
+                   if shard.device == device)
+
+    temporaries = step_temporary_bytes(cfg, mesh, batch, seq,
+                                       grad_bytes=held(state.params))
+    return max(0, int(limit * (1 - _HBM_MARGIN)) - held(state) -
+               temporaries)
+
+
 class Trainer:
-    """Minimal driver: steps, metrics, periodic checkpointing."""
+    """Minimal driver: steps, metrics, periodic checkpointing.
+
+    `sample_tokens` has the shape of the batches `run` will be fed: the
+    state is initialised with it, and what the blocks may keep for the
+    backward pass is counted for it.
+    """
 
     def __init__(self, model: nn.Module, mesh: Mesh, rng: jax.Array,
                  sample_tokens: jax.Array,
@@ -152,18 +226,41 @@ class Trainer:
         self.host = (host if host is not None
                      else f'host{jax.process_index()}')
         self._badput_exported: dict = {}
-        self.model = model
         self.mesh = mesh
         self.state, self.shardings = make_train_state(
             model, mesh, rng, sample_tokens, train_cfg, rules)
+        # A model whose checkpoints keep "what fits" and was not told how
+        # much that is learns it here, from bytes counted on this device.
+        cfg = getattr(model, 'cfg', None)
+        if (getattr(cfg, 'remat_policy', None) == 'fit' and cfg.remat and
+                cfg.remat_keep_bytes is None):
+            budget = activation_budget(cfg, mesh, self.state,
+                                       *sample_tokens.shape)
+            if budget:
+                model = model.clone(cfg=dataclasses.replace(
+                    cfg, remat_keep_bytes=budget))
+                self.state = self.state.replace(apply_fn=model.apply)
+                self.shardings = self.shardings.replace(
+                    apply_fn=model.apply)
+        self.model = model
         self.train_step = make_sharded_train_step(mesh, self.shardings)
-        # A constant of the configuration, for the MFU gauge: counted
-        # once here and not at every log boundary.  None where the
-        # model's cfg is not LlamaConfig-shaped (no MFU gauge then).
+        # Constants of the configuration, for the MFU gauge and the
+        # recompute counters: counted once here and not at every log
+        # boundary.  None where the model's cfg is not LlamaConfig-shaped
+        # (no MFU gauge and no counters then).
         try:
             self._n_params = model.cfg.num_params()
+            self._plan = llama_lib.keep_plan(model.cfg, mesh,
+                                             *sample_tokens.shape)
         except (AttributeError, TypeError):
-            self._n_params = None
+            self._n_params = self._plan = None
+        self._step_tokens = sample_tokens.size
+        if self._plan is not None:
+            from skypilot_tpu.server import metrics as metrics_lib
+            for what in llama_lib.KEEP_GROUPS:
+                metrics_lib.set_gauge('skytpu_train_kept_activation_bytes',
+                                      self._plan.kept_bytes[what],
+                                      what=what)
         self.checkpoint_dir = checkpoint_dir
         self._ckpt_mgr = None
         if checkpoint_dir is not None:
@@ -265,9 +362,7 @@ class Trainer:
                 with tracing.phase('train.export'):
                     elapsed = time.perf_counter() - window_start
                     self._export_throughput(
-                        window_tokens / max(elapsed - window_nonprod,
-                                            1e-9),
-                        batch)
+                        window_tokens, elapsed - window_nonprod, batch)
                     self._export_goodput()
                     if log_fn:
                         m['tokens_per_s'] = tokens_seen / max(
@@ -296,9 +391,7 @@ class Trainer:
                                                 1e-9)
         if window_tokens:
             self._export_throughput(
-                window_tokens / max(end - window_start - window_nonprod,
-                                    1e-9),
-                batch)
+                window_tokens, end - window_start - window_nonprod, batch)
         self._export_goodput()
         return out
 
@@ -322,14 +415,23 @@ class Trainer:
                                         delta, category=cat)
                 self._badput_exported[cat] = total
 
-    def _export_throughput(self, tokens_per_s: float, batch) -> None:
-        """tokens/sec + estimated-MFU gauges (perf/cost_model.py's
-        count).  Models without a LlamaConfig-shaped cfg just skip the
-        MFU gauge."""
+    def _export_throughput(self, tokens: int, seconds: float,
+                           batch) -> None:
+        """A logging window's tokens/sec + estimated-MFU gauges
+        (perf/cost_model.py's count), and its steps' share of the
+        recompute counters (the model's count, `keep_plan`).  Models
+        without a LlamaConfig-shaped cfg just skip all but the first."""
         from skypilot_tpu.perf import cost_model
         from skypilot_tpu.server import metrics as metrics_lib
+        tokens_per_s = tokens / max(seconds, 1e-9)
         metrics_lib.set_gauge('skytpu_train_tokens_per_second',
                               tokens_per_s)
+        if self._plan is not None:
+            steps = tokens / self._step_tokens
+            metrics_lib.inc_counter('skytpu_train_forward_flops_total',
+                                    steps * self._plan.forward_flops)
+            metrics_lib.inc_counter('skytpu_train_recomputed_flops_total',
+                                    steps * self._plan.recomputed_flops)
         cfg = getattr(self.model, 'cfg', None)
         if batch is None or cfg is None or self._n_params is None:
             return

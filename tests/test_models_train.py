@@ -1,9 +1,14 @@
 """Model + sharded-training tests on the virtual 8-device CPU mesh."""
+import collections
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.models.llama import (Llama, LlamaConfig, LLAMA_CONFIGS,
                                        init_params)
 from skypilot_tpu.parallel.mesh import MeshPlan, build_mesh, plan_mesh
@@ -173,3 +178,242 @@ def test_lm_loss_shift():
     tokens = jnp.array([[1, 2, 3, 4]])
     loss = lm_loss(logits, tokens)
     np.testing.assert_allclose(float(loss), np.log(8), rtol=1e-5)
+
+
+# ----- what a block keeps for its backward pass (models/llama.py keep_plan) --
+# float32 throughout, so that a kept value and one computed again differ by
+# rounding only; 'flash' as the training cells run (the XLA branch here).
+KEEP_CFG = dataclasses.replace(CFG, remat=True, dtype=jnp.float32,
+                               attention_impl='flash')
+_EVERYTHING = 10**12
+
+
+def _out_lse_bytes(cfg, mesh, batch, seq):
+    """The bytes that keep `out` + `lse` in every layer and nothing more."""
+    tokens, tp = llama_lib.device_share(cfg, mesh, batch, seq)
+    return (cfg.n_layers * tokens * cfg.n_heads // tp *
+            (cfg.head_dim * jnp.dtype(cfg.dtype).itemsize + 4))
+
+
+def _loss_grads(cfg, variables, tokens, mesh=None):
+    model = Llama(cfg, mesh)
+    return jax.grad(lambda p: lm_loss(model.apply({'params': p}, tokens),
+                                      tokens))(variables['params'])
+
+
+def _primitives(jaxpr, counts=None):
+    """Every equation of a jaxpr and of the jaxprs inside it, by
+    primitive; calls of jitted functions by the function's name as well."""
+    counts = collections.Counter() if counts is None else counts
+    for eqn in jaxpr.eqns:
+        counts[eqn.primitive.name] += 1
+        if 'jaxpr' in eqn.params and 'name' in eqn.params:
+            counts[eqn.params['name']] += 1
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _primitives(sub, counts)
+    return counts
+
+
+@pytest.mark.parametrize('keep', ['nothing', 'out_lse', 'everything'])
+def test_kept_activations_leave_the_gradients_as_they_are(keep):
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                                CFG.vocab_size)
+    budget = {'nothing': 0, 'everything': _EVERYTHING,
+              'out_lse': _out_lse_bytes(KEEP_CFG, None, 2, 32)}[keep]
+    cfg = dataclasses.replace(KEEP_CFG, remat_keep_bytes=budget)
+    plan = llama_lib.keep_plan(cfg, None, 2, 32)
+    assert plan.layers == ({'nothing': (), 'out_lse': ('attn_out',),
+                            'everything': tuple(llama_lib.KEEP_GROUPS)}[keep],
+                           ) * 2
+    variables = init_params(Llama(cfg), jax.random.PRNGKey(0), batch=2,
+                            seq=32)
+    plain = _loss_grads(dataclasses.replace(cfg, remat=False), variables,
+                        tokens)
+    kept = _loss_grads(cfg, variables, tokens)
+    for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(kept)):
+        np.testing.assert_allclose(a, b, rtol=2e-5, atol=1e-7)
+
+
+def test_backward_pass_runs_again_only_what_was_not_kept():
+    """One block's gradient program, by its equations.  With nothing to
+    keep it is the program of 'none' (and of a model nobody gave a
+    budget); with everything kept no matmul runs a second time: the
+    count is that of a model without checkpoints, where 'none' runs q, k,
+    v, gate, up, `o_proj` and the attention's two products again (the
+    last to feed `o_proj`: this branch's backward rule makes them once
+    more for itself either way)."""
+    cfg = dataclasses.replace(KEEP_CFG, n_layers=1)
+    tokens = jnp.zeros((2, 32), jnp.int32)
+    variables = init_params(Llama(cfg), jax.random.PRNGKey(0), batch=2,
+                            seq=32)
+
+    def counts(**kw):
+        c = dataclasses.replace(cfg, **kw)
+        found = _primitives(jax.make_jaxpr(
+            lambda p: _loss_grads(c, {'params': p}, tokens))(
+                variables['params']).jaxpr)
+        del found['name']           # a name is the identity
+        return found
+
+    none = counts(remat_policy='none')
+    assert counts(remat_keep_bytes=0) == none == counts()
+    everything = counts(remat_keep_bytes=_EVERYTHING)
+    plain = counts(remat=False)
+    assert everything['dot_general'] == plain['dot_general']
+    assert none['dot_general'] == plain['dot_general'] + 8
+    # q/k/v alone (no flash call, so they come first): the three
+    # projections are spared and nothing else.
+    qkv_bytes = llama_lib.keep_plan(
+        dataclasses.replace(cfg, remat_keep_bytes=_EVERYTHING), None, 2,
+        32).kept_bytes['qkv']
+    assert counts(remat_keep_bytes=qkv_bytes, attention_impl='xla')[
+        'dot_general'] == counts(remat_policy='none', attention_impl='xla')[
+            'dot_general'] - 3
+
+
+@pytest.mark.parametrize('mesh_shape', [None, (2, 2)],
+                         ids=['one-device', 'fsdp2-tensor2'])
+def test_keep_plan_spends_the_bytes_dearest_first(mesh_shape):
+    """Given bytes, the groups come out in `KEEP_GROUPS`' order, a layer
+    at a time, never past the bytes; on a mesh the bytes are one
+    device's (tokens over fsdp, heads and FFN columns over tensor)."""
+    mesh = None
+    if mesh_shape:
+        mesh = build_mesh(MeshPlan(1, *mesh_shape), jax.devices()[:4])
+    cfg = LlamaConfig(vocab_size=64000, dim=2048, n_layers=8, n_heads=16,
+                      n_kv_heads=16, ffn_dim=5504, max_seq_len=4096)
+    share = 4 if mesh_shape else 1      # 2 ways the tokens x 2 the heads
+    whole = llama_lib.keep_plan(
+        dataclasses.replace(cfg, remat_keep_bytes=_EVERYTHING), mesh, 4,
+        4096)
+    assert whole.kept_bytes == {
+        'attn_out': 545259520 // share, 'qkv': 1610612736 // share,
+        'gate_up': 2885681152 // share,
+        'stream': 536870912 // (2 if mesh_shape else 1)}
+    assert whole.recomputed_flops == 0
+    assert whole.forward_flops == pytest.approx(15.46e12, rel=1e-3)
+    order = list(llama_lib.KEEP_GROUPS)
+    per_layer = {g: b // 8 for g, b in whole.kept_bytes.items()}
+    previous = None
+    for budget in [0, per_layer['attn_out'] * 5, whole.kept_bytes['attn_out'],
+                   10**9 // share, 3 * 10**9 // share, _EVERYTHING]:
+        plan = llama_lib.keep_plan(
+            dataclasses.replace(cfg, remat_keep_bytes=budget), mesh, 4, 4096)
+        assert sum(plan.kept_bytes.values()) <= budget
+        assert all(plan.kept_bytes[g] == per_layer[g] * sum(
+            g in kept for kept in plan.layers) for g in order)
+        counts = [sum(g in kept for kept in plan.layers) for g in order]
+        # Dearest first: a group is begun only once no layer of a dearer
+        # one still fits in what is left.
+        left = budget - sum(plan.kept_bytes.values())
+        for g, n in zip(order, counts):
+            assert n == 8 or per_layer[g] > left
+        for kept in plan.layers:
+            assert list(kept) == [g for g in order if g in kept]
+        if previous is not None:
+            assert plan.recomputed_flops <= previous
+        previous = plan.recomputed_flops
+    nothing = llama_lib.keep_plan(
+        dataclasses.replace(cfg, remat_keep_bytes=0), mesh, 4, 4096)
+    assert 100 * nothing.recomputed_flops / nothing.forward_flops == \
+        pytest.approx(80.89, abs=0.01)
+    assert nothing == llama_lib.keep_plan(cfg, mesh, 4, 4096)  # nobody said
+
+
+def test_kept_names_survive_the_shard_map(monkeypatch):
+    """On a 2 x 2 mesh the flash call goes through `shard_map`: with
+    `out` + `lse` kept the gradient program holds the forward kernel once
+    a layer, with nothing kept twice.  Traced only (the kernel's branch is
+    chosen by the backend's name: steered here), never run."""
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    mesh = build_mesh(MeshPlan(1, 2, 2), jax.devices()[:4])
+    cfg = dataclasses.replace(KEEP_CFG, dtype=jnp.bfloat16)
+    tokens = jnp.zeros((4, 128), jnp.int32)
+    variables = jax.eval_shape(
+        lambda: Llama(dataclasses.replace(cfg, remat=False), mesh).init(
+            jax.random.PRNGKey(0), tokens))
+    import flax.linen as nn
+    params = nn.meta.unbox(variables)['params']
+
+    def kernels(budget):
+        c = dataclasses.replace(cfg, remat_keep_bytes=budget)
+        found = _primitives(jax.make_jaxpr(
+            lambda p: _loss_grads(c, {'params': p}, tokens, mesh))(
+                params).jaxpr)
+        assert found['shard_map'] >= cfg.n_layers
+        return found['flash_attention_fwd']
+
+    assert kernels(0) == 2 * cfg.n_layers
+    assert kernels(_out_lse_bytes(cfg, mesh, 4, 128)) == cfg.n_layers
+
+
+@pytest.mark.parametrize('plan', [MeshPlan(1, 1, 1), MeshPlan(1, 2, 2)],
+                         ids=['one-device', 'fsdp2-tensor2'])
+def test_trainer_hands_the_model_what_the_device_has_left(plan, monkeypatch):
+    """Where the device reports a limit (none does here: one is given),
+    the trainer counts the state's bytes on ONE device, the step's
+    temporaries and a margin, and the model it steps with has the rest
+    as `remat_keep_bytes`; the gauges say what that bought, the counters
+    what the steps still run twice.  With no limit the model is left as
+    it was and keeps nothing.  The losses are the same either way."""
+    from skypilot_tpu.server import metrics as metrics_lib
+    from skypilot_tpu.train import trainer as trainer_lib
+    n = plan.num_devices
+    mesh = build_mesh(plan, jax.devices()[:n])
+    cfg = dataclasses.replace(KEEP_CFG, dtype=jnp.bfloat16)
+    rng = jax.random.PRNGKey(0)
+    tokens = jax.random.randint(rng, (8, 32), 0, cfg.vocab_size)
+    tcfg = TrainConfig(warmup_steps=1, total_steps=10)
+
+    def losses(trainer):
+        got = []
+        trainer.run(iter([tokens] * 3), num_steps=3, log_every=1,
+                    log_fn=lambda m: got.append(float(m['loss'])))
+        return got
+
+    metrics_lib.reset_for_tests()
+    plain = Trainer(Llama(cfg, mesh), mesh, rng, tokens, tcfg)
+    assert plain.model.cfg.remat_keep_bytes is None
+    assert 'skytpu_train_kept_activation_bytes{what="attn_out"} 0' in \
+        metrics_lib.render()
+    base = losses(plain)
+    text = metrics_lib.render()
+    forward = float(re.search(
+        r'^skytpu_train_forward_flops_total (\S+)$', text, re.M).group(1))
+    again = float(re.search(
+        r'^skytpu_train_recomputed_flops_total (\S+)$', text, re.M).group(1))
+    # Two logged steps (the first, the compile, is outside every window).
+    assert forward == pytest.approx(2 * plain._plan.forward_flops)
+    assert again / forward == pytest.approx(
+        plain._plan.recomputed_flops / plain._plan.forward_flops)
+    assert 0.7 < again / forward < 0.85
+
+    # One device's state: all of it alone, about a quarter on the mesh.
+    state_bytes = sum(s.data.nbytes for leaf in jax.tree.leaves(plain.state)
+                      for s in leaf.addressable_shards
+                      if s.device == mesh.local_devices[0])
+    whole = sum(leaf.nbytes for leaf in jax.tree.leaves(plain.state))
+    assert state_bytes == whole if n == 1 else state_bytes < 0.4 * whole
+    params_bytes = sum(
+        s.data.nbytes for leaf in jax.tree.leaves(plain.state.params)
+        for s in leaf.addressable_shards
+        if s.device == mesh.local_devices[0])
+    temporaries = trainer_lib.step_temporary_bytes(cfg, mesh, 8, 32,
+                                                   params_bytes)
+    # Room for `out` + `lse` in both layers and q/k/v in one.
+    per_layer = {g: b // 2 for g, b in llama_lib.keep_plan(
+        dataclasses.replace(cfg, remat_keep_bytes=_EVERYTHING), mesh, 8,
+        32).kept_bytes.items()}
+    want = 2 * per_layer['attn_out'] + per_layer['qkv'] + 16
+    limit = (state_bytes + temporaries + want) * 32 // 31
+    monkeypatch.setattr(trainer_lib, '_bytes_limit', lambda device: limit)
+    metrics_lib.reset_for_tests()
+    fitted = Trainer(Llama(cfg, mesh), mesh, rng, tokens, tcfg)
+    left = int(limit * 31 / 32) - state_bytes - temporaries
+    assert fitted.model.cfg.remat_keep_bytes == left
+    assert fitted._plan.layers == (('attn_out', 'qkv'), ('attn_out',))
+    assert sum(fitted._plan.kept_bytes.values()) <= left
+    text = metrics_lib.render()
+    assert (f'skytpu_train_kept_activation_bytes{{what="qkv"}} '
+            f'{per_layer["qkv"]}\n') in text
+    np.testing.assert_allclose(losses(fitted), base, rtol=2e-2)

@@ -659,3 +659,60 @@ def test_decode_program_of_blocks_keeps_the_cache_as_the_kernel_reads_it(
         *state, toks, vec, vec, vec, shapes(engine._rng)).compile()
     assert prefill.as_text().count(
         'custom_call_target="tpu_custom_call"') == 1
+
+
+@pytest.mark.parametrize('vocab, layers', [(64000, 8), (8000, 4)],
+                         ids=['fullest-at-the-loss', 'fullest-in-backward'])
+def test_train_step_keeps_the_flash_output_and_fits_as_counted(
+        v5e_chip, monkeypatch, vocab, layers):
+    """The WHOLE training step at `pretrain-4k`'s shape (Yi-Coder's widths,
+    8 layers, 4 x 4,096 tokens, float32 state) compiled for the described
+    v5e.  With nothing kept the backward pass runs the flash forward kernel
+    a second time in every layer; with `out` + `lse` kept it is in the
+    program once a layer.  And the bytes the trainer counts for the step's
+    temporaries (`step_temporary_bytes` + what the plan keeps) are not
+    under the compiler's own: at the cell's vocabulary, where the step is
+    fullest at the loss, within 2% of them; at an eighth of it and half
+    the depth, where it is fullest in a block's backward pass, the count
+    is the looser one."""
+    import dataclasses
+    import numpy as np
+    from skypilot_tpu.models import llama as llama_lib
+    from skypilot_tpu.parallel import validate as validate_lib
+    from skypilot_tpu.parallel.mesh import build_mesh, plan_mesh
+    from skypilot_tpu.train import trainer as trainer_lib
+
+    # `jax.default_backend()` is the CPU here: steer the kernel's choice.
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    (device,) = v5e_chip.device_set
+    mesh = build_mesh(plan_mesh(1, fsdp=1), np.array([device]))
+    rows, seq = 4, 4096
+    cfg = llama_lib.LlamaConfig(
+        vocab_size=vocab, dim=2048, n_layers=layers, n_heads=16,
+        n_kv_heads=16,
+        ffn_dim=5504, max_seq_len=seq, attention_impl='flash')
+    tokens = jax.ShapeDtypeStruct((rows, seq), jnp.int32)
+    out_lse = layers * rows * seq * 16 * (128 * 2 + 4)
+
+    def compiled_step(keep_bytes):
+        c = dataclasses.replace(cfg, remat_keep_bytes=keep_bytes)
+        state, shardings = validate_lib._abstract_state(
+            llama_lib.Llama(c, mesh), mesh, tokens)
+        compiled = trainer_lib.make_sharded_train_step(
+            mesh, shardings).lower(state, tokens).compile()
+        forward = sum('flash_attention_fwd)' in line
+                      for line in compiled.as_text().splitlines()
+                      if 'custom_call_target="tpu_custom_call"' in line)
+        counted = trainer_lib.step_temporary_bytes(
+            c, mesh, rows, seq, grad_bytes=4 * c.num_params()) + sum(
+                llama_lib.keep_plan(c, mesh, rows, seq).kept_bytes.values())
+        return forward, compiled.memory_analysis().temp_size_in_bytes, counted
+
+    forward, temporaries, counted = compiled_step(out_lse)
+    assert forward == cfg.n_layers
+    assert temporaries <= counted
+    if vocab == 64000:
+        assert counted <= 1.02 * temporaries
+        forward, temporaries, counted = compiled_step(0)
+        assert forward == 2 * cfg.n_layers
+        assert temporaries <= counted <= 1.02 * temporaries
