@@ -569,6 +569,8 @@ class DecodeEngine:
         self._kv_fetched = 0
         self._kv_held = 0
         self._kv_empty = 0
+        self._window_fetched = 0
+        self._window_context = 0
         kv_block = getattr(model, 'decode_kv_block', None)
         self._kv_block: Optional[int] = kv_block() if kv_block else None
         self._takes_live: bool = getattr(model, 'decode_takes_live', False)
@@ -601,12 +603,18 @@ class DecodeEngine:
         self._init_cache()
         # By kind (perf/cost_model.py): keys and values a position (the
         # leaves named k / v), a latent a position (the leaves the model
-        # names in `latent_leaves`), per-slot recurrent state (the rest).
+        # names in `latent_leaves`), a window layer's ring (those it names
+        # in `window_leaves`), per-slot recurrent state (the rest).
         latent = getattr(self.model, 'latent_leaves', ())
+        window = getattr(self.model, 'window_leaves', ())
         for kind, n_bytes in cost_model_lib.cache_bytes_by_kind(
-                self._cache, latent).items():
+                self._cache, latent, window).items():
             metrics_lib.set_gauge('skytpu_engine_cache_bytes',
                                   float(n_bytes), kind=kind)
+        # The positions a window layer's ring holds (None: the model has
+        # none), for `_count_kv_read`.
+        self._window: Optional[int] = cost_model_lib.window_len(
+            self._cache, window)
         if (jax.default_backend() == 'tpu' and self._mesh is None and
                 not self._paged):
             # The AOT layout pass is specialized to the contiguous
@@ -628,7 +636,7 @@ class DecodeEngine:
             self.model.cfg, jax.tree_util.tree_leaves(self.params),
             self._cache,
             n_chips=self._mesh.size if self._mesh is not None else 1,
-            latent=latent)
+            latent=latent, window=window)
 
     @property
     def healthy(self) -> bool:
@@ -2267,7 +2275,17 @@ class DecodeEngine:
 
     def _count_kv_read(self, read: np.ndarray, held: np.ndarray) -> None:
         """`_count_kv_positions` from `read` [n_slots, steps]: the
-        positions each step's attention reads up to, a slot."""
+        positions each step's attention reads up to, a slot.  Window
+        layers are counted on their own: a step fetches a slot's ring
+        (nothing of a slot without a request where the model's step is
+        told so), against the positions the slot's context holds, which
+        is what a layer that kept the context would have read."""
+        if self._window:
+            rings = read.shape[1] * self._window * (
+                int(held.sum()) if self._takes_live and
+                self._kv_block is not None else read.shape[0])
+            self._window_fetched += rings
+            self._window_context += int(read[held].sum())
         whole = read.size * self.model.cfg.max_seq_len
         self._kv_held += whole
         if self._kv_block is None:
@@ -2896,6 +2914,14 @@ class DecodeEngine:
                 'skytpu_engine_decode_kv_positions_total',
                 float(self._kv_empty), kind='empty')
             self._kv_fetched = self._kv_held = self._kv_empty = 0
+        if self._window_context:
+            metrics_lib.inc_counter(
+                'skytpu_engine_window_kv_positions_total',
+                float(self._window_fetched), kind='fetched')
+            metrics_lib.inc_counter(
+                'skytpu_engine_window_kv_positions_total',
+                float(self._window_context), kind='context')
+            self._window_fetched = self._window_context = 0
         for program, s in self._device_s.items():
             metrics_lib.inc_counter('skytpu_engine_device_seconds_total',
                                     s, program=program)

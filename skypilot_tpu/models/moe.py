@@ -29,7 +29,9 @@ dense params expert-axis-replicated instead.
 Beside it stands the serving path's layer, `DroplessMoE`: one chip's share
 of an expert-parallel layer.  It is told which experts it holds, routes
 over all of them (sigmoid scores, or a softmax over them where the layer
-says so; the k largest, normalised), and returns
+says so; the k largest, normalised; where the layer has a correction bias,
+`router_bias`, the k largest of score + bias, weighted by the scores alone:
+`route_top_k`), and returns
 the held experts' part of the sum plus the shared expert; no capacity, no
 drop, and what the absent experts would add is another chip's.  Its sum,
 `grouped_experts`, has one meaning and two ways to be computed, chosen by
@@ -190,11 +192,21 @@ class MoEMLP(nn.Module):
 
 
 # ----- dropless routing over a held share of the experts --------------------
-def route_top_k(scores: jax.Array, top_k: int, scaling: float = 1.0):
+def route_top_k(scores: jax.Array, top_k: int, scaling: float = 1.0,
+                bias: Optional[jax.Array] = None):
     """scores [T, E] (f32) -> (expert ids [T, k], weights [T, k]): the k
     largest scores of each token, normalised to sum to 1, times
-    `scaling`."""
-    top, idx = jax.lax.top_k(scores, top_k)
+    `scaling`.  With a correction `bias` [E] (`topk_method` `noaux_tc`:
+    a vector learned beside the router to even out the experts' load) the
+    k are those with the largest `scores + bias`, and their weights are
+    still their `scores`, normalised: the bias decides who is chosen and
+    never how much a chosen expert counts.  Without one the program is
+    the one it was."""
+    if bias is None:
+        top, idx = jax.lax.top_k(scores, top_k)
+    else:
+        _, idx = jax.lax.top_k(scores + bias, top_k)
+        top = jnp.take_along_axis(scores, idx, axis=-1)
     return idx, top / jnp.sum(top, axis=-1, keepdims=True) * scaling
 
 
@@ -218,7 +230,8 @@ def expert_tile(n_tokens: int, block: int, w_gate: jax.Array,
 def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
                     local_of: jax.Array, w_gate: jax.Array, w_up: jax.Array,
                     w_down: jax.Array, block: int,
-                    mesh: Optional[Mesh] = None):
+                    mesh: Optional[Mesh] = None,
+                    valid: Optional[jax.Array] = None):
     """sum over the HELD experts e of weight_e * SwiGLU_e(x), for tokens x
     [T, D] routed to `idx` [T, k] with `weights` [T, k].
 
@@ -229,7 +242,10 @@ def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
     that chose it.  An expert nobody chose costs nothing.  Who multiplies
     follows the shape (`expert_tile`): the few tokens of a decode step go
     through one kernel call that streams the reached experts' weights,
-    anything else through the block loop.
+    anything else through the block loop.  `valid` [T] bool, where given,
+    says which tokens are real: the pairs of the others (a padded
+    prompt's rows past its length, which nothing reads) are counted as
+    routed elsewhere and multiplied by nobody.
 
     Returns (out [T, D] float32, counts [n_held + 1] int32: the pairs of
     each held expert, and last the pairs routed elsewhere, and the
@@ -237,6 +253,8 @@ def grouped_experts(x: jax.Array, idx: jax.Array, weights: jax.Array,
     """
     n_held = w_gate.shape[0]
     keys = local_of[idx]                                     # [T, k]
+    if valid is not None:
+        keys = jnp.where(valid[:, None], keys, n_held)
     one_hot = jax.nn.one_hot(keys.reshape(-1), n_held + 1, dtype=jnp.int32)
     counts = jnp.sum(one_hot, axis=0)
     tile = expert_tile(x.shape[0], block, w_gate, mesh)
@@ -313,7 +331,9 @@ class DroplessMoE(nn.Module):
 
     The router scores ALL `n_experts` (float32; `scoring` 'sigmoid', each
     expert on its own, or 'softmax' over all of them), each token takes
-    its `top_k` largest, weighted by score / sum of the k.  This module
+    its `top_k` largest, weighted by score / sum of the k (`router_bias`:
+    the largest of score + the parameter `correction_bias` [n_experts];
+    the weights stay the scores').  This module
     holds the experts `held` (ids into the n_experts) and returns their
     part of the result, `sum over held e of w_e E_e(x)`, plus the shared
     expert: what one chip of an expert-parallel group computes before the
@@ -339,9 +359,13 @@ class DroplessMoE(nn.Module):
     block: int = 256            # pairs a trip of the expert loop
     mesh: Optional[Mesh] = None
     scoring: str = 'sigmoid'    # or 'softmax' over all the experts
+    router_bias: bool = False   # choose by score + a learned bias
 
     @nn.compact
-    def __call__(self, x: jax.Array) -> jax.Array:           # [B, S, D]
+    def __call__(self, x: jax.Array,
+                 valid: Optional[jax.Array] = None) -> jax.Array:
+        """x [B, S, D]; `valid` [B, S] bool (a padded prefill): the rows
+        that are real, None for all of them (`grouped_experts`)."""
         b, s, d = x.shape
         n_held = len(self.held)
         # Router in float32 at full precision: a choice flipped by
@@ -355,7 +379,11 @@ class DroplessMoE(nn.Module):
         scores = score(jnp.dot(
             flat.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        idx, weights = route_top_k(scores, self.top_k, self.routed_scaling)
+        bias = self.param('correction_bias', nn.initializers.zeros,
+                          (self.n_experts,), self.param_dtype).astype(
+                              jnp.float32) if self.router_bias else None
+        idx, weights = route_top_k(scores, self.top_k, self.routed_scaling,
+                                   bias)
 
         def stack(name, shape):
             return self.param(name, nn.initializers.lecun_normal(),
@@ -370,7 +398,8 @@ class DroplessMoE(nn.Module):
                   stack('w_down', (self.ffn_dim, d)))
         out, counts, kernel_trips = grouped_experts(
             xin, idx, weights, jnp.asarray(local_of), *stacks,
-            min(self.block, -(-(b * s) // 8) * 8), self.mesh)
+            min(self.block, -(-(b * s) // 8) * 8), self.mesh,
+            None if valid is None else valid.reshape(b * s))
         self.sow('stats', 'expert_tokens', counts)
         self.sow('stats', 'touched', jnp.sum(counts[:n_held] > 0))
         self.sow('stats', 'kernel_trips', kernel_trips)

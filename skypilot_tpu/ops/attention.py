@@ -27,6 +27,15 @@ backend and on shapes, as
 a chunk against a cache, the paged gather, verify and training keep the
 programs they had, and the kernel's tests have their ground truth.
 
+Two things a window layer brings (models/mimo_v2.py) ride the same three
+functions, off by default so that every other program is the one it was:
+a `window` W (position i sees j iff i - W < j <= i; the flash kernel
+fetches and multiplies only the tiles of the band) and a `sink`, one
+learned logit a query head that joins the softmax's denominator and
+brings no value.  The decode step of such a model has keys wider than
+values, in two leaves (`decode_attention`'s `k_rope`), and keeps a window
+layer's cache as a ring of W positions that it bounds by min(length, W).
+
 `latent_decode_attention` is the same step for latent attention (MLA with
 the up-projection absorbed into the query): every head against one shared
 key a position, the cached latent beside its rotated part, of which the
@@ -55,7 +64,9 @@ def mha_reference(q: jax.Array,
                   scale: Optional[float] = None,
                   segment_positions: Optional[jax.Array] = None,
                   kv_positions: Optional[jax.Array] = None,
-                  mask_block: int = 1) -> jax.Array:
+                  mask_block: int = 1,
+                  window: int = 0,
+                  sink: Optional[jax.Array] = None) -> jax.Array:
     """XLA multi-head attention (numerically the ground truth for the
     Pallas kernel's tests).
 
@@ -63,6 +74,9 @@ def mha_reference(q: jax.Array,
     [B, Sq] / [B, Sk] for causal masking when q/k are *shards* of a longer
     sequence (ring attention uses this).  `mask_block` B > 1 makes the
     causal mask one by blocks: position i sees j iff j // B <= i // B.
+    `window` W > 0 narrows the causal mask to i - W < j <= i.  `sink`
+    [Hq] is a logit a query head in the softmax's denominator: it takes
+    weight and gives no value.
     """
     orig_dtype = q.dtype
     scale = scale if scale is not None else q.shape[-1]**-0.5
@@ -88,19 +102,31 @@ def mha_reference(q: jax.Array,
         if mask_block > 1:
             q_pos, k_pos = q_pos // mask_block, k_pos // mask_block
         mask = q_pos[:, None, :, None] >= k_pos[:, None, None, :]
+        if window:
+            mask &= (q_pos[:, None, :, None] - k_pos[:, None, None, :]
+                     < window)
         if group > 1:
             # The mask is tiled, not the positions: comparing `group`
             # copies of the positions read 1% slower in Yi-6B's decode.
             mask = jnp.tile(mask, (1, 1, group, 1))
         logits = jnp.where(mask, logits, -jnp.inf)
+    if sink is not None:
+        # One more column a row, the head's own logit, dropped again
+        # after the softmax: rows (g, i) of a kv head are query head g's.
+        column = jnp.repeat(sink.astype(jnp.float32).reshape(h_kv, group),
+                            s_q, axis=1)[None, :, :, None]
+        logits = jnp.concatenate([logits, jnp.broadcast_to(
+            column, logits.shape[:3] + (1,))], axis=-1)
     probs = jax.nn.softmax(logits, axis=-1)
+    if sink is not None:
+        probs = probs[..., :-1]
     # Fully-masked rows (possible for ring-attention shards) produce NaN
     # from softmax(-inf row); zero them so the combine step can ignore them.
     probs = jnp.where(jnp.isnan(probs), 0.0, probs)
     out = jnp.einsum('bhqk,bhkd->bhqd', probs.astype(v.dtype), v,
                      preferred_element_type=jnp.float32)
     if group > 1:
-        out = out.reshape(b, h_q, s_q, d)
+        out = out.reshape(b, h_q, s_q, v.shape[-1])
     return out.astype(orig_dtype)
 
 
@@ -121,28 +147,57 @@ def decode_kv_block(n_kv_heads: int, head_dim: int, seq_len: int,
                                jnp.dtype(dtype).itemsize)
 
 
+def unpack_rope_keys(k_rope: jax.Array) -> jax.Array:
+    """The leaf `k_rope` [B, Hkv / 2, S, 2 x R] (two KV heads' rotated
+    values a row, `ops/pallas/decode_attention.py`) as [B, Hkv, S, R]."""
+    b, pairs, s, wide = k_rope.shape
+    return k_rope.reshape(b, pairs, s, 2, wide // 2).transpose(
+        0, 1, 3, 2, 4).reshape(b, 2 * pairs, s, wide // 2)
+
+
+def pack_rope_keys(k_rope: jax.Array) -> jax.Array:
+    """[B, Hkv, S, R] -> the leaf's [B, Hkv / 2, S, 2 x R]."""
+    b, h, s, r = k_rope.shape
+    return k_rope.reshape(b, h // 2, 2, s, r).transpose(
+        0, 1, 3, 2, 4).reshape(b, h // 2, s, 2 * r)
+
+
 def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                      lengths: jax.Array,
-                     mesh: Optional[Mesh] = None) -> jax.Array:
+                     mesh: Optional[Mesh] = None,
+                     q_rope: Optional[jax.Array] = None,
+                     k_rope: Optional[jax.Array] = None,
+                     sink: Optional[jax.Array] = None,
+                     scale: Optional[float] = None) -> jax.Array:
     """The decode step's attention: q [B, Hq, R, D] against the cache
     leaves [B, Hkv, S, D] as they are stored, over the positions
     `< lengths[b]` (the rows written this step included).  R is 1 for a
     step of one token a slot, and a block's length for a pass over a
     block, whose R rows all read the same positions (inside a block
     nothing is masked).  A length of zero is a slot that holds no
-    request: zeros."""
+    request: zeros.  A key wider than its value comes in two leaves:
+    `k_rope` [B, Hkv / 2, S, 2 x Dr] holds its further Dr values, two KV
+    heads' a row, and `q_rope` [B, Hq, R, Dr] the query's; `sink` [Hq]
+    and `scale` (default D ** -0.5) as in `mha_reference`.  A ring of W
+    positions is a cache of S = W whose caller passes min(length, W)."""
     b, h_kv, s, d = k_cache.shape
     block = decode_kv_block(h_kv, d, s, k_cache.dtype, mesh)
     if block is not None:
         from skypilot_tpu.ops.pallas import decode_attention as pallas_da
-        return pallas_da.decode_attention_fwd(q, k_cache, v_cache, lengths,
-                                              block=block)
+        return pallas_da.decode_attention_fwd(
+            q, k_cache, v_cache, lengths, block=block, q_rope=q_rope,
+            k_rope=k_rope, sink=sink, scale=scale)
     q_pos = (lengths - 1)[:, None]
     if q.shape[2] > 1:
         q_pos = jnp.broadcast_to(q_pos, (b, q.shape[2]))
+    if k_rope is not None:
+        q = jnp.concatenate([q, q_rope], axis=-1)
+        k_cache = jnp.concatenate([k_cache, unpack_rope_keys(k_rope)],
+                                  axis=-1)
     return mha_reference(
-        q, k_cache, v_cache, causal=True, segment_positions=q_pos,
-        kv_positions=jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)))
+        q, k_cache, v_cache, causal=True, scale=scale, segment_positions=q_pos,
+        kv_positions=jnp.broadcast_to(jnp.arange(s)[None, :], (b, s)),
+        sink=sink)
 
 
 def latent_kv_block(latent_dim: int, seq_len: int,
@@ -259,7 +314,9 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def flash_attention_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
                             mesh: Optional[Mesh],
                             causal: bool = True,
-                            mask_block: int = 1) -> jax.Array:
+                            mask_block: int = 1,
+                            window: int = 0,
+                            sink: Optional[jax.Array] = None) -> jax.Array:
     """`flash_attention` inside a program partitioned over `mesh`.
 
     XLA cannot partition a Mosaic kernel ("wrap the call in a
@@ -268,7 +325,20 @@ def flash_attention_on_mesh(q: jax.Array, k: jax.Array, v: jax.Array,
     layout the model's activations have.  A dimension its axes do not
     divide stays whole: every device then repeats that work, the answer
     is the same.
+
+    With a `window` or a `sink` (`mha_reference`) it is the forward kernel
+    alone, a serving path: on one TPU device the Pallas kernel, elsewhere
+    (the CPU, a mesh of several devices) the XLA reference, which XLA can
+    partition.
     """
+    if window or sink is not None:
+        if jax.default_backend() == 'tpu' and (mesh is None or
+                                               mesh.size == 1):
+            from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
+            return pallas_fa.flash_attention_fwd(
+                q, k, v, causal=causal, window=window, sink=sink)
+        return mha_reference(q, k, v, causal=causal, window=window,
+                             sink=sink)
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal, 512, mask_block)
     return _flash_attention_sharded(q, k, v, mesh=mesh, causal=causal,

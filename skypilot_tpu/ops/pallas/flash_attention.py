@@ -34,19 +34,37 @@ from jax.experimental.pallas import tpu as pltpu
 _NEG_INF = -1e30
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
+def _band(qi, block_q: int, block_k: int, window: int):
+    """(first, last) K block that q block `qi` sees under a causal window
+    of `window` positions (i sees j iff i - window < j <= i)."""
+    first = jnp.maximum(qi * block_q - (window - 1), 0) // block_k
+    return first, (qi * block_q + block_q - 1) // block_k
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, *rest,
                scale: float, causal: bool, block_q: int, block_k: int,
-               with_lse: bool = False, mask_block: int = 1):
+               with_lse: bool = False, mask_block: int = 1,
+               window: int = 0, sink: bool = False):
+    rest = list(rest)
+    sink_ref = rest.pop(0) if sink else None
+    o_ref = rest.pop(0)
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         lse_ref = None
         m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
-    kj = pl.program_id(2)
+    step = kj = pl.program_id(2)
     n_k = pl.num_programs(2)
+    if window:
+        # The grid's last axis counts the K blocks of a q block's band,
+        # not all of them: step `step` is block first + step (the index
+        # map fetches that one, and the band's last one again past its
+        # end).
+        first, last = _band(qi, block_q, block_k, window)
+        kj = first + step
 
-    @pl.when(kj == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -60,6 +78,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
         diag_ok = (not causal) or (
             (kj * block_k) // mask_block <=
             (qi * block_q + block_q - 1) // mask_block)
+    elif window:
+        diag_ok = kj <= last
     else:
         diag_ok = (not causal) or (kj * block_k <= qi * block_q + block_q - 1)
 
@@ -77,7 +97,10 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                      jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
             if mask_block > 1:
                 q_pos, k_pos = q_pos // mask_block, k_pos // mask_block
-            s = jnp.where(q_pos >= k_pos, s, _NEG_INF)
+            seen = q_pos >= k_pos
+            if window:
+                seen = seen & (q_pos - k_pos < window)
+            s = jnp.where(seen, s, _NEG_INF)
         m_prev = m_scr[:]                              # (bq, 128)
         m_cur = jnp.max(s, axis=-1, keepdims=True)     # (bq, 1)
         m_new = jnp.maximum(m_prev, m_cur)             # broadcast → (bq,128)
@@ -90,10 +113,13 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
             preferred_element_type=jnp.float32)
         m_scr[:] = m_new
 
-    @pl.when(kj == n_k - 1)
+    @pl.when(step == n_k - 1)
     def _finalize():
         # Rows with an all-masked history keep l=0; emit 0 instead of NaN.
         l = l_scr[:, :1]
+        if sink:
+            # The head's sink logit joins the denominator, with no value.
+            l = l + jnp.exp(sink_ref[0][:, :1] - m_scr[:, :1])
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o_ref[0] = (acc_scr[:] / safe_l).astype(o_ref.dtype)
         if lse_ref is not None:
@@ -105,7 +131,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, *rest,
 
 @functools.partial(jax.jit,
                    static_argnames=('causal', 'block_size', 'interpret',
-                                    'return_residuals', 'mask_block'))
+                                    'return_residuals', 'mask_block',
+                                    'window'))
 def flash_attention_fwd(q: jax.Array,
                         k: jax.Array,
                         v: jax.Array,
@@ -113,7 +140,9 @@ def flash_attention_fwd(q: jax.Array,
                         block_size: int = 512,
                         interpret: bool = False,
                         return_residuals: bool = False,
-                        mask_block: int = 1):
+                        mask_block: int = 1,
+                        window: int = 0,
+                        sink=None):
     """q [B,Hq,S,D], k [B,Hkv,S,D], v [B,Hkv,S,Dv] → [B,Hq,S,Dv].  GQA via
     head repeat (broadcast, fused by XLA before the kernel).  Dv may
     differ from D (latent attention's prefill: keys of 192, values of
@@ -122,7 +151,11 @@ def flash_attention_fwd(q: jax.Array,
     for the backward kernels.  `mask_block` B > 1 (with `causal`) is the
     mask by blocks of generation by diffusion over blocks: position i sees
     j iff j // B <= i // B, both ways inside a block and causal from block
-    to block; the backward kernels are causal only."""
+    to block; the backward kernels are causal only.  `window` W > 0 (with
+    `causal`) is a sliding window: i sees j iff i - W < j <= i, and only
+    the K blocks of a q block's band are fetched or multiplied, O(S x W)
+    work.  `sink` [Hq] float32 is a logit a head that joins the softmax's
+    denominator and brings no value.  Both are forward only."""
     b, hq, s, d = q.shape
     dv = v.shape[-1]
     hkv = k.shape[1]
@@ -138,11 +171,30 @@ def flash_attention_fwd(q: jax.Array,
     k3 = k.reshape(b * hq, s, d)
     v3 = v.reshape(b * hq, s, dv)
     grid = (b * hq, s // block_q, s // block_k)
+    options = {'mask_block': mask_block} if mask_block > 1 else {}
+    kv_block = lambda bh, qi, kj: (bh, kj, 0)  # noqa: E731
+    operands = [q3, k3, v3]
+    if window:
+        if not causal or mask_block > 1 or return_residuals:
+            raise ValueError('a window is causal, forward only')
+        # The most K blocks a q block's band holds.
+        n_band = max((qi * block_q + block_q - 1) // block_k -
+                     max(qi * block_q - (window - 1), 0) // block_k + 1
+                     for qi in range(grid[1]))
+        grid = grid[:2] + (n_band,)
+        options['window'] = window
+
+        def kv_block(bh, qi, kj):
+            first, last = _band(qi, block_q, block_k, window)
+            return (bh, jnp.minimum(first + kj, last), 0)
+    if sink is not None:
+        operands.append(jnp.broadcast_to(
+            jnp.tile(sink.astype(jnp.float32), b)[:, None, None],
+            (b * hq, 1, 128)))
+        options['sink'] = True
     kernel = functools.partial(_fa_kernel, scale=scale, causal=causal,
                                block_q=block_q, block_k=block_k,
-                               with_lse=return_residuals,
-                               **({'mask_block': mask_block}
-                                  if mask_block > 1 else {}))
+                               with_lse=return_residuals, **options)
     out_specs = pl.BlockSpec((1, block_q, dv), lambda bh, qi, kj: (bh, qi, 0))
     out_shape = jax.ShapeDtypeStruct((b * hq, s, dv), q.dtype)
     if return_residuals:
@@ -159,9 +211,10 @@ def flash_attention_fwd(q: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi, kj: (bh, qi, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, qi, kj: (bh, kj, 0)),
-            pl.BlockSpec((1, block_k, dv), lambda bh, qi, kj: (bh, kj, 0)),
-        ],
+            pl.BlockSpec((1, block_k, d), kv_block),
+            pl.BlockSpec((1, block_k, dv), kv_block),
+        ] + ([pl.BlockSpec((1, 1, 128), lambda bh, qi, kj: (bh, 0, 0))]
+             if sink is not None else []),
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
@@ -170,12 +223,13 @@ def flash_attention_fwd(q: jax.Array,
             pltpu.VMEM((block_q, dv), jnp.float32),    # output accumulator
         ],
         cost_estimate=pl.CostEstimate(
-            flops=2 * b * hq * s * s * (d + dv) // (2 if causal else 1),
+            flops=(2 * b * hq * s * min(s, window) * (d + dv) if window else
+                   2 * b * hq * s * s * (d + dv) // (2 if causal else 1)),
             bytes_accessed=(q3.size + k3.size + v3.size) * q.dtype.itemsize,
-            transcendentals=b * hq * s * s,
+            transcendentals=b * hq * s * (min(s, window) if window else s),
         ),
         interpret=interpret,
-    )(q3, k3, v3)
+    )(*operands)
     if return_residuals:
         o, lse = out
         return o.reshape(b, hq, s, dv), lse[:, :, 0].reshape(b, hq, s)

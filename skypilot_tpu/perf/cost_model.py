@@ -27,7 +27,7 @@ is cached a position need not be keys and values a head
 (models/openpangu_moe.py: one latent a position and layer).  The
 geometry is therefore read from the engine's CACHE, not from a model
 config's field names, and from the leaves' bytes, not from their axes.
-A leaf is of one of three kinds:
+A leaf is of one of four kinds:
 
 - `k` / `v` by name (anywhere on its path: an int8 pool's data and
   scales lie under them): keys and values per position, [slots or pages,
@@ -35,11 +35,18 @@ A leaf is of one of three kinds:
 - a leaf the model names in `latent_leaves` (the engine hands the names
   on as `latent`): a latent per position, [slots, positions, width], no
   head axis, keys and values the same bytes;
+- a leaf the model names in `window_leaves` (handed on as `window`): keys
+  and values of a window layer, kept as a ring of W positions, [slots, kv
+  heads, W, head_dim]: a step reads min(context, W) of them, whatever the
+  context (models/mimo_v2.py);
 - any other leaf: per-slot state of fixed size, read and written whole
   each step (`state_bytes_per_slot`).
 
 The first two grow with the context: their bytes a position, scales and
-all, are `cache_bytes_per_pos`.  The weight stream is the installed
+all, are `cache_bytes_per_pos`.  A ring's bytes a position are
+`window_bytes_per_pos`, read up to `window_len` positions.  A layer is
+counted once whatever the number of its leaves (a key in two leaves, an
+int8 pool's data and scales).  The weight stream is the installed
 tree's bytes, whatever the layers: for an expert layer it counts every
 held expert, touched or not (an upper bound on that part; the
 benchmark's own count, benchmarks/families/, follows the routing).
@@ -109,36 +116,59 @@ def estimate_mfu(tokens_per_s: float, n_params: int, n_layers: int,
     return 100.0 * achieved_tflops / (peaks.bf16_tflops * max(1, n_chips))
 
 
-def _split_cache(cache, latent: Sequence[str] = ()) -> dict:
-    """The leaves of a cache tree by kind ('kv', 'latent', 'recurrent'):
-    `k` / `v` by name, `latent` the names the model gave its latent
-    leaves (the module's docstring has the rule)."""
-    kinds = {'kv': [], 'latent': [], 'recurrent': []}
+def _split_cache(cache, latent: Sequence[str] = (),
+                 window: Sequence[str] = ()):
+    """(the leaves of a cache tree by kind, each kind's number of layers).
+    The kinds are 'kv', 'latent', 'window' and 'recurrent': `k` / `v` by
+    name, `latent` and `window` the names the model gave those leaves
+    (the module's docstring has the rule).  A kind's layers are the
+    distinct places in the tree at which its names stand."""
+    named = (('kv', {'k', 'v'}), ('latent', set(latent)),
+             ('window', set(window)))
+    kinds = {'kv': [], 'latent': [], 'window': [], 'recurrent': []}
+    places = {kind: set() for kind in kinds}
     for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-        names = {getattr(p, 'key', None) for p in path}
-        kind = ('kv' if names & {'k', 'v'} else
-                'latent' if names & set(latent) else 'recurrent')
+        keys = [getattr(p, 'key', None) for p in path]
+        kind, at = next(((kind, i) for kind, names in named
+                         for i, key in enumerate(keys) if key in names),
+                        ('recurrent', len(keys)))
         kinds[kind].append(leaf)
-    return kinds
+        places[kind].add(tuple(keys[:at]))
+    return kinds, {kind: len(at) for kind, at in places.items()}
 
 
 def _nbytes(leaves) -> int:
     return int(sum(l.size * l.dtype.itemsize for l in leaves))
 
 
-def cache_bytes_by_kind(cache, latent: Sequence[str] = ()) -> dict:
+def cache_bytes_by_kind(cache, latent: Sequence[str] = (),
+                        window: Sequence[str] = ()) -> dict:
     """Bytes of the engine's cache by kind, kinds that hold nothing left
     out: 'kv' (keys and values per position), 'latent' (a latent per
-    position), 'recurrent' (per-slot state of fixed size)."""
-    sized = {kind: _nbytes(leaves)
-             for kind, leaves in _split_cache(cache, latent).items()}
+    position), 'window' (a window layer's ring of keys and values),
+    'recurrent' (per-slot state of fixed size)."""
+    sized = {kind: _nbytes(leaves) for kind, leaves in
+             _split_cache(cache, latent, window)[0].items()}
     return {k: v for k, v in sized.items() if v}
+
+
+def window_len(cache, window: Sequence[str]) -> Optional[int]:
+    """The positions a window layer's ring holds, None where the cache
+    has no such leaf."""
+    rings = _split_cache(cache, window=window)[0]['window']
+    return rings[0].shape[2] if rings else None
 
 
 # Where a per-position leaf keeps its positions: [slots or pages, heads,
 # positions, ...] for keys and values (and an int8 pool's scales),
-# [slots, positions, width] for a latent.
+# [slots, positions, width] for a latent; a ring's positions lie where
+# keys' and values' do.
 _POSITIONS_AXIS = {'kv': 2, 'latent': 1}
+
+
+def _bytes_per_pos(leaves, axis: int) -> float:
+    return float(sum(_nbytes([leaf]) / (leaf.shape[0] * leaf.shape[axis])
+                     for leaf in leaves))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -165,12 +195,19 @@ class EngineCostModel:
     # (a recurrent layer's matrix and taps), summed over layers.
     n_kv_layers: Optional[int] = None
     state_bytes_per_slot: float = 0.0
+    # Window layers: how many, the positions a ring holds, and the bytes
+    # a ring position takes, summed over them.  A step reads
+    # min(context, window_len) positions of each.
+    n_window_layers: int = 0
+    window_len: int = 0
+    window_bytes_per_pos: float = 0.0
 
     @classmethod
     def from_engine_state(cls, cfg, param_leaves: Sequence,
                           cache, n_chips: int = 1,
                           chip: Optional[str] = None,
-                          latent: Sequence[str] = ()) -> 'EngineCostModel':
+                          latent: Sequence[str] = (),
+                          window: Sequence[str] = ()) -> 'EngineCostModel':
         """Build from live engine state: the model's config (its
         parameter count, depth and width), the weight tree's leaves and
         the cache TREE.  A per-position leaf of any kind gives its bytes
@@ -179,22 +216,21 @@ class EngineCostModel:
         so the pool's element width is counted, not declared); the
         other leaves give the per-slot state.  Reads only leaf METADATA
         (shape/dtype) — never leaf values, so no device sync."""
-        kinds = _split_cache(cache, latent)
-        per_pos = sum(
-            _nbytes([leaf]) / (leaf.shape[0] * leaf.shape[axis])
-            for kind, axis in _POSITIONS_AXIS.items()
-            for leaf in kinds[kind])
-        # Two leaves a layer of either kind: K and V, or the latent and
-        # its rotated part (a quantized pool's scales are 3-d).
-        n_kv_layers = (sum(len(l.shape) == 4 for l in kinds['kv']) +
-                       len(kinds['latent'])) // 2
-        state = kinds['recurrent']
+        kinds, layers = _split_cache(cache, latent, window)
+        per_pos = sum(_bytes_per_pos(kinds[kind], axis)
+                      for kind, axis in _POSITIONS_AXIS.items())
+        state, rings = kinds['recurrent'], kinds['window']
         slots = state[0].shape[0] if state else 1
         return cls(n_params=cfg.num_params(), n_layers=cfg.n_layers,
                    dim=cfg.dim, param_bytes=_nbytes(param_leaves),
-                   cache_bytes_per_pos=float(per_pos), n_chips=n_chips,
-                   chip=chip or chip_kind(), n_kv_layers=n_kv_layers,
-                   state_bytes_per_slot=_nbytes(state) / slots)
+                   cache_bytes_per_pos=per_pos, n_chips=n_chips,
+                   chip=chip or chip_kind(),
+                   n_kv_layers=layers['kv'] + layers['latent'],
+                   state_bytes_per_slot=_nbytes(state) / slots,
+                   n_window_layers=layers['window'],
+                   window_len=rings[0].shape[2] if rings else 0,
+                   window_bytes_per_pos=_bytes_per_pos(
+                       rings, _POSITIONS_AXIS['kv']))
 
     # ----- FLOPs -----------------------------------------------------
     def decode_flops_per_token(self, context_len: float) -> float:
@@ -204,8 +240,9 @@ class EngineCostModel:
         over the layers that attend over a cache.  N is what the
         model's config counts as held; for an expert layer that is
         every held expert, not the few a token meets."""
-        return 2.0 * self.n_params + \
-            2.0 * self._kv_layers() * context_len * self.dim
+        return 2.0 * self.n_params + 2.0 * self.dim * (
+            self._kv_layers() * context_len +
+            self.n_window_layers * min(context_len, self.window_len))
 
     # ----- HBM bytes -------------------------------------------------
     def _kv_layers(self) -> int:
@@ -226,8 +263,10 @@ class EngineCostModel:
         this sequence's KV history read and its one-position write,
         plus its recurrent state read and written whole."""
         weights = self.param_bytes / max(1, n_active)
-        kv_read = self.kv_bytes_per_pos() * context_len
-        kv_write = self.kv_bytes_per_pos()
+        kv_read = (self.kv_bytes_per_pos() * context_len +
+                   self.window_bytes_per_pos *
+                   min(context_len, self.window_len))
+        kv_write = self.kv_bytes_per_pos() + self.window_bytes_per_pos
         return (weights + kv_read + kv_write +
                 2.0 * self.state_bytes_per_slot)
 
@@ -273,7 +312,9 @@ class EngineCostModel:
         peak_flops, hbm = self._peaks()
         if peak_flops <= 0 or hbm <= 0:
             return 0.0
-        fl = bucket * (2.0 * self.n_params +
-                       2.0 * self._kv_layers() * (bucket / 2.0) * self.dim)
-        by = self.param_bytes + self.kv_bytes_per_pos() * bucket
+        fl = bucket * (2.0 * self.n_params + 2.0 * self.dim * (
+            self._kv_layers() * (bucket / 2.0) +
+            self.n_window_layers * min(bucket / 2.0, self.window_len)))
+        by = (self.param_bytes + self.kv_bytes_per_pos() * bucket +
+              self.window_bytes_per_pos * min(bucket, self.window_len))
         return max(fl / peak_flops, by / hbm)

@@ -125,7 +125,9 @@ _HELP = {
         'Bytes of the engine\'s cache by kind, set once at build: '
         'kind="kv" keys and values per position, kind="latent" a '
         'latent per position in their place (latent attention: one '
-        'vector a layer, no head axis), kind="recurrent" per-slot '
+        'vector a layer, no head axis), kind="window" a window layer\'s '
+        'keys and values, a ring of its window\'s positions a slot '
+        'whatever the context, kind="recurrent" per-slot '
         'state of fixed size (a linear-attention layer\'s matrix and '
         'convolution taps) — a kind that holds nothing is absent',
     'skytpu_moe_pairs_total':
@@ -226,6 +228,16 @@ _HELP = {
         'kind="empty" the tiles, one a step, that slots holding no '
         'request did not fetch because the model\'s step was told so '
         '(where it is not, they count from zero into fetched)',
+    'skytpu_engine_window_kv_positions_total':
+        'K/V positions of a window layer in the contiguous decode calls, '
+        'slots x positions x steps, flushed with the loop seconds (a '
+        'model whose cache has no ring has no such series): '
+        'kind="fetched" what a window layer\'s attention asks for, a '
+        'slot\'s ring of its window\'s positions a step (nothing of a '
+        'slot that holds no request where the model\'s step is told so); '
+        'kind="context" the positions the slots\' contexts held at '
+        'those steps, which a layer that kept the whole context would '
+        'have read',
     'skytpu_engine_block_passes_total':
         'Generation by blocks: passes over a block, a slot and pass, of '
         'slots that hold a request, up to the pass that ends it: '

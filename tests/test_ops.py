@@ -716,3 +716,255 @@ def test_train_step_keeps_the_flash_output_and_fits_as_counted(
         forward, temporaries, counted = compiled_step(0)
         assert forward == 2 * cfg.n_layers
         assert temporaries <= counted <= 1.02 * temporaries
+
+
+# ----- a window, a sink, keys wider than values (models/mimo_v2.py) ----------
+@pytest.mark.parametrize('hkv', [2, 4])
+def test_the_decode_kernel_takes_a_key_in_two_leaves_and_a_sink(hkv):
+    """The decode kernel (interpret mode) at MiMo-V2's head sizes, a key of
+    128 unrotated + 64 rotated values (the 64 of two KV heads a row of the
+    second leaf) against a value of 128, with a sink a query head, against
+    the XLA path: over a context cache bounded by the length, and over a
+    ring of 128 bounded by min(length, 128); an empty slot gives zeros;
+    without a sink the denominator is the plain one."""
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    b, hq, s, dn, dr, dv = 3, 8, 256, 128, 64, 128
+    keys = jax.random.split(jax.random.PRNGKey(13), 6)
+    q = jax.random.normal(keys[0], (b, hq, 1, dn), jnp.float32)
+    q_rope = jax.random.normal(keys[1], (b, hq, 1, dr), jnp.float32)
+    k = jax.random.normal(keys[2], (b, hkv, s, dn), jnp.float32)
+    k_rope = attn_lib.pack_rope_keys(
+        jax.random.normal(keys[3], (b, hkv, s, dr), jnp.float32))
+    v = jax.random.normal(keys[4], (b, hkv, s, dv), jnp.float32)
+    sink = jax.random.normal(keys[5], (hq,), jnp.float32)
+    assert k_rope.shape == (b, hkv // 2, s, 2 * dr)
+    lengths = jnp.asarray([200, 0, 12], jnp.int32)
+    scale = (dn + dr) ** -0.5
+    for more in (dict(sink=sink), dict(sink=None)):
+        more.update(q_rope=q_rope, k_rope=k_rope, scale=scale)
+        want = attn_lib.decode_attention(q, k, v, lengths, **more)  # XLA
+        got = pallas_da.decode_attention_fwd(q, k, v, lengths, block=128,
+                                             interpret=True, **more)
+        assert got.shape == (b, hq, 1, dv)
+        assert jnp.max(jnp.abs(got - want)) < 5e-3
+        assert not jnp.any(got[1])
+    # By hand for slot 2, head 5 (KV head 5 // group): 12 positions and
+    # the sink's term in the denominator.
+    kv_head = 5 // (hq // hkv)
+    key_rows = jnp.concatenate(
+        [k[2, kv_head, :12],
+         attn_lib.unpack_rope_keys(k_rope)[2, kv_head, :12]], axis=-1)
+    a = jnp.concatenate([q[2, 5, 0], q_rope[2, 5, 0]]) @ key_rows.T * scale
+    e = jnp.exp(a - a.max())
+    by_hand = (e / (e.sum() + jnp.exp(sink[5] - a.max()))) @ v[2, kv_head,
+                                                               :12]
+    got = pallas_da.decode_attention_fwd(
+        q, k, v, lengths, block=128, interpret=True, q_rope=q_rope,
+        k_rope=k_rope, sink=sink, scale=scale)
+    assert jnp.max(jnp.abs(got[2, 5, 0] - by_hand)) < 5e-3
+    # A ring of 128: every row is read once the sequence has passed it.
+    ring = lambda t: t[:, :, :128]  # noqa: E731
+    bound = jnp.minimum(jnp.asarray([700, 90, 128], jnp.int32), 128)
+    got = pallas_da.decode_attention_fwd(
+        q, ring(k), ring(v), bound, interpret=True, q_rope=q_rope,
+        k_rope=ring(k_rope), sink=sink, scale=scale)
+    want = attn_lib.decode_attention(q, ring(k), ring(v), bound,
+                                     q_rope=q_rope, k_rope=ring(k_rope),
+                                     sink=sink, scale=scale)
+    assert jnp.max(jnp.abs(got - want)) < 5e-3
+
+
+@pytest.mark.parametrize('window, block', [(128, 128), (128, 256), (96, 128),
+                                           (300, 128)])
+def test_the_flash_kernel_takes_a_window_and_a_sink(window, block):
+    """The flash kernel (interpret mode) under a sliding window with a
+    sink a head, keys of 192 against values of 128, against
+    `mha_reference`; and the window alone.  The grid's last axis is the
+    band's K blocks, not the sequence's."""
+    keys = jax.random.split(jax.random.PRNGKey(17), 4)
+    b, hq, hkv, s = 1, 4, 2, 512
+    q = jax.random.normal(keys[0], (b, hq, s, 192), jnp.float32)
+    k = jax.random.normal(keys[1], (b, hkv, s, 192), jnp.float32)
+    v = jax.random.normal(keys[2], (b, hkv, s, 128), jnp.float32)
+    sink = jax.random.normal(keys[3], (hq,), jnp.float32)
+    for more in (dict(sink=sink), {}):
+        want = mha_reference(q, k, v, causal=True, window=window, **more)
+        got = flash_attention_fwd(q, k, v, causal=True, block_size=block,
+                                  interpret=True, window=window, **more)
+        assert got.shape == (b, hq, s, 128)
+        assert jnp.max(jnp.abs(got - want)) < 2e-3
+    whole = mha_reference(q, k, v, causal=True)
+    assert jnp.max(jnp.abs(whole - want)) > 1e-2
+    text = flash_attention_fwd.lower(q, k, v, causal=True, block_size=block,
+                                     interpret=True, window=window).as_text()
+    full = flash_attention_fwd.lower(q, k, v, causal=True, block_size=block,
+                                     interpret=True).as_text()
+    assert text != full
+
+
+def test_without_a_window_or_a_sink_every_program_is_the_one_it_was():
+    """Yi's and SDAR's decode and prefill attention, and the causal flash
+    kernel with its residuals: `window` 0, `sink` None and the decode
+    kernel's further operands left out lower to the same text as with
+    them given at those values, in the kernels and in the XLA paths (the
+    parent's text itself was compared by hand: PERF.md section 6, PR 41)."""
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    f32 = jnp.float32
+    q = jax.ShapeDtypeStruct((2, 8, 256, 128), f32)
+    kv = jax.ShapeDtypeStruct((2, 2, 256, 128), f32)
+
+    def flash(**kw):
+        return flash_attention_fwd.lower(q, kv, kv, causal=True,
+                                         block_size=128, interpret=True,
+                                         **kw).as_text()
+
+    assert flash(window=0, sink=None) == flash()
+    assert flash(window=0, sink=None, mask_block=4) == flash(mask_block=4)
+    assert flash(window=0, return_residuals=True) == flash(
+        return_residuals=True)
+    assert flash(window=128) != flash()
+
+    def on_mesh(**kw):
+        return jax.jit(lambda q, k, v: attn_lib.flash_attention_on_mesh(
+            q, k, v, None, causal=True, **kw)).lower(q, kv, kv).as_text()
+
+    assert on_mesh(window=0, sink=None) == on_mesh()
+    assert on_mesh(window=0, mask_block=4) == on_mesh(mask_block=4)
+
+    def ref(**kw):
+        return jax.jit(lambda q, k, v: mha_reference(
+            q, k, v, causal=True, **kw)).lower(q, kv, kv).as_text()
+
+    assert ref(window=0, sink=None) == ref()
+    assert ref(window=64) != ref()
+    cache = jax.ShapeDtypeStruct((4, 2, 256, 128), f32)
+    lengths = jax.ShapeDtypeStruct((4,), jnp.int32)
+    for rows in (1, 4):         # a token a slot; a block's 4 rows (SDAR)
+        row = jax.ShapeDtypeStruct((4, 8, rows, 128), f32)
+        assert pallas_da.decode_attention_fwd.lower(
+            row, cache, cache, lengths, block=128, interpret=True,
+            q_rope=None, k_rope=None, sink=None, scale=None).as_text() == \
+            pallas_da.decode_attention_fwd.lower(
+                row, cache, cache, lengths, block=128,
+                interpret=True).as_text()
+        assert jax.jit(lambda q, k, v, n: attn_lib.decode_attention(
+            q, k, v, n, None, q_rope=None, k_rope=None, sink=None,
+            scale=None)).lower(row, cache, cache, lengths).as_text() == \
+            jax.jit(lambda q, k, v, n: attn_lib.decode_attention(
+                q, k, v, n)).lower(row, cache, cache, lengths).as_text()
+
+
+def test_mimo_kernels_compile_for_v5e(v5e_chip):
+    """At the cell's shapes (32 slots, 64 query heads, keys of 128 + 64,
+    values of 128): the decode kernel over a full layer's 4 KV heads and
+    9,216 positions, and over a window layer's 8 KV heads and ring of 128
+    with its sinks; the flash kernel over a row of 8,192 positions under
+    the window of 128 with its sinks."""
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+
+    def sds(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    for hkv, kept, sink in ((4, 9216, None),
+                            (8, 128, sds(64, dtype=jnp.float32))):
+        compiled = pallas_da.decode_attention_fwd.lower(
+            sds(32, 64, 1, 128), sds(32, hkv, kept, 128),
+            sds(32, hkv, kept, 128), sds(32, dtype=jnp.int32),
+            q_rope=sds(32, 64, 1, 64), k_rope=sds(32, hkv // 2, kept, 128),
+            sink=sink, scale=192 ** -0.5).compile()
+        assert 'tpu_custom_call' in compiled.as_text()
+    compiled = flash_attention_fwd.lower(
+        sds(1, 64, 8192, 192), sds(1, 8, 8192, 192), sds(1, 8, 8192, 128),
+        causal=True, window=128, sink=sds(64, dtype=jnp.float32)).compile()
+    assert 'tpu_custom_call' in compiled.as_text()
+
+
+def test_decode_program_keeps_both_kinds_of_cache_as_the_kernel_reads_them(
+        v5e_chip, monkeypatch):
+    """The engine's decode program of MiMo-V2 at the cell's widths (the
+    dense full layer and two window expert layers, 8 slots of 9,216
+    positions) with both decode kernels in it and every layout left to the
+    compiler as `_optimize_layouts` leaves them: the full layer's K and V
+    and the rings come out row-major [B, Hkv, positions, 128] as the
+    kernel reads them (the rows of a step are written as rows of 128 over
+    (slot x head, position)), nothing makes a copy of a context leaf, and
+    no temporary is as large as one.  The prefill of a row compiles with
+    the flash kernel in every layer, the window layers' over their band."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
+    from skypilot_tpu.models import moe as moe_lib
+    from skypilot_tpu.models.mimo_v2 import MiMoV2, MiMoV2Config
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+    from skypilot_tpu.ops.pallas import grouped_experts as pallas_ge
+
+    # `jax.default_backend()` is the CPU here: steer the choices themselves.
+    monkeypatch.setattr(
+        attn_lib, 'decode_kv_block',
+        lambda h, d, s, dtype=jnp.bfloat16, mesh=None: pallas_da.block_len(
+            h, d, s, jnp.dtype(dtype).itemsize))
+    monkeypatch.setattr(
+        moe_lib, 'expert_tile',
+        lambda n_tokens, block, w_gate, mesh=None: None
+        if n_tokens > block else pallas_ge.tile_f(
+            w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
+    real_on_mesh = attn_lib.flash_attention_on_mesh
+
+    def on_mesh(q, k, v, mesh, causal=True, mask_block=1, window=0,
+                sink=None):
+        return flash_attention_fwd(q, k, v, causal=causal, window=window,
+                                   sink=sink)
+
+    monkeypatch.setattr(attn_lib, 'flash_attention_on_mesh', on_mesh)
+    del real_on_mesh
+    cfg = MiMoV2Config(
+        vocab_size=19072, n_layers=3, layer_pattern=(0, 1, 1),
+        held_experts=tuple(range(16)), max_seq_len=9216,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    model = MiMoV2(cfg)
+    assert model.decode_kv_block() == 512
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))['params'])
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=8, steps_per_call=8, prefill_buckets=(8192,)))
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=v5e_chip), tree)
+
+    lens = shapes(engine._lens_d)
+    state = (shapes(params), shapes(engine._cache), shapes(engine._last_d),
+             lens)
+    compiled = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(engine._cache), auto, auto, auto,
+                      auto),
+        out_shardings=(auto, autos(engine._cache), auto, auto)).lower(
+            *state, lens, shapes(engine._rng)).compile()
+    text = compiled.as_text()
+    # A decode-attention kernel a layer, an expert kernel in the two
+    # expert layers.
+    assert text.count('custom_call_target="tpu_custom_call"') == 5
+    assert not re.search(r'= bf16\[8,[24],9216,128\]\S* (copy|transpose)\(',
+                         text)
+    formats, _ = compiled.input_formats
+    for fmt in jax.tree.leaves(formats[1]):
+        assert fmt.layout.major_to_minor == (0, 1, 2, 3)
+    context = engine._cache['layer_0']['attn']['v']
+    assert context.shape == (8, 4, 9216, 128)
+    assert compiled.memory_analysis().temp_size_in_bytes < context.nbytes
+    # The prefill of a group, a row at a time: a flash kernel a layer.
+    toks = jax.ShapeDtypeStruct((4, 8192), jnp.int32, sharding=v5e_chip)
+    vec = jax.ShapeDtypeStruct((4,), jnp.int32, sharding=v5e_chip)
+    prefill = jax.jit(engine._prefill_raw, donate_argnums=(1, 2, 3)).lower(
+        *state, toks, vec, vec, vec, shapes(engine._rng)).compile()
+    assert prefill.as_text().count(
+        'custom_call_target="tpu_custom_call"') == 3

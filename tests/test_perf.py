@@ -175,6 +175,61 @@ def test_cost_model_counts_a_latent_per_position_without_heads():
         'recurrent': n_bytes}
 
 
+def test_cost_model_counts_a_window_layers_ring_as_a_window_of_positions():
+    """A window layer's ring ([slots, kv heads, W, width], named by the
+    model in `window_leaves`) is a fourth kind of leaf: a step reads
+    min(context, W) positions of it, not the context and not the W that
+    its own axis would give a leaf read as "positions of the context".  A
+    key kept in two leaves (its unrotated part, and the rotated parts of
+    two heads a row) is one layer, not one and a half.  Unnamed, the same
+    leaves count as per-slot state."""
+    import jax.numpy as jnp
+    w, s = 8, 64
+    cache = {
+        'layer_0': {'attn': {        # a full layer: 2 KV heads
+            'k': {'nope': _leaf((3, 2, s, 16), jnp.bfloat16),
+                  'rope': _leaf((3, 1, s, 16), jnp.bfloat16)},
+            'v': _leaf((3, 2, s, 16), jnp.bfloat16)}},
+        **{f'layer_{i}': {'attn': {  # two window layers: 4 KV heads
+            'ring_k': {'nope': _leaf((3, 4, w, 16), jnp.bfloat16),
+                       'rope': _leaf((3, 2, w, 16), jnp.bfloat16)},
+            'ring_v': _leaf((3, 4, w, 16), jnp.bfloat16)}}
+           for i in (1, 2)}}
+    names = ('ring_k', 'ring_v')
+
+    def build(**kw):
+        return cost_model_lib.EngineCostModel.from_engine_state(
+            _Cfg, [_leaf((100,), jnp.float32)], cache, chip='v5e', **kw)
+
+    cm = build(window=names)
+    full_pos = 2 * (16 + 8 + 16) * 2            # bytes a position
+    ring_pos = 2 * 4 * (16 + 8 + 16) * 2        # of both window layers
+    assert cm.kv_bytes_per_pos() == full_pos
+    assert (cm.n_kv_layers, cm.n_window_layers, cm.window_len) == (1, 2, w)
+    assert cm.window_bytes_per_pos == ring_pos and \
+        cm.state_bytes_per_slot == 0
+    assert cost_model_lib.cache_bytes_by_kind(cache, window=names) == {
+        'kv': 3 * s * full_pos, 'window': 3 * w * ring_pos}
+    assert cost_model_lib.window_len(cache, names) == w
+    assert cost_model_lib.window_len(cache, ()) is None
+    # Past the window the rings' term stands still; under it, it grows.
+    weights = cm.param_bytes / 2
+    assert cm.decode_hbm_bytes_per_token(40, 2) == (
+        weights + 41 * full_pos + (w + 1) * ring_pos)
+    assert cm.decode_hbm_bytes_per_token(5, 2) == (
+        weights + 6 * full_pos + 6 * ring_pos)
+    assert cm.decode_flops_per_token(40) == (
+        2.0 * cm.n_params + 2.0 * cm.dim * (40 + 2 * w))
+    assert cm.decode_flops_per_token(5) == (
+        2.0 * cm.n_params + 2.0 * cm.dim * (5 + 2 * 5))
+    assert cm.decode_hbm_bytes_per_token(4000, 2) - \
+        cm.decode_hbm_bytes_per_token(40, 2) == 3960 * full_pos
+    # Unnamed: state of fixed size, read and written whole.
+    assert build().state_bytes_per_slot == w * ring_pos
+    assert cost_model_lib.cache_bytes_by_kind(cache) == {
+        'kv': 3 * s * full_pos, 'recurrent': 3 * w * ring_pos}
+
+
 @pytest.mark.parametrize('path', BENCH_CONFIGS, ids=lambda p: p.stem)
 def test_program_counts_the_parameters_the_yardstick_counts(path):
     """The model object a configuration's family hands the program
