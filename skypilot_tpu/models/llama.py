@@ -141,6 +141,7 @@ class KeepPlan:
     """What the blocks keep for the backward pass of one step."""
     layers: Tuple[Tuple[str, ...], ...]  # groups kept, layer by layer
     kept_bytes: Dict[str, int]           # on one device, by group
+    layer_bytes: Dict[str, int]          # of it one layer's, if it can keep
     forward_flops: float                 # the blocks' forward, whole batch
     recomputed_flops: float              # of it, run again by the backward
 
@@ -219,6 +220,7 @@ def keep_plan(cfg: LlamaConfig, mesh, batch: int, seq: int) -> KeepPlan:
     recomputed = sum(flops for kept in layers for g, (_, flops)
                      in groups.items() if g not in kept)
     return KeepPlan(tuple(map(tuple, layers)), kept_bytes,
+                    {g: groups[g][0] for g in can_keep},
                     cfg.n_layers * forward, recomputed)
 
 
@@ -609,11 +611,12 @@ class Llama(nn.Module):
                  decode: bool = False,
                  page_table: Optional[jax.Array] = None,
                  lengths: Optional[jax.Array] = None,
-                 live: Optional[jax.Array] = None) -> jax.Array:
+                 live: Optional[jax.Array] = None,
+                 to_logits: bool = True) -> jax.Array:
         # `lengths` (each row's valid positions in a padded prefill) is
         # the engine's to pass and a recurrent layer's to need: here
         # padding lives at masked positions (_decode_attend) and it is
-        # not read.
+        # not read.  `to_logits=False` is `hidden_and_head`.
         del lengths
         cfg = self.cfg
         if positions is None:
@@ -654,15 +657,30 @@ class Llama(nn.Module):
         x = RMSNorm(cfg.norm_eps, cfg.dtype, cfg.param_dtype,
                     name='final_norm')(x)
         if cfg.tie_embeddings:
+            if not to_logits:
+                return x, embed.embedding, True
             logits = embed.attend(x)
         else:
-            logits = nn.Dense(
+            head = nn.Dense(
                 cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype,
                 kernel_init=nn.with_logical_partitioning(
                     nn.initializers.lecun_normal(), ('embed', 'vocab')),
-                name='lm_head')(x)
+                name='lm_head')
+            if not to_logits:
+                kernel = head.variables['params']['kernel']
+                return x, nn.meta.unbox(kernel), False
+            logits = head(x)
         return logits.astype(jnp.float32)
+
+    def hidden_and_head(self, tokens: jax.Array):
+        """Read by the train step (train/trainer.py), which then takes
+        the head and the loss a chunk of rows at a time and never holds
+        the logits whole (train/loss.py): the state after the final norm
+        [B, S, D], the head's weights as the parameter tree holds them,
+        and whether those are the embedding table [V, D] (tied) and not
+        a kernel [D, V]."""
+        return self(tokens, to_logits=False)
 
     def decode_kv_block(self) -> Optional[int]:
         """For the engine's `decode_kv_positions` counter: the positions

@@ -64,8 +64,12 @@ def tree_shardings(mesh: Mesh, logical_tree,
             isinstance(a, (str, type(None))) for a in x))
 
 
+# The mesh axes a batch's rows divide over (dcn = inter-slice DP on
+# multislice clusters; the expert axis doubles as data parallelism in
+# non-MoE layers).
+BATCH_AXES = ('dcn', 'data', 'fsdp', 'expert')
+
+
 def batch_sharding(mesh: Mesh) -> NamedSharding:
-    """Sharding for (batch, ...) input arrays: batch over
-    dcn+data+fsdp+expert (dcn = inter-slice DP on multislice clusters;
-    the expert axis doubles as data parallelism in non-MoE layers)."""
-    return NamedSharding(mesh, P(('dcn', 'data', 'fsdp', 'expert')))
+    """Sharding for (batch, ...) input arrays: batch over `BATCH_AXES`."""
+    return NamedSharding(mesh, P(BATCH_AXES))

@@ -190,17 +190,17 @@ def validate_placement(accelerator: str,
     state_bytes = _sharded_bytes(abstract, shardings, mesh)
     breakdown['params+optimizer_state'] = state_bytes
 
-    # The step's temporaries at its fullest moment (the logits at the
-    # loss, or every parameter's gradient beside one block's working
-    # set) and the named activations the blocks keep: the trainer's own
-    # count (train/trainer.py activation_budget spends what this leaves).
+    # The step's temporaries at its fullest moment (a chunk of logits at
+    # the loss, or the gradients that exist by a block's backward pass
+    # beside its working set) with the named activations the blocks
+    # keep: the trainer's own count (train/trainer.py activation_budget
+    # spends what this leaves).
     params_bytes = _sharded_bytes(abstract.params, shardings.params, mesh)
     from skypilot_tpu.models.llama import keep_plan
     from skypilot_tpu.train.trainer import step_temporary_bytes
-    kept = keep_plan(dataclasses.replace(cfg, remat=remat), mesh, batch,
-                     seq).kept_bytes
     act_bytes = step_temporary_bytes(
-        cfg, mesh, batch, seq, grad_bytes=params_bytes) + sum(kept.values())
+        cfg, mesh, batch, seq, params_bytes,
+        keep_plan(dataclasses.replace(cfg, remat=remat), mesh, batch, seq))
     breakdown['step_temporaries_est'] = act_bytes
 
     if compile:

@@ -298,6 +298,11 @@ _HELP = {
         'device for the backward pass, by group (what: attn_out / qkv / '
         'gate_up / stream; models/llama.py keep_plan), chosen once when '
         'the trainer is built from what the device has left',
+    'skytpu_train_loss_logit_bytes':
+        'Bytes of logits and their gradient alive on a device at the '
+        'loss, by the trainer\'s count: one chunk of rows where the head '
+        'and the loss go by chunks (train/loss.py), every row where a '
+        'module hands back the logits whole',
     'skytpu_train_forward_flops_total':
         'FLOPs of the blocks\' forward pass over the steps logged so '
         'far, by the model\'s own count (two a multiply-add, causal '

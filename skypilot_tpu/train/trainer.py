@@ -29,6 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.parallel import sharding as sharding_lib
+from skypilot_tpu.train import loss as loss_lib
 
 # The part of a device's memory the activation budget leaves alone.  The
 # runtime reserves the step program's temporaries as one arena that stays
@@ -107,18 +108,37 @@ def lm_loss(logits: jax.Array, tokens: jax.Array) -> jax.Array:
     return losses.mean()
 
 
+def offers_hidden(apply_fn) -> bool:
+    """Whether `apply_fn` is the `apply` of a module that hands out the
+    state in front of its head (`hidden_and_head`, as `models/llama.py`
+    has it), so that the logits need never be held whole."""
+    return hasattr(getattr(apply_fn, '__self__', None), 'hidden_and_head')
+
+
 def make_sharded_train_step(
     mesh: Mesh,
     state_shardings,
     loss_fn: Callable[[jax.Array, jax.Array], jax.Array] = lm_loss,
 ) -> Callable[[TrainState, jax.Array], Tuple[TrainState, dict]]:
-    """Jitted train step: donated state in, sharded state out."""
+    """Jitted train step: donated state in, sharded state out.  `lm_loss`
+    of a module that offers the state in front of its head is computed
+    by chunks of rows (train/loss.py); any other module or loss is
+    handed the logits whole."""
     batch_sharding = sharding_lib.batch_sharding(mesh)
 
     def step(state: TrainState, tokens: jax.Array):
         def compute_loss(params):
-            logits = state.apply_fn({'params': params}, tokens)
-            return loss_fn(logits, tokens)
+            variables = {'params': params}
+            if loss_fn is lm_loss and offers_hidden(state.apply_fn):
+                hidden, head, tied = state.apply_fn(
+                    variables, tokens, method='hidden_and_head')
+                chunks = loss_lib.loss_chunks(
+                    mesh, *tokens.shape, vocab=head.shape[0 if tied else 1],
+                    act_bytes=hidden.dtype.itemsize)
+                return loss_lib.chunked_lm_loss(
+                    hidden, head, tokens, chunks.positions, tied,
+                    chunks.shards)
+            return loss_fn(state.apply_fn(variables, tokens), tokens)
 
         loss, grads = jax.value_and_grad(compute_loss)(state.params)
         new_state = state.apply_gradients(grads=grads)
@@ -137,35 +157,78 @@ def make_sharded_train_step(
     )
 
 
-def step_temporary_bytes(cfg, mesh, batch: int, seq: int,
-                         grad_bytes: int) -> int:
-    """What one device holds at the fullest moment of a [batch, seq] step
-    of a `LlamaConfig`-shaped model under per-block checkpoints, besides
-    the state and the named activations the blocks keep
-    (`models/llama.py keep_plan`; those are alive all through and add to
-    this byte for byte).  An upper bound by count, held against the TPU
-    compiler's `memory_analysis()` of the whole step by
-    `tests/test_ops.py`.
+def loss_logit_bytes(mesh, batch: int, seq: int, vocab: int, act_bytes: int,
+                     chunked: bool) -> int:
+    """One device's bytes of logits and their gradient alive at the
+    loss: a chunk's where the head and the loss go by chunks
+    (train/loss.py), every row's where a module hands back the logits
+    whole."""
+    chunks = loss_lib.loss_chunks(mesh, batch, seq, vocab, act_bytes)
+    return chunks.logit_bytes * (1 if chunked else seq // chunks.positions)
 
-    The step is fullest at one of two moments.  At the loss: the float32
-    logits beside their gradient in the compute type, with no gradient of
-    a parameter alive yet.  Or in the first block's backward pass: every
-    parameter's gradient beside one block's working set (q, k, v, the
-    attention output and their gradients; gate, up, their product and
-    gradients), the logits long freed.  Either way every block's input
-    is held, with the final norm's input, output and float32 copy.  At
-    the loss the count is within 2% of the compiler's; in the backward
-    pass it is generous (by then the later blocks' inputs are freed, and
-    the compiler's working set is smaller), so a step that is fullest
-    there is handed less than would fit.
+
+def step_temporary_bytes(cfg, mesh, batch: int, seq: int, grad_bytes: int,
+                         plan: llama_lib.KeepPlan,
+                         chunked: bool = True) -> int:
+    """What one device holds at the fullest moment of a [batch, seq] step
+    of a `LlamaConfig`-shaped model under per-block checkpoints that keep
+    what `plan` says (`models/llama.py keep_plan`), besides the state.
+    An upper bound by count, held against the TPU compiler's
+    `memory_analysis()` of the whole step by `tests/test_ops.py`.
+
+    `grad_bytes` is what this device holds of the parameters, which is
+    what it will hold of their float32 gradients; `chunked` whether the
+    head and the loss go by chunks of rows.  The moments, of which the
+    fullest counts:
+
+    - at the loss: every block's input and everything the blocks kept,
+      the final norm's input, output and float32 copy, the logits alive
+      at once beside their gradient (`loss_logit_bytes`) and, by chunks,
+      the head in the compute type, its float32 gradient (twice, below)
+      and the gradient of the hidden state; no block's gradient yet;
+    - in block i's backward pass, from the last block down: the
+      gradients that exist by then (the head's, the blocks' from i on),
+      the inputs and the kept activations of blocks 0..i (the later
+      blocks' are freed), and block i's working set (q, k, v, the
+      attention output and their gradients; gate, up, their product and
+      gradients) less what it kept of it, which is not made again.
+
+    The head's gradient counts twice: the compiler holds a second buffer
+    of its size beside it from the loss on (the one the chunks' loop is
+    handed beside the one it returns; PERF.md section 6, PR 42), and
+    without it the count fell under the compiler's with everything kept.
+    With it the count is over the compiler's by 3% where the blocks keep
+    nearly everything and by more where they keep less (40% with
+    nothing: the compiler's working set is smaller than the count's).
     """
     tokens, tp = llama_lib.device_share(cfg, mesh, batch, seq)
     act = jnp.dtype(cfg.dtype).itemsize
-    at_loss = tokens * (cfg.vocab_size // tp) * (4 + act)
-    in_backward = grad_bytes + tokens * (
-        8 * cfg.dim + 6 * cfg.ffn_dim) // tp * act
-    return ((cfg.n_layers + 4) * tokens * cfg.dim * act +
-            max(at_loss, in_backward))
+    block_input = tokens * cfg.dim * act
+    head = cfg.vocab_size * cfg.dim
+    # A device's bytes a parameter: fsdp divides them, whatever the leaf.
+    per_param = grad_bytes / cfg.num_params()
+    block_params = (cfg.num_params() - head *
+                    (1 if cfg.tie_embeddings else 2) - cfg.dim) / cfg.n_layers
+    # One device's, whole under fsdp: the chunks' loop sums it a device's
+    # rows apart.
+    head_gradient = 2 * (head // tp * 4)
+    at_loss = ((cfg.n_layers + 4) * block_input +
+               sum(plan.kept_bytes.values()) +
+               loss_logit_bytes(mesh, batch, seq, cfg.vocab_size, act,
+                                chunked))
+    if chunked:
+        at_loss += head_gradient + head // tp * act + block_input
+    working = tokens * (8 * cfg.dim + 6 * cfg.ffn_dim) // tp * act
+    fullest, kept = at_loss, 0
+    for i, groups in enumerate(plan.layers):
+        here = {g: plan.layer_bytes.get(g, 0) for g in groups}
+        kept += sum(here.values())
+        in_working = sum(b for g, b in here.items() if g != 'stream')
+        gradients = head_gradient + int(per_param * (
+            cfg.dim + (cfg.n_layers - i) * block_params))
+        fullest = max(fullest, gradients + (i + 4) * block_input + kept +
+                      working - in_working)
+    return fullest
 
 
 def _bytes_limit(device) -> Optional[int]:
@@ -173,27 +236,37 @@ def _bytes_limit(device) -> Optional[int]:
     return (device.memory_stats() or {}).get('bytes_limit')
 
 
-def activation_budget(cfg, mesh, state: TrainState, batch: int,
-                      seq: int) -> int:
-    """Bytes of named activations the blocks may keep on each device:
-    what the device's limit leaves after the state this device holds, the
-    step's temporaries by count and a margin.  Zero where the device
-    reports no limit (the CPU), so the program there is the one that
-    keeps nothing."""
-    device = mesh.local_devices[0]
-    limit = _bytes_limit(device)
-    if not limit:
-        return 0
+def _held_bytes(tree, device) -> int:
+    """What `device` holds of a tree of arrays."""
+    return sum(shard.data.nbytes for leaf in jax.tree.leaves(tree)
+               for shard in leaf.addressable_shards if shard.device == device)
 
-    def held(tree) -> int:
-        return sum(shard.data.nbytes for leaf in jax.tree.leaves(tree)
-                   for shard in leaf.addressable_shards
-                   if shard.device == device)
 
-    temporaries = step_temporary_bytes(cfg, mesh, batch, seq,
-                                       grad_bytes=held(state.params))
-    return max(0, int(limit * (1 - _HBM_MARGIN)) - held(state) -
-               temporaries)
+def activation_budget(cfg, mesh, batch: int, seq: int, limit: int,
+                      state_bytes: int, grad_bytes: int,
+                      chunked: bool = True) -> int:
+    """Bytes of named activations the blocks may keep on each device
+    (`cfg.remat_keep_bytes`), given the device's `limit` and what it
+    holds of the state and of the parameters: one pass over what
+    `keep_plan` would keep, dearest first and a layer at a time, taking
+    each piece while the state, the step's temporaries by count WITH the
+    plan those bytes buy (`step_temporary_bytes`) and a margin stay
+    under the limit."""
+    room = int(limit * (1 - _HBM_MARGIN)) - state_bytes
+
+    def plan_of(budget: int) -> llama_lib.KeepPlan:
+        return llama_lib.keep_plan(
+            dataclasses.replace(cfg, remat_keep_bytes=budget), mesh, batch,
+            seq)
+
+    budget = 0
+    for piece in plan_of(0).layer_bytes.values():
+        for _ in range(cfg.n_layers):
+            if step_temporary_bytes(cfg, mesh, batch, seq, grad_bytes,
+                                    plan_of(budget + piece),
+                                    chunked) <= room:
+                budget += piece
+    return budget
 
 
 class Trainer:
@@ -232,10 +305,17 @@ class Trainer:
         # A model whose checkpoints keep "what fits" and was not told how
         # much that is learns it here, from bytes counted on this device.
         cfg = getattr(model, 'cfg', None)
+        chunked = offers_hidden(model.apply)
+        device = mesh.local_devices[0]
+        # Where the device reports no limit (the CPU) the program is the
+        # one that keeps nothing.
+        limit = _bytes_limit(device)
         if (getattr(cfg, 'remat_policy', None) == 'fit' and cfg.remat and
-                cfg.remat_keep_bytes is None):
-            budget = activation_budget(cfg, mesh, self.state,
-                                       *sample_tokens.shape)
+                cfg.remat_keep_bytes is None and limit):
+            budget = activation_budget(
+                cfg, mesh, *sample_tokens.shape, limit,
+                _held_bytes(self.state, device),
+                _held_bytes(self.state.params, device), chunked)
             if budget:
                 model = model.clone(cfg=dataclasses.replace(
                     cfg, remat_keep_bytes=budget))
@@ -252,6 +332,9 @@ class Trainer:
             self._n_params = model.cfg.num_params()
             self._plan = llama_lib.keep_plan(model.cfg, mesh,
                                              *sample_tokens.shape)
+            logit_bytes = loss_logit_bytes(
+                mesh, *sample_tokens.shape, model.cfg.vocab_size,
+                jnp.dtype(model.cfg.dtype).itemsize, chunked)
         except (AttributeError, TypeError):
             self._n_params = self._plan = None
         self._step_tokens = sample_tokens.size
@@ -261,6 +344,8 @@ class Trainer:
                 metrics_lib.set_gauge('skytpu_train_kept_activation_bytes',
                                       self._plan.kept_bytes[what],
                                       what=what)
+            metrics_lib.set_gauge('skytpu_train_loss_logit_bytes',
+                                  logit_bytes)
         self.checkpoint_dir = checkpoint_dir
         self._ckpt_mgr = None
         if checkpoint_dir is not None:

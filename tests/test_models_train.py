@@ -11,7 +11,10 @@ import pytest
 from skypilot_tpu.models import llama as llama_lib
 from skypilot_tpu.models.llama import (Llama, LlamaConfig, LLAMA_CONFIGS,
                                        init_params)
+from skypilot_tpu.parallel import sharding as sharding_lib
 from skypilot_tpu.parallel.mesh import MeshPlan, build_mesh, plan_mesh
+from skypilot_tpu.train import loss as loss_lib
+from skypilot_tpu.train import trainer as trainer_lib
 from skypilot_tpu.train.trainer import (TrainConfig, Trainer, lm_loss,
                                         make_sharded_train_step,
                                         make_train_state)
@@ -178,6 +181,182 @@ def test_lm_loss_shift():
     tokens = jnp.array([[1, 2, 3, 4]])
     loss = lm_loss(logits, tokens)
     np.testing.assert_allclose(float(loss), np.log(8), rtol=1e-5)
+
+
+# ----- the head and the loss by chunks of rows (train/loss.py) ---------------
+@pytest.mark.parametrize('dtype', [jnp.float32, jnp.bfloat16],
+                         ids=['float32', 'bfloat16'])
+@pytest.mark.parametrize('chunks', [1, 2, 8])
+@pytest.mark.parametrize('tied', [False, True], ids=['untied', 'tied'])
+def test_chunked_head_and_loss_is_lm_loss(tied, chunks, dtype):
+    """The hidden state and the head that `Llama` hands out, through the
+    chunked head-and-loss: `lm_loss`'s value over the whole logits and
+    autodiff's gradients of the hidden state and of the head's weights,
+    whatever the number of chunks, with the rows summed in one group or
+    two; in float32 to rounding, in bfloat16 compute to what the
+    gradient tests of this file allow."""
+    cfg = dataclasses.replace(CFG, tie_embeddings=tied, dtype=dtype)
+    model = Llama(cfg)
+    variables = init_params(model, jax.random.PRNGKey(0), batch=2, seq=32)
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (2, 32), 0,
+                                cfg.vocab_size)
+    hidden, head, is_tied = model.apply(variables, tokens,
+                                        method='hidden_and_head')
+    assert is_tied == tied and hidden.dtype == dtype
+    assert hidden.shape == (2, 32, cfg.dim)
+    assert head.shape == ((cfg.vocab_size, cfg.dim) if tied else
+                          (cfg.dim, cfg.vocab_size))
+
+    def whole(h, w):            # as `nn.Dense(dtype=...)` / `attend` do
+        w = w.astype(dtype)
+        logits = jnp.dot(h, w.T if tied else w).astype(jnp.float32)
+        return lm_loss(logits, tokens)
+
+    want, (want_h, want_w) = jax.value_and_grad(whole, (0, 1))(hidden, head)
+    np.testing.assert_allclose(
+        want, lm_loss(model.apply(variables, tokens), tokens), rtol=1e-6)
+    tolerance = 1e-6 if dtype == jnp.float32 else 2e-2
+    for shards in (1, 2):
+        got, (got_h, got_w) = jax.value_and_grad(
+            lambda h, w: loss_lib.chunked_lm_loss(
+                h, w, tokens, 32 // chunks, tied, shards), (0, 1))(
+                    hidden, head)
+        assert got_h.dtype == hidden.dtype and got_w.dtype == head.dtype
+        np.testing.assert_allclose(got, want, rtol=max(tolerance, 2e-6)
+                                   if dtype == jnp.float32 else 1e-3)
+        for a, b in ((got_h, want_h), (got_w, want_w)):
+            a, b = (np.asarray(x, np.float32) for x in (a, b))
+            assert np.abs(a - b).max() <= tolerance * np.abs(b).max()
+    # The cotangent scales what the forward pass stored.
+    _, (twice_h, twice_w) = jax.value_and_grad(
+        lambda h, w: 2 * loss_lib.chunked_lm_loss(
+            h, w, tokens, 32 // chunks, tied, 2), (0, 1))(hidden, head)
+    np.testing.assert_array_equal(np.asarray(twice_w), 2 * np.asarray(got_w))
+    np.testing.assert_array_equal(np.asarray(twice_h, np.float32),
+                                  2 * np.asarray(got_h, np.float32))
+
+
+def test_chunks_come_from_the_shapes():
+    """One function says how the step walks the rows and what the
+    trainer counts for them: the largest divisor of the sequence whose
+    chunk of logits stays under the constant, one device's rows and
+    words."""
+    # pretrain-4k: 4 rows x 4,096 positions over 64,000 words, 6 B a logit.
+    assert loss_lib.loss_chunks(None, 4, 4096, 64000, 2) == (
+        512, 1, 4 * 512 * 64000 * 6)
+    mesh = build_mesh(MeshPlan(1, 2, 2), jax.devices()[:4])
+    assert loss_lib.loss_chunks(mesh, 4, 4096, 64000, 2) == (
+        2048, 2, 2 * 2048 * 32000 * 6)
+    # Rows that do not divide stay whole; a sequence of no small divisor
+    # goes a position at a time sooner than over the constant.
+    assert loss_lib.loss_chunks(mesh, 3, 4096, 64000, 2).shards == 1
+    assert loss_lib.loss_chunks(None, 64, 4099, 64000, 2).positions == 1
+    # The tests' models are one chunk.
+    assert loss_lib.loss_chunks(None, 8, 32, 256, 2).positions == 32
+    with pytest.raises(ValueError, match='do not divide'):
+        loss_lib.chunked_lm_loss(jnp.zeros((2, 32, 8)), jnp.zeros((8, 16)),
+                                 jnp.zeros((2, 32), jnp.int32), 5)
+
+
+def test_chunked_loss_on_a_mesh_gathers_the_head_once():
+    """On the 2 x 2 host mesh, rows over fsdp and the vocabulary over
+    tensor as the train step's shardings have them (plain `jnp` under
+    `jit`): the one-device value and gradients; the head's kernel,
+    divided over fsdp as a parameter, is gathered in front of the loop
+    and never inside it, however many chunks; and the rows' sums of the
+    head's gradient meet in one reduction behind the loop, not one a
+    chunk."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    mesh = build_mesh(MeshPlan(1, 2, 2), jax.devices()[:4])
+    b, s, d, v = 4, 64, 64, 256
+    keys = jax.random.split(jax.random.PRNGKey(0), 3)
+    hidden = jax.random.normal(keys[0], (b, s, d), jnp.bfloat16)
+    head = jax.random.normal(keys[1], (d, v), jnp.float32) * 0.1
+    tokens = jax.random.randint(keys[2], (b, s), 0, v)
+    rows = sharding_lib.batch_sharding(mesh)
+    shardings = (rows, NamedSharding(mesh, P('fsdp', 'tensor')), rows)
+
+    def loss_and_grads(chunk, shards):
+        return jax.value_and_grad(
+            lambda h, w, t: loss_lib.chunked_lm_loss(h, w, t, chunk, False,
+                                                     shards), (0, 1))
+
+    want, (want_h, want_w) = loss_and_grads(s, 1)(hidden, head, tokens)
+    gathers = {}
+    for chunk in (s // 2, s // 8):
+        chunks = loss_lib.loss_chunks(mesh, b, s, v, 2)
+        assert chunks.shards == 2
+        compiled = jax.jit(
+            loss_and_grads(chunk, chunks.shards), in_shardings=shardings,
+            out_shardings=(None, shardings[:2])).lower(
+                hidden, head, tokens).compile()
+        got, (got_h, got_w) = compiled(hidden, head, tokens)
+        np.testing.assert_allclose(got, want, rtol=1e-5)
+        for a, b_ in ((got_h, want_h), (got_w, want_w)):
+            a, b_ = (np.asarray(x, np.float32) for x in (a, b_))
+            assert np.abs(a - b_).max() <= 2e-2 * np.abs(b_).max()
+        text = compiled.as_text()
+        (body,) = re.findall(r'body=%?([\w.\-]+)', text)
+        (body_text,) = [c for c in text.split('\n\n')
+                        if c.lstrip().startswith(f'%{body} ')]
+        assert 'all-gather' not in body_text
+        # In the loop, what crosses devices is a row's worth: no operand
+        # of the head's size on one device, [64, 128].
+        crossing = [line for line in body_text.splitlines()
+                    if re.search(r' (all-reduce|reduce-scatter|all-to-all)'
+                                 r'(-start)?\(', line)]
+        assert crossing and not any(
+            f'{d},{v // 2}]' in line.split('metadata')[0]
+            for line in crossing)
+        gathers[chunk] = len(re.findall(r' all-gather(-start)?\(', text))
+    assert gathers[s // 2] == gathers[s // 8] >= 1
+
+
+@pytest.mark.parametrize('tied', [False, True], ids=['untied', 'tied'])
+def test_train_step_by_chunks_is_the_whole_logits_step(tied, monkeypatch):
+    """A step of `LLAMA_CONFIGS['tiny']` through the chunked head and
+    loss (four chunks: the constant is set small) against the step that
+    is handed the logits whole: the same loss and `grad_norm`, the same
+    parameter tree, and no buffer of the whole logits' shape."""
+    cfg = dataclasses.replace(CFG, tie_embeddings=tied)
+    mesh = build_mesh(MeshPlan(1, 1, 1), jax.devices()[:1])
+    model = Llama(cfg, mesh)
+    rng = jax.random.PRNGKey(0)
+    tokens = jax.random.randint(rng, (8, 32), 0, cfg.vocab_size)
+    tcfg = TrainConfig(learning_rate=1e-2, warmup_steps=1, total_steps=10)
+    assert trainer_lib.offers_hidden(model.apply)
+    monkeypatch.setattr(loss_lib, '_CHUNK_BYTES',
+                        8 * 8 * cfg.vocab_size * 6)
+    assert loss_lib.loss_chunks(mesh, 8, 32, cfg.vocab_size,
+                                2).positions == 8
+
+    first, shardings = make_train_state(model, mesh, rng, tokens, tcfg)
+
+    def one_step(loss_fn):
+        state = jax.tree.map(jnp.copy, first)       # the step donates it
+        lowered = make_sharded_train_step(mesh, shardings, loss_fn).lower(
+            state, tokens)
+        state, metrics = lowered.compile()(state, tokens)
+        return ((float(metrics['loss']), float(metrics['grad_norm'])),
+                state.params, lowered.as_text())
+
+    chunked, params, text = one_step(lm_loss)
+    whole, whole_params, whole_text = one_step(
+        lambda logits, toks: lm_loss(logits, toks))
+    # The logits whole are [8, 32, V] float32; by chunks [8, 8, V].
+    assert f'tensor<8x32x{cfg.vocab_size}xf32>' in whole_text
+    assert f'tensor<8x32x{cfg.vocab_size}xf32>' not in text
+    assert f'tensor<8x8x{cfg.vocab_size}xf32>' in text
+    np.testing.assert_allclose(chunked, whole, rtol=2e-2)
+    np.testing.assert_allclose(chunked[0], whole[0], rtol=1e-3)
+    assert (jax.tree_util.tree_structure(params) ==
+            jax.tree_util.tree_structure(whole_params))
+    paths = {'/'.join(k.key for k in path) for path, _ in
+             jax.tree_util.tree_leaves_with_path(params)}
+    assert 'embed/embedding' in paths
+    assert ('lm_head/kernel' in paths) == (not tied)
+    # A module that only returns logits is handed them whole.
+    assert not trainer_lib.offers_hidden(lambda variables, toks: None)
 
 
 # ----- what a block keeps for its backward pass (models/llama.py keep_plan) --
@@ -398,22 +577,74 @@ def test_trainer_hands_the_model_what_the_device_has_left(plan, monkeypatch):
         s.data.nbytes for leaf in jax.tree.leaves(plain.state.params)
         for s in leaf.addressable_shards
         if s.device == mesh.local_devices[0])
-    temporaries = trainer_lib.step_temporary_bytes(cfg, mesh, 8, 32,
-                                                   params_bytes)
-    # Room for `out` + `lse` in both layers and q/k/v in one.
-    per_layer = {g: b // 2 for g, b in llama_lib.keep_plan(
-        dataclasses.replace(cfg, remat_keep_bytes=_EVERYTHING), mesh, 8,
-        32).kept_bytes.items()}
-    want = 2 * per_layer['attn_out'] + per_layer['qkv'] + 16
-    limit = (state_bytes + temporaries + want) * 32 // 31
+
+    def plan_of(budget):
+        return llama_lib.keep_plan(
+            dataclasses.replace(cfg, remat_keep_bytes=budget), mesh, 8, 32)
+
+    # Room for `out` + `lse` in both layers and q/k/v in one: the limit
+    # is what the count of that plan comes to, and 16 B.
+    per_layer = plan_of(0).layer_bytes
+    assert list(per_layer) == list(llama_lib.KEEP_GROUPS)
+    want = 2 * per_layer['attn_out'] + per_layer['qkv']
+    temporaries = trainer_lib.step_temporary_bytes(
+        cfg, mesh, 8, 32, params_bytes, plan_of(want))
+    assert temporaries >= trainer_lib.step_temporary_bytes(
+        cfg, mesh, 8, 32, params_bytes, plan_of(0))
+    limit = (state_bytes + temporaries + 16) * 32 // 31
     monkeypatch.setattr(trainer_lib, '_bytes_limit', lambda device: limit)
     metrics_lib.reset_for_tests()
     fitted = Trainer(Llama(cfg, mesh), mesh, rng, tokens, tcfg)
-    left = int(limit * 31 / 32) - state_bytes - temporaries
-    assert fitted.model.cfg.remat_keep_bytes == left
-    assert fitted._plan.layers == (('attn_out', 'qkv'), ('attn_out',))
-    assert sum(fitted._plan.kept_bytes.values()) <= left
+    # The pass took that and whatever more costs the fullest moment
+    # nothing (a later block's, freed before the first block's backward
+    # pass, where this small model is fullest); everything does not fit.
+    budget = trainer_lib.activation_budget(
+        cfg, mesh, 8, 32, limit, state_bytes, params_bytes)
+    assert fitted.model.cfg.remat_keep_bytes == budget >= want
+    assert fitted._plan == plan_of(budget)
+    assert sum(fitted._plan.kept_bytes.values()) == budget
+    assert fitted._plan.layers[0][:2] == ('attn_out', 'qkv')
+    room = int(limit * 31 / 32) - state_bytes
+    assert trainer_lib.step_temporary_bytes(
+        cfg, mesh, 8, 32, params_bytes, fitted._plan) <= room
+    assert trainer_lib.step_temporary_bytes(
+        cfg, mesh, 8, 32, params_bytes, plan_of(_EVERYTHING)) > room
     text = metrics_lib.render()
     assert (f'skytpu_train_kept_activation_bytes{{what="qkv"}} '
-            f'{per_layer["qkv"]}\n') in text
+            f'{fitted._plan.kept_bytes["qkv"]}\n') in text
+    # One chunk of logits here, every row's: 6 B a logit on one device.
+    tokens_here, tp = llama_lib.device_share(cfg, mesh, 8, 32)
+    assert (f'skytpu_train_loss_logit_bytes '
+            f'{tokens_here * cfg.vocab_size // tp * 6}\n') in text
     np.testing.assert_allclose(losses(fitted), base, rtol=2e-2)
+
+
+def test_trainer_keeps_five_gigabytes_at_the_cells_shape():
+    """`pretrain-4k` by arithmetic alone (Yi-Coder's widths, 8 layers, 4 x
+    4,096 tokens, float32 parameters and Adam on a v5e's 16,909,336,064
+    B): with the loss by chunks the blocks are handed more than the
+    3.2 GB that counting the backward pass as one moment would leave,
+    and with the logits whole what PR 40 measured fits, no more."""
+    cfg = LlamaConfig(vocab_size=64000, dim=2048, n_layers=8, n_heads=16,
+                      n_kv_heads=16, ffn_dim=5504, max_seq_len=4096)
+    params = 4 * cfg.num_params()
+    state = 3 * params + 64         # the parameters, Adam's moments, counts
+
+    def handed(chunked):
+        budget = trainer_lib.activation_budget(
+            cfg, None, 4, 4096, 16909336064, state, params, chunked)
+        return budget, llama_lib.keep_plan(
+            dataclasses.replace(cfg, remat_keep_bytes=budget), None, 4, 4096)
+
+    budget, plan = handed(chunked=True)
+    assert budget == sum(plan.kept_bytes.values()) >= 3.2e9
+    assert [sum(g in kept for kept in plan.layers)
+            for g in llama_lib.KEEP_GROUPS] == [8, 8, 8, 5]
+    assert 100 * plan.recomputed_flops / plan.forward_flops < 3
+    assert trainer_lib.loss_logit_bytes(None, 4, 4096, 64000, 2,
+                                        True) == 786432000
+    budget, plan = handed(chunked=False)
+    assert trainer_lib.loss_logit_bytes(None, 4, 4096, 64000, 2,
+                                        False) == 6291456000
+    assert [sum(g in kept for kept in plan.layers)
+            for g in llama_lib.KEEP_GROUPS] == [8, 3, 0, 1]
