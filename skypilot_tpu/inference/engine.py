@@ -850,21 +850,35 @@ class DecodeEngine:
             return jnp.take_along_axis(
                 logits, index[:, None, None], axis=1)[:, 0]
 
-        def a_group_at_a_time(rows, *args):
-            """`rows(*args)` over a prefill's N rows.  A model may say how
-            many rows of a prefill it can hold at once (`prefill_rows`, a
-            divisor of every admitted group's power of two): more go
-            through it that many at a time, every row on its own as in
-            one pass."""
+        def a_group_at_a_time(rows, big_cache, slots, *args):
+            """`rows(*args)` -> (what is read of the rows, their caches)
+            over a prefill's N rows.  A model may say how many rows of a
+            prefill it can hold at once (`prefill_rows`, a divisor of
+            every admitted group's power of two): more go through it that
+            many at a time, every row on its own as in one pass, and each
+            group's caches go into `big_cache` at the group's `slots` as
+            the group ends (the cache carried through the loop), so that
+            a prefill holds `prefill_rows` rows' caches beside the cache
+            and never N (64 rows of 76 MB of recurrent state would be
+            4.9 GB).  Returns (what is read, over the N rows; the N rows'
+            caches still to insert, or None where the groups went in;
+            the cache)."""
             n = args[0].shape[0]
             at_once = getattr(model, 'prefill_rows', None) or n
             if n <= at_once:
-                return rows(*args)
-            return jax.tree.map(
-                lambda t: t.reshape((n,) + t.shape[2:]),
-                jax.lax.map(lambda xs: rows(*xs), jax.tree.map(
-                    lambda t: t.reshape((n // at_once, at_once) +
-                                        t.shape[1:]), args)))
+                return rows(*args) + (big_cache,)
+
+            def group(big, xs):
+                at, some = xs
+                out, cache = rows(*some)
+                return jax.tree.map(lambda b, small: b.at[at].set(small),
+                                    big, cache), out
+
+            big_cache, out = jax.lax.scan(group, big_cache, jax.tree.map(
+                lambda t: t.reshape((n // at_once, at_once) + t.shape[1:]),
+                (slots, args)))
+            return jax.tree.map(lambda t: t.reshape((n,) + t.shape[2:]),
+                                out), None, big_cache
 
         def prefill_insert(params, big_cache, last_toks, lens, tokens,
                            lengths, slots, valid, rng):
@@ -885,8 +899,8 @@ class DecodeEngine:
                     decode=True, lengths=lengths, mutable=['cache'])
                 return logits, cache['cache']
 
-            logits, cache = a_group_at_a_time(rows, tokens, positions,
-                                              lengths)
+            logits, cache, big_cache = a_group_at_a_time(
+                rows, big_cache, slots, tokens, positions, lengths)
             last = last_logits(logits, lengths - 1)                  # [N,V]
             firsts = sample(last, rng)                               # [N]
             # Padding rows replicate row 0, so their duplicate scatter
@@ -901,7 +915,8 @@ class DecodeEngine:
                 # [n_slots, H, max_len, D] at each row's slot index.
                 return big.at[slots].set(small)
 
-            big_cache = jax.tree_util.tree_map(_ins, big_cache, cache)
+            if cache is not None:
+                big_cache = jax.tree_util.tree_map(_ins, big_cache, cache)
             return (big_cache, last_toks.at[slots].set(firsts),
                     lens.at[slots].set(lengths))
 
@@ -984,17 +999,19 @@ class DecodeEngine:
                 _, cache = model.apply(
                     {'params': params}, tokens, positions=positions,
                     decode=True, lengths=lengths, mutable=['cache'])
-                return cache['cache']
+                return (), cache['cache']
 
-            cache = a_group_at_a_time(rows, tokens, positions, lengths)
+            _, cache, big_cache = a_group_at_a_time(
+                rows, big_cache, slots, tokens, positions, lengths)
             first = lengths - lengths % blk                          # [N]
             offs = jnp.arange(blk)[None, :]
             opened = jnp.take_along_axis(
                 tokens, jnp.minimum(first[:, None] + offs, p - 1), axis=1)
             masked = (offs >= (lengths % blk)[:, None]).astype(jnp.int32)
-            big_cache = jax.tree_util.tree_map(
-                lambda big, small: big.at[slots].set(small), big_cache,
-                cache)
+            if cache is not None:
+                big_cache = jax.tree_util.tree_map(
+                    lambda big, small: big.at[slots].set(small), big_cache,
+                    cache)
             return (big_cache,
                     {'tok': block['tok'].at[slots].set(opened),
                      'masked': block['masked'].at[slots].set(masked)},

@@ -128,8 +128,10 @@ _HELP = {
         'vector a layer, no head axis), kind="window" a window layer\'s '
         'keys and values, a ring of its window\'s positions a slot '
         'whatever the context, kind="recurrent" per-slot '
-        'state of fixed size (a linear-attention layer\'s matrix and '
-        'convolution taps) — a kind that holds nothing is absent',
+        'state of fixed size, read and written whole every step (a '
+        'recurrent layer\'s float32 matrix a head, be it a delta '
+        'rule\'s or a state-space layer\'s, and its convolution\'s last '
+        'taps) — a kind that holds nothing is absent',
     'skytpu_moe_pairs_total':
         'Token-expert pairs routed by decode steps, summed over expert '
         'layers: where="held" to an expert this engine holds, '
@@ -154,6 +156,13 @@ _HELP = {
         'makes of delta_rule_step; which one a program took is fixed '
         'when it is traced, and kernel at 0 says the mechanism did not '
         'engage',
+    'skytpu_ssm_state_updates_total':
+        'Per-head states of Mamba-2 layers that decode steps updated '
+        '(slots x Mamba layers x heads x steps), by who updated them: '
+        'path="kernel" the Pallas call that reads a head\'s tile once and '
+        'writes it once in place, path="xla" ssm_step through XLA; which '
+        'one a program took is fixed when it is traced, and kernel at 0 '
+        'says the mechanism did not engage',
     'skytpu_moe_expert_tokens_total':
         'Token-expert pairs of decode steps by held expert (its id '
         'among all experts), summed over expert layers: the routing\'s '

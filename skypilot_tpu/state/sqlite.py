@@ -87,7 +87,14 @@ class SqliteBackend:
                     }
                     if m.group(2) in cols:
                         continue
-                conn.execute(stmt)
+                try:
+                    conn.execute(stmt)
+                except sqlite3.OperationalError as e:
+                    # Another connection added the column between the
+                    # look and the ALTER (two starts at once): the
+                    # migration is done either way.
+                    if m is None or 'duplicate column name' not in str(e):
+                        raise
 
 
 def reset_connections_for_tests() -> None:

@@ -550,3 +550,76 @@ def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
     untold = _kv_positions()
     assert untold['fetched'] - last['fetched'] == (3 * 3 + 3 * 1) * 4
     assert untold['empty'] == last['empty']
+
+
+def test_a_model_without_prefill_rows_keeps_the_prefill_program_it_had(
+        model_and_params):
+    """`prefill_insert` for a model that takes a group's rows whole (no
+    `prefill_rows`: Llama here) lowers to the text of the program it was
+    before a wave's insert went group by group: the rows in one pass, the
+    sample, then one scatter of every row's cache (the parent's function,
+    written out below; the parent's own lowered text was compared by hand
+    for Llama, SDAR and Solar-Open2: PERF.md section 6, PR 43)."""
+    model, params = model_and_params
+    assert not hasattr(model, 'prefill_rows')
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=4, prefill_buckets=(16,), steps_per_call=2))
+
+    def prefill_insert(params, big_cache, last_toks, lens, tokens, lengths,
+                       slots, valid, rng):
+        n, p = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
+        logits, cache = model.apply(
+            {'params': params}, tokens, positions=positions, decode=True,
+            lengths=lengths, mutable=['cache'])
+        last = jnp.take_along_axis(
+            logits, (lengths - 1)[:, None, None], axis=1)[:, 0]
+        firsts = jnp.argmax(last, axis=-1)
+        firsts = jnp.where(valid.astype(bool), firsts, firsts[0])
+        big_cache = jax.tree_util.tree_map(
+            lambda big, small: big.at[slots].set(small), big_cache,
+            cache['cache'])
+        return (big_cache, last_toks.at[slots].set(firsts),
+                lens.at[slots].set(lengths))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+    rows = jax.ShapeDtypeStruct((4, 16), jnp.int32)
+    vec = jax.ShapeDtypeStruct((4,), jnp.int32)
+    args = (shapes(params), shapes(engine._cache), shapes(engine._last_d),
+            shapes(engine._lens_d), rows, vec, vec, vec, shapes(engine._rng))
+    assert jax.jit(engine._prefill_raw).lower(*args).as_text() == \
+        jax.jit(prefill_insert).lower(*args).as_text()
+
+
+def test_a_wave_goes_into_the_cache_group_by_group(model_and_params):
+    """A model that declares `prefill_rows` r: a prefill of N > r rows
+    inserts each group of r rows at its slots as the group ends, the same
+    cache, tokens and lengths as the rows in one pass; padding rows
+    (replicas of row 0) write row 0's slot again with row 0's values,
+    whichever group they fall in."""
+    model, params = model_and_params
+
+    class TwoRows(Llama):
+        prefill_rows = 2
+
+    def served(model):
+        engine = DecodeEngine(model, params, EngineConfig(
+            n_slots=8, prefill_buckets=(16,), steps_per_call=2))
+        rng = np.random.default_rng(3)
+        tokens = rng.integers(1, CFG.vocab_size, (8, 16)).astype(np.int32)
+        lengths = np.array([9, 16, 3, 12, 7, 9, 9, 9], np.int32)
+        slots = np.array([5, 2, 7, 0, 3, 5, 5, 5], np.int32)   # 3 padding
+        tokens[5:] = tokens[0]
+        valid = np.array([1, 1, 1, 1, 1, 0, 0, 0], np.int32)
+        return jax.jit(engine._prefill_raw)(
+            params, engine._cache, engine._last_d, engine._lens_d,
+            jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(slots),
+            jnp.asarray(valid), engine._rng)
+
+    whole, grouped = served(model), served(TwoRows(CFG))
+    for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(grouped)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+    assert [int(n) for n in grouped[2]] == [12, 0, 16, 7, 0, 9, 0, 3]

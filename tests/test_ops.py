@@ -991,3 +991,143 @@ def test_decode_program_keeps_both_kinds_of_cache_as_the_kernel_reads_them(
         *state, toks, vec, vec, vec, shapes(engine._rng)).compile()
     assert prefill.as_text().count(
         'custom_call_target="tpu_custom_call"') == 3
+
+
+def _granite_at_published_widths(n_slots, rows=None, vocab=8192):
+    """Granite-4.0-H at its published widths in three layers (Mamba-2,
+    attention, Mamba-2), abstract weights, an engine built for its raw
+    programs, and the cache of `n_slots` slots as shapes."""
+    import flax.linen as nn
+    from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
+    from skypilot_tpu.models import granite_hybrid as granite_lib
+    cfg = granite_lib.GraniteHybridConfig(
+        vocab_size=vocab, n_layers=3, attention_layers=(1,),
+        max_seq_len=1024, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    kind = granite_lib.GraniteHybrid
+    if rows is not None:
+        kind = type('Rows', (kind,), {'prefill_rows': rows})
+    model = kind(cfg)
+    params = nn.meta.unbox(jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8), jnp.int32))['params']))
+    engine = DecodeEngine(model, params, EngineConfig(
+        n_slots=2, steps_per_call=8, prefill_buckets=(512,)))
+    cache = jax.eval_shape(lambda p: engine._make_cache(p, n_slots), params)
+    return engine, params, cache
+
+
+def test_granite_kernels_compile_for_v5e(v5e_chip):
+    """The state kernel at the cell's shapes (64 slots of 32 pairs of
+    heads, a state of 128 by 128 lanes a pair), and the flash kernel at a
+    head of 64 (32 query heads over 8 KV heads, 512 positions)."""
+    from skypilot_tpu.ops.pallas import ssm_state_update as pallas_ssm
+
+    def sds(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=v5e_chip)
+
+    assert pallas_ssm.block_groups(32, 128, 128) == 16
+    row = sds(64, 32, 128)
+    compiled = pallas_ssm.ssm_state_update_fwd.lower(
+        sds(64, 32, 128, 128), row, row, row, sds(64, 128),
+        sds(64, 128)).compile()
+    assert 'ssm_state_update' in compiled.as_text()
+    q = sds(8, 32, 512, 64, dtype=jnp.bfloat16)
+    kv = sds(8, 8, 512, 64, dtype=jnp.bfloat16)
+    assert 'tpu_custom_call' in flash_attention_fwd.lower(
+        q, kv, kv, causal=True).compile().as_text()
+
+
+def test_decode_program_keeps_the_ssm_state_as_the_kernel_reads_it(
+        v5e_chip, monkeypatch):
+    """Granite-4.0-H's whole decode program (three layers at the published
+    widths, 16 slots, 8 steps a call) with the state kernel and the decode
+    attention kernel in it, the cache donated and every layout left to the
+    compiler as `_optimize_layouts` leaves them: a Mosaic call a layer,
+    every state leaf and K/V leaf row-major in and out, the new cache in
+    the buffers of the old, and no temporary as large as a state leaf (33
+    MB at 16 slots): no copy of one anywhere."""
+    import re
+    from jax.experimental.layout import Format, Layout
+    from skypilot_tpu.ops import attention as attn_lib
+    from skypilot_tpu.ops.pallas import decode_attention as pallas_da
+
+    n_slots = 16
+    engine, params, cache = _granite_at_published_widths(n_slots)
+    # `jax.default_backend()` is the CPU here: steer the choices (once the
+    # engine is built: it would lay its arrays out for a chip).
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    assert cache['layer_0']['mamba']['state'].shape == (16, 32, 128, 128)
+    assert cache['layer_1']['attn']['k'].shape == (16, 4, 1024, 128)
+    assert attn_lib.decode_kv_block(4, 128, 1024) == \
+        pallas_da.block_len(4, 128, 1024)
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=v5e_chip), tree)
+
+    vec = jax.ShapeDtypeStruct((n_slots,), jnp.int32, sharding=v5e_chip)
+    compiled = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(cache), auto, auto, auto, auto),
+        out_shardings=(auto, autos(cache), auto, auto)).lower(
+            shapes(params), shapes(cache), vec, vec, vec,
+            shapes(engine._rng)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3
+    assert 'ssm_state_update' in text
+    formats_in, _ = compiled.input_formats
+    for layer in ('layer_0', 'layer_2'):
+        for formats in (formats_in[1], compiled.output_formats[1]):
+            assert formats[layer]['mamba']['state'].layout.major_to_minor \
+                == (0, 1, 2, 3)
+    for leaf in ('k', 'v'):
+        assert formats_in[1]['layer_1']['attn'][leaf].layout.major_to_minor \
+            == (0, 1, 2, 3)
+    leaf = 16 * 32 * 128 * 128 * 4
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < leaf
+    assert memory.alias_size_in_bytes >= 2 * leaf
+    assert not re.search(r'= f32\[16,32,128,128\]\S* (copy|transpose)\(',
+                         text)
+    assert not re.search(r'= bf16\[16,4,1024,128\]\S* (copy|transpose)\(',
+                         text)
+
+
+def test_a_prefill_holds_one_groups_caches_beside_the_cache(v5e_chip,
+                                                            monkeypatch):
+    """A prefill of 32 rows of 512 into a cache of 32 slots (three layers
+    at Granite-4.0-H's published widths), compiled for the chip: with
+    `prefill_rows` 4 its temporaries are those of 4 rows, not of 32.  A
+    row's caches are 6.3 MB (two states of 2 MB, the taps, 2 MB of K and
+    V over 1024 positions); the rows in one pass hold 32 of them and
+    every activation 8 times over."""
+    n = 32
+    engines = {rows: _granite_at_published_widths(n, rows)
+               for rows in (4, n)}
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+
+    def temporaries(rows):
+        engine, params, cache = engines[rows]
+
+        def shapes(tree):
+            return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+                t.shape, t.dtype, sharding=v5e_chip), tree)
+
+        vec = jax.ShapeDtypeStruct((n,), jnp.int32, sharding=v5e_chip)
+        toks = jax.ShapeDtypeStruct((n, 512), jnp.int32, sharding=v5e_chip)
+        memory = jax.jit(engine._prefill_raw, donate_argnums=(1, 2, 3)).lower(
+            shapes(params), shapes(cache), vec, vec, toks, vec, vec, vec,
+            shapes(engine._rng)).compile().memory_analysis()
+        row = sum(leaf.size * leaf.dtype.itemsize
+                  for leaf in jax.tree.leaves(cache)) // n
+        assert memory.alias_size_in_bytes >= n * row     # inserted in place
+        return memory.temp_size_in_bytes, row
+
+    (by_four, row), (whole, _) = temporaries(4), temporaries(n)
+    assert 6_000_000 < row < 7_000_000
+    assert whole - by_four > (n - 4) * row
+    assert by_four < whole / 4

@@ -230,6 +230,45 @@ def test_cost_model_counts_a_window_layers_ring_as_a_window_of_positions():
         'kv': 3 * s * full_pos, 'recurrent': 3 * w * ring_pos}
 
 
+def test_cost_model_reads_a_state_space_models_state_and_kv_from_its_cache():
+    """Granite-4.0-H as the benchmark serves it, 64 slots of 1024
+    positions, the cache as shapes: with no case of its own the cost model
+    reads 36 Mamba-2 layers' float32 state and taps as per-slot state
+    (76,437,504 B a slot, read and written whole a step) and 4 attention
+    layers' K and V, two KV heads a row, as 8,192 B a position.  At 64
+    slots and 400 positions a token then costs 100 MB of weights, 153 MB
+    of state and 3 MB of K and V: the state is three fifths of it."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import families
+    config = json.loads((REPO_ROOT / 'benchmarks' / 'configs' /
+                         'granite-4.0-h-micro.json').read_text())
+    family = families.load(config)
+    model = family.serve_model(family.dims(config), config, jnp.bfloat16)
+    params = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params']))
+    slots = config['serve']['n_slots']
+    step = jnp.zeros((slots, 1), jnp.int32)
+    cache = jax.eval_shape(lambda p: model.apply(
+        {'params': p}, step, positions=step, decode=True,
+        mutable=['cache'])[1]['cache'], params)
+    assert cost_model_lib.cache_bytes_by_kind(cache) == {
+        'kv': 64 * 1024 * 8192, 'recurrent': 64 * 76437504}
+    cm = cost_model_lib.EngineCostModel.from_engine_state(
+        model.cfg, jax.tree.leaves(params), cache, chip='v5e')
+    assert cm.param_bytes == 2 * 3191396096 == 2 * cm.n_params
+    assert (cm.n_layers, cm.n_kv_layers, cm.n_window_layers) == (40, 4, 0)
+    assert cm.kv_bytes_per_pos() == 8192
+    assert cm.state_bytes_per_slot == 36 * (64 * 64 * 128 * 4 +
+                                            3 * 4352 * 2) == 76437504
+    a_token = cm.decode_hbm_bytes_per_token(400, slots)
+    assert a_token == 2 * 3191396096 / 64 + 401 * 8192 + 2 * 76437504
+    assert 0.59 < 2 * cm.state_bytes_per_slot / a_token < 0.60
+    assert cm.decode_flops_per_token(400) == \
+        2.0 * 3191396096 + 2.0 * 2048 * 4 * 400
+
+
 @pytest.mark.parametrize('path', BENCH_CONFIGS, ids=lambda p: p.stem)
 def test_program_counts_the_parameters_the_yardstick_counts(path):
     """The model object a configuration's family hands the program
