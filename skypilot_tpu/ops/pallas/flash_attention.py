@@ -9,18 +9,42 @@ issued).  With `return_residuals=True` the kernel also emits the row
 logsumexp, stored lane-broadcast as (bh, S, 128) f32 (the TPU layout
 convention for per-row scalars) and compacted to (bh, S) outside.
 
-Backward: two kernels, both flash-style recompute from (q, k, v, lse,
-delta) so nothing O(S^2) ever lands in HBM:
+Backward: flash-style recompute from (q, k, v, lse, delta), so nothing
+O(S^2) ever lands in HBM.  `delta = rowsum(dO * O)` is the standard
+softmax-backward correction and is computed in XLA (O(S*D), fuses into
+the surrounding graph).  One kernel visits each (k block, q block) tile
+once and makes everything the tile owes: five products and one
+exponential.
+  - Grid (bh, live tile): the tiles on or under the diagonal, a k
+    block's together and its q blocks ascending, from a scalar-prefetched
+    table that the index maps read, so that no grid step is spent (and
+    no block fetched) above the diagonal.  Only the tiles the diagonal
+    crosses run the mask.
+  - A tile is computed turned, keys on the sublanes and queries on the
+    lanes (s^T = k q^T): lse and delta arrive as (1, block_q) rows that
+    broadcast down the sublanes, 2 KB a block each, and no lane-broadcast
+    plane of either exists.
+  - dk and dv accumulate in (block_k, D) float32 scratch across a k
+    block's q sweep; dq accumulates (over k blocks ascending) into a
+    float32 scratch of the pair's WHOLE dq, (S, D), resident for all of
+    the pair's steps and written out once when the pair ends.
+Where a pair's dq does not fit in VMEM (`fused_bwd_vmem_bytes` against
+`_FUSED_BWD_VMEM_BUDGET`: a sequence several times 8,192) the backward is
+the two kernels it was, chosen from the shapes alone:
   - dq:    grid (bh, q_blocks, k_blocks), k innermost, dq accumulates in
            VMEM scratch across the k sweep of one q block.
   - dk/dv: grid (bh, k_blocks, q_blocks), q innermost, dk/dv accumulate
            across the q sweep of one k block.
-`delta = rowsum(dO * O)` is the standard softmax-backward correction and is
-computed in XLA (O(S*D), fuses into the surrounding graph).
+Each of the two makes every tile for itself (seven products and two
+exponentials a tile) and takes lse and delta as (bh, S, 128) planes.
 
 Sizing: q/k/v blocks live in VMEM ((block, D) each); with block=512 and
-D=128 in bf16 that is ~128 KB per operand — far under the ~16 MB/core VMEM,
-leaving room for the f32 accumulators and double buffering.
+D=128 in bf16 that is 128 KB per operand, two buffers each.  A v5e core
+has 128 MiB of VMEM and hands a kernel 16 MiB unless it says what it
+needs: the forward and the two-kernel backward stay far under that; the
+one-kernel backward counts what it holds (`fused_bwd_vmem_bytes`: 12 MiB
+at S = 4,096, 16 at 8,192, 40 at 32,768, of which the pair's dq is 32)
+and asks for that.
 """
 from __future__ import annotations
 
@@ -331,49 +355,163 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=('causal', 'block_size', 'interpret'))
-def flash_attention_bwd(q: jax.Array,
-                        k: jax.Array,
-                        v: jax.Array,
-                        out: jax.Array,
-                        lse: jax.Array,
-                        g: jax.Array,
-                        causal: bool = True,
-                        block_size: int = 512,
-                        interpret: bool = False):
-    """Flash backward.  q/out/g [B,Hq,S,D], k/v [B,Hkv,S,D],
-    lse [B,Hq,S] f32.  Returns (dq, dk, dv) with dk/dv at Hkv heads —
-    GQA grads are group-reduced here, mirroring the repeat this function
-    performs on the way in.
-    """
-    b, hq, s, d = q.shape
-    hkv = k.shape[1]
-    k_dtype, v_dtype = k.dtype, v.dtype
-    if hkv != hq:
-        k = jnp.repeat(k, hq // hkv, axis=1)
-        v = jnp.repeat(v, hq // hkv, axis=1)
-    scale = d**-0.5
-    block_q = min(block_size, s)
-    block_k = min(block_size, s)
-    if s % block_q or s % block_k:
-        raise ValueError(f'seq len {s} must divide block size {block_q}')
-    bh = b * hq
-    q3 = q.reshape(bh, s, d)
-    k3 = k.reshape(bh, s, d)
-    v3 = v.reshape(bh, s, d)
-    do3 = g.reshape(bh, s, d)
-    # delta = rowsum(dO * O): the softmax-backward correction term.  O(S*D)
-    # in XLA; lane-broadcast to the (bh, S, 128) scalar-row convention.
-    delta = jnp.sum(do3.astype(jnp.float32) *
-                    out.reshape(bh, s, d).astype(jnp.float32), axis=-1)
+# What the one-kernel backward may ask of VMEM (a v5e core has 128 MiB;
+# a kernel gets 16 MiB unless it says what it needs), and what the
+# compiler holds at once for a visit, in float32 tiles: s^T, p^T, dp^T,
+# ds^T, and at half a tile each p^T and ds^T in the compute type and ds
+# turned for dq's product.
+_FUSED_BWD_VMEM_BUDGET = 48 << 20
+_TILE_TEMPORARIES = 6
+
+
+def fused_bwd_vmem_bytes(s: int, d: int, block_q: int, block_k: int,
+                         itemsize: int) -> int:
+    """The VMEM `_fa_bwd_kernel` holds for one (batch, head) pair: the
+    pair's whole dq as a float32 sum beside its output block's two
+    buffers, the blocks' two buffers each (q, dO; k, v, dk, dv), the two
+    statistics (a row pads to 8 sublanes), the dk and dv sums, and the
+    tile's temporaries."""
+    return (s * d * (4 + 2 * itemsize) +
+            2 * (2 * block_q + 4 * block_k) * d * itemsize +
+            2 * 2 * 8 * block_q * 4 +
+            2 * block_k * d * 4 +
+            _TILE_TEMPORARIES * block_q * block_k * 4)
+
+
+def _live_tiles(n_q: int, n_k: int, block_q: int, block_k: int,
+                causal: bool):
+    """(k block, q block) of every tile in which some query sees some
+    key, a k block's tiles together and its q blocks ascending: the order
+    the grid visits them in."""
+    return [(kj, qi) for kj in range(n_k) for qi in range(n_q)
+            if not causal or kj * block_k <= qi * block_q + block_q - 1]
+
+
+def _fa_bwd_kernel(kj_ref, qi_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
+                   delta_ref, dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr,
+                   *, scale: float, causal: bool, block_q: int, block_k: int):
+    """One visit of tile (kj, qi): everything the tile owes.  The tile is
+    computed turned, keys on the sublanes and queries on the lanes, so
+    that a query's lse and delta are a row's lanes."""
+    t = pl.program_id(1)
+    n_t = pl.num_programs(1)
+    kj, qi = kj_ref[t], qi_ref[t]
+    n_q = dq_scr.shape[0] // block_q
+    first_q = (kj * block_k) // block_q if causal else 0
+
+    @pl.when(t == 0)
+    def _init_pair():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(qi == first_q)
+    def _init_sweep():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def visit(masked: bool):
+        q = q_ref[0]                                   # (bq, D)
+        k = k_ref[0]                                   # (bk, D)
+        do = do_ref[0]                                 # (bq, D)
+        st = jax.lax.dot_general(
+            k, q, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale  # (bk, bq)
+        if masked:
+            ahead = (jax.lax.broadcasted_iota(jnp.int32, st.shape, 1) -
+                     jax.lax.broadcasted_iota(jnp.int32, st.shape, 0))
+            st = jnp.where(ahead >= kj * block_k - qi * block_q, st,
+                           _NEG_INF)
+        pt = jnp.exp(st - lse_ref[0])                  # (bk, bq)
+        dpt = jax.lax.dot_general(
+            v_ref[0], do, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bk, bq)
+        dst = (pt * (dpt - delta_ref[0]) * scale).astype(q.dtype)
+        dv_scr[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bk, D)
+        dk_scr[:] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bk, D)
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_scr[rows, :] += jax.lax.dot_general(
+            dst, k, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)        # (bq, D)
+
+    if causal:
+        # Only a tile the diagonal crosses has anything to mask: one whose
+        # last key lies past its first query.
+        crossed = kj * block_k + block_k - 1 > qi * block_q
+        pl.when(crossed)(lambda: visit(True))
+        pl.when(jnp.logical_not(crossed))(lambda: visit(False))
+    else:
+        visit(False)
+
+    @pl.when(qi == n_q - 1)
+    def _end_sweep():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(t == n_t - 1)
+    def _end_pair():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
+def _bwd_one_kernel(q3, k3, v3, do3, lse, delta, *, scale, causal, block_q,
+                    block_k, interpret):
+    """Each live tile once: grid (pair, live tile), the tiles' blocks from
+    a table the index maps read, so that no step is spent above the
+    diagonal.  lse and delta [B x H, S] go in as rows."""
+    bh, s, d = q3.shape
+    tiles = _live_tiles(s // block_q, s // block_k, block_q, block_k, causal)
+    kj_of = jnp.asarray([kj for kj, _ in tiles], jnp.int32)
+    qi_of = jnp.asarray([qi for _, qi in tiles], jnp.int32)
+    q_spec = pl.BlockSpec((1, block_q, d),
+                          lambda p, t, kj_of, qi_of: (p, qi_of[t], 0))
+    kv_spec = pl.BlockSpec((1, block_k, d),
+                           lambda p, t, kj_of, qi_of: (p, kj_of[t], 0))
+    row_spec = pl.BlockSpec((1, 1, block_q),
+                            lambda p, t, kj_of, qi_of: (p, 0, qi_of[t]))
+    pair_spec = pl.BlockSpec((1, s, d), lambda p, t, kj_of, qi_of: (p, 0, 0))
+    live = len(tiles) * block_q * block_k
+    return pl.pallas_call(
+        functools.partial(_fa_bwd_kernel, scale=scale, causal=causal,
+                          block_q=block_q, block_k=block_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bh, len(tiles)),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=[pair_spec, kv_spec, kv_spec],
+            scratch_shapes=[pltpu.VMEM((s, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32),
+                            pltpu.VMEM((block_k, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
+                   jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, s, d), v3.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=('parallel', 'arbitrary'),
+            vmem_limit_bytes=fused_bwd_vmem_bytes(
+                s, d, block_q, block_k, q3.dtype.itemsize)),
+        cost_estimate=pl.CostEstimate(
+            flops=5 * 2 * bh * live * d,
+            bytes_accessed=(7 * q3.size * q3.dtype.itemsize + 8 * bh * s),
+            transcendentals=bh * live),
+        interpret=interpret,
+    )(kj_of, qi_of, q3, k3, v3, do3, lse.reshape(bh, 1, s),
+      delta.reshape(bh, 1, s))
+
+
+def _bwd_two_kernels(q3, k3, v3, do3, lse, delta, *, scale, causal, block_q,
+                     block_k, interpret):
+    """A pair's dq does not fit in VMEM: a dq kernel and a dk/dv kernel,
+    each making every tile for itself, the statistics lane-broadcast to
+    the (bh, S, 128) scalar-row convention."""
+    bh, s, d = q3.shape
     delta3 = jnp.broadcast_to(delta[:, :, None], (bh, s, 128))
-    lse3 = jnp.broadcast_to(lse.reshape(bh, s)[:, :, None], (bh, s, 128))
+    lse3 = jnp.broadcast_to(lse[:, :, None], (bh, s, 128))
 
     q_spec = pl.BlockSpec((1, block_q, d), lambda bh_, i, j: (bh_, i, 0))
     row_spec = pl.BlockSpec((1, block_q, 128), lambda bh_, i, j: (bh_, i, 0))
-    flops = 5 * b * hq * s * s * d // (2 if causal else 1)
-    io_bytes = (q3.size * 4 + do3.size * 2) * q.dtype.itemsize
+    flops = 5 * bh * s * s * d // (2 if causal else 1)
+    io_bytes = (q3.size * 4 + do3.size * 2) * q3.dtype.itemsize
 
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
@@ -388,7 +526,7 @@ def flash_attention_bwd(q: jax.Array,
             row_spec,
         ],
         out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((bh, s, d), q3.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
             flops=3 * flops // 5, bytes_accessed=io_bytes,
@@ -408,8 +546,8 @@ def flash_attention_bwd(q: jax.Array,
         in_specs=[q_spec_t, kv_spec, kv_spec, q_spec_t, row_spec_t,
                   row_spec_t],
         out_specs=[kv_spec, kv_spec],
-        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, s, d), v.dtype)],
+        out_shape=[jax.ShapeDtypeStruct((bh, s, d), k3.dtype),
+                   jax.ShapeDtypeStruct((bh, s, d), v3.dtype)],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         cost_estimate=pl.CostEstimate(
@@ -417,6 +555,50 @@ def flash_attention_bwd(q: jax.Array,
             transcendentals=bh * s * s),
         interpret=interpret,
     )(q3, k3, v3, do3, lse3, delta3)
+    return dq, dk, dv
+
+
+@functools.partial(jax.jit,
+                   static_argnames=('causal', 'block_size', 'interpret'))
+def flash_attention_bwd(q: jax.Array,
+                        k: jax.Array,
+                        v: jax.Array,
+                        out: jax.Array,
+                        lse: jax.Array,
+                        g: jax.Array,
+                        causal: bool = True,
+                        block_size: int = 512,
+                        interpret: bool = False):
+    """Flash backward.  q/out/g [B,Hq,S,D], k/v [B,Hkv,S,D],
+    lse [B,Hq,S] f32.  Returns (dq, dk, dv) with dk/dv at Hkv heads —
+    GQA grads are group-reduced here, mirroring the repeat this function
+    performs on the way in.  One kernel where a pair's dq fits in VMEM by
+    `fused_bwd_vmem_bytes`, two where it does not: the shapes decide.
+    """
+    b, hq, s, d = q.shape
+    hkv = k.shape[1]
+    k_dtype, v_dtype = k.dtype, v.dtype
+    if hkv != hq:
+        k = jnp.repeat(k, hq // hkv, axis=1)
+        v = jnp.repeat(v, hq // hkv, axis=1)
+    scale = d**-0.5
+    block_q = min(block_size, s)
+    block_k = min(block_size, s)
+    if s % block_q or s % block_k:
+        raise ValueError(f'seq len {s} must divide block size {block_q}')
+    bh = b * hq
+    q3 = q.reshape(bh, s, d)
+    do3 = g.reshape(bh, s, d)
+    # delta = rowsum(dO * O): the softmax-backward correction term.  O(S*D)
+    # in XLA.
+    delta = jnp.sum(do3.astype(jnp.float32) *
+                    out.reshape(bh, s, d).astype(jnp.float32), axis=-1)
+    fits = fused_bwd_vmem_bytes(s, d, block_q, block_k,
+                                q.dtype.itemsize) <= _FUSED_BWD_VMEM_BUDGET
+    dq, dk, dv = (_bwd_one_kernel if fits else _bwd_two_kernels)(
+        q3, k.reshape(bh, s, d), v.reshape(bh, s, d), do3,
+        lse.reshape(bh, s), delta, scale=scale, causal=causal,
+        block_q=block_q, block_k=block_k, interpret=interpret)
     dq = dq.reshape(b, hq, s, d)
     dk = dk.reshape(b, hq, s, d)
     dv = dv.reshape(b, hq, s, d)

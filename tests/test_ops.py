@@ -1,10 +1,13 @@
 """Attention op tests: pallas kernel (interpret mode) and ring attention
 against the XLA reference. Runs on the 8-device virtual CPU mesh."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
 
 from skypilot_tpu.ops.attention import flash_attention, mha_reference
+from skypilot_tpu.ops.pallas import flash_attention as pallas_fa
 from skypilot_tpu.ops.pallas.flash_attention import (flash_attention_bwd,
                                                      flash_attention_fwd)
 from skypilot_tpu.parallel.mesh import build_mesh, plan_mesh
@@ -48,40 +51,70 @@ def test_flash_attention_dispatch_cpu_and_grad():
     assert jnp.allclose(g, g_ref, atol=1e-4)
 
 
-@pytest.mark.parametrize('causal', [True, False])
-def test_pallas_flash_bwd_matches_reference(causal):
-    q, k, v = _qkv(b=1, h=2, s=256, d=64)
+def _bwd_against_reference(q, k, v, g, causal, backward=flash_attention_bwd):
+    """(dq, dk, dv) of `backward` at blocks of 128, each held to the XLA
+    reference's."""
     out, lse = flash_attention_fwd(q, k, v, causal=causal, block_size=128,
                                    interpret=True, return_residuals=True)
-    g = jax.random.normal(jax.random.PRNGKey(7), out.shape, out.dtype)
-    dq, dk, dv = flash_attention_bwd(q, k, v, out, lse, g, causal=causal,
-                                     block_size=128, interpret=True)
+    grads = backward(q, k, v, out, lse, g, causal=causal, block_size=128,
+                     interpret=True)
     ref_out, vjp = jax.vjp(
         lambda q_, k_, v_: mha_reference(q_, k_, v_, causal=causal), q, k, v)
-    dq_ref, dk_ref, dv_ref = vjp(g)
     assert jnp.max(jnp.abs(out - ref_out)) < 5e-3
-    assert jnp.max(jnp.abs(dq - dq_ref)) < 5e-3
-    assert jnp.max(jnp.abs(dk - dk_ref)) < 5e-3
-    assert jnp.max(jnp.abs(dv - dv_ref)) < 5e-3
+    for got, ref in zip(grads, vjp(g)):
+        assert got.shape == ref.shape
+        assert jnp.max(jnp.abs(got - ref)) < 5e-3
+    return grads
+
+
+def _pallas_calls(fn, *args, **kwargs) -> int:
+    return str(jax.make_jaxpr(functools.partial(fn, **kwargs))(*args)).count(
+        'pallas_call')
+
+
+# 2 x 2 blocks, 3 x 3 (a triangle of six tiles, S not a power of two
+# times the block) and one block, whose only tile is the diagonal's.
+@pytest.mark.parametrize('s', [256, 384, 128])
+@pytest.mark.parametrize('causal', [True, False])
+def test_pallas_flash_bwd_matches_reference(causal, s):
+    q, k, v = _qkv(b=1, h=2, s=s, d=64)
+    g = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
+    _bwd_against_reference(q, k, v, g, causal)
 
 
 def test_pallas_flash_bwd_gqa_group_reduce():
     # flash_attention_bwd owns the GQA repeat AND the matching group
     # reduction — grads must come back at Hkv heads and match the
-    # reference (the production _flash_bwd delegates to exactly this).
+    # reference (the production _flash_bwd delegates to exactly this),
+    # through the one kernel.
     q, k, v = _qkv(b=1, h=4, hkv=2, s=256, d=64)
-    out, lse = flash_attention_fwd(q, k, v, causal=True, block_size=128,
-                                   interpret=True, return_residuals=True)
-    g = jnp.ones_like(out)
-    dq, dk, dv = flash_attention_bwd(
-        q, k, v, out, lse, g, causal=True, block_size=128, interpret=True)
+    g = jnp.ones_like(q)
+    _, dk, dv = _bwd_against_reference(q, k, v, g, True)
     assert dk.shape == k.shape and dv.shape == v.shape
-    _, vjp = jax.vjp(
-        lambda q_, k_, v_: mha_reference(q_, k_, v_, causal=True), q, k, v)
-    dq_ref, dk_ref, dv_ref = vjp(g)
-    assert jnp.max(jnp.abs(dq - dq_ref)) < 5e-3
-    assert jnp.max(jnp.abs(dk - dk_ref)) < 5e-3
-    assert jnp.max(jnp.abs(dv - dv_ref)) < 5e-3
+    assert _pallas_calls(flash_attention_bwd.__wrapped__, q, k, v, q,
+                         q[..., 0], g, block_size=128, interpret=True) == 1
+
+
+@pytest.mark.parametrize('causal', [True, False])
+def test_pallas_flash_bwd_falls_back_where_a_pairs_dq_does_not_fit(
+        monkeypatch, causal):
+    """The choice is the count's: with less VMEM than a pair's float32 dq
+    (S x D x 4) the backward is the two kernels it was, and both paths
+    agree with the reference and with each other."""
+    s, d = 384, 64
+    q, k, v = _qkv(b=1, h=2, s=s, d=d)
+    g = jax.random.normal(jax.random.PRNGKey(7), q.shape, q.dtype)
+    assert pallas_fa.fused_bwd_vmem_bytes(s, d, 128, 128, 4) > s * d * 4
+    # (Unjitted: a cached trace would not read the budget again.)
+    backward = flash_attention_bwd.__wrapped__
+    kwargs = dict(causal=causal, block_size=128, interpret=True)
+    assert _pallas_calls(backward, q, k, v, q, q[..., 0], g, **kwargs) == 1
+    one = _bwd_against_reference(q, k, v, g, causal, backward)
+    monkeypatch.setattr(pallas_fa, '_FUSED_BWD_VMEM_BUDGET', s * d * 4 - 1)
+    assert _pallas_calls(backward, q, k, v, q, q[..., 0], g, **kwargs) == 2
+    two = _bwd_against_reference(q, k, v, g, causal, backward)
+    for a, b in zip(one, two):
+        assert jnp.max(jnp.abs(a - b)) < 1e-3
 
 
 def _repeat_attention(q, k, v, q_pos, k_pos):
@@ -257,9 +290,9 @@ def test_ring_attention_grad():
 # ----- the real kernels, compiled for a described (not attached) v5e ---------
 # Interpret mode cannot see what the TPU compiler refuses (tiling, VMEM).
 # The main-path shapes [B, Hq, Hkv, S, D]: bench-1b training at 4k and 8k,
-# and what model.init traces for llama2-7b.
+# what model.init traces for llama2-7b, and `pretrain-4k`'s own (MHA).
 _MAIN_PATH_SHAPES = [(4, 16, 8, 4096, 128), (2, 16, 8, 8192, 128),
-                     (1, 32, 32, 256, 128)]
+                     (1, 32, 32, 256, 128), (4, 16, 16, 4096, 128)]
 
 
 @pytest.fixture(scope='module')
@@ -293,7 +326,20 @@ def test_pallas_flash_compiles_for_v5e(v5e_chip, shape, direction):
         lse = sds(b, hq, s, dtype=jnp.float32)
         lowered = flash_attention_bwd.lower(q, kv, kv, q, lse, q,
                                             causal=True)
-    assert 'tpu_custom_call' in lowered.compile().as_text()
+    calls = [line for line in lowered.compile().as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert calls
+    if direction == 'bwd':
+        # A pair's dq fits at every one of these by the count: each tile
+        # is visited by one kernel, which takes lse and delta as rows and
+        # no [B x H, S, 128] float32 plane of either.
+        block = min(512, s)
+        assert pallas_fa.fused_bwd_vmem_bytes(
+            s, d, block, block, 2) <= pallas_fa._FUSED_BWD_VMEM_BUDGET
+        assert len(calls) == 1
+        operands = calls[0].split('operand_layout_constraints={')[1]
+        assert f'f32[{b * hq},1,{s}]' in operands
+        assert f'f32[{b * hq},{s},128]' not in operands
 
 
 # The decode step's attention at the serving cells' shapes [B, Hq, Hkv, S]:
@@ -669,7 +715,8 @@ def test_train_step_keeps_the_flash_output_and_fits_as_counted(
     8 layers, 4 x 4,096 tokens, float32 state) compiled for the described
     v5e.  With nothing kept the backward pass runs the flash forward kernel
     a second time in every layer; with `out` + `lse` kept it is in the
-    program once a layer.  The head and the loss go by chunks of rows, so
+    program once a layer, beside the one backward kernel: two custom
+    calls a layer.  The head and the loss go by chunks of rows, so
     no buffer has the whole logits' shape and the step's temporaries with
     nothing kept are the gradients and a block's working set (7.02 GB
     with the logits whole).  And the bytes the trainer counts for the
@@ -708,9 +755,14 @@ def test_train_step_keeps_the_flash_output_and_fits_as_counted(
         compiled = trainer_lib.make_sharded_train_step(
             mesh, shardings).lower(state, tokens).compile()
         text = compiled.as_text()
-        forward = sum('flash_attention_fwd)' in line
-                      for line in text.splitlines()
-                      if 'custom_call_target="tpu_custom_call"' in line)
+        kernels = [line for line in text.splitlines()
+                   if 'custom_call_target="tpu_custom_call"' in line]
+        forward = sum('flash_attention_fwd)' in line for line in kernels)
+        # The backward is one kernel a layer (it was two), so a step
+        # that keeps `out` + `lse` has two custom calls a layer.
+        assert sum('flash_attention_bwd)' in line
+                   for line in kernels) == cfg.n_layers
+        assert len(kernels) == forward + cfg.n_layers
         # (An eighth of the vocabulary is one chunk, and whole.)
         assert vocab == 8000 or not re.search(
             rf'f32\[({rows * seq}|{rows},{seq}),{vocab}\]', text)
