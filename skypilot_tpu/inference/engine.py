@@ -480,6 +480,12 @@ class DecodeEngine:
         # bookkeeping is loop-thread state; only the table itself is
         # shipped to device (async H2D, refreshed when dirty).
         self._paged = config.kv_page_size is not None
+        # How many rows of a prefill the model takes at once, where it
+        # says so: its prefill program is then one a bucket and reads how
+        # many rows it was handed (`a_group_at_a_time`).  The paged
+        # prefill runs a group's rows whole.
+        self._prefill_rows: Optional[int] = (
+            None if self._paged else getattr(model, 'prefill_rows', None))
         self._kv_quant = self._paged and config.kv_dtype == 'int8'
         self._spec_k = config.speculation if self._paged else 0
         self._page_size = config.kv_page_size
@@ -850,35 +856,63 @@ class DecodeEngine:
             return jnp.take_along_axis(
                 logits, index[:, None, None], axis=1)[:, 0]
 
-        def a_group_at_a_time(rows, big_cache, slots, *args):
+        at_once = self._prefill_rows
+
+        def a_group_at_a_time(rows, big_cache, slots, valid, *args):
             """`rows(*args)` -> (what is read of the rows, their caches)
-            over a prefill's N rows.  A model may say how many rows of a
-            prefill it can hold at once (`prefill_rows`, a divisor of
-            every admitted group's power of two): more go through it that
-            many at a time, every row on its own as in one pass, and each
-            group's caches go into `big_cache` at the group's `slots` as
-            the group ends (the cache carried through the loop), so that
-            a prefill holds `prefill_rows` rows' caches beside the cache
-            and never N (64 rows of 76 MB of recurrent state would be
-            4.9 GB).  Returns (what is read, over the N rows; the N rows'
-            caches still to insert, or None where the groups went in;
-            the cache)."""
-            n = args[0].shape[0]
-            at_once = getattr(model, 'prefill_rows', None) or n
-            if n <= at_once:
+            over a prefill's rows.  A model may say how many rows of a
+            prefill it can hold at once (`prefill_rows`).  Its program
+            is compiled for the most rows a prefill can bring (the
+            length of `args`) and READS how many it was handed, the sum
+            of `valid` (the admitted rows come first): that many go
+            through the model, `prefill_rows` at a time and what is
+            left over a row at a time (two loops of a trip count the
+            device reads, in one program), every row on its own as in
+            one pass; the rows past them are never computed.  Each
+            group's caches go into `big_cache` at the group's `slots`
+            as the group ends (the cache carried through the loops), so
+            that a prefill holds `prefill_rows` rows' caches beside the
+            cache and never N (64 rows of 76 MB of recurrent state
+            would be 4.9 GB), and what is read of a group goes into a
+            buffer of the whole length at the group's rows.  Returns
+            (what is read, over the whole length: zeros past the rows
+            that ran; the rows' caches still to insert, or None where
+            the groups went in; the cache)."""
+            if not at_once:
                 return rows(*args) + (big_cache,)
+            length = args[0].shape[0]
+            n = jnp.sum(valid)
 
-            def group(big, xs):
-                at, some = xs
-                out, cache = rows(*some)
-                return jax.tree.map(lambda b, small: b.at[at].set(small),
-                                    big, cache), out
+            def cut(tree, at, width):
+                return jax.tree.map(
+                    lambda t: jax.lax.dynamic_slice_in_dim(t, at, width),
+                    tree)
 
-            big_cache, out = jax.lax.scan(group, big_cache, jax.tree.map(
-                lambda t: t.reshape((n // at_once, at_once) + t.shape[1:]),
-                (slots, args)))
-            return jax.tree.map(lambda t: t.reshape((n,) + t.shape[2:]),
-                                out), None, big_cache
+            def group(width, at, carry):
+                """`width` rows from row `at` through the model; their
+                caches to their slots, what is read to their rows."""
+                big, out = carry
+                some, cache = rows(*cut(args, at, width))
+                to = cut(slots, at, width)
+                return (jax.tree.map(lambda b, small: b.at[to].set(small),
+                                     big, cache),
+                        jax.tree.map(
+                            lambda o, s: jax.lax.dynamic_update_slice_in_dim(
+                                o, s, at, 0), out, some))
+
+            read = jax.eval_shape(lambda: rows(*cut(args, 0, 1))[0])
+            carry = (big_cache, jax.tree.map(
+                lambda t: jnp.zeros((length,) + t.shape[1:], t.dtype), read))
+            whole = n // at_once
+            if length >= at_once:
+                carry = jax.lax.fori_loop(
+                    0, whole,
+                    lambda i, c: group(at_once, i * at_once, c), carry)
+            if at_once > 1:
+                carry = jax.lax.fori_loop(whole * at_once, n,
+                                          functools.partial(group, 1), carry)
+            big_cache, out = carry
+            return out, None, big_cache
 
         def prefill_insert(params, big_cache, last_toks, lens, tokens,
                            lengths, slots, valid, rng):
@@ -888,7 +922,9 @@ class DecodeEngine:
             of two by replicating row 0 (`valid`=0 for padding rows);
             batching the prefill keeps the MXU on one big [N*P] matmul
             instead of N small ones — the TTFT lever under admission
-            bursts."""
+            bursts.  For a model that declares `prefill_rows` N is the
+            engine's slots whatever the group, and the padding rows are
+            never run (`a_group_at_a_time`)."""
             n, p = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
             # `lengths`: a layer with recurrent state stops at each
@@ -900,7 +936,7 @@ class DecodeEngine:
                 return logits, cache['cache']
 
             logits, cache, big_cache = a_group_at_a_time(
-                rows, big_cache, slots, tokens, positions, lengths)
+                rows, big_cache, slots, valid, tokens, positions, lengths)
             last = last_logits(logits, lengths - 1)                  # [N,V]
             firsts = sample(last, rng)                               # [N]
             # Padding rows replicate row 0, so their duplicate scatter
@@ -991,7 +1027,7 @@ class DecodeEngine:
             the cache past the whole blocks hold what the padded rows
             wrote: nothing reads them before the block's passes overwrite
             them."""
-            del valid, rng               # padding rows carry row 0's values
+            del rng                      # padding rows carry row 0's values
             n, p = tokens.shape
             positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
 
@@ -1002,7 +1038,7 @@ class DecodeEngine:
                 return (), cache['cache']
 
             _, cache, big_cache = a_group_at_a_time(
-                rows, big_cache, slots, tokens, positions, lengths)
+                rows, big_cache, slots, valid, tokens, positions, lengths)
             first = lengths - lengths % blk                          # [N]
             offs = jnp.arange(blk)[None, :]
             opened = jnp.take_along_axis(
@@ -1570,7 +1606,9 @@ class DecodeEngine:
     def _prefill_for(self, bucket: int, padded_n: int):
         """Prefill executable for one (bucket, batch) shape, pinned to
         the decode-chosen param/cache layouts on TPU (plain jit
-        elsewhere)."""
+        elsewhere).  `padded_n` is `_padded_rows` of the group: one
+        value, so one executable a bucket, for a model that declares
+        `prefill_rows`."""
         if self._fmt_params is None:
             return self._prefill_insert
         key = (bucket, padded_n)
@@ -1970,7 +2008,9 @@ class DecodeEngine:
         """Compile every prefill shape up front (TPU layout path only).
 
         Admission pads groups to powers of two, so the shape set is
-        |buckets| x (log2(n_slots)+1).  Without this, the first burst
+        |buckets| x (log2(n_slots)+1); for a model that declares
+        `prefill_rows`, whose program reads how many rows it was handed,
+        it is |buckets|.  Without this, the first burst
         that hits a new shape stalls the whole decode batch behind a
         multi-second XLA compile — a mid-traffic TTFT/TPOT spike.
 
@@ -2008,11 +2048,6 @@ class DecodeEngine:
 
     def _prewarm_pinned(self) -> None:
         """AOT-compile every pinned prefill and chunk program."""
-        # Include the first power of two >= n_slots: _admit_group pads to
-        # the NEXT power of two, which exceeds n_slots when n_slots is not
-        # itself one (n_slots=6, burst of 5 -> pad 8) — without it the
-        # first such burst hits the mid-traffic compile stall prewarm
-        # exists to prevent.
         for bucket in self.cfg.prefill_buckets:
             for size in self._prewarm_sizes():
                 self._prefill_for(bucket, size)
@@ -2029,16 +2064,24 @@ class DecodeEngine:
         (so the chunked-prefill programs are reachable)."""
         return self.max_prompt_len > self.cfg.prefill_buckets[-1]
 
+    def _padded_rows(self, n: int) -> int:
+        """The rows of the prefill program that admits a group of `n`:
+        the next power of two, or for a model that declares
+        `prefill_rows` the engine's slots, whatever `n` (its program
+        reads `n`)."""
+        if self._prefill_rows:
+            return self.cfg.n_slots
+        return 1 << (n - 1).bit_length()
+
     def _prewarm_sizes(self):
-        """Padded admission-group row counts: powers of two up to and
-        including the first one >= n_slots (see prewarm)."""
-        n, sizes = 1, []
-        while True:
-            sizes.append(n)
-            if n >= self.cfg.n_slots:
-                break
-            n *= 2
-        return sizes
+        """Every value of `_padded_rows`: the powers of two up to and
+        including the first one >= n_slots, which exceeds n_slots when
+        that is not itself one (n_slots=6, burst of 5 -> pad 8: without
+        it the first such burst hits the mid-traffic compile stall
+        prewarm exists to prevent); the one row count of a model that
+        declares `prefill_rows`."""
+        return sorted({self._padded_rows(n)
+                       for n in range(1, self.cfg.n_slots + 1)})
 
     def _warm(self, kind: str, fn, *args, **shape: int):
         """One dummy dispatch of prewarm.  The call returns when the
@@ -2336,10 +2379,15 @@ class DecodeEngine:
 
         The group is padded to a power-of-two row count (few compiled
         shapes: |buckets| x log2(n_slots)); padding replicates row 0,
-        whose duplicate scatter writes are identical-value no-ops.
+        whose duplicate scatter writes are identical-value no-ops.  For
+        a model that declares `prefill_rows` the arrays are as long as
+        the engine has slots (one compiled shape a bucket) and the
+        program runs the group's rows alone, which it counts from
+        `valid`.
         """
         n = len(group)
-        padded_n = 1 << (n - 1).bit_length()
+        padded_n = self._padded_rows(n)
+        ran = n if self._prefill_rows else padded_n
         tokens = np.zeros((padded_n, bucket), np.int32)
         lengths = np.zeros((padded_n,), np.int32)
         slots = np.zeros((padded_n,), np.int32)
@@ -2361,7 +2409,7 @@ class DecodeEngine:
             pt_rows[n:] = pt_rows[0]
         prefill = self._prefill_for(bucket, padded_n)
         t0 = time.perf_counter()
-        self._carry(t0, 'prefill', bucket, padded_n, n)
+        self._carry(t0, 'prefill', bucket, ran, n)
         if self._paged:
             self._cache, self._last_d, self._lens_d = prefill(
                 self.params, self._cache, self._last_d, self._lens_d,
@@ -2411,6 +2459,12 @@ class DecodeEngine:
             self._queued_tokens -= n_tokens
         metrics_lib.inc_counter('skytpu_engine_prefill_tokens_total',
                                 float(n_tokens))
+        # Rows with a request against the rows the device computed: the
+        # padding to a power of two, none where the program reads `n`.
+        metrics_lib.inc_counter('skytpu_engine_prefill_rows_total',
+                                float(n), kind='admitted')
+        metrics_lib.inc_counter('skytpu_engine_prefill_rows_total',
+                                float(ran), kind='run')
         if self._kv_quant:
             # Real (non-trash) pages quantized at this insert's scatter.
             metrics_lib.inc_counter(
@@ -2989,7 +3043,7 @@ class DecodeEngine:
                held: int = 1) -> None:
         """A program dispatched at `t0` that is no decode call: it rides
         in front of the next one, whose engine.call span lists it
-        (`rows` as compiled, `held` of them with a request)."""
+        (`rows` the device ran, `held` of them with a request)."""
         if not self._carried:
             self._carried_t0 = t0
         self._carried.append({'kind': kind, 'bucket': bucket, 'rows': rows,

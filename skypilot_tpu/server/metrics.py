@@ -67,6 +67,11 @@ _HELP = {
         '((finish - first token) / (tokens - 1))',
     'skytpu_engine_prefill_tokens_total':
         'Prompt tokens prefilled into decode slots',
+    'skytpu_engine_prefill_rows_total':
+        'Rows of the batched prefill programs by kind: admitted (rows '
+        'with a request) and run (rows the device computed: with the '
+        'padding to a power of two, without it where the program reads '
+        'how many rows it was handed, a model with prefill_rows)',
     'skytpu_engine_prefill_chunks_total':
         'Chunked-prefill dispatches (fixed-size chunks of long prompts '
         'interleaved with decode calls)',

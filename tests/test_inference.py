@@ -495,14 +495,18 @@ def test_decode_step_reads_nothing_of_a_row_that_is_not_live(
                  after_told['cache'], after['cache'])
 
 
-def _kv_positions():
+def _by_kind(family):
+    """The registry's series of `family` by their `kind` label."""
     import re
     from skypilot_tpu.server import metrics as metrics_lib
-    series = re.compile(
-        r'^skytpu_engine_decode_kv_positions_total\{kind="(\w+)"\} (\S+)$')
+    series = re.compile(rf'^{family}\{{kind="(\w+)"\}} (\S+)$')
     return {m.group(1): float(m.group(2))
             for m in map(series.match, metrics_lib.render().splitlines())
             if m}
+
+
+def _kv_positions():
+    return _by_kind('skytpu_engine_decode_kv_positions_total')
 
 
 def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
@@ -623,3 +627,106 @@ def test_a_wave_goes_into_the_cache_group_by_group(model_and_params):
     for a, b in zip(jax.tree.leaves(whole), jax.tree.leaves(grouped)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
     assert [int(n) for n in grouped[2]] == [12, 0, 16, 7, 0, 9, 0, 3]
+
+
+def _prefill_rows_total():
+    found = _by_kind('skytpu_engine_prefill_rows_total')
+    return found.get('admitted', 0.0), found.get('run', 0.0)
+
+
+@pytest.mark.parametrize('rows_at_once,group', [
+    (1, 1), (1, 3), (1, 2), (1, 8), (4, 1), (4, 3), (4, 5), (4, 8)])
+def test_a_prefill_program_reads_how_many_rows_it_was_handed(
+        model_and_params, rows_at_once, group):
+    """A model that declares `prefill_rows`: groups of 1, 3, `prefill_rows`
+    + 1 and `n_slots` rows through the ONE program a bucket give the
+    tokens, the inserted cache and the lengths of the programs compiled a
+    power of two of rows (the same weights without `prefill_rows`), and
+    the device runs the admitted rows alone: `prefill_rows` at a time and
+    what is left over a row at a time, nothing past them."""
+    from skypilot_tpu.inference import engine as engine_mod
+    from skypilot_tpu.server import tracing
+    model, params = model_and_params
+    kind = type('Rows', (Llama,), {'prefill_rows': rows_at_once})
+    rng = np.random.default_rng(group)
+    prompts = [rng.integers(1, CFG.vocab_size, int(n)).tolist()
+               for n in rng.integers(2, 17, group)]
+
+    def served(model, pinned):
+        # (A copy: the layout pass donates the tree it is handed.)
+        engine = DecodeEngine(model, jax.tree.map(jnp.copy, params),
+                              EngineConfig(n_slots=8, prefill_buckets=(16,),
+                                           steps_per_call=2))
+        if pinned:                     # the TPU path, as tests/test_phases.py
+            engine._optimize_layouts()
+            engine.prewarm()
+        before = _prefill_rows_total()
+        requests = [engine.submit(p, 5) for p in prompts]
+        engine.step()                   # one group, then a decode call
+        after = _prefill_rows_total()
+        state = jax.tree.map(np.asarray, (engine._cache, engine._lens_d))
+        for _ in range(50):
+            if all(r.finished_at is not None for r in requests):
+                break
+            engine.step()
+        return (engine, [r.tokens() for r in requests], state,
+                (after[0] - before[0], after[1] - before[1]))
+
+    def prefill_compiles():
+        return [e['attrs'] for e in tracing.events_for(
+            engine_mod.SETUP_REQUEST_ID)
+            if e['name'] == 'engine.setup.compile' and
+            e['attrs']['kind'] == 'prefill']
+
+    _, want, state, padded = served(model, pinned=False)
+    compiled_before = len(prefill_compiles())
+    engine, got, loop_state, ran = served(kind(CFG), pinned=True)
+    assert got == want and all(len(t) == 5 for t in got)
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(loop_state)):
+        np.testing.assert_allclose(a, b, atol=1e-5)
+    # The rows the device ran: the group's, where the padding was run too.
+    assert ran == (group, group)
+    assert padded == (group, 1 << (group - 1).bit_length())
+    # One program a bucket, compiled by prewarm() and by nothing after it.
+    assert engine._prewarm_sizes() == [8]
+    assert {key: fn.as_text().split(',', 1)[0].split()[-1]
+            for key, fn in engine._prefill_compiled.items()} == {
+                (16, 8): 'jit_prefill_insert_b16_n8'}
+    assert prefill_compiles()[compiled_before:] == [
+        {'kind': 'prefill', 'bucket': 16, 'rows': 8}]
+    calls = [e['attrs']['carried'] for e in tracing.events_for('engine-loop')
+             if e['name'] == 'engine.call' and e['attrs']['carried']]
+    assert calls[-1] == [{'kind': 'prefill', 'bucket': 16, 'rows': group,
+                          'held': group}]
+
+
+def test_rows_past_the_group_are_never_computed(model_and_params):
+    """What the counter's `run` says, seen on the device: five rows of
+    eight admitted at `prefill_rows` 4 go through the model as one group
+    of four and one row alone; the three rows behind them, here with
+    prompts and slots of their own, leave their slots' cache untouched
+    (the program compiled for eight rows would have written them)."""
+    model, params = model_and_params
+    rng = np.random.default_rng(11)
+    tokens = rng.integers(1, CFG.vocab_size, (8, 16)).astype(np.int32)
+    lengths = rng.integers(2, 17, 8).astype(np.int32)
+    slots = np.array([5, 2, 7, 0, 3, 1, 4, 6], np.int32)
+    valid = np.array([1, 1, 1, 1, 1, 0, 0, 0], np.int32)
+
+    def served(model):
+        engine = DecodeEngine(model, params, EngineConfig(
+            n_slots=8, prefill_buckets=(16,), steps_per_call=2))
+        return jax.jit(engine._prefill_raw)(
+            params, engine._cache, engine._last_d, engine._lens_d,
+            jnp.asarray(tokens), jnp.asarray(lengths), jnp.asarray(slots),
+            jnp.asarray(valid), engine._rng)
+
+    whole = served(model)
+    looped = served(type('Rows', (Llama,), {'prefill_rows': 4})(CFG))
+    for a, b in zip(jax.tree.leaves(whole[0]), jax.tree.leaves(looped[0])):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_allclose(a[slots[:5]], b[slots[:5]], atol=1e-5)
+        assert np.abs(a[slots[5:]]).max() > 0.0
+        assert not b[slots[5:]].any()
+    np.testing.assert_array_equal(np.asarray(whole[1])[slots[:5]],
+                                  np.asarray(looped[1])[slots[:5]])

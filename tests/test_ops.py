@@ -1183,3 +1183,65 @@ def test_a_prefill_holds_one_groups_caches_beside_the_cache(v5e_chip,
     assert 6_000_000 < row < 7_000_000
     assert whole - by_four > (n - 4) * row
     assert by_four < whole / 4
+
+
+def test_the_one_prefill_program_keeps_the_cache_where_decode_pinned_it(
+        v5e_chip, monkeypatch):
+    """The WHOLE prefill program of a model with `prefill_rows` (three
+    layers at Granite-4.0-H's published widths, 16 slots, a bucket of 512),
+    compiled for the chip as `_prefill_for` compiles it: the length of its
+    arrays is the slots', the rows it runs a value it reads, two loops (8
+    rows at a time, then a row at a time) that carry the donated cache,
+    which comes in and goes out in the layouts the decode program chose.
+    No state or K/V leaf is copied or transposed anywhere, each body
+    scatters its rows in place, and the program's temporaries are at most
+    those of the parent's program of 16 rows (two groups of 8 under a
+    `lax.scan`: 340,414,464 bytes, a scratch compile of commit 551dff7),
+    so the one program costs a start no more memory than the largest of
+    the five it stands for."""
+    import re
+    from jax.experimental.layout import Format, Layout
+
+    n_slots = 16
+    engine, params, cache = _granite_at_published_widths(n_slots)
+    assert engine.model.prefill_rows == 8
+    monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
+    auto = Format(Layout.AUTO, v5e_chip)
+
+    def autos(tree):
+        return jax.tree.map(lambda _: auto, tree)
+
+    def shapes(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=v5e_chip), tree)
+
+    vec = jax.ShapeDtypeStruct((n_slots,), jnp.int32, sharding=v5e_chip)
+    state = (shapes(params), shapes(cache), vec, vec)
+    decode = jax.jit(
+        engine._decode_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(autos(params), autos(cache), auto, auto, auto, auto),
+        out_shardings=(auto, autos(cache), auto, auto)).lower(
+            *state, vec, shapes(engine._rng)).compile()
+    pinned, _ = decode.input_formats
+    toks = jax.ShapeDtypeStruct((n_slots, 512), jnp.int32, sharding=v5e_chip)
+    prefill = jax.jit(
+        engine._prefill_raw, donate_argnums=(1, 2, 3),
+        in_shardings=(*pinned[:4], None, None, None, None, None),
+        out_shardings=tuple(pinned[1:4])).lower(
+            *state, toks, vec, vec, vec, shapes(engine._rng)).compile()
+    text = prefill.as_text()
+    # A flash kernel a body (the one attention layer), and the two loops.
+    assert text.count('custom_call_target="tpu_custom_call"') == 2
+    for leaf in (r'f32\[16,32,128,128\]', r'bf16\[16,4,1024,128\]'):
+        assert not re.search(rf'= {leaf}\S* (copy|transpose)\(', text)
+    formats_in, _ = prefill.input_formats
+    for got in (formats_in[1], prefill.output_formats[0]):
+        for want, fmt in zip(jax.tree.leaves(pinned[1]),
+                             jax.tree.leaves(got)):
+            assert fmt.layout.major_to_minor == want.layout.major_to_minor
+    memory = prefill.memory_analysis()
+    row = sum(leaf.size * leaf.dtype.itemsize
+              for leaf in jax.tree.leaves(cache)) // n_slots
+    assert memory.alias_size_in_bytes >= n_slots * row   # inserted in place
+    assert memory.temp_size_in_bytes <= 340_414_464
+

@@ -291,6 +291,48 @@ def test_prewarm_records_one_compile_span_per_program():
     assert engine._prefill_chunk._cache_size() == 1
 
 
+def test_mesh_prewarm_compiles_one_program_a_bucket_with_prefill_rows():
+    """The virtual-mesh prewarm path for a model that declares
+    `prefill_rows`: one prefill program a bucket, compiled for the slots'
+    rows (the dummy dispatch hands it no valid row, so its loops take no
+    trip), and a group through it compiles nothing more."""
+    import dataclasses
+    import jax
+    import jax.numpy as jnp
+    from skypilot_tpu.inference import engine as engine_mod
+    from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama, init_params
+    from skypilot_tpu.parallel.mesh import build_serve_mesh
+    cfg = dataclasses.replace(LLAMA_CONFIGS['tiny'], dtype=jnp.float32)
+    params = init_params(Llama(cfg), jax.random.PRNGKey(0))['params']
+    mesh = build_serve_mesh(2, n_heads=cfg.n_heads,
+                            n_kv_heads=cfg.n_kv_heads)
+    kind = type('ARow', (Llama,), {'prefill_rows': 1})
+    engine = engine_mod.DecodeEngine(
+        kind(cfg, mesh), params,
+        engine_mod.EngineConfig(mesh=mesh, n_slots=4,
+                                prefill_buckets=(8, 16),
+                                max_prompt_len=16))
+    before = len(tracing.events_for(engine_mod.SETUP_REQUEST_ID))
+    engine.prewarm()
+    shapes = [(e['attrs']['kind'], e['attrs'].get('bucket'),
+               e['attrs'].get('rows'))
+              for e in tracing.events_for(
+                  engine_mod.SETUP_REQUEST_ID)[before:]
+              if e['name'] == 'engine.setup.compile']
+    assert sorted(shapes, key=str) == sorted(
+        [('prefill', 8, 4), ('prefill', 16, 4), ('decode', None, None)],
+        key=str)
+    assert engine._prefill_insert._cache_size() == 2
+    reqs = [engine.submit([1, 2, 3], 4), engine.submit([5, 6], 4),
+            engine.submit(list(range(1, 12)), 3)]
+    for _ in range(100):
+        engine.step_pipelined()
+        if all(r.finished_at is not None for r in reqs):
+            break
+    assert [len(r.tokens()) for r in reqs] == [4, 4, 3]
+    assert engine._prefill_insert._cache_size() == 2
+
+
 def test_pinned_programs_carry_their_shape_in_their_name():
     """jit names a program after its function: the pinned prefill and
     chunk programs are named for their shapes, prefix unchanged."""

@@ -320,6 +320,63 @@ def test_a_wave_hands_over_as_one_group(engines, monkeypatch):
     assert groups[0] == 4 and len(groups) > 3
 
 
+def test_a_prefill_of_blocks_keeps_a_program_a_power_of_two(params):
+    """A model that declares no `prefill_rows` keeps its prefill programs:
+    one a bucket and power of two of rows up to the slots, named for both
+    (the TPU path, run here by calling the layout pass by hand), and each
+    lowers to the text of the program it was before a model with
+    `prefill_rows` got one program a bucket (the parent's function,
+    written out below: the rows in one pass under the mask by blocks, one
+    scatter of every row's K and V, the first block opened)."""
+    family, dims, config = tiny_config('sequential')
+    model = family.serve_model(dims, config, DTYPE)
+    assert not hasattr(model, 'prefill_rows')
+    # (A copy: the layout pass donates the tree it is handed.)
+    engine = DecodeEngine(model, jax.tree.map(jnp.copy, params), EngineConfig(
+        n_slots=3, prefill_buckets=(8, 16), steps_per_call=3))
+    assert engine._prewarm_sizes() == [1, 2, 4]
+
+    def prefill_insert_blocks(params, big_cache, block, starts, tokens,
+                              lengths, slots, valid, rng):
+        del valid, rng
+        n, p = tokens.shape
+        positions = jnp.broadcast_to(jnp.arange(p)[None, :], (n, p))
+        _, cache = model.apply(
+            {'params': params}, tokens, positions=positions, decode=True,
+            lengths=lengths, mutable=['cache'])
+        first = lengths - lengths % BLOCK
+        offs = jnp.arange(BLOCK)[None, :]
+        opened = jnp.take_along_axis(
+            tokens, jnp.minimum(first[:, None] + offs, p - 1), axis=1)
+        masked = (offs >= (lengths % BLOCK)[:, None]).astype(jnp.int32)
+        big_cache = jax.tree_util.tree_map(
+            lambda big, small: big.at[slots].set(small), big_cache,
+            cache['cache'])
+        return (big_cache,
+                {'tok': block['tok'].at[slots].set(opened),
+                 'masked': block['masked'].at[slots].set(masked)},
+                starts.at[slots].set(first))
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), tree)
+
+    for n in (1, 4):
+        rows = jax.ShapeDtypeStruct((n, 16), jnp.int32)
+        vec = jax.ShapeDtypeStruct((n,), jnp.int32)
+        args = (shapes(params), shapes(engine._cache), shapes(engine._last_d),
+                shapes(engine._lens_d), rows, vec, vec, vec,
+                shapes(engine._rng))
+        assert jax.jit(engine._prefill_raw).lower(*args).as_text() == \
+            jax.jit(prefill_insert_blocks).lower(*args).as_text()
+    engine._optimize_layouts()
+    engine.prewarm()
+    assert {key: fn.as_text().split(',', 1)[0].split()[-1]
+            for key, fn in engine._prefill_compiled.items()} == {
+                (bucket, n): f'jit_prefill_insert_b{bucket}_n{n}'
+                for bucket in (8, 16) for n in (1, 2, 4)}
+
+
 def test_an_end_token_cuts_the_block(engines, params):
     """A request ends at its end token inside a block: the rest of the
     block is not output."""
