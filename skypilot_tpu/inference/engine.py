@@ -130,6 +130,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import inspect
 import os
 import queue
 import threading
@@ -143,6 +144,7 @@ import numpy as np
 from skypilot_tpu import sky_logging
 from skypilot_tpu.inference import kv_quant
 from skypilot_tpu.inference.paging import TRASH_PAGE, PagePool, RadixCache
+from skypilot_tpu.models import served as served_lib
 from skypilot_tpu.perf import compile_telemetry
 from skypilot_tpu.perf import cost_model as cost_model_lib
 from skypilot_tpu.server import metrics as metrics_lib
@@ -409,15 +411,33 @@ def _passes_to_tokens(masked_left: int, passes: int, block: int,
 
 
 class DecodeEngine:
-    """Slot-based continuous batching over a Llama-family model.
+    """Slot-based continuous batching over a decoder that declares what
+    it needs of the engine (`model.served()`, models/served.py `Served`:
+    the whole of the seam, read once here and checked against the model
+    and its cache before any program is compiled).
 
-    `model.cfg.max_seq_len` bounds prompt+generation; the per-layer KV
-    cache is [n_slots, n_kv_heads, max_seq_len, head_dim].
+    `model.cfg.max_seq_len` bounds prompt+generation; a cache leaf leads
+    with the slot ([n_slots, n_kv_heads, max_seq_len, head_dim] for keys
+    and values).
     """
 
     def __init__(self, model, params, config: EngineConfig = EngineConfig()):
+        if config.mesh is not None:
+            from skypilot_tpu.parallel import mesh as mesh_lib
+            mcfg = model.cfg
+            mesh_lib.validate_tensor_parallel(
+                int(config.mesh.shape.get(config.tensor_axis, 1)),
+                n_heads=mcfg.n_heads,
+                n_kv_heads=getattr(mcfg, 'n_kv_heads', None))
+            if model.mesh is None:
+                # The model needs the mesh too (activation constraints,
+                # the one-hot embed that keeps a vocab-sharded table
+                # gather-free), and what it declares follows from it
+                # (`decode_kv_block`).
+                model = model.clone(mesh=config.mesh)
         self.model = model
         self.params = params
+        served = served_lib.read(model)
         if config.n_slots <= 0:
             raise ValueError(
                 f'EngineConfig.n_slots must be a positive slot count, '
@@ -431,32 +451,21 @@ class DecodeEngine:
             buckets = (max_len,)
         config = dataclasses.replace(config, prefill_buckets=buckets)
         self._validate_paging(config, max_len)
-        # A model whose cache the page manager cannot hold says why
-        # (`unpaged_cache`: the clause that follows its name below).
-        unpaged = getattr(model, 'unpaged_cache', None)
-        if unpaged and (config.kv_page_size is not None or
-                        config.speculation):
+        if served.unpaged_cache and (config.kv_page_size is not None or
+                                     config.speculation):
             raise ValueError(
-                f'{type(model).__name__} {unpaged}, and the page manager '
-                f'holds keys and values only, one token a sequence and '
-                f'step: kv_page_size, speculation and KV transfer '
-                f'(submit_prefill / submit_adopt, which need pages) are '
-                f'not available with it; leave kv_page_size None and '
-                f'speculation 0')
+                f'{type(model).__name__} {served.unpaged_cache}, and the '
+                f'page manager holds keys and values only, one token a '
+                f'sequence and step: kv_page_size, speculation and KV '
+                f'transfer (submit_prefill / submit_adopt, which need '
+                f'pages) are not available with it; leave kv_page_size '
+                f'None and speculation 0')
         # Generation by blocks: a model that declares a `block_length`
         # is served a pass over a block a step (the module docstring),
         # by its `block_schedule`.
-        self._block: Optional[int] = getattr(model, 'block_length', None)
-        self._schedule = (model.block_schedule if self._block else None)
+        self._block: Optional[int] = served.block_length
+        self._schedule = served.block_schedule
         self._admission_held = False     # _hold_admission, last iteration
-        if self._block:
-            off = [v for v in buckets + (max_len,) if v % self._block]
-            if off:
-                raise ValueError(
-                    f'{type(model).__name__} generates by blocks of '
-                    f'{self._block} positions aligned to absolute '
-                    f'positions: every prefill bucket and max_seq_len '
-                    f'must be multiples of it; offending values: {off}')
         self.cfg = config
         self._rng = jax.random.PRNGKey(config.seed)
         self._prefill_q: 'queue.Queue[Request]' = queue.Queue()
@@ -480,12 +489,9 @@ class DecodeEngine:
         # bookkeeping is loop-thread state; only the table itself is
         # shipped to device (async H2D, refreshed when dirty).
         self._paged = config.kv_page_size is not None
-        # How many rows of a prefill the model takes at once, where it
-        # says so: its prefill program is then one a bucket and reads how
-        # many rows it was handed (`a_group_at_a_time`).  The paged
-        # prefill runs a group's rows whole.
+        # The paged prefill runs a group's rows whole.
         self._prefill_rows: Optional[int] = (
-            None if self._paged else getattr(model, 'prefill_rows', None))
+            None if self._paged else served.prefill_rows)
         self._kv_quant = self._paged and config.kv_dtype == 'int8'
         self._spec_k = config.speculation if self._paged else 0
         self._page_size = config.kv_page_size
@@ -577,9 +583,8 @@ class DecodeEngine:
         self._kv_empty = 0
         self._window_fetched = 0
         self._window_context = 0
-        kv_block = getattr(model, 'decode_kv_block', None)
-        self._kv_block: Optional[int] = kv_block() if kv_block else None
-        self._takes_live: bool = getattr(model, 'decode_takes_live', False)
+        self._kv_block: Optional[int] = served.decode_kv_block
+        self._takes_live: bool = served.decode_takes_live
         self._setup_programs = 0    # engine.setup.compile spans so far
         # Minimum attribution window; benchmarks/tests shrink or grow
         # it to bracket exactly their measured region.
@@ -595,24 +600,26 @@ class DecodeEngine:
         self._cache_shardings = None
         self._repl = None
         self._scratch_shardings = None
+        cache_abs = jax.eval_shape(self._make_cache, self.params)
+        self._check_served(served, cache_abs)
         if self._mesh is not None:
-            self._setup_mesh()
+            self._setup_mesh(cache_abs)
         # True when the installed tree is an engine-private device copy
         # (mesh/TPU-layout device_put) that update_params may DELETE
         # after a swap; on the plain path the tree is the caller's and
         # is only ever dereferenced.
         self._params_owned = self._mesh is not None
+        self._publish_stats = served.publish_stats
         self._stats_abs = (self._decode_stats_abs()
-                           if hasattr(model, 'publish_stats') and
-                           not self._paged else None)
+                           if self._publish_stats and not self._paged
+                           else None)
         self._build_fns()
-        self._init_cache()
+        self._init_cache(cache_abs)
         # By kind (perf/cost_model.py): keys and values a position (the
-        # leaves named k / v), a latent a position (the leaves the model
-        # names in `latent_leaves`), a window layer's ring (those it names
-        # in `window_leaves`), per-slot recurrent state (the rest).
-        latent = getattr(self.model, 'latent_leaves', ())
-        window = getattr(self.model, 'window_leaves', ())
+        # leaves named k / v), a latent a position, a window layer's ring
+        # (the leaves the model declares as such), per-slot recurrent
+        # state (the rest).
+        latent, window = served.latent_leaves, served.window_leaves
         for kind, n_bytes in cost_model_lib.cache_bytes_by_kind(
                 self._cache, latent, window).items():
             metrics_lib.set_gauge('skytpu_engine_cache_bytes',
@@ -736,9 +743,50 @@ class DecodeEngine:
                     f'(max_seq_len {max_len} / kv_page_size {ps} '
                     f'+ 1 trash page)')
 
+    def _check_served(self, served: served_lib.Served, cache_abs) -> None:
+        """Hold what the model declares against the model and its cache
+        (`cache_abs`: `_make_cache`, abstractly), before any program is
+        compiled: a declaration that does not fit would otherwise be a
+        counter that is wrong or a program of the wrong kind, in silence."""
+        name = type(self.model).__name__
+        leaves = {getattr(p, 'key', None) for path, _ in
+                  jax.tree_util.tree_flatten_with_path(cache_abs)[0]
+                  for p in path}
+        for field in ('latent_leaves', 'window_leaves'):
+            missing = sorted(set(getattr(served, field)) - leaves)
+            if missing:
+                raise ValueError(
+                    f'{name}.served().{field} names {missing}, which its '
+                    f'cache does not hold (its leaves: '
+                    f'{sorted(map(str, leaves))})')
+        other = sorted(set(cost_model_lib.cache_bytes_by_kind(
+            cache_abs, served.latent_leaves, served.window_leaves)) - {'kv'})
+        if other and not served.unpaged_cache:
+            raise ValueError(
+                f'{name} caches leaves of kind {other} beside or in place '
+                f'of keys and values a position and head (perf/'
+                f'cost_model.py has the rule), which the page manager '
+                f'cannot hold: {name}.served().unpaged_cache must say why')
+        if served.decode_takes_live and 'live' not in inspect.signature(
+                type(self.model).__call__).parameters:
+            raise ValueError(
+                f'{name}.served().decode_takes_live is set, and '
+                f'{name}.__call__ takes no `live`')
+        if served.block_length:
+            max_len = self.model.cfg.max_seq_len
+            off = [v for v in self.cfg.prefill_buckets + (max_len,)
+                   if v % served.block_length]
+            if off:
+                raise ValueError(
+                    f'{name} generates by blocks of '
+                    f'{served.block_length} positions aligned to absolute '
+                    f'positions: every prefill bucket and max_seq_len '
+                    f'must be multiples of it; offending values: {off}')
+
     # ----- mesh setup --------------------------------------------------------
-    def _setup_mesh(self):
-        """Commit engine state to fixed NamedShardings.
+    def _setup_mesh(self, cache_abs):
+        """Commit engine state to fixed NamedShardings (`cache_abs`: the
+        dense cache of `_make_cache`, abstractly).
 
         Params shard per the model's logical axes (serving_shardings),
         the KV cache over its kv-heads dim, and everything the host
@@ -750,17 +798,8 @@ class DecodeEngine:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from skypilot_tpu.inference.weights import serving_shardings
-        from skypilot_tpu.parallel import mesh as mesh_lib
 
         mesh, axis = self._mesh, self.cfg.tensor_axis
-        mcfg = self.model.cfg
-        mesh_lib.validate_tensor_parallel(
-            int(mesh.shape.get(axis, 1)), n_heads=mcfg.n_heads,
-            n_kv_heads=getattr(mcfg, 'n_kv_heads', None))
-        if getattr(self.model, 'mesh', None) is None:
-            # The model needs the mesh too (activation constraints, the
-            # one-hot embed that keeps a vocab-sharded table gather-free).
-            self.model = self.model.clone(mesh=mesh)
         self._repl = NamedSharding(mesh, P())
         self._param_shardings = serving_shardings(self.model, mesh)
         # Unbox first: flax logical-axis metadata boxes carry init-time
@@ -780,7 +819,6 @@ class DecodeEngine:
             tp = int(mesh.shape.get(axis, 1))
             return kv if n_kv and n_kv % tp == 0 else self._repl
 
-        cache_abs = jax.eval_shape(self._make_cache, self.params)
         if self._paged:
             # The page pool [n_pages, n_kv_heads, page_size, head_dim]
             # shards over the same kv-heads dim as the dense cache, so
@@ -1479,13 +1517,14 @@ class DecodeEngine:
                     'masked': jnp.zeros((n, self._block), jnp.int32)}
         return jnp.zeros((n,), jnp.int32)
 
-    def _init_cache(self):
-        """Materialize the big cache from a trace of a dummy decode batch.
-        Under a mesh it is created ALREADY sharded (jit out_shardings) —
-        at no point does a full cache exist on one device."""
+    def _init_cache(self, cache_abs):
+        """Materialize the big cache from a trace of a dummy decode batch
+        (`cache_abs`).  Under a mesh it is created ALREADY sharded (jit
+        out_shardings) — at no point does a full cache exist on one
+        device."""
         n = self.cfg.n_slots
         if self._paged:
-            self._init_pool()
+            self._init_pool(cache_abs)
             return
         if self._mesh is None:
             # Zeros of the traced shapes (as _init_pool makes its pool):
@@ -1493,8 +1532,7 @@ class DecodeEngine:
             # model here, op by op, cost a model of several kinds of
             # layer a minute and a half of small compiles.
             self._cache = jax.tree.map(
-                lambda a: jnp.zeros(a.shape, a.dtype),
-                jax.eval_shape(self._make_cache, self.params))
+                lambda a: jnp.zeros(a.shape, a.dtype), cache_abs)
             self._last_d = self._last_zeros()
             self._lens_d = jnp.zeros((n,), jnp.int32)
             return
@@ -1506,14 +1544,13 @@ class DecodeEngine:
         self._lens_d = jax.device_put(jnp.zeros((n,), jnp.int32),
                                       self._repl)
 
-    def _init_pool(self):
+    def _init_pool(self, cache_abs):
         """Materialize the PAGE POOL: the dense cache tree's shape with
         [n_slots, ..., max_seq_len, ...] swapped for [n_pages, ...,
         page_size, ...].  Total HBM = n_pages x page bytes — sized by
         kv_pages, not by n_slots x max_seq_len; that delta is the
         reservation paging removes.  Created sharded under a mesh."""
         n = self.cfg.n_slots
-        cache_abs = jax.eval_shape(self._make_cache, self.params)
 
         def make_pool(_params):
             # _pool_abs: a ShapeDtypeStruct, or a QuantPages pair of
@@ -3168,7 +3205,7 @@ class DecodeEngine:
         self._close_call(call, ph)
         with tracing.phase('engine.loop.emit') as ph:
             if stats is not None:
-                self.model.publish_stats(stats)
+                self._publish_stats(stats)
             snapshot = {i: self._slots[i] for i in active}
             if self._spec_k:
                 # Speculative verify: the last output row is the
@@ -3249,7 +3286,7 @@ class DecodeEngine:
             self._close_call(call, ph)
         with tracing.phase('engine.loop.emit') as ph:
             if stats is not None:
-                self.model.publish_stats(stats)
+                self._publish_stats(stats)
             if snapshot is not None and self._block:
                 self._process_blocks(out, snapshot, (t_prev, t_fetched))
             elif snapshot is not None:

@@ -75,6 +75,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 
 from skypilot_tpu.models.llama import RMSNorm
+from skypilot_tpu.models.served import Served, publish_state_updates
 from skypilot_tpu.ops import attention as attn_lib
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -508,14 +509,22 @@ class GraniteHybrid(nn.Module):
     # The mesh the program is partitioned over, if any: the Pallas kernels
     # are for one device (ops/attention.py, `ssm_step_groups`).
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine: why the paged manager, speculation and KV
-    # transfer cannot hold this model's cache yet; and how many rows of a
-    # prefill go through the stack at once.  A row of 512 positions builds
-    # a chunk's pairwise decays [64 heads, 256, 256] in float32 (17 MB,
-    # and the products beside them) and leaves 76 MB of state: 8 rows are
-    # 4,096 tokens a matrix product and under a gigabyte of both.
-    unpaged_cache = 'keeps recurrent state beside its keys and values'
-    prefill_rows = 8
+
+    def served(self) -> Served:
+        cfg = self.cfg
+        return Served(
+            unpaged_cache='keeps recurrent state beside its keys and values',
+            # A row of 512 positions builds a chunk's pairwise decays
+            # [64 heads, 256, 256] in float32 (17 MB, and the products
+            # beside them) and leaves 76 MB of state: 8 rows are 4,096
+            # tokens a matrix product and under a gigabyte of both.
+            prefill_rows=8,
+            # A tile of an attention layer's decode kernel: two KV heads
+            # a row of the cache.
+            decode_kv_block=attn_lib.decode_kv_block(
+                cfg.n_kv_heads // 2, 2 * cfg.head_dim, cfg.max_seq_len,
+                cfg.dtype, self.mesh),
+            publish_stats=self.publish_stats)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -551,15 +560,6 @@ class GraniteHybrid(nn.Module):
                             preferred_element_type=jnp.float32)
         return logits / cfg.logits_scaling
 
-    def decode_kv_block(self) -> Optional[int]:
-        """For the engine's `decode_kv_positions` counter: the positions a
-        tile of an attention layer's decode kernel covers, None where it
-        reads every slot whole."""
-        cfg = self.cfg
-        return attn_lib.decode_kv_block(
-            cfg.n_kv_heads // 2, 2 * cfg.head_dim, cfg.max_seq_len,
-            cfg.dtype, self.mesh)
-
     def publish_stats(self, stats) -> None:
         """A decode call's summed `stats` collection (host arrays), to the
         /metrics registry: the head-states its steps updated, under the
@@ -567,18 +567,9 @@ class GraniteHybrid(nn.Module):
         cfg = self.cfg
         rows = int(stats['rows_stepped'][0][0])
         publish_state_updates(
+            'skytpu_ssm_state_updates_total',
             rows * (cfg.n_layers - len(cfg.attention_layers)) * cfg.ssm_heads,
             ssm_step_groups(jax.ShapeDtypeStruct(
                 (1, cfg.ssm_heads // cfg.ssm_pack, cfg.ssm_state,
                  cfg.ssm_pack * cfg.ssm_head_dim),
                 jnp.float32), 1, self.mesh) is not None)
-
-
-def publish_state_updates(head_states: int, by_kernel: bool) -> None:
-    """A decode call's Mamba head-states updated (slots x Mamba layers x
-    heads x steps), to the /metrics registry under the path that updated
-    them."""
-    from skypilot_tpu.server import metrics as metrics_lib
-    for path, took in (('kernel', by_kernel), ('xla', not by_kernel)):
-        metrics_lib.inc_counter('skytpu_ssm_state_updates_total',
-                                float(head_states * took), path=path)

@@ -26,6 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
 from skypilot_tpu.inference import kv_quant
+from skypilot_tpu.models.served import Served
 from skypilot_tpu.ops import attention as attn_lib
 
 
@@ -49,11 +50,8 @@ class LlamaConfig:
     # (`keep_plan`): the flash kernel's output and logsumexp in every
     # layer, then q/k/v, gate/up and the post-attention stream a layer at
     # a time.  'none' keeps nothing and the backward pass runs the whole
-    # forward again (min HBM; also what 'fit' is with no bytes).  'dots'
-    # keeps every matmul's output whatever that takes and recomputes the
-    # elementwise work; it never kept the attention kernel's output (a
-    # Pallas call is no dot), so the flash forward still ran twice.
-    remat_policy: str = 'fit'          # 'fit' | 'none' | 'dots'
+    # forward again (min HBM; also what 'fit' is with no bytes).
+    remat_policy: str = 'fit'          # 'fit' | 'none'
     # Bytes of named activations a device may keep under 'fit'.  None is
     # "whoever runs the step says": `Trainer` counts what the device has
     # left beside the state, the gradients and the loss's temporaries
@@ -71,12 +69,6 @@ class LlamaConfig:
     @property
     def head_dim(self) -> int:
         return self.dim // self.n_heads
-
-    def flops_per_token(self) -> float:
-        """Approx dense fwd+bwd FLOPs/token (6N + attention term) for MFU."""
-        n_params = self.num_params()
-        attn = 12 * self.n_layers * self.dim * self.max_seq_len
-        return 6 * n_params + attn
 
     def num_params(self) -> int:
         d, f = self.dim, self.ffn_dim
@@ -181,6 +173,9 @@ def keep_plan(cfg: LlamaConfig, mesh, batch: int, seq: int) -> KeepPlan:
     causal attention at half its square; the block's last matmul
     (down_proj) is dead in the recompute and never counted in it.
     """
+    if cfg.remat_policy not in ('fit', 'none'):
+        raise ValueError(f"remat_policy must be 'fit' or 'none', got "
+                         f'{cfg.remat_policy!r}')
     local, tp = device_share(cfg, mesh, batch, seq)
     tokens = batch * seq
     act = jnp.dtype(cfg.dtype).itemsize
@@ -205,9 +200,6 @@ def keep_plan(cfg: LlamaConfig, mesh, batch: int, seq: int) -> KeepPlan:
     if not cfg.remat:
         # No checkpoint: autodiff keeps these and more, nothing runs twice.
         layers = [list(groups)] * cfg.n_layers
-    elif cfg.remat_policy == 'dots':
-        # Every matmul's output whatever it takes; never the kernel's.
-        layers = [[g for g in can_keep if g != 'attn_out']] * cfg.n_layers
     elif cfg.remat_policy == 'fit':
         left = cfg.remat_keep_bytes or 0
         for g in can_keep:
@@ -600,10 +592,12 @@ class Llama(nn.Module):
     cfg: LlamaConfig
     mesh: Optional[Mesh] = None
 
-    # Read by DecodeEngine: the one-row step takes `live` [B], which
-    # rows hold a request, and its attention reads nothing of the others
-    # (ops/attention.py decode_attention: a length of zero).
-    decode_takes_live = True
+    def served(self) -> Served:
+        # The one-row step's attention reads nothing of a row that `live`
+        # says holds no request (ops/attention.py decode_attention: a
+        # length of zero).
+        return Served(decode_takes_live=True,
+                      decode_kv_block=_decode_kv_block(self.cfg, self.mesh))
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -634,15 +628,10 @@ class Llama(nn.Module):
         x = embed(tokens)
         blocks = [Block] * cfg.n_layers
         if cfg.remat and not decode:
-            if cfg.remat_policy == 'dots':
-                policies = [jax.checkpoint_policies
-                            .dots_with_no_batch_dims_saveable] * cfg.n_layers
-            else:
-                plan = keep_plan(cfg, self.mesh, *tokens.shape)
-                policies = [plan.policy(i) for i in range(cfg.n_layers)]
+            plan = keep_plan(cfg, self.mesh, *tokens.shape)
             blocks = [nn.remat(
                 Block, static_argnums=(3,),  # (self, x, positions, decode)
-                policy=policy) for policy in policies]
+                policy=plan.policy(i)) for i in range(cfg.n_layers)]
         # Keep the historical 3-arg call where there is nothing more to
         # pass (the remat wrapper's static_argnums indexing depends on
         # it).
@@ -681,12 +670,6 @@ class Llama(nn.Module):
         and whether those are the embedding table [V, D] (tied) and not
         a kernel [D, V]."""
         return self(tokens, to_logits=False)
-
-    def decode_kv_block(self) -> Optional[int]:
-        """For the engine's `decode_kv_positions` counter: the positions
-        a tile of the decode step's attention covers, None where it
-        reads every slot whole."""
-        return _decode_kv_block(self.cfg, self.mesh)
 
 
 def init_params(model: Llama, rng: jax.Array, batch: int = 1,

@@ -68,6 +68,7 @@ kind of K and V: ROADMAP B2, B3).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -78,11 +79,12 @@ from jax.sharding import Mesh
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.llama import RMSNorm, _rope
 from skypilot_tpu.models.openpangu_moe import DenseFFN
+from skypilot_tpu.models.served import Served
 from skypilot_tpu.ops import attention as attn_lib
 
 
-# A window layer's cache leaves: rings of `window` positions a slot (read by
-# `DecodeEngine` off the model as `window_leaves`).
+# A window layer's cache leaves: rings of `window` positions a slot (what
+# the model declares as `Served.window_leaves`).
 WINDOW_LEAVES = ('ring_k', 'ring_v')
 
 
@@ -392,22 +394,29 @@ class MiMoV2(nn.Module):
     # The mesh the program is partitioned over, if any: the Pallas kernels
     # are for one device (ops/attention.py, models/moe.py `expert_tile`).
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine.  A slot's cache is of two kinds: the page
-    # manager, speculation and KV transfer hold one (`unpaged_cache` says
-    # why).  The leaves of these names are rings of `window` positions
-    # ([slots, heads, window, width]), which the cost model and the
-    # engine's counters count as kind "window": min(context, window)
-    # positions read a step, whatever the context.  A prefill of many rows
-    # runs `prefill_rows` rows at a time through the whole stack: a row of
-    # 8,192 positions builds 64 heads' queries of 192 (0.2 GB), a full
-    # layer's K and V repeated for them (0.33 GB) and the outputs, beside
-    # 6.9 GB of weights and 1.6 GB of cache.  The decode step is told
-    # which rows hold a request (`decode_takes_live`).
-    unpaged_cache = ('keeps a ring of its window\'s positions in its window '
-                     'layers beside the whole context in its full layers')
-    window_leaves = WINDOW_LEAVES
-    prefill_rows = 1
-    decode_takes_live = True
+
+    def served(self) -> Served:
+        cfg = self.cfg
+        return Served(
+            # A slot's cache is of two kinds; the page manager holds one.
+            unpaged_cache=("keeps a ring of its window's positions in its "
+                           'window layers beside the whole context in its '
+                           'full layers'),
+            # Rings of `window` positions: a step reads min(context,
+            # window) of them, whatever the context.
+            window_leaves=WINDOW_LEAVES,
+            # One row at a time through the whole stack: a row of 8,192
+            # positions builds 64 heads' queries of 192 (0.2 GB), a full
+            # layer's K and V repeated for them (0.33 GB) and the outputs,
+            # beside 6.9 GB of weights and 1.6 GB of cache.
+            prefill_rows=1,
+            decode_takes_live=True,
+            # A tile of a FULL layer's decode attention.
+            decode_kv_block=attn_lib.decode_kv_block(
+                cfg.n_kv_heads, cfg.qk_dim - cfg.rope_dim, cfg.max_seq_len,
+                cfg.dtype, self.mesh),
+            publish_stats=functools.partial(moe_lib.publish_stats,
+                                            cfg.held_experts))
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -435,22 +444,3 @@ class MiMoV2(nn.Module):
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype, name='lm_head')(x)
         return logits.astype(jnp.float32)
-
-    def decode_kv_block(self) -> Optional[int]:
-        """For the engine's `decode_kv_positions` counter: the positions a
-        tile of a FULL layer's decode attention covers, None where it
-        reads every slot whole."""
-        cfg = self.cfg
-        return attn_lib.decode_kv_block(
-            cfg.n_kv_heads, cfg.qk_dim - cfg.rope_dim, cfg.max_seq_len,
-            cfg.dtype, self.mesh)
-
-    def publish_stats(self, stats) -> None:
-        """A decode call's summed `stats` collection (host arrays), to the
-        /metrics registry: the expert layers' counts added up."""
-        layers = [layer['moe'] for layer in stats.values()]
-        moe_lib.publish_routing(
-            self.cfg.held_experts,
-            sum(moe['expert_tokens'][0] for moe in layers),
-            sum(moe['touched'][0] for moe in layers),
-            sum(moe['kernel_trips'][0] for moe in layers))

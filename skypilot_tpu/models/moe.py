@@ -435,3 +435,16 @@ def publish_routing(held: tuple, expert_tokens, touched,
         if n:
             metrics_lib.inc_counter('skytpu_moe_expert_tokens_total',
                                     float(n), expert=str(e))
+
+
+def publish_stats(held: tuple, stats):
+    """A decode call's summed `stats` collection (host arrays; every entry
+    a layer whose `moe` holds what `DroplessMoE` sows), to the /metrics
+    registry: the expert layers' counts added up, one update.  Returns the
+    summed `expert_tokens`."""
+    layers = [layer['moe'] for layer in stats.values()]
+    expert_tokens = sum(moe['expert_tokens'][0] for moe in layers)
+    publish_routing(held, expert_tokens,
+                    sum(moe['touched'][0] for moe in layers),
+                    sum(moe['kernel_trips'][0] for moe in layers))
+    return expert_tokens

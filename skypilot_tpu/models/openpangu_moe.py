@@ -61,6 +61,7 @@ pool holds keys and values, not a latent (ROADMAP B3).
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -70,6 +71,7 @@ from jax.sharding import Mesh
 
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.llama import RMSNorm, _rope
+from skypilot_tpu.models.served import Served
 from skypilot_tpu.ops import attention as attn_lib
 
 
@@ -278,22 +280,28 @@ class OpenPanguMoE(nn.Module):
     # The mesh the program is partitioned over, if any: the Pallas kernels
     # are for one device (ops/attention.py, models/moe.py `expert_tile`).
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine.  The cache is a latent a position, not keys and
-    # values a head: the paged manager, speculation and KV transfer cannot
-    # hold it yet (`unpaged_cache` says why), and the cost model counts the
-    # leaves of these names, [slots, positions, width], as kind "latent".
-    # A prefill of many rows runs one row at a time through the whole
-    # stack: what a row of 4,096 positions builds per layer, 128 heads'
-    # queries, keys and values among it, is 1.7 GB (a v5e compile at the
-    # published widths; two rows at a time leave 0.3 GB of the chip beside
-    # 9.8 GB of weights and 32 slots of cache), and the expert layer over
-    # every row's tokens at once does not fit either, so bounding a
-    # sublayer's rows inside the model (models/solar_open2.py `_by_rows`)
-    # would not do.
-    unpaged_cache = ('caches a latent a position in place of keys and '
-                     'values a head')
-    latent_leaves = ('c_kv', 'k_pe')
-    prefill_rows = 1
+
+    def served(self) -> Served:
+        cfg = self.cfg
+        return Served(
+            # The cache is a latent a position ([slots, positions, width]
+            # leaves), not keys and values a head.
+            unpaged_cache=('caches a latent a position in place of keys and '
+                           'values a head'),
+            latent_leaves=('c_kv', 'k_pe'),
+            # One row at a time through the whole stack: what a row of
+            # 4,096 positions builds per layer, 128 heads' queries, keys
+            # and values among it, is 1.7 GB (a v5e compile at the
+            # published widths; two rows at a time leave 0.3 GB of the chip
+            # beside 9.8 GB of weights and 32 slots of cache), and the
+            # expert layer over every row's tokens at once does not fit
+            # either, so bounding a sublayer's rows inside the model
+            # (models/solar_open2.py `_by_rows`) would not do.
+            prefill_rows=1,
+            decode_kv_block=attn_lib.latent_kv_block(
+                cfg.kv_rank, cfg.max_seq_len, self.mesh),
+            publish_stats=functools.partial(moe_lib.publish_stats,
+                                            cfg.held_experts))
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -320,20 +328,3 @@ class OpenPanguMoE(nn.Module):
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype, name='lm_head')(x)
         return logits.astype(jnp.float32)
-
-    def decode_kv_block(self) -> Optional[int]:
-        """For the engine's `decode_kv_positions` counter: the positions
-        a tile of the decode step's attention covers, None where it
-        reads every slot whole."""
-        return attn_lib.latent_kv_block(self.cfg.kv_rank,
-                                        self.cfg.max_seq_len, self.mesh)
-
-    def publish_stats(self, stats) -> None:
-        """A decode call's summed `stats` collection (host arrays), to the
-        /metrics registry: the expert layers' counts added up."""
-        layers = [layer['moe'] for layer in stats.values()]
-        moe_lib.publish_routing(
-            self.cfg.held_experts,
-            sum(moe['expert_tokens'][0] for moe in layers),
-            sum(moe['touched'][0] for moe in layers),
-            sum(moe['kernel_trips'][0] for moe in layers))

@@ -21,10 +21,10 @@ and the block itself, logits are read AT each masked position (no shift)
 and some positions take their token (`BlockSchedule`); when none is masked
 a *commit pass* runs the B clean tokens, which leaves their K and V in the
 cache, and the block is the output.  So the serving step is a pass over a
-block a slot, not one token a slot: `DecodeEngine` reads `block_length`
-and `block_schedule` off the model and runs such passes (inference/
-engine.py, "Generation by blocks"); a model without them is served a token
-a step as before.
+block a slot, not one token a slot: the model declares `block_length`
+and `block_schedule` (`served()`) and `DecodeEngine` runs such passes
+(inference/engine.py, "Generation by blocks"); a model without them is
+served a token a step as before.
 
 Three paths from the one set of weights:
 
@@ -48,6 +48,7 @@ The rows of a pass are scattered over (slot x head, position) as rows of
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Optional, Tuple
 
 import flax.linen as nn
@@ -57,6 +58,7 @@ from jax.sharding import Mesh
 
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.llama import RMSNorm, _rope
+from skypilot_tpu.models.served import Served
 from skypilot_tpu.ops import attention as attn_lib
 
 REMASKINGS = ('low_confidence_static', 'low_confidence_dynamic',
@@ -66,7 +68,7 @@ REMASKINGS = ('low_confidence_static', 'low_confidence_dynamic',
 @dataclasses.dataclass(frozen=True)
 class BlockSchedule:
     """How a block's masked positions take their tokens, a denoising pass
-    (`DecodeEngine` reads it off the model as `block_schedule`):
+    (what the model declares as `Served.block_schedule`):
 
     - `low_confidence_static`: the k masked positions whose chosen token
       has the highest probability, k = block_length / `steps`;
@@ -262,23 +264,26 @@ class SDARMoE(nn.Module):
     position alone, [B, 1, vocab] (a prompt's logits are not read)."""
     cfg: SDARMoEConfig
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine.  A step is a pass over a block (`block_length`,
-    # `block_schedule` below), so one token a sequence and step, which the
-    # page manager, speculation and KV transfer count by, does not hold;
-    # the pass takes `live` [B] and reads nothing of a slot without a
-    # request.
-    unpaged_cache = 'generates by passes over blocks of positions'
-    decode_takes_live = True
 
-    @property
-    def block_length(self) -> int:
-        return self.cfg.block_length
-
-    @property
-    def block_schedule(self) -> BlockSchedule:
+    def served(self) -> Served:
         cfg = self.cfg
-        return BlockSchedule(cfg.mask_id, cfg.remasking, cfg.denoising_steps,
-                             cfg.confidence_threshold)
+        return Served(
+            # A step is a pass over a block, so one token a sequence and
+            # step, which the page manager, speculation and KV transfer
+            # count by, does not hold.
+            unpaged_cache='generates by passes over blocks of positions',
+            block_length=cfg.block_length,
+            block_schedule=BlockSchedule(
+                cfg.mask_id, cfg.remasking, cfg.denoising_steps,
+                cfg.confidence_threshold),
+            # A pass reads nothing of a slot that `live` says holds no
+            # request.
+            decode_takes_live=True,
+            decode_kv_block=attn_lib.decode_kv_block(
+                cfg.n_kv_heads, cfg.head_dim, cfg.max_seq_len, cfg.dtype,
+                self.mesh),
+            publish_stats=functools.partial(moe_lib.publish_stats,
+                                            cfg.held_experts))
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -307,21 +312,3 @@ class SDARMoE(nn.Module):
         logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
                           param_dtype=cfg.param_dtype, name='lm_head')(x)
         return logits.astype(jnp.float32)
-
-    def decode_kv_block(self) -> Optional[int]:
-        """For the engine's `decode_kv_positions` counter: the positions
-        a tile of a pass's attention covers, None where it reads every
-        slot whole."""
-        cfg = self.cfg
-        return attn_lib.decode_kv_block(cfg.n_kv_heads, cfg.head_dim,
-                                        cfg.max_seq_len, cfg.dtype, self.mesh)
-
-    def publish_stats(self, stats) -> None:
-        """A decode call's summed `stats` collection (host arrays), to the
-        /metrics registry: the expert layers' counts added up."""
-        layers = [layer['moe'] for layer in stats.values()]
-        moe_lib.publish_routing(
-            self.cfg.held_experts,
-            sum(moe['expert_tokens'][0] for moe in layers),
-            sum(moe['touched'][0] for moe in layers),
-            sum(moe['kernel_trips'][0] for moe in layers))

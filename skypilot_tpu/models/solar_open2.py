@@ -58,6 +58,7 @@ from jax.sharding import Mesh
 
 from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.llama import RMSNorm
+from skypilot_tpu.models.served import Served, publish_state_updates
 from skypilot_tpu.ops import attention as attn_lib
 
 _HIGHEST = jax.lax.Precision.HIGHEST
@@ -425,9 +426,11 @@ class SolarOpen2(nn.Module):
     # of the expert layer (models/moe.py `expert_tile`) and of the KDA
     # state (`kda_step_heads`) are for one device.
     mesh: Optional[Mesh] = None
-    # Read by DecodeEngine: why the paged manager, speculation and KV
-    # transfer cannot hold this model's cache yet.
-    unpaged_cache = 'keeps recurrent state beside its keys and values'
+
+    def served(self) -> Served:
+        return Served(
+            unpaged_cache='keeps recurrent state beside its keys and values',
+            publish_stats=self.publish_stats)
 
     @nn.compact
     def __call__(self, tokens: jax.Array,
@@ -457,30 +460,17 @@ class SolarOpen2(nn.Module):
 
     def publish_stats(self, stats) -> None:
         """A decode call's summed `stats` collection (host arrays), to the
-        /metrics registry: the layers' counts added up, one update."""
+        /metrics registry: the routing counts, and the KDA head-states its
+        steps updated under the path the program was traced with."""
         cfg = self.cfg
-        layers = [layer['moe'] for layer in stats.values()]
-        pairs = sum(moe['expert_tokens'][0] for moe in layers)
-        moe_lib.publish_routing(
-            cfg.held_experts, pairs,
-            sum(moe['touched'][0] for moe in layers),
-            sum(moe['kernel_trips'][0] for moe in layers))
-        # Every expert layer routed each (slot, step) of the call to
-        # `experts_per_token` experts, and every KDA layer updated all its
-        # heads' states at each; who updated is what the program was
-        # traced with.
-        slot_steps = int(pairs.sum()) // (len(layers) * cfg.experts_per_token)
+        pairs = moe_lib.publish_stats(cfg.held_experts, stats)
+        # Every expert layer (each has an entry in `stats`) routed each
+        # (slot, step) of the call to `experts_per_token` experts, and
+        # every KDA layer updated all its heads' states at each.
+        slot_steps = int(pairs.sum()) // (len(stats) * cfg.experts_per_token)
         publish_state_updates(
+            'skytpu_kda_state_updates_total',
             slot_steps * (cfg.n_layers - len(cfg.gqa_layers)) * cfg.kda_heads,
             kda_step_heads(jax.ShapeDtypeStruct(
                 (1, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_head_dim),
                 jnp.float32), 1, self.mesh) is not None)
-
-
-def publish_state_updates(head_states: int, by_kernel: bool) -> None:
-    """A decode call's KDA head-states updated (slots x KDA layers x heads
-    x steps), to the /metrics registry under the path that updated them."""
-    from skypilot_tpu.server import metrics as metrics_lib
-    for path, took in (('kernel', by_kernel), ('xla', not by_kernel)):
-        metrics_lib.inc_counter('skytpu_kda_state_updates_total',
-                                float(head_states * took), path=path)

@@ -32,6 +32,7 @@ from skypilot_tpu.models.granite_hybrid import (GraniteHybrid,  # noqa: E402
                                                 state_from_leaf,
                                                 state_to_leaf)
 from skypilot_tpu.ops.pallas import ssm_state_update as pallas_ssm  # noqa: E402
+from served_utils import declaring  # noqa: E402
 
 SEED = 2**31 + 43
 DTYPE = jnp.float32
@@ -48,10 +49,9 @@ def published_config():
                               f'{CONFIG_FILE}.json')
 
 
-class TwoRows(GraniteHybrid):
-    """The model with two rows of a prefill at a time, so that a padded
-    group of four goes into the cache in two groups."""
-    prefill_rows = 2
+# The model with two rows of a prefill at a time, so that a padded group of
+# four goes into the cache in two groups.
+TwoRows = declaring(GraniteHybrid, prefill_rows=2)
 
 
 @pytest.fixture(scope='module')
@@ -406,9 +406,10 @@ def test_the_updates_counter_says_who_updated(seeded, monkeypatch):
                     f'skytpu_ssm_state_updates_total{{path="{path}"}}')}
 
     before = updates()
-    model.publish_stats({'rows_stepped': (np.array([4 * 3]),)})   # the CPU
+    model.served().publish_stats(                          # the CPU
+        {'rows_stepped': (np.array([4 * 3]),)})
     monkeypatch.setattr(granite_lib, 'ssm_step_groups', lambda *_: 4)
-    model.publish_stats({'rows_stepped': (np.array([4 * 8]),)})
+    model.served().publish_stats({'rows_stepped': (np.array([4 * 8]),)})
     after = updates()
     assert after['xla'] - before.get('xla', 0.0) == 4 * 3 * 3 * 4
     assert after['kernel'] - before.get('kernel', 0.0) == 4 * 8 * 3 * 4

@@ -11,6 +11,7 @@ import pytest
 
 from skypilot_tpu.inference.engine import DecodeEngine, EngineConfig
 from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama, init_params
+from served_utils import declaring
 
 CFG = LLAMA_CONFIGS['tiny']
 
@@ -548,12 +549,15 @@ def test_engine_counts_kv_positions_held_and_fetched(model_and_params):
 
     # A model whose step does not take the signal: the empty slots' tiles
     # are fetched, and counted so.
-    engine._takes_live = False
-    engine._count_kv_positions(np.array([8, 0]), np.array([True, False]))
-    engine._flush_loop_seconds()
-    untold = _kv_positions()
-    assert untold['fetched'] - last['fetched'] == (3 * 3 + 3 * 1) * 4
-    assert untold['empty'] == last['empty']
+    untold = DecodeEngine(
+        declaring(Llama, decode_takes_live=False, decode_kv_block=4)(CFG),
+        params, config)
+    assert not untold._takes_live and untold._kv_block == 4
+    untold._count_kv_positions(np.array([8, 0]), np.array([True, False]))
+    untold._flush_loop_seconds()
+    counted = _kv_positions()
+    assert counted['fetched'] - last['fetched'] == (3 * 3 + 3 * 1) * 4
+    assert counted['empty'] == last['empty']
 
 
 def test_a_model_without_prefill_rows_keeps_the_prefill_program_it_had(
@@ -565,7 +569,7 @@ def test_a_model_without_prefill_rows_keeps_the_prefill_program_it_had(
     written out below; the parent's own lowered text was compared by hand
     for Llama, SDAR and Solar-Open2: PERF.md section 6, PR 43)."""
     model, params = model_and_params
-    assert not hasattr(model, 'prefill_rows')
+    assert model.served().prefill_rows is None
     engine = DecodeEngine(model, params, EngineConfig(
         n_slots=4, prefill_buckets=(16,), steps_per_call=2))
 
@@ -606,8 +610,7 @@ def test_a_wave_goes_into_the_cache_group_by_group(model_and_params):
     whichever group they fall in."""
     model, params = model_and_params
 
-    class TwoRows(Llama):
-        prefill_rows = 2
+    TwoRows = declaring(Llama, prefill_rows=2)
 
     def served(model):
         engine = DecodeEngine(model, params, EngineConfig(
@@ -647,7 +650,7 @@ def test_a_prefill_program_reads_how_many_rows_it_was_handed(
     from skypilot_tpu.inference import engine as engine_mod
     from skypilot_tpu.server import tracing
     model, params = model_and_params
-    kind = type('Rows', (Llama,), {'prefill_rows': rows_at_once})
+    kind = declaring(Llama, prefill_rows=rows_at_once)
     rng = np.random.default_rng(group)
     prompts = [rng.integers(1, CFG.vocab_size, int(n)).tolist()
                for n in rng.integers(2, 17, group)]
@@ -722,7 +725,7 @@ def test_rows_past_the_group_are_never_computed(model_and_params):
             jnp.asarray(valid), engine._rng)
 
     whole = served(model)
-    looped = served(type('Rows', (Llama,), {'prefill_rows': 4})(CFG))
+    looped = served(declaring(Llama, prefill_rows=4)(CFG))
     for a, b in zip(jax.tree.leaves(whole[0]), jax.tree.leaves(looped[0])):
         a, b = np.asarray(a), np.asarray(b)
         np.testing.assert_allclose(a[slots[:5]], b[slots[:5]], atol=1e-5)

@@ -298,7 +298,7 @@ def test_each_kind_of_layer_keeps_a_cache_of_its_own(tiny, served):
     assert shapes == {'layer_0/attn/k/nope': (4, 2, 64, 16),
                       'layer_0/attn/k/rope': (4, 1, 64, 16),
                       'layer_0/attn/v': (4, 2, 64, 16), **ring}
-    names = engine.model.window_leaves
+    names = engine.model.served().window_leaves
     assert names == ('ring_k', 'ring_v')
     full = 4 * 64 * dims.kv_bytes_per_position(False, 4)
     rings = 4 * WINDOW * dims.kv_bytes_per_position(True, 4)

@@ -450,6 +450,18 @@ def test_backward_pass_runs_again_only_what_was_not_kept():
             'dot_general'] - 3
 
 
+def test_a_remat_policy_that_is_neither_fit_nor_none_is_refused():
+    """'fit' and 'none' are what there is ('dots' went: no run set it, and
+    the compiler refused what it kept); anything else is refused where the
+    config is read, not taken for 'none'."""
+    cfg = dataclasses.replace(KEEP_CFG, remat_policy='dots')
+    with pytest.raises(ValueError, match="'fit' or 'none', got 'dots'"):
+        llama_lib.keep_plan(cfg, None, 2, 32)
+    with pytest.raises(ValueError, match="'fit' or 'none', got 'dots'"):
+        jax.eval_shape(lambda: Llama(cfg).init(
+            jax.random.PRNGKey(0), jnp.zeros((2, 32), jnp.int32)))
+
+
 @pytest.mark.parametrize('mesh_shape', [None, (2, 2)],
                          ids=['one-device', 'fsdp2-tensor2'])
 def test_keep_plan_spends_the_bytes_dearest_first(mesh_shape):

@@ -353,9 +353,9 @@ def test_cache_and_cost_model_carry_a_latent(tiny, served):
     assert shapes == {f'layer_{i}/attn/{name}': (4, 64, width)
                       for i in range(3)
                       for name, width in (('c_kv', 32), ('k_pe', 8))}
-    assert engine.model.latent_leaves == ('c_kv', 'k_pe')
-    assert cost_model_lib.cache_bytes_by_kind(
-            engine._cache, engine.model.latent_leaves) == {
+    latent = engine.model.served().latent_leaves
+    assert latent == ('c_kv', 'k_pe')
+    assert cost_model_lib.cache_bytes_by_kind(engine._cache, latent) == {
         'latent': 4 * 64 * dims.latent_bytes_per_position(4)}
     cm = engine.perf_cost_model
     assert cm.n_kv_layers == 3 and cm.state_bytes_per_slot == 0

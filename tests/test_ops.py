@@ -1000,7 +1000,7 @@ def test_decode_program_keeps_both_kinds_of_cache_as_the_kernel_reads_them(
         held_experts=tuple(range(16)), max_seq_len=9216,
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     model = MiMoV2(cfg)
-    assert model.decode_kv_block() == 512
+    assert model.served().decode_kv_block == 512
     params = jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
                            jnp.zeros((1, 8), jnp.int32))['params'])
@@ -1057,7 +1057,8 @@ def _granite_at_published_widths(n_slots, rows=None, vocab=8192):
         max_seq_len=1024, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     kind = granite_lib.GraniteHybrid
     if rows is not None:
-        kind = type('Rows', (kind,), {'prefill_rows': rows})
+        from served_utils import declaring
+        kind = declaring(kind, prefill_rows=rows)
     model = kind(cfg)
     params = nn.meta.unbox(jax.eval_shape(
         lambda: model.init(jax.random.PRNGKey(0),
@@ -1204,7 +1205,7 @@ def test_the_one_prefill_program_keeps_the_cache_where_decode_pinned_it(
 
     n_slots = 16
     engine, params, cache = _granite_at_published_widths(n_slots)
-    assert engine.model.prefill_rows == 8
+    assert engine.model.served().prefill_rows == 8
     monkeypatch.setattr(jax, 'default_backend', lambda: 'tpu')
     auto = Format(Layout.AUTO, v5e_chip)
 

@@ -306,7 +306,8 @@ def test_mesh_prewarm_compiles_one_program_a_bucket_with_prefill_rows():
     params = init_params(Llama(cfg), jax.random.PRNGKey(0))['params']
     mesh = build_serve_mesh(2, n_heads=cfg.n_heads,
                             n_kv_heads=cfg.n_kv_heads)
-    kind = type('ARow', (Llama,), {'prefill_rows': 1})
+    from served_utils import declaring
+    kind = declaring(Llama, prefill_rows=1)
     engine = engine_mod.DecodeEngine(
         kind(cfg, mesh), params,
         engine_mod.EngineConfig(mesh=mesh, n_slots=4,

@@ -330,7 +330,7 @@ def test_a_prefill_of_blocks_keeps_a_program_a_power_of_two(params):
     scatter of every row's K and V, the first block opened)."""
     family, dims, config = tiny_config('sequential')
     model = family.serve_model(dims, config, DTYPE)
-    assert not hasattr(model, 'prefill_rows')
+    assert model.served().prefill_rows is None
     # (A copy: the layout pass donates the tree it is handed.)
     engine = DecodeEngine(model, jax.tree.map(jnp.copy, params), EngineConfig(
         n_slots=3, prefill_buckets=(8, 16), steps_per_call=3))
@@ -666,7 +666,8 @@ def test_parameter_count_is_the_arithmetic():
     assert dims.kv_bytes_per_position() == 12_288
     model = family.serve_model(dims, config, jnp.bfloat16)
     assert model.cfg.num_params() == dims.num_params()
-    assert (model.block_length, model.block_schedule) == (
+    served = model.served()
+    assert (served.block_length, served.block_schedule) == (
         4, BlockSchedule(151669, 'sequential', 4, 0.9))
     whole = dict(config, num_hidden_layers=48)
     assert family.dims(whole).num_params() == 30_532_122_624

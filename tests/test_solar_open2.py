@@ -612,9 +612,9 @@ def test_the_updates_counter_says_who_updated(tiny, served, monkeypatch):
     kda_layers = cfg.n_layers - len(cfg.gqa_layers)
     assert (kda_layers, cfg.kda_heads) == (3, 4)
     before = updates()
-    model.publish_stats(stats_of(4, 3))                 # the CPU: XLA
+    model.served().publish_stats(stats_of(4, 3))        # the CPU: XLA
     monkeypatch.setattr(solar_lib, 'kda_step_heads', lambda *_: 4)
-    model.publish_stats(stats_of(4, 8))
+    model.served().publish_stats(stats_of(4, 8))
     after = updates()
     assert after['xla'] - before.get('xla', 0.0) == 4 * 3 * 3 * 4
     assert after['kernel'] - before.get('kernel', 0.0) == 4 * 3 * 4 * 8
