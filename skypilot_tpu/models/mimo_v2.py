@@ -369,8 +369,9 @@ class Block(nn.Module):
             ffn = moe_lib.DroplessMoE(
                 dim=cfg.dim, ffn_dim=cfg.expert_dim,
                 n_experts=cfg.n_experts, held=cfg.held_experts,
-                top_k=cfg.experts_per_token, n_shared=0, router_bias=True,
-                dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+                router=moe_lib.LinearRouter(top_k=cfg.experts_per_token,
+                                            bias=True),
+                n_shared=0, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                 block=cfg.expert_block, mesh=self.mesh, name='moe')
         h = norm('ffn_norm', x)
         if self.index < cfg.n_dense_layers or lengths is None or \

@@ -27,16 +27,21 @@ full repartition (replicate-then-shard) — correct but slow; keep the
 dense params expert-axis-replicated instead.
 
 Beside it stands the serving path's layer, `DroplessMoE`: one chip's share
-of an expert-parallel layer.  It is told which experts it holds, routes
-over all of them (sigmoid scores, or a softmax over them where the layer
-says so; the k largest, normalised; where the layer has a correction bias,
-`router_bias`, the k largest of score + bias, weighted by the scores alone:
-`route_top_k`), and returns
-the held experts' part of the sum plus the shared expert; no capacity, no
-drop, and what the absent experts would add is another chip's.  Its sum,
-`grouped_experts`, has one meaning and two ways to be computed, chosen by
-what the code can see (`expert_tile`: the backend, the mesh, the dtype and
-the shapes; nothing a configuration sets):
+of an expert-parallel layer.  It is told which experts it holds and is
+HANDED its routing, a token's expert ids and weights over all the experts:
+by the `router` the model gives it (`LinearRouter`, the router of one
+matrix: sigmoid scores, or a softmax over them; the k largest, normalised;
+with a correction bias the k largest of score + bias, weighted by the
+scores alone: `route_top_k`), or as `routed` arrays where the model routes
+itself because its router reads more than the layer's own tokens
+(models/zaya.py: an MLP router with a state carried from layer to layer,
+one expert a token weighted by its probability, and an output that is no
+expert).  It returns the held experts' part of the sum plus the shared
+expert; no capacity, no drop, and what the absent experts would add is
+another chip's.  Its sum, `grouped_experts`, has one meaning and two ways
+to be computed, chosen by what the code can see (`expert_tile`: the
+backend, the mesh, the dtype and the shapes; nothing a configuration
+sets):
 
 - a decode step's few tokens (no more than one block) on one TPU device
   are bound by the bytes of the experts they reach, so one Pallas call
@@ -53,7 +58,8 @@ Both count what they routed (`expert_tokens`, `touched`), and
 """
 from __future__ import annotations
 
-from typing import Any, Optional
+import dataclasses
+from typing import Any, Callable, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -326,71 +332,107 @@ def _block_loop(x, keys, weights, one_hot, counts, w_gate, w_up, w_down,
                              jnp.zeros(x.shape, jnp.float32))
 
 
-class DroplessMoE(nn.Module):
-    """An expert layer that is told which experts it holds.
-
-    The router scores ALL `n_experts` (float32; `scoring` 'sigmoid', each
-    expert on its own, or 'softmax' over all of them), each token takes
-    its `top_k` largest, weighted by score / sum of the k (`router_bias`:
-    the largest of score + the parameter `correction_bias` [n_experts];
-    the weights stay the scores').  This module
-    holds the experts `held` (ids into the n_experts) and returns their
-    part of the result, `sum over held e of w_e E_e(x)`, plus the shared
-    expert: what one chip of an expert-parallel group computes before the
-    exchange.  What the absent experts would add is left out; nothing
-    here stands in for the other chips.  With `held` = all experts it is
-    the whole layer.  No token is ever dropped (`grouped_experts`).
-
-    Under `mutable=['stats']` it sows, a call: `expert_tokens` [n_held +
-    1] (pairs of each held expert, then pairs routed elsewhere), `touched`
-    (held experts with at least one token) and `kernel_trips` (those of
-    them that the decode kernel multiplied: `touched` where it runs, 0
-    where the block loop does).
-    """
-    dim: int
-    ffn_dim: int
-    n_experts: int
-    held: tuple
+@dataclasses.dataclass(frozen=True)
+class LinearRouter:
+    """The router of one matrix, as a step `DroplessMoE` is handed: scores
+    of ALL the layer's experts (float32; `scoring` 'sigmoid', each expert
+    on its own, or 'softmax' over all of them), each token's `top_k`
+    largest, weighted by score / sum of the k times `scaling` (`bias`: the
+    largest of score + the parameter `correction_bias` [n_experts]; the
+    weights stay the scores').  Its parameters, `router` [dim, n_experts]
+    and `correction_bias`, are the layer's own."""
     top_k: int = 8
-    n_shared: int = 1
-    routed_scaling: float = 1.0
-    dtype: Any = jnp.bfloat16
-    param_dtype: Any = jnp.float32
-    block: int = 256            # pairs a trip of the expert loop
-    mesh: Optional[Mesh] = None
     scoring: str = 'sigmoid'    # or 'softmax' over all the experts
-    router_bias: bool = False   # choose by score + a learned bias
+    bias: bool = False          # choose by score + a learned bias
+    scaling: float = 1.0
 
-    @nn.compact
-    def __call__(self, x: jax.Array,
-                 valid: Optional[jax.Array] = None) -> jax.Array:
-        """x [B, S, D]; `valid` [B, S] bool (a padded prefill): the rows
-        that are real, None for all of them (`grouped_experts`)."""
-        b, s, d = x.shape
-        n_held = len(self.held)
+    def __call__(self, layer: 'DroplessMoE', flat: jax.Array):
+        """flat [T, dim] -> (expert ids [T, k], weights [T, k])."""
         # Router in float32 at full precision: a choice flipped by
         # rounding changes which weights a token meets.
-        router = self.param('router', nn.initializers.lecun_normal(),
-                            (d, self.n_experts), self.param_dtype)
-        flat = x.reshape(b * s, d)
+        router = layer.param('router', nn.initializers.lecun_normal(),
+                             (layer.dim, layer.n_experts), layer.param_dtype)
         score = {'sigmoid': jax.nn.sigmoid,
                  'softmax': lambda z: jax.nn.softmax(z, axis=-1)}[
                      self.scoring]
         scores = score(jnp.dot(
             flat.astype(jnp.float32), router.astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST))
-        bias = self.param('correction_bias', nn.initializers.zeros,
-                          (self.n_experts,), self.param_dtype).astype(
-                              jnp.float32) if self.router_bias else None
-        idx, weights = route_top_k(scores, self.top_k, self.routed_scaling,
-                                   bias)
+        bias = layer.param('correction_bias', nn.initializers.zeros,
+                           (layer.n_experts,), layer.param_dtype).astype(
+                               jnp.float32) if self.bias else None
+        return route_top_k(scores, self.top_k, self.scaling, bias)
+
+
+class DroplessMoE(nn.Module):
+    """An expert layer that is told which experts it holds and is handed
+    its routing.
+
+    Who routes is the model's: `router` is a callable `(this layer, flat
+    tokens [T, dim]) -> (expert ids [T, k], weights [T, k])` over ALL
+    `n_experts` (`LinearRouter`, whose parameters are this layer's), or
+    the model routes before the call and hands the two arrays as `routed`
+    (a router with inputs of its own).  Both ways stay: `LinearRouter`'s
+    matrix and bias are parameters of THIS layer (`<layer>/moe/router`),
+    where the four families' checkpoints and seeded trees have them, so a
+    model that routed before the call would move them in the tree; and a
+    router that reads another layer's state cannot be a step of this one.
+    This module holds the experts
+    `held` (ids into the n_experts) and returns their part of the result,
+    `sum over held e of w_e E_e(x)`, plus the shared expert: what one chip
+    of an expert-parallel group computes before the exchange.  What the
+    absent experts would add is left out; nothing here stands in for the
+    other chips.  With `held` = all experts it is the whole layer.  No
+    token is ever dropped (`grouped_experts`).
+
+    An id from `n_experts` up (`n_skip` of them) is no expert: a router
+    with such outputs sends a token past the layer's experts.  Its pairs
+    are multiplied by nobody and counted as neither held nor elsewhere;
+    what the token gets in the experts' place is the model's to add.
+
+    Under `mutable=['stats']` it sows, a call: `expert_tokens` [n_held +
+    1] (pairs of each held expert, then pairs routed elsewhere), `touched`
+    (held experts with at least one token), `kernel_trips` (those of
+    them that the decode kernel multiplied: `touched` where it runs, 0
+    where the block loop does) and, with `n_skip`, `skipped` (pairs sent
+    past the experts).
+    """
+    dim: int
+    ffn_dim: int
+    n_experts: int
+    held: tuple
+    router: Optional[Callable] = None   # None: `__call__` takes `routed`
+    n_shared: int = 1
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    block: int = 256            # pairs a trip of the expert loop
+    mesh: Optional[Mesh] = None
+    n_skip: int = 0             # router outputs past n_experts: no expert
+
+    @nn.compact
+    def __call__(self, x: jax.Array, valid: Optional[jax.Array] = None,
+                 routed: Optional[Tuple[jax.Array, jax.Array]] = None
+                 ) -> jax.Array:
+        """x [B, S, D]; `valid` [B, S] bool (a padded prefill): the rows
+        that are real, None for all of them (`grouped_experts`); `routed`
+        (ids [B * S, k], weights [B * S, k]) where the model has routed."""
+        b, s, d = x.shape
+        n_held = len(self.held)
+        flat = x.reshape(b * s, d)
+        if (routed is None) == (self.router is None):
+            raise ValueError('DroplessMoE is handed its routing once: by '
+                             'its `router`, or as `routed`')
+        idx, weights = routed if routed is not None else \
+            self.router(self, flat)
 
         def stack(name, shape):
             return self.param(name, nn.initializers.lecun_normal(),
                               (n_held,) + shape,
                               self.param_dtype).astype(self.dtype)
 
-        local_of = np.full((self.n_experts,), n_held, np.int32)
+        # An id held elsewhere, and one that is no expert, go to the row
+        # past the held stacks: multiplied by nobody.
+        local_of = np.full((self.n_experts + self.n_skip,), n_held, np.int32)
         local_of[list(self.held)] = np.arange(n_held)
         xin = flat.astype(self.dtype)
         stacks = (stack('w_gate', (d, self.ffn_dim)),
@@ -400,6 +442,13 @@ class DroplessMoE(nn.Module):
             xin, idx, weights, jnp.asarray(local_of), *stacks,
             min(self.block, -(-(b * s) // 8) * 8), self.mesh,
             None if valid is None else valid.reshape(b * s))
+        if self.n_skip:
+            past = idx >= self.n_experts
+            if valid is not None:
+                past = past & valid.reshape(b * s, 1)
+            skipped = jnp.sum(past.astype(jnp.int32))
+            counts = counts.at[n_held].add(-skipped)
+            self.sow('stats', 'skipped', skipped)
         self.sow('stats', 'expert_tokens', counts)
         self.sow('stats', 'touched', jnp.sum(counts[:n_held] > 0))
         self.sow('stats', 'kernel_trips', kernel_trips)
@@ -414,13 +463,17 @@ class DroplessMoE(nn.Module):
         return out.reshape(b, s, d).astype(x.dtype)
 
 
-def publish_routing(held: tuple, expert_tokens, touched,
-                    kernel_trips) -> None:
+def publish_routing(held: tuple, expert_tokens, touched, kernel_trips,
+                    skipped=0) -> None:
     """One fetch's routing counts, to the /metrics registry (host side;
-    `expert_tokens` [n_held + 1], `touched` and `kernel_trips` as sown,
-    summed over the layers and steps of the fetch)."""
+    `expert_tokens` [n_held + 1], `touched`, `kernel_trips` and, of a
+    router with an output that is no expert, `skipped` as sown, summed
+    over the layers and steps of the fetch)."""
     from skypilot_tpu.server import metrics as metrics_lib
     n_held = len(held)
+    if skipped:
+        metrics_lib.inc_counter('skytpu_moe_skipped_pairs_total',
+                                float(skipped))
     metrics_lib.inc_counter('skytpu_moe_pairs_total',
                             float(expert_tokens[:n_held].sum()), where='held')
     metrics_lib.inc_counter('skytpu_moe_pairs_total',
@@ -440,11 +493,14 @@ def publish_routing(held: tuple, expert_tokens, touched,
 def publish_stats(held: tuple, stats):
     """A decode call's summed `stats` collection (host arrays; every entry
     a layer whose `moe` holds what `DroplessMoE` sows), to the /metrics
-    registry: the expert layers' counts added up, one update.  Returns the
-    summed `expert_tokens`."""
+    registry: the expert layers' counts added up, one update, and the
+    pairs a router with a skip output sent past the experts under a
+    counter of their own.  Returns the summed `expert_tokens`."""
     layers = [layer['moe'] for layer in stats.values()]
     expert_tokens = sum(moe['expert_tokens'][0] for moe in layers)
     publish_routing(held, expert_tokens,
                     sum(moe['touched'][0] for moe in layers),
-                    sum(moe['kernel_trips'][0] for moe in layers))
+                    sum(moe['kernel_trips'][0] for moe in layers),
+                    sum(moe['skipped'][0] for moe in layers
+                        if 'skipped' in moe))
     return expert_tokens

@@ -265,8 +265,9 @@ class Block(nn.Module):
             ffn = moe_lib.DroplessMoE(
                 dim=cfg.dim, ffn_dim=cfg.expert_dim,
                 n_experts=cfg.n_experts, held=cfg.held_experts,
-                top_k=cfg.experts_per_token, n_shared=cfg.n_shared_experts,
-                routed_scaling=cfg.routed_scaling, dtype=cfg.dtype,
+                router=moe_lib.LinearRouter(top_k=cfg.experts_per_token,
+                                            scaling=cfg.routed_scaling),
+                n_shared=cfg.n_shared_experts, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype, mesh=self.mesh, name='moe')
         return x + norm('ffn_post_norm', ffn(norm('ffn_norm', x)))
 

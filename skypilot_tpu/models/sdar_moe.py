@@ -250,8 +250,9 @@ class Block(nn.Module):
             norm('attn_norm', x), positions, decode, live)
         return x + moe_lib.DroplessMoE(
             dim=cfg.dim, ffn_dim=cfg.expert_dim, n_experts=cfg.n_experts,
-            held=cfg.held_experts, top_k=cfg.experts_per_token, n_shared=0,
-            scoring='softmax', dtype=cfg.dtype, param_dtype=cfg.param_dtype,
+            held=cfg.held_experts, router=moe_lib.LinearRouter(
+                top_k=cfg.experts_per_token, scoring='softmax'),
+            n_shared=0, dtype=cfg.dtype, param_dtype=cfg.param_dtype,
             mesh=self.mesh, name='moe')(norm('moe_norm', x))
 
 

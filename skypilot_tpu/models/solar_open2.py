@@ -409,10 +409,9 @@ class Block(nn.Module):
                     name='moe_norm')(x)
         return x + moe_lib.DroplessMoE(
             dim=cfg.dim, ffn_dim=cfg.expert_dim, n_experts=cfg.n_experts,
-            held=cfg.held_experts, top_k=cfg.experts_per_token,
-            n_shared=cfg.n_shared_experts,
-            routed_scaling=cfg.routed_scaling,
-            dtype=cfg.dtype, param_dtype=cfg.param_dtype, mesh=self.mesh,
+            held=cfg.held_experts, router=moe_lib.LinearRouter(
+                top_k=cfg.experts_per_token, scaling=cfg.routed_scaling),
+            n_shared=cfg.n_shared_experts, dtype=cfg.dtype, param_dtype=cfg.param_dtype, mesh=self.mesh,
             name='moe')(h)
 
 

@@ -172,6 +172,12 @@ _HELP = {
         'Token-expert pairs of decode steps by held expert (its id '
         'among all experts), summed over expert layers: the routing\'s '
         'evenness',
+    'skytpu_moe_skipped_pairs_total':
+        'Token-expert pairs of decode steps that the router sent to an '
+        'output that is no expert (the token skips the layer\'s experts '
+        'and reads none of their weights), summed over expert layers; '
+        'in neither series of skytpu_moe_pairs_total; absent where no '
+        'router has such an output',
     'skytpu_engine_batch_occupancy_ratio':
         'Active decode slots / total slots, sampled each loop step',
     'skytpu_engine_active_slots': 'Decode slots occupied this step',

@@ -70,22 +70,34 @@ def test_every_cell_of_an_expert_family_reports_the_expert_metric(metric):
     cells is in each `moe_*` metric's list, so a new expert cell cannot
     leave the expert layer unread.  The experts reached a step are read by
     the one of the two readers that divides by the step's rows: a cell is
-    in that one's list and not in the other's."""
+    in that one's list and not in the other's.  A reader whose manifest
+    says `cells_with` reads what only some families' sizes have (a router
+    with an output that is no expert): its list is the expert cells whose
+    sizes have that attribute, and the per-step reader, which takes its
+    layer-steps from pairs that leave the skipped ones out, lists none of
+    those."""
     families_of = {
         w['name']: families.load(manifest.config_of(MAN, w['config']))
         for w in MAN['workloads']}
     expert_cells = {name for name, family in families_of.items()
                     if hasattr(family, 'touched_experts')}
-    by_blocks = {
-        name for name in expert_cells if getattr(families_of[name].dims(
-            manifest.config_of(MAN, manifest.cell(MAN, name)['config'])),
-            'block', None)}
+    def having(attribute):
+        return {
+            name for name in expert_cells if getattr(families_of[name].dims(
+                manifest.config_of(MAN, manifest.cell(MAN, name)['config'])),
+                attribute, None)}
+
+    by_blocks, skipping = having('block'), having('skip_outputs')
+    only_with = manifest.reducer_spec(metric).get('cells_with')
     assert len(expert_cells) >= 3 and len(MOE_METRICS) >= 5 and by_blocks
     listed = set(next(m for m in MAN['per_layer']
                       if m['name'] == metric)['workloads'])
     if metric == PER_PASS:
         assert listed == by_blocks, (metric, by_blocks)
     elif metric == PER_STEP:
-        assert listed == expert_cells - by_blocks, (metric, expert_cells)
+        assert listed == expert_cells - by_blocks - skipping, (
+            metric, expert_cells)
+    elif only_with:
+        assert listed and listed == having(only_with), (metric, listed)
     else:
         assert expert_cells <= listed, (metric, expert_cells)

@@ -206,8 +206,8 @@ def test_the_sink_takes_weight_and_gives_no_value():
 # ----- the expert layer's shares and the correction bias ---------------------
 def moe_layer(held):
     return moe_lib.DroplessMoE(
-        dim=64, ffn_dim=32, n_experts=16, held=tuple(held), top_k=2,
-        n_shared=0, router_bias=True, dtype=DTYPE, param_dtype=DTYPE,
+        dim=64, ffn_dim=32, n_experts=16, held=tuple(held),
+        router=moe_lib.LinearRouter(top_k=2, bias=True), n_shared=0, dtype=DTYPE, param_dtype=DTYPE,
         block=16)
 
 

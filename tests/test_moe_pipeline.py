@@ -2,13 +2,16 @@
 mesh — the §2.15 greenfield rows the reference only reaches via recipe
 flags."""
 import dataclasses
+from typing import Any
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from skypilot_tpu.models.llama import LLAMA_CONFIGS, Llama, init_params
+from skypilot_tpu.models import moe as moe_lib
 from skypilot_tpu.models.moe import MoEMLP, top_k_dispatch
 from skypilot_tpu.parallel.mesh import MeshPlan, build_mesh, plan_mesh
 from skypilot_tpu.parallel import pipeline as pipeline_lib
@@ -183,3 +186,157 @@ def test_pipeline_rejects_bad_microbatching():
     with pytest.raises(ValueError):
         pipeline_lib.pipeline_apply(_mlp_stage, stacked, x, mesh=mesh,
                                     n_microbatches=4)
+
+# ----- the dropless layer is handed its routing ------------------------------
+class DroplessMoEAsItWas(nn.Module):
+    """`models/moe.py DroplessMoE` before its routing was handed in (the
+    parent of PR 47), kept here word for word but for its docstrings: the
+    router is the layer's own, one matrix, chosen by four fields."""
+    dim: int
+    ffn_dim: int
+    n_experts: int
+    held: tuple
+    top_k: int = 8
+    n_shared: int = 1
+    routed_scaling: float = 1.0
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+    block: int = 256
+    mesh: Any = None
+    scoring: str = 'sigmoid'
+    router_bias: bool = False
+
+    @nn.compact
+    def __call__(self, x, valid=None):
+        b, s, d = x.shape
+        n_held = len(self.held)
+        router = self.param('router', nn.initializers.lecun_normal(),
+                            (d, self.n_experts), self.param_dtype)
+        flat = x.reshape(b * s, d)
+        score = {'sigmoid': jax.nn.sigmoid,
+                 'softmax': lambda z: jax.nn.softmax(z, axis=-1)}[
+                     self.scoring]
+        scores = score(jnp.dot(
+            flat.astype(jnp.float32), router.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        bias = self.param('correction_bias', nn.initializers.zeros,
+                          (self.n_experts,), self.param_dtype).astype(
+                              jnp.float32) if self.router_bias else None
+        idx, weights = moe_lib.route_top_k(scores, self.top_k,
+                                           self.routed_scaling, bias)
+
+        def stack(name, shape):
+            return self.param(name, nn.initializers.lecun_normal(),
+                              (n_held,) + shape,
+                              self.param_dtype).astype(self.dtype)
+
+        local_of = np.full((self.n_experts,), n_held, np.int32)
+        local_of[list(self.held)] = np.arange(n_held)
+        xin = flat.astype(self.dtype)
+        stacks = (stack('w_gate', (d, self.ffn_dim)),
+                  stack('w_up', (d, self.ffn_dim)),
+                  stack('w_down', (self.ffn_dim, d)))
+        out, counts, kernel_trips = moe_lib.grouped_experts(
+            xin, idx, weights, jnp.asarray(local_of), *stacks,
+            min(self.block, -(-(b * s) // 8) * 8), self.mesh,
+            None if valid is None else valid.reshape(b * s))
+        self.sow('stats', 'expert_tokens', counts)
+        self.sow('stats', 'touched', jnp.sum(counts[:n_held] > 0))
+        self.sow('stats', 'kernel_trips', kernel_trips)
+        if self.n_shared:
+            dense = lambda name, feat: nn.Dense(  # noqa: E731
+                feat, use_bias=False, dtype=self.dtype,
+                param_dtype=self.param_dtype, name=name)
+            width = self.n_shared * self.ffn_dim
+            h = nn.silu(dense('shared_gate', width)(xin)) * \
+                dense('shared_up', width)(xin)
+            out = out + dense('shared_down', d)(h).astype(jnp.float32)
+        return out.reshape(b, s, d).astype(x.dtype)
+
+
+# The four families' routers as their models hand them (models/
+# solar_open2.py, openpangu_moe.py, sdar_moe.py, mimo_v2.py), each beside
+# the fields that chose the same router before.
+HANDED = {
+    'solar': (dict(top_k=2), dict(top_k=2), 1, False),
+    'openpangu': (dict(top_k=2, scaling=2.5),
+                  dict(top_k=2, routed_scaling=2.5), 1, False),
+    'sdar': (dict(top_k=2, scoring='softmax'),
+             dict(top_k=2, scoring='softmax'), 0, False),
+    'mimo': (dict(top_k=2, bias=True), dict(top_k=2, router_bias=True), 0,
+             True),
+}
+
+
+@pytest.mark.parametrize('family', list(HANDED))
+@pytest.mark.parametrize('tokens', [8, 40], ids=['a_step', 'a_prompt'])
+def test_a_handed_linear_router_is_the_layer_as_it_was(family, tokens):
+    """`DroplessMoE` under the `LinearRouter` its model hands it gives the
+    outputs and the counts of the layer that routed for itself, bit for
+    bit, from the same parameter tree, and its program lowers to the same
+    text: a decode step's few tokens and a padded prompt's rows (`valid`
+    where the model passes it)."""
+    now, then, n_shared, padded = HANDED[family]
+    common = dict(dim=64, ffn_dim=32, n_experts=16, held=(2, 3, 4, 5),
+                  n_shared=n_shared, dtype=jnp.float32,
+                  param_dtype=jnp.float32, block=16)
+    new = moe_lib.DroplessMoE(router=moe_lib.LinearRouter(**now), **common)
+    old = DroplessMoEAsItWas(**then, **common)
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, tokens // 2, 64))
+    valid = (jnp.arange(tokens // 2)[None, :] <
+             jnp.asarray([tokens // 4, tokens // 2])[:, None]
+             ) if padded and tokens > 8 else None
+    params = old.init(jax.random.PRNGKey(2), x)['params']
+    if 'correction_bias' in params:
+        params = dict(params, correction_bias=0.1 * jax.random.normal(
+            jax.random.PRNGKey(3), (16,)))
+    assert jax.tree.structure(new.init(jax.random.PRNGKey(2), x)[
+        'params']) == jax.tree.structure(params)
+
+    def layer(module):
+        def moe(params, x, valid):
+            return module.apply({'params': params}, x, valid,
+                                mutable=['stats'])
+        return moe
+
+    got, want = (layer(m)(params, x, valid) for m in (new, old))
+    assert all(np.array_equal(np.asarray(a), np.asarray(b)) for a, b in zip(
+        jax.tree.leaves(got), jax.tree.leaves(want)))
+    text = [jax.jit(layer(m)).lower(params, x, valid).as_text()
+            for m in (new, old)]
+    assert text[0] == text[1]
+
+
+def test_top_1_shares_add_up_to_the_whole_layer():
+    """With `held` a proper subset and a router of one expert a token
+    weighted by its probability (handed in as `routed`, as models/zaya.py
+    hands it), the parts that all the shares give add up to the whole
+    layer: four shares of four experts against the layer that holds all
+    sixteen; every pair is held by exactly one share."""
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 20, 64))
+    p = jax.nn.softmax(2.0 * jax.random.normal(jax.random.PRNGKey(2),
+                                               (40, 16)), axis=-1)
+    idx = jnp.argmax(p, axis=-1)[:, None]
+    routed = (idx, jnp.take_along_axis(p, idx, axis=-1))
+
+    def layer(held):
+        return moe_lib.DroplessMoE(
+            dim=64, ffn_dim=32, n_experts=16, held=tuple(held), n_shared=0,
+            dtype=jnp.float32, param_dtype=jnp.float32, block=16)
+
+    params = layer(range(16)).init(jax.random.PRNGKey(3), x,
+                                   routed=routed)['params']
+    whole = layer(range(16)).apply({'params': params}, x, routed=routed)
+    total, held_pairs = 0.0, 0
+    for lo in range(0, 16, 4):
+        share = {k: v[lo:lo + 4] for k, v in params.items()}
+        out, stats = layer(range(lo, lo + 4)).apply(
+            {'params': share}, x, routed=routed, mutable=['stats'])
+        counts = np.asarray(stats['stats']['expert_tokens'][0])
+        assert counts.sum() == 40
+        held_pairs += counts[:4].sum()
+        total = total + out
+    assert held_pairs == 40
+    np.testing.assert_allclose(np.asarray(total), np.asarray(whole),
+                               atol=1e-5)
+    assert np.abs(np.asarray(whole)).max() > 1e-2

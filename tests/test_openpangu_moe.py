@@ -249,8 +249,9 @@ def test_the_kernel_is_engaged_by_backend_mesh_and_shapes(monkeypatch):
 # ----- the expert layer's shares ---------------------------------------------
 def moe_layer(held, n_shared=1):
     return moe_lib.DroplessMoE(
-        dim=64, ffn_dim=32, n_experts=16, held=tuple(held), top_k=2,
-        n_shared=n_shared, routed_scaling=2.5, dtype=DTYPE,
+        dim=64, ffn_dim=32, n_experts=16, held=tuple(held),
+        router=moe_lib.LinearRouter(top_k=2, scaling=2.5),
+        n_shared=n_shared, dtype=DTYPE,
         param_dtype=DTYPE, block=16)
 
 

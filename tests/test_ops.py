@@ -343,9 +343,11 @@ def test_pallas_flash_compiles_for_v5e(v5e_chip, shape, direction):
 
 
 # The decode step's attention at the serving cells' shapes [B, Hq, Hkv, S]:
-# Yi-Coder (MHA), Yi-6B (8 query heads a KV head), and Solar-Open2's softmax
-# layer, whose 1,664 positions take the smallest block.
-_DECODE_SHAPES = [(16, 16, 16, 1024), (8, 32, 4, 1024), (32, 64, 8, 1664)]
+# Yi-Coder (MHA), Yi-6B (8 query heads a KV head), Solar-Open2's softmax
+# layer, whose 1,664 positions take the smallest block, and ZAYA1's
+# compressed latent: 2 KV heads under 8 query heads over 13,312 positions.
+_DECODE_SHAPES = [(16, 16, 16, 1024), (8, 32, 4, 1024), (32, 64, 8, 1664),
+                  (16, 8, 2, 13312)]
 
 
 @pytest.mark.parametrize('shape', _DECODE_SHAPES,
@@ -448,7 +450,7 @@ def test_decode_program_keeps_the_expert_stacks_as_the_kernel_reads_them(
             w_gate.shape[1], w_gate.shape[2], w_gate.dtype.itemsize))
     layer = moe_lib.DroplessMoE(
         dim=4096, ffn_dim=1280, n_experts=320, held=tuple(range(40)),
-        top_k=8, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+        router=moe_lib.LinearRouter(top_k=8), dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     x = jax.ShapeDtypeStruct((32, 1, 4096), jnp.bfloat16, sharding=v5e_chip)
     params = jax.eval_shape(
         lambda: layer.init(jax.random.PRNGKey(0),

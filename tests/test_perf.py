@@ -269,6 +269,44 @@ def test_cost_model_reads_a_state_space_models_state_and_kv_from_its_cache():
         2.0 * 3191396096 + 2.0 * 2048 * 4 * 400
 
 
+def test_cost_model_reads_a_convolved_latents_kv_and_fixed_leaves():
+    """ZAYA1-8B's stage as the benchmark serves it, 16 slots of 13,312
+    positions, the cache as shapes: with no case of its own the cost model
+    reads 20 layers' K and V of the 2 latent heads as 20,480 B a position
+    (a multi-head cache at this width would be 163,840) and the three
+    leaves of fixed size, two rows of taps and a half value a layer, as
+    107,520 B of per-slot state read and written a step.  At 16 slots and
+    10,500 positions a token then costs 586 MB of weights (every expert
+    counted: the cost model does not know the routing) and 215 MB of K
+    and V; the fixed leaves are 0.2 MB of it."""
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+    from benchmarks import families
+    config = json.loads((REPO_ROOT / 'benchmarks' / 'configs' /
+                         'zaya1-8b-pp2.json').read_text())
+    family = families.load(config)
+    model = family.serve_model(family.dims(config), config, jnp.bfloat16)
+    params = nn.meta.unbox(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))['params']))
+    slots = config['serve']['n_slots']
+    step = jnp.zeros((slots, 1), jnp.int32)
+    cache = jax.eval_shape(lambda p: model.apply(
+        {'params': p}, step, positions=step, decode=True,
+        mutable=['cache'])[1]['cache'], params)
+    assert cost_model_lib.cache_bytes_by_kind(cache) == {
+        'kv': 16 * 13312 * 20480, 'recurrent': 16 * 107520}
+    cm = cost_model_lib.EngineCostModel.from_engine_state(
+        model.cfg, jax.tree.leaves(params), cache, chip='v5e')
+    assert cm.param_bytes == 2 * 4688810364 == 2 * cm.n_params
+    assert (cm.n_layers, cm.n_kv_layers, cm.n_window_layers) == (20, 20, 0)
+    assert cm.kv_bytes_per_pos() == 20480
+    assert cm.state_bytes_per_slot == 20 * (1280 + 1280 + 128) * 2 == 107520
+    a_token = cm.decode_hbm_bytes_per_token(10500, slots)
+    assert a_token == 2 * 4688810364 / 16 + 10501 * 20480 + 2 * 107520
+    assert 0.26 < 10501 * 20480 / a_token < 0.27
+
+
 @pytest.mark.parametrize('path', BENCH_CONFIGS, ids=lambda p: p.stem)
 def test_program_counts_the_parameters_the_yardstick_counts(path):
     """The model object a configuration's family hands the program

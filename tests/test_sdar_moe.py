@@ -537,27 +537,30 @@ def test_paging_speculation_and_transfer_are_refused(engines, params):
 
 # ----- (e) the softmax router with every expert held -------------------------
 def test_softmax_router_with_every_expert_held_is_the_dense_sum(params):
-    """`DroplessMoE` with `scoring='softmax'`, `held` = all and no shared
-    expert against the reference's loop over every expert under its mask;
-    and `scoring` left out is the sigmoid layer as it was."""
+    """`DroplessMoE` under a `LinearRouter` with `scoring='softmax'`,
+    `held` = all and no shared expert against the reference's loop over
+    every expert under its mask; and `scoring` left out is the sigmoid
+    router."""
     _, dims, _ = tiny_config('sequential')
     w = params['layer_0']['moe']
     x = jax.random.normal(jax.random.PRNGKey(3), (2, 5, dims.hidden))
     layer = moe_lib.DroplessMoE(
         dim=dims.hidden, ffn_dim=dims.expert_ffn, n_experts=dims.experts,
-        held=dims.held_ids, top_k=dims.top_k, n_shared=0, scoring='softmax',
-        dtype=DTYPE, param_dtype=DTYPE)
+        held=dims.held_ids, router=moe_lib.LinearRouter(
+            top_k=dims.top_k, scoring='softmax'), n_shared=0, dtype=DTYPE,
+        param_dtype=DTYPE)
     got = layer.apply({'params': w}, x)
     with jax.default_matmul_precision('highest'):
         want = sdar_moe_ref.expert_layer(
             w, x, top_k=dims.top_k, matmul=sdar_moe_ref.plain_matmul)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=1e-5, rtol=0)
-    sigmoid = layer.clone(scoring='sigmoid').apply({'params': w}, x)
+    sigmoid = layer.clone(router=moe_lib.LinearRouter(
+        top_k=dims.top_k, scoring='sigmoid')).apply({'params': w}, x)
     default = moe_lib.DroplessMoE(
         dim=dims.hidden, ffn_dim=dims.expert_ffn, n_experts=dims.experts,
-        held=dims.held_ids, top_k=dims.top_k, n_shared=0, dtype=DTYPE,
-        param_dtype=DTYPE).apply({'params': w}, x)
+        held=dims.held_ids, router=moe_lib.LinearRouter(top_k=dims.top_k),
+        n_shared=0, dtype=DTYPE, param_dtype=DTYPE).apply({'params': w}, x)
     assert np.array_equal(np.asarray(sigmoid), np.asarray(default))
     assert np.abs(np.asarray(sigmoid) - np.asarray(got)).max() > 1e-3
 

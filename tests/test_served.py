@@ -63,6 +63,12 @@ DECLARED = {
     'GraniteHybrid': ('granite-4.0-h-micro', Served(
         unpaged_cache='keeps recurrent state beside its keys and values',
         prefill_rows=8, decode_kv_block=512), True, (1, 32, 64)),
+    'Zaya': ('zaya1-8b-pp2', Served(
+        unpaged_cache=("keeps the last position's convolution taps and "
+                       'half value, of fixed size a slot, beside its keys '
+                       'and values'),
+        prefill_rows=1, decode_takes_live=True, decode_kv_block=512), True,
+        (2, 16, 64)),
 }
 
 

@@ -222,7 +222,8 @@ def test_chunked_prefill_carries_the_state_from_zero(tiny, served):
 
 def moe_layer(held, n_shared=1, block=16):
     return moe_lib.DroplessMoE(
-        dim=64, ffn_dim=32, n_experts=16, held=tuple(held), top_k=2,
+        dim=64, ffn_dim=32, n_experts=16, held=tuple(held),
+        router=moe_lib.LinearRouter(top_k=2),
         n_shared=n_shared, dtype=DTYPE, param_dtype=DTYPE, block=block)
 
 
@@ -301,7 +302,8 @@ def test_a_decode_steps_few_tokens_go_through_the_same_loop(moe_weights):
     ids = (2, 3, 4, 5, 9)
     held = share_of(params, ids, True)
     out, stats = moe_lib.DroplessMoE(
-        dim=64, ffn_dim=32, n_experts=16, held=ids, top_k=2, dtype=DTYPE,
+        dim=64, ffn_dim=32, n_experts=16, held=ids,
+        router=moe_lib.LinearRouter(top_k=2), dtype=DTYPE,
         param_dtype=DTYPE).apply({'params': held}, few, mutable=['stats'])
     with jax.default_matmul_precision('highest'):
         want = solar_open2_ref.expert_layer(
@@ -354,7 +356,8 @@ def test_the_decode_kernel_sums_what_the_reference_sums(kernel_forced, case):
     each held expert, and every reached expert was the kernel's."""
     n_tokens, ids, picks, dtype, pairs, atol = KERNEL_CASES[case]
     layer = moe_lib.DroplessMoE(
-        dim=128, ffn_dim=256, n_experts=16, held=ids, top_k=2, dtype=dtype,
+        dim=128, ffn_dim=256, n_experts=16, held=ids,
+        router=moe_lib.LinearRouter(top_k=2), dtype=dtype,
         param_dtype=dtype, block=16 if case == 'tokens_fill_the_block'
         else 256)
     x = jnp.abs(jax.random.normal(jax.random.PRNGKey(3), (1, n_tokens, 128),
@@ -409,7 +412,8 @@ def test_the_rule_sends_everything_else_to_the_block_loop(monkeypatch, why):
         else (16, 16)
     assert moe_lib.expert_tile(n_tokens, block, w_gate, mesh) is None
     layer = moe_lib.DroplessMoE(
-        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5), top_k=2,
+        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5),
+        router=moe_lib.LinearRouter(top_k=2),
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, block=block, mesh=mesh)
     x = jax.random.normal(jax.random.PRNGKey(5), (1, n_tokens, 128),
                           jnp.bfloat16)
@@ -425,7 +429,8 @@ def test_the_stacks_reach_the_kernel_as_they_are_stored(kernel_forced):
     transpose, convert, slice or gather of a stack stands between), so the
     program keeps one copy of them in the layout they are stored in."""
     layer = moe_lib.DroplessMoE(
-        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5), top_k=2,
+        dim=128, ffn_dim=256, n_experts=16, held=(2, 3, 4, 5),
+        router=moe_lib.LinearRouter(top_k=2),
         dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
     x = jnp.zeros((32, 1, 128), jnp.bfloat16)
     params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
